@@ -29,11 +29,11 @@ import sys
 
 sys.path.insert(0, ".")
 
-from pytorch_distributed_nn_tpu.runtime.platform import (  # noqa: E402
-    apply_platform_overrides,
+from pytorch_distributed_nn_tpu.runtime.device import (  # noqa: E402
+    configure_compile_cache,
 )
 
-apply_platform_overrides()
+configure_compile_cache()
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

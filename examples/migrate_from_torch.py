@@ -11,11 +11,11 @@ import sys
 
 sys.path.insert(0, ".")
 
-from pytorch_distributed_nn_tpu.runtime.platform import (
-    apply_platform_overrides,
+from pytorch_distributed_nn_tpu.runtime.device import (
+    configure_compile_cache,
 )
 
-apply_platform_overrides()
+configure_compile_cache()
 
 import numpy as np
 import torch

@@ -26,65 +26,4 @@ parity targets come from /root/repo/BASELINE.json.
 
 from pytorch_distributed_nn_tpu.version import __version__
 
-
-def _install_jax_compat() -> None:
-    """Back-fill the small slice of newer-jax API this codebase uses
-    (``jax.shard_map`` with ``check_vma``/``axis_names``,
-    ``jax.lax.axis_size``, ``jax.lax.pcast``) on older jax installs,
-    where they live at ``jax.experimental.shard_map.shard_map``
-    (``check_rep``/``auto`` spelling) and ``jax.core.axis_frame``.
-    Attribute-level shim only — no behavior changes on jax versions
-    that already have the API."""
-    import functools
-
-    import jax
-    from jax import lax
-
-    if not hasattr(lax, "axis_size"):
-        import jax.core as _core
-
-        def _axis_size(axis_name):
-            # 0.4.x: axis_frame(name) resolves to the trace-time size
-            return _core.axis_frame(axis_name)
-
-        lax.axis_size = _axis_size
-
-    if not hasattr(lax, "pcast"):
-        # newer jax: pcast only re-tags the varying-manual-axes type
-        # (no data movement). Old jax tracks replication only under
-        # check_rep, which the shim below disables wherever auto axes
-        # are in play — identity is the faithful translation.
-        def _pcast(x, axis_name=None, *, to=None):
-            return x
-
-        lax.pcast = _pcast
-
-    if hasattr(jax, "shard_map"):
-        return
-    try:
-        from jax.experimental.shard_map import shard_map as _sm
-    except ImportError:  # very old jax: nothing to shim with
-        return
-
-    @functools.wraps(_sm)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if "axis_names" in kwargs:
-            # newer API: axis_names = the MANUAL axes; older API takes
-            # the complement as `auto`, and only supports it with
-            # replication checking off
-            manual = frozenset(kwargs.pop("axis_names"))
-            mesh = kwargs.get("mesh", args[1] if len(args) > 1 else None)
-            auto = frozenset(mesh.axis_names) - manual
-            if auto:
-                kwargs["auto"] = auto
-                kwargs["check_rep"] = False
-        return _sm(*args, **kwargs)
-
-    jax.shard_map = shard_map
-
-
-_install_jax_compat()
-
 __all__ = ["__version__"]

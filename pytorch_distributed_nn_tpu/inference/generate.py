@@ -182,8 +182,7 @@ def _decode_loop(model, params, cache, next_logits, rng, n_steps,
     """The whole autoregressive loop as ONE device program: ``lax.scan``
     over decode steps (sample → feed → next logits). One dispatch for
     all ``n_steps`` tokens — per-token host round-trips would otherwise
-    dominate wall-clock when the chip sits behind a network tunnel (and
-    still cost ~dispatch-latency × n_steps locally). ``temperature`` is
+    cost ~dispatch-latency × n_steps. ``temperature`` is
     a traced operand (per-request values don't recompile); only
     n_steps/top_k/eos_token key the compile cache. Returns (n_steps, B)
     sampled tokens."""

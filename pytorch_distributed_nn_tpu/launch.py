@@ -35,7 +35,11 @@ CLI::
 
 On a real multi-host pod each host runs one agent with
 ``--node-rank``/``--nnodes`` so rank offsets and the coordinator
-address line up; workers then hold the hosts' chips via PJRT.
+address line up, and ``--nprocs 1``: a chip belongs to one process and
+one process drives all of a host's chips via PJRT, so several workers
+on one host are for CPU gangs. The agent itself imports jax (through
+obs/ and runtime/) but never initialises a backend — its worker needs
+the chip.
 """
 
 from __future__ import annotations

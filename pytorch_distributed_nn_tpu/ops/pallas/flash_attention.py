@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from pytorch_distributed_nn_tpu.ops.pallas import warn_reference_fallback
+
 NEG_INF = -1e30
 
 
@@ -547,6 +549,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024,
     bq = _pick_block(T, min(block_q, T))
     bk = _pick_block(T, min(block_k, T))
     if bq is None or bk is None:
+        warn_reference_fallback("flash_attention", tuple(q.shape))
         return from_bh(_attention_reference(qb, expand(kb), expand(vb),
                                             causal=causal))
     return from_bh(

@@ -132,7 +132,12 @@ def int8_matmul(x, q, s, *, out_dtype=jnp.bfloat16):
         x = jnp.pad(x, ((0, 0), (0, kp - x.shape[1])))
     if jax.default_backend() == "tpu":
         return _int8_matmul_tpu(x, q, s, out_dtype=out_dtype)
-    # fallback: same W8A16 numerics (bf16 operands, f32 accumulate)
+    return _int8_matmul_reference(x, q, s, out_dtype=out_dtype)
+
+
+def _int8_matmul_reference(x, q, s, *, out_dtype=jnp.bfloat16):
+    """jnp oracle and off-TPU path: the kernel's W8A16 numerics (bf16
+    operands, f32 accumulate, scale on the accumulator)."""
     w = q.astype(jnp.bfloat16)
     acc = jax.lax.dot_general(
         x.astype(jnp.bfloat16), w, (((1,), (0,)), ((), ())),

@@ -75,7 +75,12 @@ def quantize_int8(x, scale, *, seed):
     seed: int or traced int32 scalar."""
     if jax.default_backend() == "tpu":
         return _quantize_tpu(x.ravel(), scale, seed).reshape(x.shape)
-    # jnp fallback: stochastic rounding via uniform noise
+    return _quantize_reference(x, scale, seed)
+
+
+def _quantize_reference(x, scale, seed):
+    """jnp oracle and off-TPU path: stochastic rounding via uniform
+    noise (another random stream than the kernel's, the same law)."""
     key = jax.random.fold_in(jax.random.key(17), seed)
     scaled = x / scale
     noise = jax.random.uniform(key, scaled.shape)

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from pytorch_distributed_nn_tpu.ops import collectives as cc
+from pytorch_distributed_nn_tpu.ops.pallas import warn_reference_fallback
 from pytorch_distributed_nn_tpu.runtime.mesh import AXIS_SEQ
 
 _NEG_INF = -1e30
@@ -244,6 +245,9 @@ def _ring_fused_bwd(axis, causal, interpret, res, g):
     if bq is None or bk is None or not (on_tpu or interpret):
         # no viable block tiling (tiny shards) or CPU without interpret:
         # recompute through the differentiable jnp schedule
+        if on_tpu:
+            warn_reference_fallback("ring_attention backward",
+                                    tuple(q.shape))
         _, vjp = jax.vjp(
             lambda a, b, c: _ring_attention_xla(a, b, c, axis=axis,
                                                 causal=causal),

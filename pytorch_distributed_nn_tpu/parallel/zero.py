@@ -207,6 +207,9 @@ def make_zero_train_step(mesh: Mesh, loss_fn: Callable, *, stage: int = 3,
             raise RuntimeError("call place_state before stepping")
         return compiled["step"](state, x, y)
 
+    # the jit's AOT entry, so callers can read the compiled program
+    # (kernel and collective counts) of whichever strategy they hold
+    step_dispatch.lower = lambda *args: compiled["step"].lower(*args)
     return step_dispatch, place_state
 
 

@@ -1,0 +1,93 @@
+"""What an entry point settles before its first backend use.
+
+Platform and virtual-device count come from the environment and need no
+code: ``JAX_PLATFORMS=cpu`` with ``JAX_NUM_CPU_DEVICES=8`` for the CPU
+test route, nothing for the chip. Three things do need code:
+
+- :func:`configure_compile_cache` — where JAX's persistent compilation
+  cache lives. Every entry point calls it first;
+- :func:`require_tpu` — the one device check of the measuring entry
+  points (``bench.py``'s modes, ``chip_smoke.py``): no chip is an
+  error, never a CPU number;
+- :func:`claim_chip` — a chip belongs to one process at a time, so a
+  second process that wants it on the same host fails with a message
+  instead of waiting inside backend initialisation.
+"""
+
+from __future__ import annotations
+
+import fcntl
+import os
+import tempfile
+from pathlib import Path
+
+import jax
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = Path(__file__).resolve().parents[2]
+
+
+def configure_compile_cache() -> str | None:
+    """Place the persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins: JAX reads it itself and this
+    sets nothing. Otherwise the cache is ``<checkout>/.jax_cache`` — a
+    fixed path, because the path is part of the cache key and a
+    directory that moves never hits."""
+    if not os.environ.get(CACHE_ENV):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(_CHECKOUT / ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
+def _cpu_asked_for() -> bool:
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
+def require_tpu(*, explicit_cpu_ok: bool = False) -> jax.Device:
+    """``jax.devices()[0]``, which must be a TPU. With
+    ``explicit_cpu_ok`` a CPU device passes when ``JAX_PLATFORMS=cpu``
+    asked for it by name (the tests' route through ``bench.py``); a CPU
+    that JAX fell back to is refused either way."""
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        return dev
+    if explicit_cpu_ok and dev.platform == "cpu" and _cpu_asked_for():
+        return dev
+    raise RuntimeError(
+        f"no TPU: jax.devices()[0] is {dev.platform}:{dev.device_kind} "
+        f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}). This "
+        "entry point measures the chip and does not fall back to the "
+        "CPU.")
+
+
+def claim_chip(lock_dir: str | None = None) -> int | None:
+    """Take this host's chip for the life of the process, or exit
+    saying who holds it. Returns the open lock descriptor (None when
+    ``JAX_PLATFORMS=cpu``: nothing to claim).
+
+    One process drives every chip of a host unless ``TPU_VISIBLE_CHIPS``
+    hands it a subset, so the lock is per host and per that subset.
+    Held through an open ``flock``: the kernel releases it when the
+    process ends, however it ends."""
+    if _cpu_asked_for():
+        return None
+    chips = os.environ.get("TPU_VISIBLE_CHIPS", "all").replace(",", "_")
+    path = os.path.join(lock_dir or tempfile.gettempdir(),
+                        f"tpunn-chip-{chips}.lock")
+    fd = os.open(path, os.O_RDWR | os.O_CREAT, 0o666)
+    try:
+        fcntl.flock(fd, fcntl.LOCK_EX | fcntl.LOCK_NB)
+    except BlockingIOError:
+        holder = os.read(fd, 32).decode(errors="replace").strip()
+        os.close(fd)
+        raise SystemExit(
+            f"the TPU of this host (chips: {chips}) is held by pid "
+            f"{holder or '?'}: a chip belongs to one process at a time, "
+            "and a second process would wait for it inside backend "
+            "initialisation. Run one chip-backed worker per host (one "
+            "process drives all its chips), give each worker its own "
+            "TPU_VISIBLE_CHIPS, or set JAX_PLATFORMS=cpu.") from None
+    os.ftruncate(fd, 0)
+    os.write(fd, str(os.getpid()).encode())
+    return fd

@@ -281,7 +281,11 @@ def maybe_start_heartbeat(rank: int | None = None) -> HeartbeatReporter | None:
     """Start heartbeating iff launched under the elastic agent.
 
     Reads the agent's env contract; a plain (non-agent) launch has no
-    ``TPUNN_STORE_PORT`` and this is a no-op. Idempotent.
+    ``TPUNN_STORE_PORT`` and this is a no-op. Idempotent. Under the
+    agent a native library that cannot be built raises
+    (:class:`native.NativeUnavailable`): the agent is listening for
+    beats, and a worker that carried on without them would be killed
+    as hung.
     """
     global _reporter
     if _reporter is not None:
@@ -306,7 +310,7 @@ def maybe_start_heartbeat(rank: int | None = None) -> HeartbeatReporter | None:
             interval_s=float(os.environ.get(ENV_HB_INTERVAL, "1.0")),
             progress_window_s=float(window) if window else None,
         )
-    except (native.NativeUnavailable, ConnectionError, OSError) as e:
+    except (ConnectionError, OSError) as e:
         log.warning("heartbeat disabled: %s", e)
         return None
     # flight-recorder dump triggers ride the agent contract: fatal

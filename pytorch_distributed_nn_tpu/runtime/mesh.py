@@ -162,7 +162,8 @@ def make_mesh(
     (devices spanning DCN): ``create_hybrid_device_mesh`` with the DCN
     degrees peeled onto the outermost axes (:func:`dcn_factors`), so
     cross-slice traffic is only pipe edges / DP gradient allreduce.
-    Falls back to row-major reshape (fine for CPU test meshes).
+    Where the assigner refuses a shape, CPU test meshes fall back to a
+    row-major reshape; on TPU devices the refusal raises.
 
     ``force_slices``: treat the device list as that many DCN-connected
     slices (row-major groups) even when the backend reports one — the
@@ -197,9 +198,9 @@ def make_mesh(
         n = len(AXES)
         order = [ax for i in range(n) for ax in (i, n + i)]
         return Mesh(arr.transpose(order).reshape(spec.shape), AXES)
-    try:
-        from jax.experimental import mesh_utils
+    from jax.experimental import mesh_utils
 
+    try:
         if n_slices > 1:
             dev_array = mesh_utils.create_hybrid_device_mesh(
                 ici_shape, tuple(dcn[a] for a in AXES),
@@ -209,12 +210,14 @@ def make_mesh(
             dev_array = mesh_utils.create_device_mesh(
                 spec.shape, devices=list(devices)
             )
-    except ImportError:
-        dev_array = np.asarray(devices, dtype=object).reshape(spec.shape)
     except Exception as e:  # topology assigner rejected the shape
+        if devices[0].platform == "tpu":
+            # on a chip a refused assignment means inner axes off the
+            # ICI rings: an error, not a slower mesh
+            raise
         logging.getLogger(__name__).warning(
             "mesh_utils device assignment failed (%s); falling back to "
-            "row-major placement — inner axes may not be ICI-adjacent", e
+            "row-major placement (CPU mesh: adjacency means nothing)", e
         )
         dev_array = np.asarray(devices, dtype=object).reshape(spec.shape)
     return Mesh(dev_array, AXES)

@@ -10,8 +10,8 @@ reference borrowed from torch (SURVEY.md §2b):
 - :func:`gen_images` / :func:`gen_lm` / :func:`gen_templates` — the
   threaded native data generator behind the ``native`` dataset backend.
 
-The library is built on demand with ``make`` (g++ is in the image;
-pybind11 is not, hence the C ABI).
+The library is built with ``make`` on first load in each process (g++
+is in the image; pybind11 is not, hence the C ABI).
 """
 
 from __future__ import annotations
@@ -35,24 +35,24 @@ class NativeUnavailable(RuntimeError):
     pass
 
 
-def load_library(build: bool = True) -> ctypes.CDLL:
-    """Load (building if needed) the native library; cached."""
+def load_library() -> ctypes.CDLL:
+    """Build (``make``: nothing to do when current) and load the native
+    library; cached. Always asking ``make`` means a library left in the
+    tree by an earlier build is never loaded stale: what runs was built
+    from ``store.cpp`` / ``datagen.cpp`` as they stand."""
     global _lib
     with _lock:
         if _lib is not None:
             return _lib
-        if not _LIB_PATH.exists():
-            if not build:
-                raise NativeUnavailable(f"{_LIB_PATH} not built")
-            try:
-                subprocess.run(["make", "-C", str(_NATIVE_DIR)],
-                               check=True, capture_output=True)
-            except (subprocess.CalledProcessError, OSError) as e:
-                out = getattr(e, "stderr", b"")
-                raise NativeUnavailable(
-                    f"native build failed: {e}: "
-                    f"{out.decode() if isinstance(out, bytes) else out}"
-                ) from e
+        try:
+            subprocess.run(["make", "-C", str(_NATIVE_DIR)],
+                           check=True, capture_output=True)
+        except (subprocess.CalledProcessError, OSError) as e:
+            out = getattr(e, "stderr", b"")
+            raise NativeUnavailable(
+                f"native build failed: {e}: "
+                f"{out.decode() if isinstance(out, bytes) else out}"
+            ) from e
         lib = ctypes.CDLL(str(_LIB_PATH))
         _declare(lib)
         _lib = lib

@@ -14,7 +14,14 @@ per-row math — prefill via :func:`inference.generate.prefill_ragged`
 (batch of one) and per-round steps via the same per-row decode apply,
 where every row's attention is masked to exactly its own filled cache
 prefix; masked slots contribute exact 0.0 after softmax, so sharing a
-batch with strangers cannot perturb a row's floats.
+batch with strangers cannot perturb a row's floats. That holds on the
+CPU backend, where the golden test runs. On the TPU in bf16 it does
+not (chip_smoke.py, PR 23: at Llama-3-8B widths 9 of 12 requests left
+the sequential path): a row goes through programs of other shapes here
+(8 slots over a 256-row cache) than there (one row, a cache of its own
+length), and the compiler orders the same sums differently. Every
+divergence seen was a choice between logits less than half a bf16 step
+apart; chip_smoke.py gates on that margin.
 
 Hot-loop discipline (lint-enforced): :meth:`ServingEngine._decode_round`
 contains the per-round device work and performs NO host->device
@@ -1122,20 +1129,19 @@ class ServingEngine:
         """Analytic forward FLOPs of ONE token through this model
         (:func:`utils.flops.fwd_flops` at batch 1, seq 1) — the unit
         every Abacus billing multiplies. Integer (exact per-tenant
-        sums), computed once per engine, 0 when no backend with a cost
-        model is reachable (billing then meters tokens/residency/wire
-        only). Only metered paths call this, so an unarmed process
-        never pays the lowering."""
+        sums), computed once per engine. On a CPU backend a failed
+        count bills 0 FLOPs (tokens/residency/wire still meter); on the
+        chip it raises. Only metered paths call this, so an unarmed
+        process never pays the lowering."""
         if self._flops_per_token is None:
-            from pytorch_distributed_nn_tpu.utils.flops import (
-                CostModelUnavailable,
-                fwd_flops,
-            )
+            from pytorch_distributed_nn_tpu.utils.flops import fwd_flops
 
             try:
                 self._flops_per_token = int(round(
                     fwd_flops(self.model, (1, 1), jnp.int32)))
-            except (CostModelUnavailable, RuntimeError):
+            except RuntimeError:
+                if jax.default_backend() == "tpu":
+                    raise
                 self._flops_per_token = 0
         return self._flops_per_token
 

@@ -76,8 +76,9 @@ import numpy as np
 
 from pytorch_distributed_nn_tpu.obs import audit, meter, trace
 from pytorch_distributed_nn_tpu.runtime import chaos, failure
-from pytorch_distributed_nn_tpu.runtime.platform import (
-    apply_platform_overrides,
+from pytorch_distributed_nn_tpu.runtime.device import (
+    claim_chip,
+    configure_compile_cache,
 )
 from pytorch_distributed_nn_tpu.serve import kv_wire
 from pytorch_distributed_nn_tpu.serve.store import (
@@ -87,9 +88,7 @@ from pytorch_distributed_nn_tpu.serve.store import (
 )
 from pytorch_distributed_nn_tpu.serve.stub import stub_next_token
 
-# entrypoint contract: honor JAX_PLATFORMS before first backend use —
-# a fleet of tiny-backend workers must not pile onto the one real chip
-apply_platform_overrides()
+configure_compile_cache()
 
 log = logging.getLogger(__name__)
 
@@ -518,6 +517,10 @@ def main(argv=None) -> int:
     logging.basicConfig(
         level=logging.INFO,
         format=f"[fleet-worker r{args.replica_index}] %(message)s")
+    # tiny/preset run on a jax backend: off the CPU that is the chip,
+    # which one process holds — claim it (the open descriptor is the
+    # lock) or exit saying who has it, before anything else starts
+    chip_lock = claim_chip() if args.backend != "stub" else None  # noqa: F841
     chaos.maybe_init()
     failure.install_preemption_handler(force=True)
     client = make_store(args.store)

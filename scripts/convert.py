@@ -30,11 +30,11 @@ import sys
 
 sys.path.insert(0, ".")  # run from repo root without install
 
-from pytorch_distributed_nn_tpu.runtime.platform import (
-    apply_platform_overrides,
+from pytorch_distributed_nn_tpu.runtime.device import (
+    configure_compile_cache,
 )
 
-apply_platform_overrides()
+configure_compile_cache()
 
 
 def _load_state_dict(path: str):

@@ -29,6 +29,14 @@ The multi-host deployment loop (docs/serving.md has the full runbook):
     # anywhere: what does the store say the fleet looks like?
     python scripts/fleet_deploy.py status --store hostA:7777
 
+One chip-backed replica per host: ``--backend tiny`` and ``preset``
+run on a jax backend, and a chip belongs to one process — a second such
+worker on the same host exits saying which pid holds the chip
+(runtime/device.claim_chip). Put further replicas on other hosts
+(``--spawn-template``), give each its own ``TPU_VISIBLE_CHIPS``, or run
+them on the CPU (``JAX_PLATFORMS=cpu``). The coordinator itself never
+touches a device.
+
 ``start``/``recover`` run until SIGINT/SIGTERM, then drain and stop.
 ``status`` is read-only: one JSON object from the store's own state
 (membership, coordinator beat age, journal depths) — exactly what a
@@ -45,11 +53,11 @@ import time
 
 sys.path.insert(0, ".")  # run from repo root without install
 
-from pytorch_distributed_nn_tpu.runtime.platform import (
-    apply_platform_overrides,
+from pytorch_distributed_nn_tpu.runtime.device import (
+    configure_compile_cache,
 )
 
-apply_platform_overrides()
+configure_compile_cache()
 
 
 def _cmd_store(args) -> int:

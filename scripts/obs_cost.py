@@ -48,11 +48,11 @@ import sys
 
 sys.path.insert(0, ".")  # run from repo root without install
 
-from pytorch_distributed_nn_tpu.runtime.platform import (  # noqa: E402
-    apply_platform_overrides,
+from pytorch_distributed_nn_tpu.runtime.device import (  # noqa: E402
+    configure_compile_cache,
 )
 
-apply_platform_overrides()
+configure_compile_cache()
 
 from pytorch_distributed_nn_tpu.obs.meter import (  # noqa: E402
     LEDGER_FIELDS,
@@ -164,8 +164,12 @@ def render(report: dict) -> str:
 # ---------------------------------------------------------------------------
 
 def _selftest() -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    apply_platform_overrides()  # re-assert: setdefault above may be first
+    import jax
+
+    if not os.environ.get("JAX_PLATFORMS"):
+        # a host-side drill: the environment was read when jax was
+        # imported, so a default chosen here goes through the config
+        jax.config.update("jax_platforms", "cpu")
     import tempfile
 
     import numpy as np

@@ -29,11 +29,11 @@ import time
 
 sys.path.insert(0, ".")  # run from repo root without install
 
-from pytorch_distributed_nn_tpu.runtime.platform import (
-    apply_platform_overrides,
+from pytorch_distributed_nn_tpu.runtime.device import (
+    configure_compile_cache,
 )
 
-apply_platform_overrides()
+configure_compile_cache()
 
 import jax
 import jax.numpy as jnp

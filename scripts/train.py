@@ -18,11 +18,11 @@ import sys
 
 sys.path.insert(0, ".")  # run from repo root without install
 
-from pytorch_distributed_nn_tpu.runtime.platform import (
-    apply_platform_overrides,
+from pytorch_distributed_nn_tpu.runtime.device import (
+    configure_compile_cache,
 )
 
-apply_platform_overrides()  # honor JAX_PLATFORMS before first backend use
+configure_compile_cache()
 
 from pytorch_distributed_nn_tpu.config import get_config, parse_overrides
 from pytorch_distributed_nn_tpu.runtime import bootstrap

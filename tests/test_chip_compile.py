@@ -1,0 +1,184 @@
+"""Mosaic compiles of the main-path kernels at real widths, without a
+chip.
+
+The TPU's compiler is installed and compiles for a chip that is
+described, not attached (``topologies.get_topology_desc``). Interpret
+mode cannot show what this shows: a slice off the tiling, too much
+VMEM, a kernel that cannot be partitioned. The dispatchers see the CPU
+here and would take their reference branch, so the kernel functions are
+compiled directly. Nothing runs: a compile that passes is not a chip
+run. Skipped as a whole where the topology cannot be described (no
+libtpu, or its lock is held by another process).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+KERNEL = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any refusal means: skip
+        pytest.skip(f"v5e:2x2 topology cannot be described: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compile_cache_off():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip: the next one would
+    warn and compile again. Off around this module."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _flash(heads, kv_heads, T, D, causal):
+    """Forward and both backward passes: ``_flash_diff`` under grad."""
+    from pytorch_distributed_nn_tpu.ops.pallas import flash_attention as fa
+
+    def build(arg):
+        def loss(q, k, v):
+            o = fa._flash_diff(q, k, v, causal, fa._pick_block(T, 1024),
+                               fa._pick_block(T, 1024))
+            return o.astype(jnp.float32).sum()
+
+        return (jax.grad(loss, argnums=(0, 1, 2)),
+                [arg((heads, T, D), jnp.bfloat16),
+                 arg((kv_heads, T, D), jnp.bfloat16),
+                 arg((kv_heads, T, D), jnp.bfloat16)], 3)
+    return build
+
+
+def _int8(m, k, n):
+    from pytorch_distributed_nn_tpu.ops.pallas import int8_matmul as i8
+
+    def build(arg):
+        kp, np_ = i8.padded_kn(k, n)
+        return (lambda x, q, s: i8._int8_matmul_tpu(
+                    x, q, s, out_dtype=jnp.bfloat16),
+                [arg((m, kp), jnp.bfloat16), arg((kp, np_), jnp.int8),
+                 arg((1, np_), jnp.float32)], 1)
+    return build
+
+
+def _quantize(n):
+    from pytorch_distributed_nn_tpu.ops.pallas import quantize as qz
+
+    def build(arg):
+        return (lambda x, s: qz._quantize_tpu(x, s, 0),
+                [arg((n,), jnp.float32), arg((), jnp.float32)], 1)
+    return build
+
+
+def _bn_stats(shape, dot):
+    from pytorch_distributed_nn_tpu.ops.pallas import bn_stats as bn
+
+    def build(arg):
+        def run(*xs):
+            return bn._run([bn._view_2d(x)[0] for x in xs], dot=dot)
+
+        return run, [arg(shape, jnp.bfloat16)] * (2 if dot else 1), 1
+    return build
+
+
+def _ring_block(bh, tl, D):
+    from pytorch_distributed_nn_tpu.ops.pallas import ring_attention as ra
+
+    def build(arg):
+        def run(q, k, v, m, l, acc, offs):
+            return ra._ring_block_pallas(
+                q, k, v, m, l, acc, offs, causal=True, block_q=512,
+                block_k=512, interpret=False)
+
+        qkv = arg((bh, tl, D), jnp.bfloat16)
+        stat = arg((bh, tl, ra.STAT_LANES), jnp.float32)
+        return run, [qkv, qkv, qkv, stat, stat,
+                     arg((bh, tl, D), jnp.float32),
+                     arg((2,), jnp.int32)], 1
+    return build
+
+
+CASES = {
+    # Llama-3-8B's head layout (32 q / 8 kv heads of 128), long context
+    "flash_fwd_bwd_d128_gqa_T8192": _flash(32, 8, 8192, 128, True),
+    # BERT-base's head layout (12 heads of 64), bidirectional
+    "flash_fwd_bwd_d64_T1024": _flash(12, 12, 1024, 64, False),
+    # every weight shape of the int8 8B — q/o, k/v, q|k|v fused, gate/up,
+    # gate|up fused, down, LM head — for one decode row, a decode round
+    # of 8 slots, a prefill bucket of 32 and a prefill chunk of 256
+    **{f"int8_matmul_M{m}_K{k}_N{n}": _int8(m, k, n)
+       for k, n in ((4096, 4096), (4096, 1024), (4096, 6144),
+                    (4096, 14336), (4096, 28672), (14336, 4096),
+                    (4096, 128256))
+       for m in (1, 8, 32, 256)},
+    # ResNet-50's gradient in one bucket
+    "quantize_25M": _quantize(25_557_032),
+    # ResNet-50 stage 1 at batch 128: C = 64 folds into the 128 lanes
+    "bn_stats_sumsq_c64": _bn_stats((128, 56, 56, 64), False),
+    "bn_stats_dot_c256": _bn_stats((128, 56, 56, 256), True),
+    # one ring step of a 32k sequence over four devices
+    "ring_block_Tl8192_d128": _ring_block(8, 8192, 128),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_for_v5e(topo, name):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args, min_kernels = CASES[name](arg)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count(KERNEL) >= min_kernels, (
+        f"{name}: {text.count(KERNEL)} Pallas calls in the compiled "
+        f"program, want >= {min_kernels}")
+
+
+def test_ring_attention_fwd_bwd_compiles_for_four_chips(topo, monkeypatch):
+    """The fused ring forward and its Pallas backward as one program
+    across the four described chips: kernels AND the KV ring's
+    collective-permutes. The backward asks the backend which branch to
+    take; this test answers for the chip."""
+    from pytorch_distributed_nn_tpu.parallel.sequence import ring_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(topo.devices, ("seq",))
+    spec = P(None, "seq")
+
+    def grads(q, k, v):
+        def loss(q, k, v):
+            o = ring_attention(q, k, v, axis="seq", causal=True,
+                               impl="pallas")
+            return (o.astype(jnp.float32) ** 2).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    fn = jax.jit(jax.shard_map(grads, mesh=mesh, in_specs=(spec,) * 3,
+                               out_specs=(spec,) * 3, check_vma=False))
+    sh = NamedSharding(mesh, spec)
+    q = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16, sharding=sh)
+    kv = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.bfloat16, sharding=sh)
+    text = fn.lower(q, kv, kv).compile().as_text()
+    assert text.count(KERNEL) >= 3
+    assert "collective-permute" in text
