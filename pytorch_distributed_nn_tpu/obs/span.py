@@ -1,26 +1,39 @@
-"""Span tracing: Chrome trace-event JSON per host.
+"""Span tracing: one boundary, two sinks.
 
-``with obs.span("data/next_batch"): ...`` marks a host-side phase. When
-tracing is disabled (the default) a span costs one module-global read
-and yields a shared null context — no allocation, no clock read — so
+``with obs.span("data/next_batch"): ...`` marks a host-side phase. With
+neither sink armed (the default) a span costs one module-global read and
+one call that asks the profiler whether a session is running, and yields
+a shared null context — no allocation, no clock read — so
 instrumentation can stay in the hot loop permanently.
 
-Enabled (:func:`enable_tracing`), spans record complete events
-(``ph: "X"``, microsecond ``ts``/``dur``) into an in-memory buffer that
-:func:`write_trace` serializes as Chrome trace-event JSON — the same
-format ``jax.profiler``'s ``perfetto_trace.json.gz`` uses, so
-:func:`merge_chrome_traces` can splice host spans and device slices
-into one timeline (chrome://tracing / Perfetto both open it).
+Sink 1, the profiler. While a ``jax.profiler`` session is running
+(``start_trace``, an xray capture, the benchmark's traced run) a span
+is written into it as a ``jax.profiler.TraceAnnotation`` with the
+span's name and its keyword arguments as stats. It lands in the
+session's ``.xplane.pb`` on the ``/host:CPU`` plane, on the line of the
+thread that ran it, on the timeline the device planes use; nesting on
+one thread is the parent link. Nobody arms this sink: starting a
+profile does. It is recorded at ``host_tracer_level`` 1 and up.
+
+Sink 2, the recorder. Enabled (:func:`enable_tracing`), spans record
+complete events (``ph: "X"``, microsecond ``ts``/``dur``) into an
+in-memory buffer that :func:`write_trace` serializes as Chrome
+trace-event JSON: a host-only timeline that needs no profiler.
+:func:`merge_chrome_traces` concatenates such files for a viewer.
 
 Thread-safe: producer threads (data prefetch) trace under the same
 recorder; ``tid`` keeps their tracks apart.
+
+This module imports no jax: spans stay usable before and without a
+backend. The profiler is looked up in ``sys.modules`` once the program
+has imported jax itself.
 """
 
 from __future__ import annotations
 
 import gzip
 import json
-import os
+import sys
 import threading
 import time
 from pathlib import Path
@@ -36,6 +49,9 @@ class _NullSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **args):
+        """Arguments known only at the span's end; dropped here."""
 
 
 _NULL = _NullSpan()
@@ -92,35 +108,73 @@ class TraceRecorder:
 
 
 class _Span:
-    __slots__ = ("_rec", "_name", "_cat", "_args", "_t0")
+    """A span with at least one sink armed: ``_rec`` the recorder or
+    None, ``_ann`` the profiler's annotation or None."""
 
-    def __init__(self, rec: TraceRecorder, name: str, cat: str,
-                 args: dict | None) -> None:
+    __slots__ = ("_rec", "_ann", "_name", "_cat", "_args", "_t0")
+
+    def __init__(self, rec: TraceRecorder | None, annotation, name: str,
+                 cat: str, args: dict) -> None:
         self._rec = rec
+        self._ann = annotation(name, **args) \
+            if annotation is not None else None
         self._name = name
         self._cat = cat
         self._args = args
 
     def __enter__(self):
-        self._t0 = self._rec._now_us()
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._rec is not None:
+            self._t0 = self._rec._now_us()
         return self
 
+    def set(self, **args):
+        """Add arguments known only at the span's end (a count of what
+        it did). Call inside the ``with`` block."""
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+        if self._rec is not None:
+            self._args = {**self._args, **args}
+
     def __exit__(self, *exc):
-        t1 = self._rec._now_us()
-        self._rec.add_event(self._name, self._t0, t1 - self._t0,
-                            self._cat, self._args)
+        if self._rec is not None:
+            t1 = self._rec._now_us()
+            self._rec.add_event(self._name, self._t0, t1 - self._t0,
+                                self._cat, self._args or None)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
 _recorder: TraceRecorder | None = None
+# jax.profiler.TraceAnnotation, once the program has imported jax
+_annotation = None
+
+
+def _session_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is
+    recording host events, else None."""
+    global _annotation
+    ann = _annotation
+    if ann is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:
+            return None
+        ann = _annotation = profiler.TraceAnnotation
+    return ann if ann.is_enabled() else None
 
 
 def span(name: str, cat: str = "app", **args):
-    """Context manager marking a host-side phase. Free when disabled."""
+    """Context manager marking a host-side phase. Written to the running
+    profiler session, to the recorder, to both, or (neither armed) to
+    nothing: then it is the shared null context."""
     rec = _recorder
-    if rec is None:
+    ann = _session_annotation()
+    if rec is None and ann is None:
         return _NULL
-    return _Span(rec, name, cat, args or None)
+    return _Span(rec, ann, name, cat, args)
 
 
 def tracing_enabled() -> bool:
@@ -193,7 +247,8 @@ def merge_chrome_traces(paths, out) -> Path:
     slices) into one Chrome trace. Each input keeps its own pid tracks;
     offset alignment is the viewer's job (both sides stamp relative
     timestamps) — the merged file is for eyeballing phase overlap, not
-    sub-ms cross-clock skew."""
+    sub-ms cross-clock skew. For host spans on the device's own
+    timeline, start a profiler session instead: the spans are in it."""
     events: list[dict] = []
     for path in paths:
         events.extend(_load_trace(path))

@@ -534,59 +534,65 @@ class ServingEngine:
         there was nothing to do (caller may sleep/park)."""
         sched = self.scheduler
         sched.round += 1
-        # chaos tenant_flood: synthetic burst traffic lands through the
-        # REAL submit path (quota checks, DRR queues, reject counters)
-        for tenant, owed in chaos.on_tenant_flood():
-            for _ in range(owed):
-                self.submit(np.asarray([3, 5, 7], np.int32), 2,
-                            tenant=tenant)
-        changed = self._admit()
-        if self.active_slots == 0:
-            self._g_occ.set(0)
-            if changed:
-                self._sync_slots()
-            return changed
-        host_tok, dt = self._decode_round()
-        self.round_seconds.append(dt)
-        self._h_tok.observe(dt)
-        occ = self.active_slots
-        self._g_occ.set(occ)
-        self._c_tokens.inc(occ)
-        self._occ_sum += occ
-        flight.record("serve", "decode_round", step=sched.round,
-                      note=f"occ={occ}/{self.max_slots}")
-        # watchtower feed (token-latency SLO + queue/KV pressure):
-        # here, NOT in _decode_round — its hot-loop lint bans extras
-        watchtower.on_serve_round(
-            sched.round, dt, queue_depth=sched.queue_depth,
-            queue_max=sched.max_queue,
-            kv_free=sched.pool.free_blocks,
-            kv_total=sched.pool.num_blocks)
-        # helm feed (instantaneous queue/KV between control ticks);
-        # inert one-comparison no-op unless TPUNN_AUTOSCALE armed it
-        autoscale.on_serve_round(
-            sched.round, dt, queue_depth=sched.queue_depth,
-            queue_max=sched.max_queue,
-            kv_free=sched.pool.free_blocks,
-            kv_total=sched.pool.num_blocks)
-        # xray capture clock (serving-side): rounds advance an active
-        # capture window / interval trigger, same placement rule
-        xray.on_serve_round(sched.round)
-        # Abacus decode billing: one token per active slot this round,
-        # split by tenant — here, NOT in _decode_round (hot-loop lint).
-        # enabled() gate so the slot scan + FLOPs lookup never run on
-        # an unarmed process (the armed-vs-unset A/B contract)
-        if meter.enabled():
-            # Lighthouse shadow/probe legs are audit duplicates, not
-            # customer traffic — their decode rounds are never billed
-            meter.on_decode_round(
-                [s.req.tenant for s in self._slots if s is not None
-                 and s.req.tenant != audit.SHADOW_TENANT],
-                self.flops_per_token())
-        retired = self._collect(host_tok)
-        if retired:
-            self._sync_slots()
-        return True
+        with obs.span("serve/round", round=sched.round) as rnd:
+            # chaos tenant_flood: synthetic burst traffic lands through the
+            # REAL submit path (quota checks, DRR queues, reject counters)
+            for tenant, owed in chaos.on_tenant_flood():
+                for _ in range(owed):
+                    self.submit(np.asarray([3, 5, 7], np.int32), 2,
+                                tenant=tenant)
+            changed = self._admit()
+            if self.active_slots == 0:
+                self._g_occ.set(0)
+                if changed:
+                    self._sync_slots()
+                rnd.set(occ=0)
+                return changed
+            with obs.span("serve/decode"):
+                host_tok, dt = self._decode_round()
+            with obs.span("serve/round_host") as host_span:
+                self.round_seconds.append(dt)
+                self._h_tok.observe(dt)
+                occ = self.active_slots
+                self._g_occ.set(occ)
+                self._c_tokens.inc(occ)
+                self._occ_sum += occ
+                flight.record("serve", "decode_round", step=sched.round,
+                              note=f"occ={occ}/{self.max_slots}")
+                # watchtower feed (token-latency SLO + queue/KV pressure):
+                # here, NOT in _decode_round — its hot-loop lint bans extras
+                watchtower.on_serve_round(
+                    sched.round, dt, queue_depth=sched.queue_depth,
+                    queue_max=sched.max_queue,
+                    kv_free=sched.pool.free_blocks,
+                    kv_total=sched.pool.num_blocks)
+                # helm feed (instantaneous queue/KV between control ticks);
+                # inert one-comparison no-op unless TPUNN_AUTOSCALE armed it
+                autoscale.on_serve_round(
+                    sched.round, dt, queue_depth=sched.queue_depth,
+                    queue_max=sched.max_queue,
+                    kv_free=sched.pool.free_blocks,
+                    kv_total=sched.pool.num_blocks)
+                # xray capture clock (serving-side): rounds advance an active
+                # capture window / interval trigger, same placement rule
+                xray.on_serve_round(sched.round)
+                # Abacus decode billing: one token per active slot this round,
+                # split by tenant — here, NOT in _decode_round (hot-loop lint).
+                # enabled() gate so the slot scan + FLOPs lookup never run on
+                # an unarmed process (the armed-vs-unset A/B contract)
+                if meter.enabled():
+                    # Lighthouse shadow/probe legs are audit duplicates, not
+                    # customer traffic — their decode rounds are never billed
+                    meter.on_decode_round(
+                        [s.req.tenant for s in self._slots if s is not None
+                         and s.req.tenant != audit.SHADOW_TENANT],
+                        self.flops_per_token())
+                retired = self._collect(host_tok)
+                if retired:
+                    self._sync_slots()
+                host_span.set(retired=retired)
+            rnd.set(occ=occ - retired)
+            return True
 
     def run_until_idle(self) -> None:
         """Drive rounds until queue and batch are both empty."""
@@ -614,14 +620,16 @@ class ServingEngine:
         admitted = self.scheduler.next_admissions(len(free))
         if not admitted:
             return False
-        for req in admitted:
-            # a branched request claims one row per branch (the
-            # scheduler already counted them against free_slots)
-            slots = [free.pop(0) for _ in range(req.branches)]
-            self._prefill_into(slots, req)
-        # a budget-1 (or instant-eos) request retires in the same pass
-        self._retire_finished()
-        self._sync_slots()
+        with obs.span("serve/admit", n=len(admitted)):
+            for req in admitted:
+                # a branched request claims one row per branch (the
+                # scheduler already counted them against free_slots)
+                slots = [free.pop(0) for _ in range(req.branches)]
+                self._prefill_into(slots, req)
+            # a budget-1 (or instant-eos) request retires in the same
+            # pass
+            self._retire_finished()
+            self._sync_slots()
         return True
 
     def _prefill_into(self, slots: list, req: Request) -> None:
@@ -648,106 +656,111 @@ class ServingEngine:
             if match is not None else 0
         pad = min(_bucket_len(max(m + t_pad, restore_top)),
                   self.max_seq_len)
-        tokens = np.zeros((1, t_pad), np.int32)
-        tokens[0, :T] = suffix  # left-ALIGNED (pad tail is masked)
-        row_cache = _fresh_cache(self.model, 1, pad)
-        if m > 0:
-            nb = len(match.restore_blocks)
-            table = np.zeros((self._blocks_per_seq,), np.int32)
-            table[:nb] = match.restore_blocks
-            t_restore = time.monotonic()
-            row_cache = _restore_blocks(
-                row_cache, self._store, bs, table, np.int32(nb))
-            trace.on_segment(req.trace, "restore", t_restore,
-                             time.monotonic(), blocks=nb, cached=m)
-        logps: Optional[list] = None
-        with obs.span("serve/prefill", request=req.request_id,
-                      prompt_len=L, cached=m):
-            if not sampled:
-                # inert-defaults contract: this arm is the EXACT
-                # pre-Prism call (test_quality pins its shape), so
-                # greedy requests stay byte-identical
-                if self.lora_bank is None:
-                    tok0, row_cache = _serve_prefill(
-                        self.model, self.params, row_cache,
-                        jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
-                        jnp.asarray([m], jnp.int32))
+        with obs.span("serve/prefill_into", request=req.request_id,
+                      tokens=T, padded=t_pad, cached=m, row_len=pad):
+            tokens = np.zeros((1, t_pad), np.int32)
+            tokens[0, :T] = suffix  # left-ALIGNED (pad tail is masked)
+            with obs.span("serve/fresh_cache"):
+                row_cache = _fresh_cache(self.model, 1, pad)
+            if m > 0:
+                nb = len(match.restore_blocks)
+                table = np.zeros((self._blocks_per_seq,), np.int32)
+                table[:nb] = match.restore_blocks
+                t_restore = time.monotonic()
+                with obs.span("serve/restore", blocks=nb):
+                    row_cache = _restore_blocks(
+                        row_cache, self._store, bs, table, np.int32(nb))
+                trace.on_segment(req.trace, "restore", t_restore,
+                                 time.monotonic(), blocks=nb, cached=m)
+            logps: Optional[list] = None
+            with obs.span("serve/prefill", request=req.request_id,
+                          prompt_len=L, cached=m):
+                if not sampled:
+                    # inert-defaults contract: this arm is the EXACT
+                    # pre-Prism call (test_quality pins its shape), so
+                    # greedy requests stay byte-identical
+                    if self.lora_bank is None:
+                        tok0, row_cache = _serve_prefill(
+                            self.model, self.params, row_cache,
+                            jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
+                            jnp.asarray([m], jnp.int32))
+                    else:
+                        tok0, row_cache = _serve_prefill_lora(
+                            self.model, self.params, row_cache,
+                            jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
+                            jnp.asarray([m], jnp.int32), self.lora_bank,
+                            jnp.asarray([req.adapter], jnp.int32))
+                    firsts = [int(np.asarray(tok0)[0])]
                 else:
-                    tok0, row_cache = _serve_prefill_lora(
-                        self.model, self.params, row_cache,
-                        jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
-                        jnp.asarray([m], jnp.int32), self.lora_bank,
-                        jnp.asarray([req.adapter], jnp.int32))
-                firsts = [int(np.asarray(tok0)[0])]
-            else:
-                if self.lora_bank is None:
-                    next_logits, row_cache = _serve_prefill_logits(
-                        self.model, self.params, row_cache,
-                        jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
-                        jnp.asarray([m], jnp.int32))
-                else:
-                    next_logits, row_cache = _serve_prefill_logits_lora(
-                        self.model, self.params, row_cache,
-                        jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
-                        jnp.asarray([m], jnp.int32), self.lora_bank,
-                        jnp.asarray([req.adapter], jnp.int32))
-                toks, lps = _sample_first(
-                    next_logits, len(slots),
-                    np.float32(spec.temperature), np.int32(spec.top_k),
-                    np.float32(spec.top_p), np.int32(spec.seed),
-                    np.int32(req.decode_step0))
-                firsts = [int(t) for t in np.asarray(toks)]
-                logps = [float(x) for x in np.asarray(lps)]
-        if match is not None:
-            # restored rows are copied out; the COW tail pin can drop
-            self.prefix_cache.finish_restore(match)
-            req.prefix_match = None
-        now = time.monotonic()
-        req.t_first_token = now
-        # TTFT is charged from the logical request's ORIGINAL arrival
-        # (t_origin: set by the fleet on resubmitted legs), and only
-        # when THIS leg delivers the first token — a disagg decode leg
-        # or a post-first-token failover re-admission arrives with
-        # t_first_origin already set and must not observe again (the
-        # capacity sim's accounting, now pinned for the live fleet too)
-        if req.t_first_origin == 0.0:
-            ttft = now - (req.t_origin or req.t_submit)
-            self._h_ttft.observe(ttft)
-            self._h_ttft_tenant.observe(ttft, tenant=req.tenant)
-        sids = branch_seq_ids(req)
-        for k, slot in enumerate(slots):
-            self._cache = _insert_row(self._cache, row_cache, slot)
-            s = _Slot(req, firsts[k], depth=L, cached=m,
-                      seq_id=sids[k], branch=k)
-            self._slots[slot] = s
-            self._h_last[slot] = firsts[k]
-            self._h_depth[slot] = L
-            self._h_active[slot] = True
-            self._h_adapter[slot] = req.adapter
-            # reset the sampling mirrors: slots are reused, and a
-            # greedy row landing on a retired sampled row must read
-            # temperature 0 (the jit's per-row greedy branch)
-            self._h_temp[slot] = spec.temperature if sampled else 0.0
-            self._h_topk[slot] = spec.top_k if sampled else 0
-            self._h_topp[slot] = spec.top_p if sampled else 0.0
-            self._h_seed[slot] = spec.seed if sampled else 0
-            self._h_branch[slot] = k
-            self._pending_logprob[slot] = logps[k] if sampled else 0.0
-            self._c_tokens.inc()  # the prefill-produced first token
-            flight.record("serve", "admit", step=self.scheduler.round,
-                          note=f"{sids[k]} slot={slot} L={L} "
-                               f"cached={m}")
-            if k == 0:
-                # first chunk = the client-visible TTFT event (no-op
-                # for non-streaming requests)
-                self._emit_chunk(s)
-        # Abacus prefill billing: the suffix actually computed, plus
-        # the cached-prefix FLOPs the restore SKIPPED as a credit
-        # (audit shadow/probe legs are never billed)
-        if meter.enabled() and req.tenant != audit.SHADOW_TENANT:
-            meter.on_prefill(req.request_id, req.tenant,
-                             new_tokens=T, cached_tokens=m,
-                             flops_per_token=self.flops_per_token())
+                    if self.lora_bank is None:
+                        next_logits, row_cache = _serve_prefill_logits(
+                            self.model, self.params, row_cache,
+                            jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
+                            jnp.asarray([m], jnp.int32))
+                    else:
+                        next_logits, row_cache = _serve_prefill_logits_lora(
+                            self.model, self.params, row_cache,
+                            jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
+                            jnp.asarray([m], jnp.int32), self.lora_bank,
+                            jnp.asarray([req.adapter], jnp.int32))
+                    toks, lps = _sample_first(
+                        next_logits, len(slots),
+                        np.float32(spec.temperature), np.int32(spec.top_k),
+                        np.float32(spec.top_p), np.int32(spec.seed),
+                        np.int32(req.decode_step0))
+                    firsts = [int(t) for t in np.asarray(toks)]
+                    logps = [float(x) for x in np.asarray(lps)]
+            if match is not None:
+                # restored rows are copied out; the COW tail pin can drop
+                self.prefix_cache.finish_restore(match)
+                req.prefix_match = None
+            now = time.monotonic()
+            req.t_first_token = now
+            # TTFT is charged from the logical request's ORIGINAL arrival
+            # (t_origin: set by the fleet on resubmitted legs), and only
+            # when THIS leg delivers the first token — a disagg decode leg
+            # or a post-first-token failover re-admission arrives with
+            # t_first_origin already set and must not observe again (the
+            # capacity sim's accounting, now pinned for the live fleet too)
+            if req.t_first_origin == 0.0:
+                ttft = now - (req.t_origin or req.t_submit)
+                self._h_ttft.observe(ttft)
+                self._h_ttft_tenant.observe(ttft, tenant=req.tenant)
+            sids = branch_seq_ids(req)
+            with obs.span("serve/insert_row", rows=len(slots)):
+                for k, slot in enumerate(slots):
+                    self._cache = _insert_row(self._cache, row_cache, slot)
+                    s = _Slot(req, firsts[k], depth=L, cached=m,
+                              seq_id=sids[k], branch=k)
+                    self._slots[slot] = s
+                    self._h_last[slot] = firsts[k]
+                    self._h_depth[slot] = L
+                    self._h_active[slot] = True
+                    self._h_adapter[slot] = req.adapter
+                    # reset the sampling mirrors: slots are reused, and a
+                    # greedy row landing on a retired sampled row must read
+                    # temperature 0 (the jit's per-row greedy branch)
+                    self._h_temp[slot] = spec.temperature if sampled else 0.0
+                    self._h_topk[slot] = spec.top_k if sampled else 0
+                    self._h_topp[slot] = spec.top_p if sampled else 0.0
+                    self._h_seed[slot] = spec.seed if sampled else 0
+                    self._h_branch[slot] = k
+                    self._pending_logprob[slot] = logps[k] if sampled else 0.0
+                    self._c_tokens.inc()  # the prefill-produced first token
+                    flight.record("serve", "admit", step=self.scheduler.round,
+                                  note=f"{sids[k]} slot={slot} L={L} "
+                                       f"cached={m}")
+                    if k == 0:
+                        # first chunk = the client-visible TTFT event (no-op
+                        # for non-streaming requests)
+                        self._emit_chunk(s)
+            # Abacus prefill billing: the suffix actually computed, plus
+            # the cached-prefix FLOPs the restore SKIPPED as a credit
+            # (audit shadow/probe legs are never billed)
+            if meter.enabled() and req.tenant != audit.SHADOW_TENANT:
+                meter.on_prefill(req.request_id, req.tenant,
+                                 new_tokens=T, cached_tokens=m,
+                                 flops_per_token=self.flops_per_token())
 
     def _decode_round(self):
         """THE hot loop body (see module docstring for the lint
@@ -836,28 +849,30 @@ class ServingEngine:
 
     def _retire_finished(self) -> int:
         retired = 0
-        for i, s in enumerate(self._slots):
-            if s is None or not self._done(s):
-                continue
-            self._slots[i] = None
-            self._h_active[i] = False
-            retired += 1
-            req = s.req
-            if req.branches > 1:
-                self._retire_branch(i, s)
-                continue
-            if self.prefix_cache is not None:
-                # donate BEFORE retire: release() indexes the physical
-                # blocks into the radix, so their bytes must already be
-                # in the store when another admission can match them
-                self._donate_blocks(i, s)
-            # final flush BEFORE retire: the closing chunk must be in
-            # the stream when done.set() wakes the client
-            self._emit_chunk(s, final=True)
-            self.scheduler.retire(req, np.asarray(s.tokens, np.int32))
-            flight.record("serve", "retire", step=self.scheduler.round,
-                          note=f"{req.request_id} tokens={s.emitted}")
-            self._finish_record(req, s)
+        with obs.span("serve/retire") as sp:
+            for i, s in enumerate(self._slots):
+                if s is None or not self._done(s):
+                    continue
+                self._slots[i] = None
+                self._h_active[i] = False
+                retired += 1
+                req = s.req
+                if req.branches > 1:
+                    self._retire_branch(i, s)
+                    continue
+                if self.prefix_cache is not None:
+                    # donate BEFORE retire: release() indexes the physical
+                    # blocks into the radix, so their bytes must already be
+                    # in the store when another admission can match them
+                    self._donate_blocks(i, s)
+                # final flush BEFORE retire: the closing chunk must be in
+                # the stream when done.set() wakes the client
+                self._emit_chunk(s, final=True)
+                self.scheduler.retire(req, np.asarray(s.tokens, np.int32))
+                flight.record("serve", "retire", step=self.scheduler.round,
+                              note=f"{req.request_id} tokens={s.emitted}")
+                self._finish_record(req, s)
+            sp.set(n=retired)
         return retired
 
     def _retire_branch(self, slot: int, s: _Slot) -> None:

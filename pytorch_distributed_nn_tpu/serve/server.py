@@ -39,6 +39,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from pytorch_distributed_nn_tpu import obs
 from pytorch_distributed_nn_tpu.obs import flight, watchtower
 from pytorch_distributed_nn_tpu.runtime import failure
 from pytorch_distributed_nn_tpu.serve.engine import ServingEngine
@@ -79,7 +80,8 @@ class InferenceServer:
             else:
                 # park until a submit wakes us (bounded so stop/SIGTERM
                 # polls stay live even with no traffic)
-                self._wake.wait(self.idle_wait_s)
+                with obs.span("serve/parked"):
+                    self._wake.wait(self.idle_wait_s)
                 self._wake.clear()
         self.engine.drain()
         self._drained.set()
