@@ -447,8 +447,11 @@ class Fleet:
         stalls a worker for the whole XLA compile — long enough to
         starve its progress watchdog and read as a hang to the failure
         detector (a false replica_down on a healthy fleet). One
-        throwaway forward per bucket; the jit cache is keyed on the
-        model so every replica shares the result."""
+        throwaway forward per bucket, on a row from ``_fresh_cache``:
+        that compiles the bucket's zero-cache program too, so a cold
+        replica's first admission pays no compile for its row either.
+        The jit cache is keyed on the model so every replica shares
+        the result."""
         from pytorch_distributed_nn_tpu.serve.engine import (
             _bucket_len,
             _fresh_cache,

@@ -18,14 +18,16 @@ import sys
 import time
 from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
 
 from pytorch_distributed_nn_tpu import obs
-from pytorch_distributed_nn_tpu.inference.generate import generate
+from pytorch_distributed_nn_tpu.inference.generate import generate, init_cache
 from pytorch_distributed_nn_tpu.obs import flight
 from pytorch_distributed_nn_tpu.runtime import chaos
 from pytorch_distributed_nn_tpu.serve import (
+    DecodeSpec,
     InferenceServer,
     KVPool,
     Scheduler,
@@ -33,6 +35,7 @@ from pytorch_distributed_nn_tpu.serve import (
     open_loop_client,
     ragged_prompt_sampler,
 )
+from pytorch_distributed_nn_tpu.serve import engine as engine_mod
 
 VOCAB = 97
 
@@ -241,6 +244,92 @@ def test_engine_budget_one_matches_prefill_argmax(tiny_llama):
     ref = np.asarray(generate(model, params, p[None], 1))
     assert r.state == "done"
     np.testing.assert_array_equal(r.tokens, ref[0, len(p):])
+
+
+# ---------------------------------------------------------------------------
+# The zeroed cache a prefill writes into (ISSUE 28): one compiled
+# program, the same tree as init_cache's, fresh buffers on every call
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("batch", [1, 3], ids=["row", "max_slots"])
+def test_zero_cache_is_init_caches_tree_leaf_for_leaf(tiny_llama, batch):
+    model, _ = tiny_llama
+    got = engine_mod._fresh_cache(model, batch, 32)
+    want = init_cache(model, batch, 32)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    leaves = jax.tree.leaves(got)
+    assert len(leaves) == 3 * 2     # key, value, index of two layers
+    for g, w in zip(leaves, jax.tree.leaves(want)):
+        assert (g.shape, g.dtype, g.weak_type) \
+            == (w.shape, w.dtype, w.weak_type)
+        assert g.sharding == w.sharding and g.committed == w.committed
+        assert not np.asarray(g).any()
+
+
+def test_zero_cache_never_hands_out_a_buffer_twice(tiny_llama):
+    """The row cache is donated to the prefill program: two calls, and
+    two leaves of one call, must not share a buffer."""
+    model, _ = tiny_llama
+    first = jax.tree.leaves(engine_mod._fresh_cache(model, 1, 16))
+    again = jax.tree.leaves(engine_mod._fresh_cache(model, 1, 16))
+    ptrs = [x.unsafe_buffer_pointer() for x in first + again]
+    assert len(set(ptrs)) == len(ptrs)
+
+
+def test_two_admissions_of_one_bucket_back_to_back(tiny_llama):
+    """Both prefills of a pass take the 16-token bucket's zero cache
+    and both donate it; the second must not get the first one's."""
+    model, params = tiny_llama
+    prompts = _prompts([9, 12], seed=8)
+    eng = ServingEngine(model, params, max_slots=2, max_seq_len=32,
+                        max_prefills_per_round=2)
+    reqs = [eng.submit(p, 4) for p in prompts]
+    eng.step()
+    assert eng.active_slots == 2    # one pass admitted both
+    eng.run_until_idle()
+    for p, r in zip(prompts, reqs):
+        assert r.state == "done", (r.state, r.reject_reason)
+        ref = np.asarray(generate(model, params, p[None], 4))
+        np.testing.assert_array_equal(r.tokens, ref[0, len(p):])
+
+
+def _tokens_of(kind, model, params, p):
+    """The tokens of one request of ``kind`` for prompt ``p`` through a
+    new engine, and how many prompt tokens the prefix cache gave."""
+    eng = ServingEngine(model, params, max_slots=3, max_seq_len=64,
+                        block_size=16)
+    if kind == "sampled":
+        spec = DecodeSpec(temperature=0.9, top_k=20, best_of=2, n=2,
+                          seed=5)
+        r = eng.submit(p, 6, decode=spec)
+        eng.run_until_idle()
+        assert r.state == "done" and len(r.n_best) == 2
+        return [list(map(int, b["tokens"])) for b in r.n_best], 0
+    if kind == "hit":       # the same prompt again: restore, then prefill
+        eng.submit(p, 2)
+        eng.run_until_idle()
+    r = eng.submit(p, 6)
+    eng.run_until_idle()
+    assert r.state == "done"
+    return list(map(int, r.tokens)), eng.completed[-1]["cached_tokens"]
+
+
+@pytest.mark.parametrize("kind", ["miss", "hit", "sampled"])
+def test_tokens_are_the_eager_zero_caches(tiny_llama, monkeypatch, kind):
+    """Greedy tokens of a miss, of a prefix hit (``_restore_blocks``
+    into the zero row) and both branches of a sampled request: what the
+    engine gave when ``_fresh_cache`` minted its zeros eagerly."""
+    model, params = tiny_llama
+    (p,) = _prompts([21], seed=12)
+    got, cached = _tokens_of(kind, model, params, p)
+    assert cached == (16 if kind == "hit" else 0)
+    # init_cache is what _fresh_cache was before ISSUE 28: every leaf
+    # an eager jnp.zeros on the host
+    monkeypatch.setattr(engine_mod, "_fresh_cache", init_cache)
+    assert _tokens_of(kind, model, params, p) == (got, cached)
+    if kind != "sampled":
+        ref = np.asarray(generate(model, params, p[None], 6))
+        assert got == list(map(int, ref[0, len(p):]))
 
 
 def test_engine_ttft_and_latency_histograms_populated(tiny_llama):

@@ -294,15 +294,21 @@ def test_spans_of_one_request_share_its_id(traced):
 # The programs the engine thread dispatches for REQUESTS, from building
 # the engine to the last retire; C is the eager ``convert_element_type``
 # (``jnp.asarray``) and B the eager ``broadcast_in_dim`` (``jnp.zeros``).
-# Taken at the parent commit (7b183ec, before any span was added) with
-# this file's ``_drive`` and ``_programs``. A span that moved, added or
-# renamed a device program, or an eager operation, changes this list.
-PINNED_AT_PARENT = (
-    "C C B C B C C B C B C C B C B C C B C B C B C C B C B C C B C B "
-    "C C _serve_prefill _insert_row C C B C B C C B C B C C "
-    "_serve_prefill _insert_row _serve_step _save_blocks _serve_step "
-    "C C B C B C C B C B _restore_blocks C C _serve_prefill "
-    "_insert_row _serve_step _save_blocks "
+# A span that moved, added or renamed a device program, or an eager
+# operation, changes this list. First taken at 7b183ec, before any span
+# was added. ISSUE 28 changed it on purpose: ``_fresh_cache`` became the
+# one compiled ``_zero_cache`` program, so each run of ``C C B C B`` a
+# layer that stood before a ``_serve_prefill`` or ``_restore_blocks``
+# (and before the constructor's prefix store) is now one dispatch; the
+# two ``C`` before a prefill are its ``jnp.asarray`` uploads, and the
+# ``B`` left are the constructor's store and decode state.
+PINNED_DISPATCHES = (
+    "_zero_cache C C B C B C C B C B C B "
+    "_zero_cache C C _serve_prefill _insert_row "
+    "_zero_cache C C _serve_prefill _insert_row "
+    "_serve_step _save_blocks _serve_step "
+    "_zero_cache _restore_blocks C C _serve_prefill _insert_row "
+    "_serve_step _save_blocks "
 ).split()
 _SHORT = {"convert_element_type": "C", "broadcast_in_dim": "B"}
 
@@ -319,7 +325,7 @@ def _dispatches(tiny_llama, tmp_path):
 def test_spans_dispatch_the_parents_programs_armed_and_unarmed(
         tiny_llama, tmp_path, monkeypatch):
     """Same programs, same order, same tokens: with the spans written
-    to the session, with the spans unarmed, and at the parent."""
+    to the session, with the spans unarmed, and as pinned."""
     tokens_a, armed = _dispatches(tiny_llama, tmp_path / "armed")
     assert any(n == "serve/decode" for n, *_ in armed)
     # unarmed: the session still runs (it is the instrument that lists
@@ -330,7 +336,32 @@ def test_spans_dispatch_the_parents_programs_armed_and_unarmed(
     assert tokens_a == tokens_u
     assert _programs(armed) == _programs(unarmed)
     assert [_SHORT.get(n, n) for n in _programs(unarmed)] \
-        == PINNED_AT_PARENT
+        == PINNED_DISPATCHES
+
+
+def test_admission_mints_its_row_cache_in_one_dispatch(traced):
+    """ISSUE 28: from the engine's first round to its last retire no
+    eager ``jnp.zeros`` runs, and every prefill takes its zeroed row
+    cache from exactly one ``_zero_cache`` dispatch, under its
+    ``serve/fresh_cache`` span."""
+    line = traced["engine"]
+    first = min(s for n, s, _, _ in line if n == "serve/round")
+    last = max(e for n, _, e, _ in line if n == "serve/retire")
+
+    def inside(lo, hi):
+        return _programs([ev for ev in line if lo <= ev[1] and ev[2] <= hi])
+
+    live = inside(first, last)
+    assert "_serve_prefill" in live and "_restore_blocks" in live
+    assert "broadcast_in_dim" not in live
+    spans = {name: [(s, e) for n, s, e, _ in line if n == name]
+             for name in ("serve/prefill_into", "serve/fresh_cache")}
+    assert len(spans["serve/prefill_into"]) == 3
+    for s, e in spans["serve/prefill_into"]:
+        assert inside(s, e).count("_zero_cache") == 1
+    assert [inside(s, e) for s, e in spans["serve/fresh_cache"]] \
+        == [["_zero_cache"]] * 3
+    assert live.count("_zero_cache") == 3
 
 
 # sha256 of ServingEngine._decode_round's source at the parent. The hot
