@@ -182,3 +182,61 @@ def test_ring_attention_fwd_bwd_compiles_for_four_chips(topo, monkeypatch):
     text = fn.lower(q, kv, kv).compile().as_text()
     assert text.count(KERNEL) >= 3
     assert "collective-permute" in text
+
+
+def test_dp_explicit_step_reduces_leaves_in_place_on_four_chips(topo):
+    """The bucketed dp_explicit step of a one-layer BERT-base (published
+    widths, so the two embedding matrices are 94 MB leaves) as the
+    chip's compiler leaves it: a few all-reduces whose operands are the
+    leaves in their own shapes, nothing packed into a flat buffer. The
+    step compiles under its own compiler options (a name this libtpu
+    does not know is refused), whether or not the combiner leaves an
+    all-reduce of one operand for them to make asynchronous."""
+    import re
+
+    from pytorch_distributed_nn_tpu.config import ModelConfig, OptimConfig
+    from pytorch_distributed_nn_tpu.models import get_model
+    from pytorch_distributed_nn_tpu.ops.buckets import make_bucket_reduce
+    from pytorch_distributed_nn_tpu.parallel.dp import (
+        make_dp_train_step_explicit,
+    )
+    from pytorch_distributed_nn_tpu.runtime.mesh import (
+        MeshSpec, batch_pspec, make_mesh,
+    )
+    from pytorch_distributed_nn_tpu.train.losses import get_loss_fn
+    from pytorch_distributed_nn_tpu.train.optim import make_optimizer
+    from pytorch_distributed_nn_tpu.train.state import TrainState
+
+    mesh = make_mesh(MeshSpec(data=4), devices=list(topo.devices))
+    model = get_model(ModelConfig(name="bert_base",
+                                  extra=dict(num_layers=1)))
+
+    def init():
+        variables = model.init(jax.random.key(0),
+                               jnp.zeros((1, 128), jnp.int32), train=False)
+        return TrainState.create(
+            apply_fn=model.apply, params=variables["params"],
+            tx=make_optimizer(OptimConfig(name="adamw"), total_steps=10),
+            model_state={}, rng=jax.random.key(1))
+
+    def on(spec):
+        sharding = NamedSharding(mesh, spec)
+        return lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                              sharding=sharding)
+
+    state = jax.tree.map(on(P()), jax.eval_shape(init))
+    tokens = on(batch_pspec())(jax.ShapeDtypeStruct((32, 128), jnp.int32))
+    step = make_dp_train_step_explicit(
+        mesh, get_loss_fn("mlm_synthetic"),
+        bucket_reduce=make_bucket_reduce(bucket_mb=100.0))
+    text = step.lower(state, tokens, tokens).compile().as_text()
+
+    reduced = [line.split("=", 1)[1] for line in text.splitlines()
+               if re.search(r" all-reduce\(|^\s+%async-collective-start"
+                            r"[.\d]* = ", line)]
+    assert 1 <= len(reduced) < 10
+    flat = [int(n) for result in reduced
+            for n in re.findall(r"f32\[(\d+)\]", result.split(" all-")[0])]
+    assert max(flat, default=0) <= 30522  # the largest rank-1 leaf
+    # and no gradient-sized rank-1 buffer anywhere in the program
+    assert max(map(int, re.findall(r"= f32\[(\d+)\]", text))) < 1 << 20
