@@ -11,10 +11,11 @@ consumes it as per-row device arrays.
 Contracts (all lint- or golden-enforced):
 
 - **inert defaults**: ``DecodeSpec()`` (temperature 0, one branch) IS
-  the greedy path. The engine routes default requests through the
-  exact pre-Prism jits (``_serve_prefill`` / ``_serve_step``), so
-  greedy outputs, JSONL records, and Lighthouse fingerprint chains
-  stay byte-identical to a build without this module;
+  the greedy path. A batch with no sampled row runs
+  ``_serve_prefill`` / ``_serve_step`` without their ``sampling``
+  argument, programs that hold nothing of this module, so greedy
+  outputs, JSONL records, and Lighthouse fingerprint chains are those
+  of a build without it;
 - **seeded determinism**: every sampled token is drawn with a key
   derived *inside the jit* as ``fold_in(fold_in(key(seed), branch),
   step)`` — a pure function of ``(seed, branch, step)``, independent

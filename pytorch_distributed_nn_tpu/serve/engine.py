@@ -23,6 +23,16 @@ length), and the compiler orders the same sums differently. Every
 divergence seen was a choice between logits less than half a bf16 step
 apart; chip_smoke.py gates on that margin.
 
+There are two model programs, :func:`_serve_prefill` and
+:func:`_serve_step`, and what the engine holds and the batch contains
+decides their form: each takes ``lora`` (the bank and the rows' adapter
+ids) if the engine was built with a bank, and ``sampling`` (the rows'
+specs, RNG lanes and running logprobs) while a row of a sampled request
+is active. Neither is an option. An absent argument is ``None``, an
+empty pytree to ``jax.jit``: the program lowers to the text of a
+function that never had the argument (tests/test_quality.py reads it),
+so a greedy batch of an engine without a bank pays for neither.
+
 Hot-loop discipline (lint-enforced): :meth:`ServingEngine._decode_round`
 contains the per-round device work and performs NO host->device
 transfers and no jnp/jax array construction — slot state (last token,
@@ -81,6 +91,11 @@ _TOKEN_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
 # decode rounds between two readings of a model's device-side counters:
 # ~100 us of fetch every second or two of serving
 _COUNTER_ROUNDS = 64
+# one row of the programs' ``sampling`` argument: a DecodeSpec's
+# numbers, the row's RNG lane and step, its running logprob
+_SAMPLING_ROW = dict(temp=np.float32, top_k=np.int32, top_p=np.float32,
+                     seed=np.int32, branch=np.int32, step=np.int32,
+                     logprob=np.float32)
 
 
 def _mask_kw(model, mask) -> dict:
@@ -100,8 +115,8 @@ def _apply_prefill_at(model, params, cache, tokens, lengths, starts,
     [0, starts[i]) are already in ``cache`` and the suffix computes
     exactly the floats a full from-zero prefill would have. Returns
     ((B, V) logits at each row's LAST real suffix position, cache).
-    ``extra`` forwards per-request LoRA (lora_bank + adapter_ids) so
-    TransformerLM-family models never see unknown kwargs."""
+    ``extra`` goes to the model as keywords (an engine's ``lora``), so
+    a model never sees a keyword its engine has no use for."""
     logits, mutated = model.apply(
         {"params": params, "cache": cache}, tokens,
         train=False, decode=True, mutable=["cache"],
@@ -114,156 +129,75 @@ def _apply_prefill_at(model, params, cache, tokens, lengths, starts,
     return next_logits, mutated["cache"]
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _serve_prefill(model, params, cache, tokens, lengths, starts):
-    """Batch-of-one (suffix) prefill + greedy first token: (1,) int32
-    token, filled (1, P_pad, ...) row cache. ``starts`` (1,) int32 is
-    the number of rows already restored from the prefix cache (0 for a
-    miss). The argmax runs on device so the only host transfer is the
-    token itself."""
-    next_logits, cache = _apply_prefill_at(model, params, cache,
-                                           tokens, lengths, starts)
-    return jnp.argmax(next_logits, axis=-1).astype(jnp.int32), cache
+def _draw(logits, sampling, active):
+    """Each row's token under its own spec, and the rows' sampling
+    state one step on. Row i draws with the key
+    ``fold_in(fold_in(key(seed), branch), step)``, the same in a
+    prefill and in a decode round, so a branch's whole stream is one
+    unbroken (seed, branch, step) sequence; a temperature-0 row takes
+    the argmax. ``logprob`` sums the chosen tokens' log-probabilities
+    under the model distribution: best-of-n ranks by it at retirement
+    and needs no fetch before."""
+    keys = decoding.row_keys(sampling["seed"], sampling["branch"],
+                             sampling["step"])
+    toks = decoding.sample_rows(logits, sampling["temp"], sampling["top_k"],
+                                sampling["top_p"], keys).astype(jnp.int32)
+    logprob = sampling["logprob"] + decoding.token_logprobs(logits, toks)
+    return toks, dict(
+        sampling,
+        step=jnp.where(active, sampling["step"] + 1, sampling["step"]),
+        logprob=jnp.where(active, logprob, sampling["logprob"]))
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _serve_prefill_lora(model, params, cache, tokens, lengths, starts,
-                        bank, ids):
-    """LoRA twin of :func:`_serve_prefill`: same math plus per-row
-    adapter deltas. A separate jit (not a None-bank branch) keeps the
-    base path's trace free of the bank pytree."""
+def _serve_prefill(model, params, cache, tokens, lengths, starts,
+                   lora=None, sampling=None):
+    """Batch-of-one (suffix) prefill and the first token, chosen on the
+    device so that the only host transfer is the token itself:
+    ``(tokens, filled (1, P_pad, ...) row cache, sampling one step
+    on)``. ``starts`` (1,) int32 is the number of rows already restored
+    from the prefix cache (0 for a miss). ``lora`` is the model's
+    keywords for a bank (``lora_bank``, and ``adapter_ids`` (1,)).
+    Without ``sampling`` the token is the (1,) argmax; with it (the
+    step's layout, one row for each of the request's ``n`` branches)
+    the one prompt's logits fan into ``n`` first tokens. An absent
+    argument is an empty pytree: the program lowers as if the argument
+    had never been written, and its output is absent too."""
     next_logits, cache = _apply_prefill_at(
-        model, params, cache, tokens, lengths, starts,
-        lora_bank=bank, adapter_ids=ids)
-    return jnp.argmax(next_logits, axis=-1).astype(jnp.int32), cache
+        model, params, cache, tokens, lengths, starts, **(lora or {}))
+    if sampling is None:
+        return jnp.argmax(next_logits, axis=-1).astype(jnp.int32), cache, None
+    n = sampling["step"].shape[0]
+    toks, sampling = _draw(
+        jnp.broadcast_to(next_logits[0], (n, next_logits.shape[-1])),
+        sampling, True)
+    return toks, cache, sampling
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _serve_step(model, params, cache, last_tok, lengths, active):
+def _serve_step(model, params, cache, last_tok, lengths, active,
+                lora=None, sampling=None):
     """One decode round over all slots: feed every row its last token
-    at its own cache depth, take greedy argmax. Inactive rows still
-    flow through the batched apply (a dynamic batch size would
-    recompile); their tokens/depths are frozen by the ``active`` mask
-    and their cache writes land in retired rows that the next
-    occupant's prefill overwrites (and masks until it grows there)."""
+    at its own cache depth and choose its next, by argmax or, with
+    ``sampling`` (per-slot ``temp``, ``top_k``, ``top_p``, ``seed``,
+    ``branch``, ``step``, ``logprob``), under each row's own spec: a
+    greedy row in a mixed batch sees the same logits and keeps its
+    argmax. Inactive rows still flow through the batched apply (a
+    dynamic batch size would recompile); their tokens/depths are frozen
+    by the ``active`` mask and their cache writes land in retired rows
+    that the next occupant's prefill overwrites (and masks until it
+    grows there). ``lora`` and absent arguments as in
+    :func:`_serve_prefill`, with ``adapter_ids`` (slots,)."""
     logits, cache = _apply_decode_ragged(
-        model, params, cache, last_tok, lengths,
+        model, params, cache, last_tok, lengths, **(lora or {}),
         **_mask_kw(model, active[:, None]))
-    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    if sampling is None:
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    else:
+        nxt, sampling = _draw(logits, sampling, active)
     nxt = jnp.where(active, nxt, last_tok)
     lengths = jnp.where(active, lengths + 1, lengths)
-    return nxt, lengths, cache
-
-
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _serve_step_lora(model, params, cache, last_tok, lengths, active,
-                     bank, ids):
-    """LoRA twin of :func:`_serve_step`: each row applies its own
-    adapter's deltas (ids is the per-slot adapter mirror), so one
-    batched decode serves every tenant's fine-tune at once."""
-    logits, mutated = model.apply(
-        {"params": params, "cache": cache}, last_tok[:, None],
-        train=False, decode=True, last_only=True, mutable=["cache"],
-        cache_positions=lengths.astype(jnp.int32),
-        lora_bank=bank, adapter_ids=ids,
-    )
-    nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-    nxt = jnp.where(active, nxt, last_tok)
-    lengths = jnp.where(active, lengths + 1, lengths)
-    return nxt, lengths, mutated["cache"]
-
-
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _serve_prefill_logits(model, params, cache, tokens, lengths, starts):
-    """Sampled-path prefill twin of :func:`_serve_prefill`: returns the
-    (1, V) next-token logits instead of their argmax, so the host can
-    fan ONE prompt's logits into n branch first-tokens (and their
-    logprobs) without a second forward. The greedy path never routes
-    here — its jit (and bytes) are untouched."""
-    return _apply_prefill_at(model, params, cache, tokens, lengths,
-                             starts)
-
-
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _serve_prefill_logits_lora(model, params, cache, tokens, lengths,
-                               starts, bank, ids):
-    """LoRA twin of :func:`_serve_prefill_logits`."""
-    return _apply_prefill_at(model, params, cache, tokens, lengths,
-                             starts, lora_bank=bank, adapter_ids=ids)
-
-
-@functools.partial(jax.jit, static_argnums=(1,))
-def _sample_first(next_logits, n, temp, top_k, top_p, seed, step0):
-    """First token for each of a request's ``n`` branches from one
-    prefill's (1, V) logits: branch k draws with key
-    ``fold_in(fold_in(key(seed), k), step0)`` — the same derivation
-    the decode-step jit uses, so a branch's whole stream is one
-    unbroken (seed, branch, step) sequence. Returns ((n,) int32
-    tokens, (n,) float32 logprobs under the model distribution).
-    ``n`` is static: one program per distinct branch count, not per
-    spec."""
-    row = next_logits[0]
-    logits = jnp.broadcast_to(row, (n, row.shape[-1]))
-    branches = jnp.arange(n, dtype=jnp.int32)
-    seeds = jnp.full((n,), seed, jnp.int32)
-    steps = jnp.full((n,), step0, jnp.int32)
-    temps = jnp.full((n,), temp, jnp.float32)
-    top_ks = jnp.full((n,), top_k, jnp.int32)
-    top_ps = jnp.full((n,), top_p, jnp.float32)
-    keys = decoding.row_keys(seeds, branches, steps)
-    toks = decoding.sample_rows(logits, temps, top_ks, top_ps,
-                                keys).astype(jnp.int32)
-    return toks, decoding.token_logprobs(logits, toks)
-
-
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _serve_step_sample(model, params, cache, last_tok, lengths, active,
-                       temps, top_ks, top_ps, seeds, branches, steps,
-                       logprob):
-    """Sampled twin of :func:`_serve_step`: the SAME ragged forward
-    (greedy rows in a mixed batch still see bit-identical logits and
-    take the per-row greedy ``where`` branch), then per-row seeded
-    sampling with traced temperature/top_k/top_p. Per-row RNG steps
-    and cumulative logprobs advance INSIDE the jit, so the hot loop
-    stays transfer-free and best-of-n ranking needs no per-round
-    fetch."""
-    logits, cache = _apply_decode_ragged(
-        model, params, cache, last_tok, lengths,
-        **_mask_kw(model, active[:, None]))
-    keys = decoding.row_keys(seeds, branches, steps)
-    drawn = decoding.sample_rows(logits, temps, top_ks, top_ps,
-                                 keys).astype(jnp.int32)
-    logprob = jnp.where(active,
-                        logprob + decoding.token_logprobs(logits, drawn),
-                        logprob)
-    nxt = jnp.where(active, drawn, last_tok)
-    lengths = jnp.where(active, lengths + 1, lengths)
-    steps = jnp.where(active, steps + 1, steps)
-    return nxt, lengths, steps, logprob, cache
-
-
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _serve_step_sample_lora(model, params, cache, last_tok, lengths,
-                            active, bank, ids, temps, top_ks, top_ps,
-                            seeds, branches, steps, logprob):
-    """LoRA twin of :func:`_serve_step_sample`."""
-    raw, mutated = model.apply(
-        {"params": params, "cache": cache}, last_tok[:, None],
-        train=False, decode=True, last_only=True, mutable=["cache"],
-        cache_positions=lengths.astype(jnp.int32),
-        lora_bank=bank, adapter_ids=ids,
-    )
-    logits = raw[:, -1, :]
-    keys = decoding.row_keys(seeds, branches, steps)
-    drawn = decoding.sample_rows(logits, temps, top_ks, top_ps,
-                                 keys).astype(jnp.int32)
-    logprob = jnp.where(active,
-                        logprob + decoding.token_logprobs(logits, drawn),
-                        logprob)
-    nxt = jnp.where(active, drawn, last_tok)
-    lengths = jnp.where(active, lengths + 1, lengths)
-    steps = jnp.where(active, steps + 1, steps)
-    return nxt, lengths, steps, logprob, mutated["cache"]
+    return nxt, lengths, cache, sampling
 
 
 @functools.partial(jax.jit, static_argnums=(2,), donate_argnums=(1,))
@@ -455,33 +389,30 @@ class ServingEngine:
         self._h_last = np.zeros((max_slots,), np.int32)
         self._h_depth = np.zeros((max_slots,), np.int32)
         self._h_active = np.zeros((max_slots,), bool)
-        self._h_adapter = np.zeros((max_slots,), np.int32)
         self._d_last = jnp.asarray(self._h_last)
         self._d_depth = jnp.asarray(self._h_depth)
         self._d_active = jnp.asarray(self._h_active)
-        self._d_adapter = jnp.asarray(self._h_adapter)
-        # Prism per-row sampling mirrors (serve/decoding.py): synced on
-        # admission/retirement like the four above; the decode-sample
-        # jit consumes them as traced arrays so any greedy/sampled row
-        # mix runs one compiled program. Steps + cumulative logprobs
-        # advance ON DEVICE inside the jit.
-        self._h_temp = np.zeros((max_slots,), np.float32)
-        self._h_topk = np.zeros((max_slots,), np.int32)
-        self._h_topp = np.zeros((max_slots,), np.float32)
-        self._h_seed = np.zeros((max_slots,), np.int32)
-        self._h_branch = np.zeros((max_slots,), np.int32)
-        self._h_step = np.zeros((max_slots,), np.int32)
-        self._d_temp = jnp.asarray(self._h_temp)
-        self._d_topk = jnp.asarray(self._h_topk)
-        self._d_topp = jnp.asarray(self._h_topp)
-        self._d_seed = jnp.asarray(self._h_seed)
-        self._d_branch = jnp.asarray(self._h_branch)
-        self._d_step = jnp.asarray(self._h_step)
-        self._d_logprob = jnp.zeros((max_slots,), jnp.float32)
-        # prefill-sampled first-token logprobs, applied to _d_logprob
-        # at the next sync (slot index -> value)
+        # the programs' ``lora`` argument: the bank and each slot's
+        # adapter id (pushed with the three mirrors above), or None, and
+        # then no program takes it and no adapter ids are kept or pushed
+        self._lora = None if lora_bank is None else dict(
+            lora_bank=lora_bank,
+            adapter_ids=jnp.zeros((max_slots,), jnp.int32))
+        # the step's ``sampling`` argument (serve/decoding.py), one row
+        # a slot: the host's mirror, and the device's copy, pushed at a
+        # sync while a sampled row is active and handed to the step only
+        # then. Traced arrays, so every mix of greedy and sampled rows
+        # runs one program; steps and running logprobs advance on the
+        # device inside it.
+        self._h_sampling = {
+            name: np.zeros((max_slots,), dtype)
+            for name, dtype in _SAMPLING_ROW.items()}
+        self._d_sampling = jax.tree.map(jnp.asarray, self._h_sampling)
+        # first-token logprobs of rows prefilled since the last sync
+        # (slot -> value): they are on the host, the older rows' sums
+        # on the device
         self._pending_logprob: dict[int, float] = {}
-        self._n_sampled = 0  # active slots needing the sampled jit
+        self._n_sampled = 0  # active rows of sampled requests
         # best-of-n bookkeeping: request_id -> {branch: (tokens, logprob)}
         self._branch_done: dict[str, dict[int, tuple]] = {}
         # incremental streaming: tokens per chunk (1 = every token is
@@ -670,6 +601,37 @@ class ServingEngine:
             self._sync_slots()
         return True
 
+    def _row_lora(self, adapter: int):
+        """The prefill's ``lora`` argument: the bank and the one row's
+        adapter id."""
+        return None if self._lora is None else dict(
+            self._lora, adapter_ids=jnp.asarray([adapter], jnp.int32))
+
+    def warmup(self, prompt_lens=(8,)) -> None:
+        """Compile what this engine runs for greedy requests (with its
+        bank, if it has one) before a driving thread does: the zeroed
+        row cache and the prefill of each prompt bucket, the row insert,
+        the decode step. One throwaway forward a bucket into a throwaway
+        batch cache: the engine's own state is not touched, so a
+        replica may be warmed while its driver loop idles."""
+        cache = _fresh_cache(self.model, self.max_slots, self.max_seq_len)
+        for plen in prompt_lens:
+            pad = min(_bucket_len(int(plen)), self.max_seq_len)
+            _, row, _ = _serve_prefill(
+                self.model, self.params, _fresh_cache(self.model, 1, pad),
+                jnp.zeros((1, pad), jnp.int32),
+                jnp.asarray([int(plen)], jnp.int32),
+                jnp.zeros((1,), jnp.int32), self._row_lora(0), None)
+            # static arguments as _prefill_into passes them: a default left
+            # out is another program to jax.jit
+            cache = _insert_row(cache, row, 0, totals=self._counter_leaf,
+                                count=True)
+        idle = jnp.zeros((self.max_slots,), jnp.int32)
+        nxt, _, _, _ = _serve_step(
+            self.model, self.params, cache, idle, idle,
+            jnp.zeros((self.max_slots,), bool), self._lora, None)
+        np.asarray(nxt)  # block until compiled + executed
+
     def _prefill_into(self, slots: list, req: Request) -> None:
         """Prefill ONE request into ``len(slots)`` batch rows. The
         prompt forward runs once; branched requests fan the resulting
@@ -710,44 +672,29 @@ class ServingEngine:
                         row_cache, self._store, bs, table, np.int32(nb))
                 trace.on_segment(req.trace, "restore", t_restore,
                                  time.monotonic(), blocks=nb, cached=m)
-            logps: Optional[list] = None
+            # the request's rows of the sampling mirror: its spec, one
+            # RNG lane a branch, all at the leg's first step. Slots are
+            # reused: a greedy row landing on a retired sampled row must
+            # read temperature 0 (the step's per-row greedy branch)
+            h = self._h_sampling
+            h["temp"][slots] = spec.temperature if sampled else 0.0
+            h["top_k"][slots] = spec.top_k if sampled else 0
+            h["top_p"][slots] = spec.top_p if sampled else 0.0
+            h["seed"][slots] = spec.seed if sampled else 0
+            h["branch"][slots] = np.arange(len(slots))
+            h["step"][slots] = req.decode_step0
+            h["logprob"][slots] = 0.0
+            rows = {k: v[slots] for k, v in h.items()} if sampled else None
             with obs.span("serve/prefill", request=req.request_id,
                           prompt_len=L, cached=m):
-                if not sampled:
-                    # inert-defaults contract: this arm is the EXACT
-                    # pre-Prism call (test_quality pins its shape), so
-                    # greedy requests stay byte-identical
-                    if self.lora_bank is None:
-                        tok0, row_cache = _serve_prefill(
-                            self.model, self.params, row_cache,
-                            jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
-                            jnp.asarray([m], jnp.int32))
-                    else:
-                        tok0, row_cache = _serve_prefill_lora(
-                            self.model, self.params, row_cache,
-                            jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
-                            jnp.asarray([m], jnp.int32), self.lora_bank,
-                            jnp.asarray([req.adapter], jnp.int32))
-                    firsts = [int(np.asarray(tok0)[0])]
-                else:
-                    if self.lora_bank is None:
-                        next_logits, row_cache = _serve_prefill_logits(
-                            self.model, self.params, row_cache,
-                            jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
-                            jnp.asarray([m], jnp.int32))
-                    else:
-                        next_logits, row_cache = _serve_prefill_logits_lora(
-                            self.model, self.params, row_cache,
-                            jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
-                            jnp.asarray([m], jnp.int32), self.lora_bank,
-                            jnp.asarray([req.adapter], jnp.int32))
-                    toks, lps = _sample_first(
-                        next_logits, len(slots),
-                        np.float32(spec.temperature), np.int32(spec.top_k),
-                        np.float32(spec.top_p), np.int32(spec.seed),
-                        np.int32(req.decode_step0))
-                    firsts = [int(t) for t in np.asarray(toks)]
-                    logps = [float(x) for x in np.asarray(lps)]
+                tok0, row_cache, drawn = _serve_prefill(
+                    self.model, self.params, row_cache,
+                    jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
+                    jnp.asarray([m], jnp.int32),
+                    self._row_lora(req.adapter), rows)
+                firsts = [int(t) for t in np.asarray(tok0)]
+                if sampled:
+                    h["logprob"][slots] = np.asarray(drawn["logprob"])
             if match is not None:
                 # restored rows are copied out; the COW tail pin can drop
                 self.prefix_cache.finish_restore(match)
@@ -778,16 +725,7 @@ class ServingEngine:
                     self._h_last[slot] = firsts[k]
                     self._h_depth[slot] = L
                     self._h_active[slot] = True
-                    self._h_adapter[slot] = req.adapter
-                    # reset the sampling mirrors: slots are reused, and a
-                    # greedy row landing on a retired sampled row must read
-                    # temperature 0 (the jit's per-row greedy branch)
-                    self._h_temp[slot] = spec.temperature if sampled else 0.0
-                    self._h_topk[slot] = spec.top_k if sampled else 0
-                    self._h_topp[slot] = spec.top_p if sampled else 0.0
-                    self._h_seed[slot] = spec.seed if sampled else 0
-                    self._h_branch[slot] = k
-                    self._pending_logprob[slot] = logps[k] if sampled else 0.0
+                    self._pending_logprob[slot] = float(h["logprob"][slot])
                     self._c_tokens.inc()  # the prefill-produced first token
                     flight.record("serve", "admit", step=self.scheduler.round,
                                   note=f"{sids[k]} slot={slot} L={L} "
@@ -815,34 +753,12 @@ class ServingEngine:
         # injected slow round shows up in the latency histograms
         # exactly like a real one
         chaos.on_step(self.scheduler.round)
-        if self._n_sampled == 0:
-            # inert-defaults contract: an all-greedy batch runs the
-            # EXACT pre-Prism jits (test_quality pins the call shape),
-            # so default requests stay byte-identical
-            if self.lora_bank is None:
-                nxt, depth, self._cache = _serve_step(
-                    self.model, self.params, self._cache, self._d_last,
-                    self._d_depth, self._d_active)
-            else:
-                nxt, depth, self._cache = _serve_step_lora(
-                    self.model, self.params, self._cache, self._d_last,
-                    self._d_depth, self._d_active, self.lora_bank,
-                    self._d_adapter)
-        elif self.lora_bank is None:
-            nxt, depth, self._d_step, self._d_logprob, self._cache = \
-                _serve_step_sample(
-                    self.model, self.params, self._cache, self._d_last,
-                    self._d_depth, self._d_active, self._d_temp,
-                    self._d_topk, self._d_topp, self._d_seed,
-                    self._d_branch, self._d_step, self._d_logprob)
-        else:
-            nxt, depth, self._d_step, self._d_logprob, self._cache = \
-                _serve_step_sample_lora(
-                    self.model, self.params, self._cache, self._d_last,
-                    self._d_depth, self._d_active, self.lora_bank,
-                    self._d_adapter, self._d_temp, self._d_topk,
-                    self._d_topp, self._d_seed, self._d_branch,
-                    self._d_step, self._d_logprob)
+        nxt, depth, self._cache, drawn = _serve_step(
+            self.model, self.params, self._cache, self._d_last,
+            self._d_depth, self._d_active, self._lora,
+            self._d_sampling if self._n_sampled else None)
+        if drawn is not None:
+            self._d_sampling = drawn
         self._d_last, self._d_depth = nxt, depth
         host_tok = np.asarray(nxt)
         return host_tok, time.monotonic() - t0
@@ -931,7 +847,7 @@ class ServingEngine:
         # token's logprob to device — so the pending value wins.
         lp = self._pending_logprob.pop(slot, None)
         if lp is None:
-            lp = float(np.asarray(self._d_logprob)[slot])
+            lp = float(np.asarray(self._d_sampling["logprob"])[slot])
         self.scheduler.release_branch(req, s.seq_id)
         done = self._branch_done.setdefault(req.request_id, {})
         done[s.branch] = (list(s.tokens), lp)
@@ -1079,8 +995,8 @@ class ServingEngine:
         if self.tag:
             rec["replica"] = self.tag
         # Prism keys: absent for default requests (key-absent wire
-        # discipline — a greedy, non-streaming run's JSONL is
-        # byte-identical to a pre-Prism build)
+        # discipline: a greedy, non-streaming run's JSONL holds
+        # nothing of the decode policy)
         if req.decode is not None:
             rec["decode"] = req.decode.to_wire()
         if req.n_best is not None:
@@ -1151,36 +1067,33 @@ class ServingEngine:
 
     def _sync_slots(self) -> None:
         """Push the host slot mirrors to device (admission/retirement
-        path only — never per round)."""
+        path only — never per round), each only if a program of this
+        engine, or of this batch, reads it."""
         self._d_last = jnp.asarray(self._h_last)
         self._d_depth = jnp.asarray(self._h_depth)
         self._d_active = jnp.asarray(self._h_active)
-        self._d_adapter = jnp.asarray(self._h_adapter)
-        # Prism mirrors: recompute which rows need the sampled jit and
-        # each row's RNG step (step0 + emitted — recomputable host-side
-        # by design, so a flip-drill mid-round resync cannot skew the
-        # device counter)
-        self._n_sampled = 0
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
-            self._h_step[i] = s.step0 + s.emitted
-            if s.req.decode is not None and s.req.decode.sampled:
-                self._n_sampled += 1
-        if self._n_sampled or self._pending_logprob:
-            # logprobs accumulate ON DEVICE: pull, overlay the prefill
-            # first-token values, push back (retirement path only)
-            h_logprob = np.asarray(self._d_logprob).copy()
+        if self._lora is not None:
+            self._lora = dict(self._lora, adapter_ids=jnp.asarray(np.array(
+                [s.req.adapter if s is not None else 0
+                 for s in self._slots], np.int32)))
+        live = [(i, s) for i, s in enumerate(self._slots) if s is not None]
+        self._n_sampled = sum(
+            s.req.decode is not None and s.req.decode.sampled
+            for _, s in live)
+        if self._n_sampled:
+            h = self._h_sampling
+            # a row's RNG step is step0 + emitted: recomputable here by
+            # design, so a flip drill's resync mid-round cannot skew the
+            # device's counter
+            for i, s in live:
+                h["step"][i] = s.step0 + s.emitted
+            # logprobs accumulate on the device: pull, overlay the
+            # first-token values of the rows prefilled since, push back
+            h["logprob"] = np.array(self._d_sampling["logprob"])
             for slot, v in self._pending_logprob.items():
-                h_logprob[slot] = v
-            self._pending_logprob.clear()
-            self._d_logprob = jnp.asarray(h_logprob)
-        self._d_temp = jnp.asarray(self._h_temp)
-        self._d_topk = jnp.asarray(self._h_topk)
-        self._d_topp = jnp.asarray(self._h_topp)
-        self._d_seed = jnp.asarray(self._h_seed)
-        self._d_branch = jnp.asarray(self._h_branch)
-        self._d_step = jnp.asarray(self._h_step)
+                h["logprob"][slot] = v
+            self._d_sampling = jax.tree.map(jnp.asarray, h)
+        self._pending_logprob.clear()
 
     def flops_per_token(self) -> int:
         """Analytic forward FLOPs of ONE token through this model

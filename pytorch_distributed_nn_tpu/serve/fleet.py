@@ -441,43 +441,15 @@ class Fleet:
                 self._set_state(h, READY, reason="join:warm+beat")
 
     def warmup(self, prompt_lens=(8,), *, engine=None) -> None:
-        """Compile the serve jits (prefill per prompt bucket, row
-        insert, the batched decode step) before any worker thread
-        runs them. Without this, the first decode on a cold process
-        stalls a worker for the whole XLA compile — long enough to
-        starve its progress watchdog and read as a hang to the failure
-        detector (a false replica_down on a healthy fleet). One
-        throwaway forward per bucket, on a row from ``_fresh_cache``:
-        that compiles the bucket's zero-cache program too, so a cold
-        replica's first admission pays no compile for its row either.
-        The jit cache is keyed on the model so every replica shares
-        the result."""
-        from pytorch_distributed_nn_tpu.serve.engine import (
-            _bucket_len,
-            _fresh_cache,
-            _insert_row,
-            _serve_prefill,
-            _serve_step,
-        )
-        import jax.numpy as jnp
+        """Compile a replica's serve programs
+        (:meth:`ServingEngine.warmup`) before any worker thread runs
+        them. Without this, the first decode on a cold process stalls a
+        worker for the whole XLA compile — long enough to starve its
+        progress watchdog and read as a hang to the failure detector (a
+        false replica_down on a healthy fleet). The jit cache is keyed
+        on the model so every replica shares the result."""
         eng = engine if engine is not None else self._replicas[0].engine
-        max_slots = eng.max_slots
-        cache = _fresh_cache(self.model, max_slots, eng.max_seq_len)
-        for plen in prompt_lens:
-            pad = min(_bucket_len(int(plen)), eng.max_seq_len)
-            row = _fresh_cache(self.model, 1, pad)
-            _, row = _serve_prefill(
-                self.model, self.params, row,
-                jnp.zeros((1, pad), jnp.int32),
-                jnp.asarray([int(plen)], jnp.int32),
-                jnp.zeros((1,), jnp.int32))
-            cache = _insert_row(cache, row, 0)
-        nxt, _, _ = _serve_step(
-            self.model, self.params, cache,
-            jnp.zeros((max_slots,), jnp.int32),
-            jnp.zeros((max_slots,), jnp.int32),
-            jnp.zeros((max_slots,), bool))
-        np.asarray(nxt)  # block until compiled + executed
+        eng.warmup(prompt_lens)
 
     def start(self, *, warmup_prompt_lens=(8,)) -> "Fleet":
         """Start every replica's worker plus the supervisor thread.

@@ -1460,40 +1460,82 @@ def test_audit_fingerprint_fold_is_single_homed_in_engine():
         f"ServingEngine._finish_record, found {callers}")
 
 
-def test_decode_spec_defaults_are_provably_inert():
-    """ISSUE 20 lint: the all-greedy arm of ``_decode_round`` calls the
-    pre-Prism ``_serve_step`` with the EXACT original argument shape —
-    ``(self.model, self.params, self._cache, self._d_last,
-    self._d_depth, self._d_active)`` and nothing else. Default
-    ``DecodeSpec()`` requests ride this arm (the scheduler normalizes
-    an explicit default to None), so greedy outputs, JSONL records, and
-    fingerprint chains stay byte-identical to main; threading a sampled
-    mirror into this call would silently retrace every greedy batch."""
-    eng = (Path(__file__).parent.parent / "pytorch_distributed_nn_tpu"
-           / "serve" / "engine.py")
-    tree = ast.parse(eng.read_text())
-    calls = []
-    for node in ast.walk(tree):
-        if isinstance(node, ast.FunctionDef) and \
-                node.name == "_decode_round":
-            for call in ast.walk(node):
-                if (isinstance(call, ast.Call)
-                        and isinstance(call.func, ast.Name)
-                        and call.func.id == "_serve_step"):
-                    calls.append(call)
-    assert len(calls) == 1, "_decode_round must call _serve_step once"
-    call = calls[0]
-    got = []
-    for arg in call.args:
-        assert (isinstance(arg, ast.Attribute)
-                and isinstance(arg.value, ast.Name)
-                and arg.value.id == "self"), ast.dump(arg)
-        got.append(arg.attr)
-    assert not call.keywords, "greedy _serve_step call grew kwargs"
-    assert got == ["model", "params", "_cache", "_d_last", "_d_depth",
-                   "_d_active"], (
-        f"greedy _serve_step arg shape changed: {got} — the inert-"
-        f"defaults contract (DecodeSpec() == pre-Prism bytes) is off")
+# stablehlo.sort in a greedy program: the model's own (LongCat groups
+# its tokens by expert with one argsort), never the sampler's
+_OWN_SORTS = dict(llama=0, longcat=1)
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+@pytest.mark.parametrize("family", ["llama", "longcat"])
+def test_decode_spec_defaults_are_provably_inert(tiny_llama, family,
+                                                 program):
+    """A greedy batch of an engine without a bank runs a program that
+    knows nothing of sampling or adapters: with ``lora`` and
+    ``sampling`` absent, ``_serve_step`` / ``_serve_prefill`` lower,
+    apart from the module's name, to the text of a plain function
+    written here without those arguments — no sort or random bits of
+    the sampler, no parameter beyond params, cache and the three slot
+    arrays. Default ``DecodeSpec()`` requests ride it (the scheduler
+    normalizes an explicit default to None). With ``sampling`` present
+    the same reading finds the sampler: the witness can see it."""
+    from pytorch_distributed_nn_tpu.inference.generate import (
+        _apply_decode_ragged,
+        init_cache,
+    )
+    from pytorch_distributed_nn_tpu.serve import engine
+    from test_longcat_flash import _model as small_longcat
+
+    model = tiny_llama[0] if family == "llama" else small_longcat(2, 0)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                           train=False))["params"]
+    slots, pad = 4, 16
+
+    def vec(n, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct((n,), dtype)
+
+    if program == "step":
+        served, rows = engine._serve_step, slots
+        cache = jax.eval_shape(lambda: init_cache(model, slots, 64))
+        args = (model, params, cache, vec(slots), vec(slots),
+                vec(slots, jnp.bool_))
+
+        def plain(model, params, cache, last_tok, lengths, active):
+            logits, cache = _apply_decode_ragged(
+                model, params, cache, last_tok, lengths,
+                **engine._mask_kw(model, active[:, None]))
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (jnp.where(active, nxt, last_tok),
+                    jnp.where(active, lengths + 1, lengths), cache)
+    else:
+        served, rows = engine._serve_prefill, 3
+        cache = jax.eval_shape(lambda: init_cache(model, 1, pad))
+        args = (model, params, cache,
+                jax.ShapeDtypeStruct((1, pad), jnp.int32), vec(1), vec(1))
+
+        def plain(model, params, cache, tokens, lengths, starts):
+            next_logits, cache = engine._apply_prefill_at(
+                model, params, cache, tokens, lengths, starts)
+            return jnp.argmax(next_logits, axis=-1).astype(jnp.int32), cache
+
+    want = jax.jit(plain, static_argnums=(0,), donate_argnums=(2,)) \
+        .lower(*args).as_text()
+    name = f"jit_{served.__name__}"
+    # arguments left out, and passed as None the way the engine does
+    for text in (served.lower(*args).as_text(),
+                 served.lower(*args, None, None).as_text()):
+        assert name in text
+        assert text.replace(name, "jit_plain") == want
+    signature = re.search(r"func\.func public @main\((.*?)\) ->", text)
+    assert len(re.findall(r"%arg\d+:", signature.group(1))) == len(
+        jax.tree.leaves((params, cache))) + 3
+    assert text.count("stablehlo.sort") == _OWN_SORTS[family]
+    assert "threefry" not in text and "stablehlo.rng" not in text
+
+    sampling = {k: vec(rows, dt) for k, dt in engine._SAMPLING_ROW.items()}
+    sampled = served.lower(*args, None, sampling).as_text()
+    assert sampled.count("stablehlo.sort") > _OWN_SORTS[family]
+    assert "threefry" in sampled
 
 
 def test_branch_fork_is_single_homed_in_scheduler():

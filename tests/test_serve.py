@@ -24,6 +24,7 @@ import pytest
 
 from pytorch_distributed_nn_tpu import obs
 from pytorch_distributed_nn_tpu.inference.generate import generate, init_cache
+from pytorch_distributed_nn_tpu.nn.lora import init_lora_bank
 from pytorch_distributed_nn_tpu.obs import flight
 from pytorch_distributed_nn_tpu.runtime import chaos
 from pytorch_distributed_nn_tpu.serve import (
@@ -250,6 +251,103 @@ def test_engine_budget_one_matches_prefill_argmax(tiny_llama):
 # The zeroed cache a prefill writes into (ISSUE 28): one compiled
 # program, the same tree as init_cache's, fresh buffers on every call
 # ---------------------------------------------------------------------------
+
+class _MaskedLoraStub:
+    """Stands where a model stands in the step, for a model that takes
+    both a bank and the token mask. Row i's next token is its adapter's
+    id plus the bank's shift; the mask it was given comes back as the
+    cache."""
+
+    takes_token_mask = True
+
+    def apply(self, variables, tokens, *, lora_bank, adapter_ids,
+              token_mask, **kw):
+        logits = jax.nn.one_hot(adapter_ids + lora_bank["shift"], 8)
+        return logits[:, None, :], {"cache": {"mask": token_mask}}
+
+
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+def test_token_mask_reaches_the_step_of_an_engine_with_a_bank(sampled):
+    """A model that takes ``token_mask`` gets the active rows' mask in
+    every combination the step has: with a bank, and with a bank and
+    sampled rows (temperature 0 here, so the rows keep their argmax)."""
+    active = np.asarray([True, False, True])
+    sampling = None
+    if sampled:
+        sampling = {k: np.zeros((3,), dt)
+                    for k, dt in engine_mod._SAMPLING_ROW.items()}
+    nxt, lengths, cache, drawn = engine_mod._serve_step(
+        _MaskedLoraStub(), {}, {"mask": np.zeros((3, 1), bool)},
+        np.asarray([7, 7, 7], np.int32), np.asarray([4, 5, 6], np.int32),
+        active, dict(lora_bank={"shift": np.int32(2)},
+                     adapter_ids=np.asarray([0, 1, 3], np.int32)),
+        sampling)
+    np.testing.assert_array_equal(cache["mask"], active[:, None])
+    np.testing.assert_array_equal(nxt, [2, 7, 5])
+    np.testing.assert_array_equal(lengths, [5, 5, 7])
+    assert (drawn is None) == (not sampled)
+    if sampled:
+        np.testing.assert_array_equal(drawn["step"], [1, 0, 1])
+
+
+def test_sync_pushes_only_the_mirrors_a_program_reads(tiny_llama,
+                                                      monkeypatch):
+    """A sync uploads the three slot arrays; the adapter ids only for
+    an engine with a bank; the sampling rows only while a sampled row
+    is active."""
+    model, params = tiny_llama
+    pushed = []
+    upload = engine_mod.jnp.asarray
+    monkeypatch.setattr(
+        engine_mod.jnp, "asarray",
+        lambda x, *a, **kw: (pushed.append(x), upload(x, *a, **kw))[1])
+
+    def pushes(eng):
+        del pushed[:]
+        eng._sync_slots()
+        return len(pushed)
+
+    kw = dict(max_slots=2, max_seq_len=64, block_size=8)
+    eng = ServingEngine(model, params, **kw)
+    assert eng._lora is None
+    assert pushes(eng) == 3
+    bank = init_lora_bank(model, num_adapters=2, rank=2)
+    assert pushes(ServingEngine(model, params, lora_bank=bank, **kw)) == 4
+    eng.submit(np.arange(1, 9, dtype=np.int32), 4,
+               decode=DecodeSpec(temperature=0.8, seed=1))
+    eng.step()
+    assert eng._n_sampled == 1
+    assert pushes(eng) == 3 + len(engine_mod._SAMPLING_ROW)
+    eng.run_until_idle()
+    assert eng._n_sampled == 0 and pushes(eng) == 3
+
+
+@pytest.mark.parametrize("with_bank", [False, True], ids=["plain", "bank"])
+def test_warmup_compiles_what_the_engine_then_runs(tiny_llama, with_bank):
+    """After ``warmup`` a greedy request of a warmed prompt bucket
+    compiles nothing, with a bank or without, and the warm-up leaves
+    the engine as it found it."""
+    model, params = tiny_llama
+    bank = init_lora_bank(model, num_adapters=2, rank=2) \
+        if with_bank else None
+    # a batch size no other test of the session uses: the step is new
+    # here (a prefill's row knows nothing of the batch)
+    eng = ServingEngine(model, params, max_slots=5, max_seq_len=80,
+                        block_size=8, lora_bank=bank)
+    programs = (engine_mod._zero_cache, engine_mod._serve_prefill,
+                engine_mod._insert_row, engine_mod._serve_step)
+    cold = [f._cache_size() for f in programs]
+    eng.warmup((8,))
+    warm = [f._cache_size() for f in programs]
+    assert warm[3] > cold[3]
+    assert eng.active_slots == 0 and not eng.has_work
+    r = eng.submit(np.arange(1, 9, dtype=np.int32), 3,
+                   adapter=int(with_bank))
+    eng.run_until_idle()
+    assert r.state == "done"
+    assert [f._cache_size() for f in programs] == warm
+
 
 @pytest.mark.parametrize("batch", [1, 3], ids=["row", "max_slots"])
 def test_zero_cache_is_init_caches_tree_leaf_for_leaf(tiny_llama, batch):
