@@ -589,7 +589,8 @@ def serve_kernel_counts(model, params, engine, buckets) -> dict:
     counts = {"decode_step": kernel_count(eng._serve_step.lower(
         model, p, jax.eval_shape(lambda: init_cache(model, slots,
                                                     max_seq)),
-        vec(slots), vec(slots), vec(slots, jnp.bool_)))}
+        vec(slots), vec(slots), vec(slots, jnp.bool_), vec(slots),
+        jax.ShapeDtypeStruct((), jnp.int32)))}
     for b in sorted(buckets):
         counts[f"prefill_{b}"] = kernel_count(eng._serve_prefill.lower(
             model, p, jax.eval_shape(lambda: init_cache(model, 1, b)),

@@ -33,14 +33,47 @@ empty pytree to ``jax.jit``: the program lowers to the text of a
 function that never had the argument (tests/test_quality.py reads it),
 so a greedy batch of an engine without a bank pays for neither.
 
+The loop keeps one decode round in flight. In steady state a call of
+:meth:`ServingEngine._decode_round` dispatches round n+1 and *then*
+fetches round n's tokens, so all the host does for a round (the fetch,
+the hooks, streaming, retirement, a retire's ``_save_blocks``, the next
+``next_admissions``) runs while the chip computes the next one; JAX's
+asynchronous dispatch does the rest, each step's outputs being the next
+step's inputs. Three rules make that a reordering of host work and not
+another result:
+
+- *a row stops on the device.* ``_serve_step`` carries each row's
+  remaining budget and the engine's stop token beside ``active`` and
+  returns the next round's mask: a row is frozen in the very step that
+  produces its last token, so the round already dispatched computes
+  nothing for it. The host applies the same rule (:meth:`_done`) one
+  fetch later and retires the row then.
+- *only an admission writes device slot state.* The host's mirrors
+  (``_Slot``) lag the device by the round in flight, so the host never
+  pushes them wholesale: an admission sets the rows it filled (last
+  token, depth, active, budget; adapter id and sampling rows where the
+  engine has them) in one program of one shape, ``_write_rows``, queued
+  behind the round in flight and the rows' ``_insert_row``; a retire
+  writes nothing. (The chaos flip drill writes its one corrupted row
+  the same way, after dropping the round that was fed the true token.)
+- *a round knows its rows.* Rounds are numbered as they are dispatched
+  and a ``_Slot`` records the first round that holds it: the token a
+  round carries for a slot filled after its dispatch is the last
+  occupant's and reaches nobody.
+
+``has_work`` is true while a round is unfetched, so every driver that
+steps while it is (``run_until_idle``, ``drain``, the server's loop
+before it parks, the fleets) leaves nothing in flight; an admission's
+prefill fetch drains the device, and the next call refills the
+pipeline.
+
 Hot-loop discipline (lint-enforced): :meth:`ServingEngine._decode_round`
 contains the per-round device work and performs NO host->device
 transfers and no jnp/jax array construction — slot state (last token,
-per-row cache depth, active mask) lives on device across rounds, and
-the one device->host fetch per round (the sampled tokens the scheduler
-must see to detect eos/budget) is a single ``np.asarray`` of a (slots,)
-array. Slot mutations (admission, retirement) happen outside the hot
-method and push the refreshed slot arrays once.
+per-row cache depth, active mask, remaining budget) lives on device
+across rounds, and the one device->host fetch per call (the tokens the
+host must see to stream them and to retire rows) is a single
+``np.asarray`` of a (slots,) array.
 
 Observability: TTFT + per-token latency histograms, batch-occupancy /
 queue-depth / KV-utilization gauges, one flight-ring ``serve`` event
@@ -51,6 +84,7 @@ per-request ``serve_request`` JSONL records through MetricsLogger.
 
 from __future__ import annotations
 
+import collections
 import functools
 import time
 from typing import Optional
@@ -175,8 +209,8 @@ def _serve_prefill(model, params, cache, tokens, lengths, starts,
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _serve_step(model, params, cache, last_tok, lengths, active,
-                lora=None, sampling=None):
+def _serve_step(model, params, cache, last_tok, lengths, active, remaining,
+                eos, lora=None, sampling=None):
     """One decode round over all slots: feed every row its last token
     at its own cache depth and choose its next, by argmax or, with
     ``sampling`` (per-slot ``temp``, ``top_k``, ``top_p``, ``seed``,
@@ -186,8 +220,15 @@ def _serve_step(model, params, cache, last_tok, lengths, active,
     dynamic batch size would recompile); their tokens/depths are frozen
     by the ``active`` mask and their cache writes land in retired rows
     that the next occupant's prefill overwrites (and masks until it
-    grows there). ``lora`` and absent arguments as in
-    :func:`_serve_prefill`, with ``adapter_ids`` (slots,)."""
+    grows there). A row stops HERE, in the step that produces its last
+    token: ``remaining`` (slots,) is the tokens a row may still emit
+    and ``eos`` the engine's stop token (a scalar, -1 for none: no
+    token is negative), and the mask that comes back is the next
+    round's, so a round dispatched before the host has seen this one's
+    tokens computes nothing for a finished row. ``lora`` and absent
+    arguments as in :func:`_serve_prefill`, with ``adapter_ids``
+    (slots,). Returns ``(tokens, lengths, active, remaining, cache,
+    sampling)``."""
     logits, cache = _apply_decode_ragged(
         model, params, cache, last_tok, lengths, **(lora or {}),
         **_mask_kw(model, active[:, None]))
@@ -197,7 +238,36 @@ def _serve_step(model, params, cache, last_tok, lengths, active,
         nxt, sampling = _draw(logits, sampling, active)
     nxt = jnp.where(active, nxt, last_tok)
     lengths = jnp.where(active, lengths + 1, lengths)
-    return nxt, lengths, cache, sampling
+    alive = active & (remaining > 1) & (nxt != eos)
+    remaining = jnp.where(active, remaining - 1, remaining)
+    return nxt, lengths, alive, remaining, cache, sampling
+
+
+@jax.jit
+def _write_rows(last_tok, lengths, active, remaining, rows,
+                adapter_ids=None, sampling=None, mirror=None):
+    """Set the slot state of the rows an admission filled, and of no
+    other: the rest are the device's, one round ahead of what the host
+    has seen. ``rows`` (5, slots) int32 is the host's ``(written, last
+    token, depth, remaining, adapter)``; a written row is active if it
+    has tokens left to emit. With ``sampling`` (the device's) and
+    ``mirror`` (the host's, every slot) a row's spec and RNG lane
+    are the host's for every slot (the device never changes them) and
+    its step and running logprob only where written. One program of one
+    shape for any number of rows; absent arguments as in
+    :func:`_serve_prefill`."""
+    written = rows[0] > 0
+    out = (jnp.where(written, rows[1], last_tok),
+           jnp.where(written, rows[2], lengths),
+           jnp.where(written, rows[3] > 0, active),
+           jnp.where(written, rows[3], remaining))
+    if adapter_ids is not None:
+        adapter_ids = jnp.where(written, rows[4], adapter_ids)
+    if sampling is not None:
+        sampling = {k: jnp.where(written, v, sampling[k])
+                    if k in ("step", "logprob") else v
+                    for k, v in mirror.items()}
+    return out, adapter_ids, sampling
 
 
 @functools.partial(jax.jit, static_argnums=(2,), donate_argnums=(1,))
@@ -296,11 +366,16 @@ class _Slot:
     """Host-side mirror of one batch row (= one decode branch)."""
 
     __slots__ = ("req", "emitted", "tokens", "depth", "cached",
-                 "seq_id", "branch", "step0", "streamed")
+                 "seq_id", "branch", "step0", "streamed", "first_round")
 
     def __init__(self, req: Request, first_token: int, depth: int,
-                 cached: int = 0, seq_id: str = "", branch: int = 0):
+                 first_round: int, cached: int = 0, seq_id: str = "",
+                 branch: int = 0):
         self.req = req
+        # the first decode round (by the engine's count of dispatches)
+        # that holds this row: an earlier round, still unfetched when
+        # the row was admitted, has the slot's last occupant's token
+        self.first_round = first_round
         self.tokens = [int(first_token)]
         self.emitted = 1
         self.depth = depth  # cache rows filled (prompt + emitted - 1)
@@ -386,31 +461,43 @@ class ServingEngine:
         )
         self.scheduler.metrics = metrics
         self._slots: list[Optional[_Slot]] = [None] * max_slots
-        self._h_last = np.zeros((max_slots,), np.int32)
-        self._h_depth = np.zeros((max_slots,), np.int32)
-        self._h_active = np.zeros((max_slots,), bool)
-        self._d_last = jnp.asarray(self._h_last)
-        self._d_depth = jnp.asarray(self._h_depth)
-        self._d_active = jnp.asarray(self._h_active)
+        # slot state (last token, cache depth, active, tokens left to
+        # emit): the device's. The step advances it and stops a row;
+        # the host writes a row only when it fills one (_write_slots)
+        # and otherwise follows one round behind, in the _Slot mirrors
+        self._d_slots = (jnp.zeros((max_slots,), jnp.int32),
+                         jnp.zeros((max_slots,), jnp.int32),
+                         jnp.zeros((max_slots,), bool),
+                         jnp.zeros((max_slots,), jnp.int32))
+        self._d_eos = jnp.asarray(
+            -1 if eos_token is None else eos_token, jnp.int32)
+        # decode rounds dispatched and not yet fetched, oldest first:
+        # (tokens, time of dispatch, the slot and sampling state the
+        # round was given). One in steady state, two for a moment
+        # inside _decode_round, none when the batch is empty.
+        self._flight: collections.deque = collections.deque()
+        self._round_no = 0  # the newest dispatched round's number
+        self._t_fetched = 0.0  # when the last fetch returned
+        self._overlapped = 0  # rounds dispatched over an unfetched one
         # the programs' ``lora`` argument: the bank and each slot's
-        # adapter id (pushed with the three mirrors above), or None, and
+        # adapter id (written with the slot state above), or None, and
         # then no program takes it and no adapter ids are kept or pushed
         self._lora = None if lora_bank is None else dict(
             lora_bank=lora_bank,
             adapter_ids=jnp.zeros((max_slots,), jnp.int32))
         # the step's ``sampling`` argument (serve/decoding.py), one row
-        # a slot: the host's mirror, and the device's copy, pushed at a
-        # sync while a sampled row is active and handed to the step only
-        # then. Traced arrays, so every mix of greedy and sampled rows
-        # runs one program; steps and running logprobs advance on the
-        # device inside it.
+        # a slot: the host's mirror, and the device's copy, written at
+        # an admission while a sampled row is live and handed to the
+        # step only then. Traced arrays, so every mix of greedy and
+        # sampled rows runs one program; steps and running logprobs
+        # advance on the device inside it.
         self._h_sampling = {
             name: np.zeros((max_slots,), dtype)
             for name, dtype in _SAMPLING_ROW.items()}
         self._d_sampling = jax.tree.map(jnp.asarray, self._h_sampling)
-        # first-token logprobs of rows prefilled since the last sync
-        # (slot -> value): they are on the host, the older rows' sums
-        # on the device
+        # first-token logprobs of the rows this admission pass
+        # prefilled (slot -> value): they are on the host, the older
+        # rows' sums on the device
         self._pending_logprob: dict[int, float] = {}
         self._n_sampled = 0  # active rows of sampled requests
         # best-of-n bookkeeping: request_id -> {branch: (tokens, logprob)}
@@ -445,6 +532,12 @@ class ServingEngine:
         self._c_stream_chunks = reg.counter(
             "serve_stream_chunks_total",
             "token chunks pushed to streaming clients")
+        # over serve_token_latency_seconds' count (every decode round)
+        # this is the share of rounds the chip did not wait for
+        self._c_overlapped = reg.counter(
+            "serve_rounds_overlapped_total",
+            "decode rounds dispatched before the previous round's "
+            "tokens were fetched")
         # a model's device-side counters (obs/device_counters.py): the
         # model names the cache leaf that holds them and its entries;
         # read every _COUNTER_ROUNDS rounds from the batch cache
@@ -481,14 +574,19 @@ class ServingEngine:
 
     @property
     def has_work(self) -> bool:
-        return self.active_slots > 0 or self.scheduler.queue_depth > 0
+        """Rows to decode, requests queued, or a dispatched round whose
+        tokens nobody has fetched yet: a driver that parks only when
+        this is false leaves nothing in flight."""
+        return self.active_slots > 0 or self.scheduler.queue_depth > 0 \
+            or bool(self._flight)
 
     # -- engine loop pieces (one driving thread) ---------------------------
 
     def step(self) -> bool:
-        """One scheduler round: admit + prefill into free slots, one
-        batched decode round, retire finished rows. Returns False when
-        there was nothing to do (caller may sleep/park)."""
+        """One scheduler round: admit + prefill into free slots,
+        dispatch the next decode round and fetch the one before it,
+        retire the rows that one finished. Returns False when there
+        was nothing to do (caller may sleep/park)."""
         sched = self.scheduler
         sched.round += 1
         with obs.span("serve/round", round=sched.round) as rnd:
@@ -499,10 +597,8 @@ class ServingEngine:
                     self.submit(np.asarray([3, 5, 7], np.int32), 2,
                                 tenant=tenant)
             changed = self._admit()
-            if self.active_slots == 0:
+            if self.active_slots == 0 and not self._flight:
                 self._g_occ.set(0)
-                if changed:
-                    self._sync_slots()
                 rnd.set(occ=0)
                 return changed
             with obs.span("serve/decode"):
@@ -510,7 +606,17 @@ class ServingEngine:
             with obs.span("serve/round_host") as host_span:
                 self.round_seconds.append(dt)
                 self._h_tok.observe(dt)
-                occ = self.active_slots
+                if self._flight:
+                    # the call left a round in flight: it dispatched it
+                    # while the round it fetched was still unfetched
+                    self._overlapped += 1
+                    self._c_overlapped.inc()
+                # the rows of the fetched round: a row admitted since
+                # its dispatch is in the round in flight, not in this
+                fetched = self._round_no - len(self._flight)
+                live = [(i, s) for i, s in enumerate(self._slots)
+                        if s is not None and s.first_round <= fetched]
+                occ = len(live)
                 self._g_occ.set(occ)
                 self._c_tokens.inc(occ)
                 self._occ_sum += occ
@@ -541,22 +647,21 @@ class ServingEngine:
                     # Lighthouse shadow/probe legs are audit duplicates, not
                     # customer traffic — their decode rounds are never billed
                     meter.on_decode_round(
-                        [s.req.tenant for s in self._slots if s is not None
-                         and s.req.tenant != audit.SHADOW_TENANT],
+                        [s.req.tenant for _, s in live
+                         if s.req.tenant != audit.SHADOW_TENANT],
                         self.flops_per_token())
-                retired = self._collect(host_tok)
-                if retired:
-                    self._sync_slots()
+                retired = self._collect(host_tok, live)
                 if sched.round % _COUNTER_ROUNDS == 0:
                     self.publish_device_counters()
                 host_span.set(retired=retired)
-            rnd.set(occ=occ - retired)
+            rnd.set(occ=self.active_slots)
             return True
 
     def publish_device_counters(self) -> None:
-        """Fetch the model's running totals from the batch cache (they
-        are there since the round's fetch: nothing to wait for) and add
-        what is new to the registry."""
+        """Fetch the model's running totals from the batch cache and
+        add what is new to the registry. The newest cache is the round
+        in flight's: the fetch waits for it, once in
+        ``_COUNTER_ROUNDS`` rounds."""
         if self._device_counters is not None:
             leaf = self._cache
             for key in self._counter_leaf:
@@ -573,7 +678,7 @@ class ServingEngine:
         in-flight sequence, leave the batch empty. Returns the number
         of requests that were still queued (now rejected)."""
         rejected = self.scheduler.drain()
-        while self.active_slots > 0:
+        while self.active_slots > 0 or self._flight:
             self.step()
         flight.record("serve", "drained",
                       note=f"rejected_queued={rejected}")
@@ -590,15 +695,18 @@ class ServingEngine:
         if not admitted:
             return False
         with obs.span("serve/admit", n=len(admitted)):
+            filled = []
             for req in admitted:
                 # a branched request claims one row per branch (the
                 # scheduler already counted them against free_slots)
                 slots = [free.pop(0) for _ in range(req.branches)]
                 self._prefill_into(slots, req)
+                filled += slots
             # a budget-1 (or instant-eos) request retires in the same
             # pass
             self._retire_finished()
-            self._sync_slots()
+            self._write_slots(filled)
+            self._pending_logprob.clear()
         return True
 
     def _row_lora(self, adapter: int):
@@ -611,9 +719,10 @@ class ServingEngine:
         """Compile what this engine runs for greedy requests (with its
         bank, if it has one) before a driving thread does: the zeroed
         row cache and the prefill of each prompt bucket, the row insert,
-        the decode step. One throwaway forward a bucket into a throwaway
-        batch cache: the engine's own state is not touched, so a
-        replica may be warmed while its driver loop idles."""
+        an admission's write of its rows' slot state, the decode step.
+        One throwaway forward a bucket into a throwaway batch cache: the
+        engine's own state is not touched, so a replica may be warmed
+        while its driver loop idles."""
         cache = _fresh_cache(self.model, self.max_slots, self.max_seq_len)
         for plen in prompt_lens:
             pad = min(_bucket_len(int(plen)), self.max_seq_len)
@@ -627,9 +736,13 @@ class ServingEngine:
             cache = _insert_row(cache, row, 0, totals=self._counter_leaf,
                                 count=True)
         idle = jnp.zeros((self.max_slots,), jnp.int32)
-        nxt, _, _, _ = _serve_step(
-            self.model, self.params, cache, idle, idle,
-            jnp.zeros((self.max_slots,), bool), self._lora, None)
+        state, _, _ = _write_rows(
+            idle, idle, jnp.zeros((self.max_slots,), bool), idle,
+            np.zeros((5, self.max_slots), np.int32),
+            None if self._lora is None else self._lora["adapter_ids"],
+            None, None)  # every argument, as _write_slots passes them
+        nxt, *_ = _serve_step(self.model, self.params, cache, *state,
+                              self._d_eos, self._lora, None)
         np.asarray(nxt)  # block until compiled + executed
 
     def _prefill_into(self, slots: list, req: Request) -> None:
@@ -719,12 +832,11 @@ class ServingEngine:
                     self._cache = _insert_row(
                         self._cache, row_cache, slot, totals=totals,
                         count=k == 0 or totals is None)
-                    s = _Slot(req, firsts[k], depth=L, cached=m,
+                    s = _Slot(req, firsts[k], depth=L,
+                              first_round=self._round_no + 1, cached=m,
                               seq_id=sids[k], branch=k)
                     self._slots[slot] = s
-                    self._h_last[slot] = firsts[k]
-                    self._h_depth[slot] = L
-                    self._h_active[slot] = True
+                    self._n_sampled += sampled
                     self._pending_logprob[slot] = float(h["logprob"][slot])
                     self._c_tokens.inc()  # the prefill-produced first token
                     flight.record("serve", "admit", step=self.scheduler.round,
@@ -746,26 +858,52 @@ class ServingEngine:
         """THE hot loop body (see module docstring for the lint
         contract: no host->device transfers, no jnp/jax array
         construction — device state stays resident; one (slots,)
-        device->host fetch)."""
+        device->host fetch). Dispatches until one round is in flight
+        beyond the oldest, then fetches the oldest: ``(its tokens, its
+        seconds)``, the seconds from the later of its dispatch and the
+        previous fetch's return to this fetch's return."""
         t0 = time.monotonic()
         # chaos slow@/crash@/preempt@ key on the decode round the way
         # they key on the training step; inside the timed window so an
         # injected slow round shows up in the latency histograms
         # exactly like a real one
         chaos.on_step(self.scheduler.round)
-        nxt, depth, self._cache, drawn = _serve_step(
-            self.model, self.params, self._cache, self._d_last,
-            self._d_depth, self._d_active, self._lora,
-            self._d_sampling if self._n_sampled else None)
-        if drawn is not None:
-            self._d_sampling = drawn
-        self._d_last, self._d_depth = nxt, depth
+        unfetched = self._flight  # (``flight`` is the obs module here)
+        while not unfetched or (len(unfetched) == 1
+                                and self._rows_outlast_flight()):
+            given = (self._d_slots, self._d_sampling)
+            nxt, depth, active, remaining, self._cache, drawn = _serve_step(
+                self.model, self.params, self._cache, *self._d_slots,
+                self._d_eos, self._lora,
+                self._d_sampling if self._n_sampled else None)
+            if drawn is not None:
+                self._d_sampling = drawn
+            self._d_slots = (nxt, depth, active, remaining)
+            self._round_no += 1
+            unfetched.append((nxt, t0, given))
+        nxt, dispatched, _ = unfetched.popleft()
         host_tok = np.asarray(nxt)
-        return host_tok, time.monotonic() - t0
+        now = time.monotonic()
+        dt = now - max(dispatched, self._t_fetched)
+        self._t_fetched = now
+        return host_tok, dt
 
-    def _collect(self, host_tok: np.ndarray) -> int:
-        """Fold one round's tokens into the host slot mirrors and
-        retire rows that hit eos or budget. Returns retired count."""
+    def _rows_outlast_flight(self) -> bool:
+        """Whether some row's budget reaches past the rounds in flight
+        (an eos the host has not seen yet may still stop it: the round
+        dispatched for it then computes nothing and is fetched like any
+        other). A row admitted after a round's dispatch is not in it."""
+        fetched = self._round_no - len(self._flight)
+        return any(
+            s is not None and s.emitted + self._round_no
+            - max(fetched, s.first_round - 1) < s.req.max_new_tokens
+            for s in self._slots)
+
+    def _collect(self, host_tok: np.ndarray, live: list) -> int:
+        """Fold one round's tokens into the host mirrors of its rows
+        (``live``: slot and mirror of every row the round held) and
+        retire rows that hit eos or budget, which the device stopped in
+        that round. Returns retired count."""
         # chaos flip@replica=K: perturb ONE fetched token (first active
         # slot) this round — a silent corruption: the wrong id flows
         # into the slot mirror, the JSONL record, and the fingerprint
@@ -773,30 +911,36 @@ class ServingEngine:
         # _decode_round (its hot-loop lint bans extras).
         flip = chaos.on_flip_token(self.replica_index,
                                    self.scheduler.round)
-        flipped = False
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
+        flipped = None
+        for i, s in live:
             tok = int(host_tok[i])
             if flip:
                 flip = False
-                flipped = True
+                flipped = i
                 tok = tok - 1 if tok > 0 else tok + 1
             s.tokens.append(tok)
             s.emitted += 1
             s.depth += 1
-            self._h_last[i] = tok
-            self._h_depth[i] = s.depth
             self.scheduler.pool.extend(s.seq_id, s.depth)
             if s.req.stream is not None and \
                     len(s.tokens) - s.streamed >= self.stream_chunk_tokens:
                 self._emit_chunk(s)
         retired = self._retire_finished()
-        if flipped:
-            # push the corrupted last-token mirror to device (mirrors
-            # are all current here) so the flip PROPAGATES: subsequent
-            # tokens condition on the wrong id, exactly like real rot
-            self._sync_slots()
+        if flipped is not None:
+            # write the corrupted token to the device so the flip
+            # PROPAGATES: subsequent tokens condition on the wrong id,
+            # exactly like real rot. The round in flight was fed the
+            # true one: it is dropped (what it wrote to the cache the
+            # round dispatched in its place writes again), and the
+            # device's state is what that round was given.
+            while self._flight:
+                _, _, (self._d_slots, self._d_sampling) = self._flight.pop()
+                self._round_no -= 1
+            if self._n_sampled:
+                # the row's running logprob is the device's
+                self._h_sampling["logprob"][flipped] = np.asarray(
+                    self._d_sampling["logprob"])[flipped]
+            self._write_slots([flipped])
         return retired
 
     def _done(self, s: _Slot) -> bool:
@@ -812,9 +956,10 @@ class ServingEngine:
                 if s is None or not self._done(s):
                     continue
                 self._slots[i] = None
-                self._h_active[i] = False
                 retired += 1
                 req = s.req
+                self._n_sampled -= req.decode is not None \
+                    and req.decode.sampled
                 if req.branches > 1:
                     self._retire_branch(i, s)
                     continue
@@ -842,9 +987,11 @@ class ServingEngine:
         logprob, hand the client the top ``n``."""
         req = s.req
         # outside the hot loop (retirement path), so the fetch is
-        # legal. A budget-1 branch retires in the same _admit pass
-        # that prefilled it — before _sync_slots merged its first
-        # token's logprob to device — so the pending value wins.
+        # legal; it waits for the round in flight, which left the
+        # stopped row's sum as it was. A budget-1 branch retires in the
+        # same _admit pass that prefilled it — before _write_slots put
+        # its first token's logprob on the device — so the pending
+        # value wins.
         lp = self._pending_logprob.pop(slot, None)
         if lp is None:
             lp = float(np.asarray(self._d_sampling["logprob"])[slot])
@@ -1065,35 +1212,38 @@ class ServingEngine:
                         t0_us + off_us, dur_us, cat="serve")
                 off_us += dur_us
 
-    def _sync_slots(self) -> None:
-        """Push the host slot mirrors to device (admission/retirement
-        path only — never per round), each only if a program of this
-        engine, or of this batch, reads it."""
-        self._d_last = jnp.asarray(self._h_last)
-        self._d_depth = jnp.asarray(self._h_depth)
-        self._d_active = jnp.asarray(self._h_active)
-        if self._lora is not None:
-            self._lora = dict(self._lora, adapter_ids=jnp.asarray(np.array(
-                [s.req.adapter if s is not None else 0
-                 for s in self._slots], np.int32)))
-        live = [(i, s) for i, s in enumerate(self._slots) if s is not None]
-        self._n_sampled = sum(
-            s.req.decode is not None and s.req.decode.sampled
-            for _, s in live)
-        if self._n_sampled:
-            h = self._h_sampling
-            # a row's RNG step is step0 + emitted: recomputable here by
-            # design, so a flip drill's resync mid-round cannot skew the
-            # device's counter
-            for i, s in live:
-                h["step"][i] = s.step0 + s.emitted
-            # logprobs accumulate on the device: pull, overlay the
-            # first-token values of the rows prefilled since, push back
-            h["logprob"] = np.array(self._d_sampling["logprob"])
-            for slot, v in self._pending_logprob.items():
-                h["logprob"][slot] = v
-            self._d_sampling = jax.tree.map(jnp.asarray, h)
-        self._pending_logprob.clear()
+    def _write_slots(self, slots: list) -> None:
+        """Write the device's slot state of the rows ``slots`` from
+        their host mirrors, in one dispatch behind the round in flight
+        and the rows' ``_insert_row`` (admission path; the flip drill).
+        Only a row the host has just filled is the host's to write:
+        every other live row is one round further on the device than
+        its mirror here, and a finished row the device has stopped
+        itself. A slot whose row retired in the pass that filled it is
+        written inactive. The adapter ids go with an engine's bank, the
+        sampling rows while a sampled row is live."""
+        rows = np.zeros((5, self.max_slots), np.int32)
+        rows[0, slots] = 1
+        for i in slots:
+            s = self._slots[i]
+            if s is not None:
+                rows[1:, i] = (s.tokens[-1], s.depth,
+                               s.req.max_new_tokens - s.emitted,
+                               s.req.adapter)
+                # a row's RNG step is step0 + emitted: recomputable
+                # here by design, so a flip drill's write mid-stream
+                # cannot skew the device's counter
+                self._h_sampling["step"][i] = s.step0 + s.emitted
+        sampled = self._n_sampled > 0
+        self._d_slots, ids, drawn = _write_rows(
+            *self._d_slots, rows,
+            None if self._lora is None else self._lora["adapter_ids"],
+            self._d_sampling if sampled else None,
+            self._h_sampling if sampled else None)
+        if ids is not None:
+            self._lora = dict(self._lora, adapter_ids=ids)
+        if drawn is not None:
+            self._d_sampling = drawn
 
     def flops_per_token(self) -> int:
         """Analytic forward FLOPs of ONE token through this model
@@ -1126,6 +1276,9 @@ class ServingEngine:
         occ = self._occ_sum / max(rounds * self.max_slots, 1)
         out = dict(
             rounds=rounds,
+            # rounds dispatched before the one before them was fetched:
+            # over ``rounds``, the share the chip did not wait for
+            rounds_overlapped=self._overlapped,
             requests_done=len(self.completed),
             tokens_out=int(sum(r["new_tokens"] for r in self.completed)),
             occupancy=occ,

@@ -712,8 +712,9 @@ def test_decode_hot_loop_has_no_host_device_transfers():
     """ISSUE 5 lint: ``ServingEngine._decode_round`` is the per-token
     hot path — it must not construct or upload device arrays (``jnp.``
     / ``jax.`` are banned outright; slot state stays device-resident
-    across rounds) and must fetch device->host exactly once per round
-    (a single ``np.asarray`` of the sampled tokens)."""
+    across rounds) and must fetch device->host exactly once per call
+    (a single ``np.asarray`` of one round's tokens: since ISSUE 32 the
+    round before the one the call dispatches)."""
     tree = ast.parse((_SERVE / "engine.py").read_text())
     engine = next(n for n in tree.body if isinstance(n, ast.ClassDef)
                   and n.name == "ServingEngine")
@@ -1474,10 +1475,15 @@ def test_decode_spec_defaults_are_provably_inert(tiny_llama, family,
     ``sampling`` absent, ``_serve_step`` / ``_serve_prefill`` lower,
     apart from the module's name, to the text of a plain function
     written here without those arguments — no sort or random bits of
-    the sampler, no parameter beyond params, cache and the three slot
-    arrays. Default ``DecodeSpec()`` requests ride it (the scheduler
-    normalizes an explicit default to None). With ``sampling`` present
-    the same reading finds the sampler: the witness can see it."""
+    the sampler, no parameter beyond params, cache and the slot state
+    (the prefill's three arrays; the step's four and its stop token).
+    Default ``DecodeSpec()`` requests ride it (the scheduler normalizes
+    an explicit default to None). With ``sampling`` present the same
+    reading finds the sampler: the witness can see it. The step's plain
+    text holds the stop test (ISSUE 32: a row stops on the device, in
+    the step that produces its last token), and every form of the step,
+    with a bank and with sampled rows too, returns the four slot arrays
+    first, the next round's mask among them."""
     from pytorch_distributed_nn_tpu.inference.generate import (
         _apply_decode_ragged,
         init_cache,
@@ -1498,15 +1504,19 @@ def test_decode_spec_defaults_are_provably_inert(tiny_llama, family,
         served, rows = engine._serve_step, slots
         cache = jax.eval_shape(lambda: init_cache(model, slots, 64))
         args = (model, params, cache, vec(slots), vec(slots),
-                vec(slots, jnp.bool_))
+                vec(slots, jnp.bool_), vec(slots),
+                jax.ShapeDtypeStruct((), jnp.int32))
 
-        def plain(model, params, cache, last_tok, lengths, active):
+        def plain(model, params, cache, last_tok, lengths, active,
+                  remaining, eos):
             logits, cache = _apply_decode_ragged(
                 model, params, cache, last_tok, lengths,
                 **engine._mask_kw(model, active[:, None]))
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (jnp.where(active, nxt, last_tok),
-                    jnp.where(active, lengths + 1, lengths), cache)
+            nxt = jnp.where(active, nxt, last_tok)
+            return (nxt, jnp.where(active, lengths + 1, lengths),
+                    active & (remaining > 1) & (nxt != eos),
+                    jnp.where(active, remaining - 1, remaining), cache)
     else:
         served, rows = engine._serve_prefill, 3
         cache = jax.eval_shape(lambda: init_cache(model, 1, pad))
@@ -1528,7 +1538,7 @@ def test_decode_spec_defaults_are_provably_inert(tiny_llama, family,
         assert text.replace(name, "jit_plain") == want
     signature = re.search(r"func\.func public @main\((.*?)\) ->", text)
     assert len(re.findall(r"%arg\d+:", signature.group(1))) == len(
-        jax.tree.leaves((params, cache))) + 3
+        jax.tree.leaves((params, cache))) + (5 if program == "step" else 3)
     assert text.count("stablehlo.sort") == _OWN_SORTS[family]
     assert "threefry" not in text and "stablehlo.rng" not in text
 
@@ -1536,6 +1546,23 @@ def test_decode_spec_defaults_are_provably_inert(tiny_llama, family,
     sampled = served.lower(*args, None, sampling).as_text()
     assert sampled.count("stablehlo.sort") > _OWN_SORTS[family]
     assert "threefry" in sampled
+    if program == "step":
+        forms = [text, sampled]
+        if family == "llama":
+            from pytorch_distributed_nn_tpu.nn.lora import init_lora_bank
+
+            bank = jax.eval_shape(
+                lambda: init_lora_bank(model, num_adapters=2, rank=2))
+            lora = dict(lora_bank=bank, adapter_ids=vec(slots))
+            forms += [served.lower(*args, lora, None).as_text(),
+                      served.lower(*args, lora, sampling).as_text()]
+        for form in forms:
+            results = re.search(
+                r"func\.func public @main\(.*?\) -> \((.*?)\) \{", form,
+                re.S).group(1)
+            assert re.findall(r"tensor<[^>]*>", results)[:4] == [
+                f"tensor<{slots}xi32>", f"tensor<{slots}xi32>",
+                f"tensor<{slots}xi1>", f"tensor<{slots}xi32>"]
 
 
 def test_branch_fork_is_single_homed_in_scheduler():

@@ -271,63 +271,79 @@ class _MaskedLoraStub:
 def test_token_mask_reaches_the_step_of_an_engine_with_a_bank(sampled):
     """A model that takes ``token_mask`` gets the active rows' mask in
     every combination the step has: with a bank, and with a bank and
-    sampled rows (temperature 0 here, so the rows keep their argmax)."""
+    sampled rows (temperature 0 here, so the rows keep their argmax).
+    The mask that comes back is the next round's: row 0 has emitted its
+    budget's last token, row 1 was not active, row 2 goes on."""
     active = np.asarray([True, False, True])
     sampling = None
     if sampled:
         sampling = {k: np.zeros((3,), dt)
                     for k, dt in engine_mod._SAMPLING_ROW.items()}
-    nxt, lengths, cache, drawn = engine_mod._serve_step(
+    nxt, lengths, alive, remaining, cache, drawn = engine_mod._serve_step(
         _MaskedLoraStub(), {}, {"mask": np.zeros((3, 1), bool)},
         np.asarray([7, 7, 7], np.int32), np.asarray([4, 5, 6], np.int32),
-        active, dict(lora_bank={"shift": np.int32(2)},
-                     adapter_ids=np.asarray([0, 1, 3], np.int32)),
+        active, np.asarray([1, 4, 3], np.int32), np.int32(-1),
+        dict(lora_bank={"shift": np.int32(2)},
+             adapter_ids=np.asarray([0, 1, 3], np.int32)),
         sampling)
     np.testing.assert_array_equal(cache["mask"], active[:, None])
     np.testing.assert_array_equal(nxt, [2, 7, 5])
     np.testing.assert_array_equal(lengths, [5, 5, 7])
+    np.testing.assert_array_equal(alive, [False, False, True])
+    np.testing.assert_array_equal(remaining, [0, 4, 2])
     assert (drawn is None) == (not sampled)
     if sampled:
         np.testing.assert_array_equal(drawn["step"], [1, 0, 1])
 
 
-def test_sync_pushes_only_the_mirrors_a_program_reads(tiny_llama,
-                                                      monkeypatch):
-    """A sync uploads the three slot arrays; the adapter ids only for
-    an engine with a bank; the sampling rows only while a sampled row
-    is active."""
+def test_an_admission_writes_its_rows_and_what_a_program_reads(
+        tiny_llama, monkeypatch):
+    """An admission hands the device one (5, slots) array that marks
+    the rows it filled, and nothing of the others; the adapter ids go
+    only in an engine with a bank; the sampling rows only while a
+    sampled row is live. A retire writes nothing."""
     model, params = tiny_llama
-    pushed = []
-    upload = engine_mod.jnp.asarray
+    calls = []
+    write = engine_mod._write_rows
     monkeypatch.setattr(
-        engine_mod.jnp, "asarray",
-        lambda x, *a, **kw: (pushed.append(x), upload(x, *a, **kw))[1])
+        engine_mod, "_write_rows",
+        lambda *a: (calls.append(a), write(*a))[1])
 
-    def pushes(eng):
-        del pushed[:]
-        eng._sync_slots()
-        return len(pushed)
+    def admitted(eng, **kw):
+        """What the one write of a request's admission was given."""
+        del calls[:]
+        eng.submit(np.arange(1, 9, dtype=np.int32), 4, **kw)
+        eng.step()
+        (call,) = calls
+        eng.run_until_idle()
+        assert len(calls) == 1      # the retire wrote nothing
+        return call[4], call[5:]
 
     kw = dict(max_slots=2, max_seq_len=64, block_size=8)
     eng = ServingEngine(model, params, **kw)
     assert eng._lora is None
-    assert pushes(eng) == 3
+    rows, rest = admitted(eng)
+    np.testing.assert_array_equal(rows[0], [1, 0])      # slot 0 alone
+    assert rows[2, 0] == 8 and rows[3, 0] == 3  # depth, tokens left
+    assert rest == (None, None, None)
     bank = init_lora_bank(model, num_adapters=2, rank=2)
-    assert pushes(ServingEngine(model, params, lora_bank=bank, **kw)) == 4
-    eng.submit(np.arange(1, 9, dtype=np.int32), 4,
-               decode=DecodeSpec(temperature=0.8, seed=1))
-    eng.step()
-    assert eng._n_sampled == 1
-    assert pushes(eng) == 3 + len(engine_mod._SAMPLING_ROW)
-    eng.run_until_idle()
-    assert eng._n_sampled == 0 and pushes(eng) == 3
+    rows, (ids, *sampling) = admitted(
+        ServingEngine(model, params, lora_bank=bank, **kw), adapter=1)
+    assert ids is not None and rows[4, 0] == 1 and sampling == [None, None]
+    _, (_, given, drawn) = admitted(
+        eng, decode=DecodeSpec(temperature=0.8, seed=1))
+    assert set(given) == set(drawn) == set(engine_mod._SAMPLING_ROW)
+    assert eng._n_sampled == 0
+    assert admitted(eng)[1] == (None, None, None)
 
 
 @pytest.mark.parametrize("with_bank", [False, True], ids=["plain", "bank"])
 def test_warmup_compiles_what_the_engine_then_runs(tiny_llama, with_bank):
-    """After ``warmup`` a greedy request of a warmed prompt bucket
-    compiles nothing, with a bank or without, and the warm-up leaves
-    the engine as it found it."""
+    """After ``warmup`` greedy requests of a warmed prompt bucket
+    compile nothing, with a bank or without, and the warm-up leaves
+    the engine as it found it. An admission's write of its rows' slot
+    state is one program of one shape, whether a pass fills one row or
+    two."""
     model, params = tiny_llama
     bank = init_lora_bank(model, num_adapters=2, rank=2) \
         if with_bank else None
@@ -336,17 +352,19 @@ def test_warmup_compiles_what_the_engine_then_runs(tiny_llama, with_bank):
     eng = ServingEngine(model, params, max_slots=5, max_seq_len=80,
                         block_size=8, lora_bank=bank)
     programs = (engine_mod._zero_cache, engine_mod._serve_prefill,
-                engine_mod._insert_row, engine_mod._serve_step)
+                engine_mod._insert_row, engine_mod._serve_step,
+                engine_mod._write_rows)
     cold = [f._cache_size() for f in programs]
     eng.warmup((8,))
     warm = [f._cache_size() for f in programs]
-    assert warm[3] > cold[3]
+    assert warm[3] > cold[3] and warm[4] > cold[4]
     assert eng.active_slots == 0 and not eng.has_work
-    r = eng.submit(np.arange(1, 9, dtype=np.int32), 3,
-                   adapter=int(with_bank))
-    eng.run_until_idle()
-    assert r.state == "done"
-    assert [f._cache_size() for f in programs] == warm
+    for n in (1, 2):   # prompts that share no prefix: no restore
+        reqs = [eng.submit(np.arange(8, dtype=np.int32) + 10 * (n + k), 3,
+                           adapter=int(with_bank)) for k in range(n)]
+        eng.run_until_idle()
+        assert all(r.state == "done" for r in reqs)
+        assert [f._cache_size() for f in programs] == warm
 
 
 @pytest.mark.parametrize("batch", [1, 3], ids=["row", "max_slots"])

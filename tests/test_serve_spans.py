@@ -295,16 +295,20 @@ def test_spans_of_one_request_share_its_id(traced):
 # (``jnp.asarray``) and B the eager ``broadcast_in_dim`` (``jnp.zeros``).
 # A span that moved, added or renamed a device program, or an eager
 # operation, changes this list. The first line is the constructor's:
-# the batch cache, then the prefix store and the slot and sampling
-# mirrors of an engine without a bank. Each admission takes its zeroed
-# row from one ``_zero_cache``; the two ``C`` before a prefill are its
-# ``jnp.asarray`` uploads.
+# the batch cache, then the prefix store, the slot state, the stop
+# token and the sampling mirrors of an engine without a bank. Each
+# admission takes its zeroed row from one ``_zero_cache``; the two ``C``
+# before a prefill are its ``jnp.asarray`` uploads; one ``_write_rows``
+# an admission pass sets the slot state of the rows it filled. The loop
+# keeps a round in flight (ISSUE 32): the second ``_serve_step`` is
+# dispatched before the first one's tokens are fetched, and the short
+# request's retire (``_save_blocks``) follows both.
 PINNED_DISPATCHES = (
-    "_zero_cache C C B C B C C B C B "
+    "_zero_cache C C B C B C C B C B C B C B C B C B C "
     "_zero_cache C C _serve_prefill _insert_row "
-    "_zero_cache C C _serve_prefill _insert_row "
-    "_serve_step _save_blocks _serve_step "
-    "_zero_cache _restore_blocks C C _serve_prefill _insert_row "
+    "_zero_cache C C _serve_prefill _insert_row _write_rows "
+    "_serve_step _serve_step _save_blocks "
+    "_zero_cache _restore_blocks C C _serve_prefill _insert_row _write_rows "
     "_serve_step _save_blocks "
 ).split()
 _SHORT = {"convert_element_type": "C", "broadcast_in_dim": "B"}
@@ -362,12 +366,15 @@ def test_admission_mints_its_row_cache_in_one_dispatch(traced):
 
 
 def test_decode_round_is_the_parents_byte_for_byte():
-    """The hot loop holds no span and dispatches one program a round.
-    That the program of a greedy batch is the one it always was is read
-    off its lowered text (test_quality.py), not off this source."""
+    """The hot loop holds no span and one dispatch site, of the one
+    step program: a call runs it until a round is in flight beyond the
+    one it fetches (once in steady state). What that program is for a
+    greedy batch is read off its lowered text (test_quality.py), not
+    off this source."""
     src = inspect.getsource(ServingEngine._decode_round)
     assert "obs.span" not in src
     assert src.count("_serve_step(") == 1
+    assert src.count("_write_rows(") == src.count("_insert_row(") == 0
 
 
 def test_unarmed_spans_of_a_decode_round_cost_under_20_us():
