@@ -138,12 +138,18 @@ def _apply_prefill_ragged(model, params, cache, tokens, lengths):
     sequential prefill. Returns ((B, V) logits at each row's LAST real
     position, cache). Full logits are materialized (not ``last_only``)
     because "last" differs per row — fine at serving batch sizes; the
-    (P-1) extra head rows are the price of one fused prefill."""
+    (P-1) extra head rows are the price of one fused prefill. A model
+    that takes ``token_mask`` is told which columns are real: padding
+    written by position is masked until overwritten, but in a ring
+    cache (a sliding window's) it would displace real rows."""
     zeros = jnp.zeros((tokens.shape[0],), jnp.int32)
+    extra = {"token_mask": jnp.arange(tokens.shape[1])[None, :]
+             < lengths[:, None]} \
+        if getattr(model, "takes_token_mask", False) else {}
     logits, mutated = model.apply(
         {"params": params, "cache": cache}, tokens,
         train=False, decode=True, mutable=["cache"],
-        cache_positions=zeros,
+        cache_positions=zeros, **extra,
     )
     last = (lengths.astype(jnp.int32) - 1)[:, None, None]
     next_logits = jnp.take_along_axis(logits, last, axis=1)[:, 0, :]

@@ -25,6 +25,7 @@ def get_model(cfg: ModelConfig):
     # import for registration side effects
     from pytorch_distributed_nn_tpu.models import (  # noqa: F401
         bert,
+        k_exaone,
         lenet,
         llama,
         longcat_flash,
@@ -54,6 +55,7 @@ def get_model(cfg: ModelConfig):
 def available_models() -> list[str]:
     from pytorch_distributed_nn_tpu.models import (  # noqa: F401
         bert,
+        k_exaone,
         lenet,
         llama,
         longcat_flash,

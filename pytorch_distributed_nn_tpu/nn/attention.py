@@ -190,6 +190,91 @@ def _cache_attention(q, k, v, pos_mask, dtype, kscale=None, vscale=None):
     return out.reshape(B, T, H, D).astype(dtype)
 
 
+def _ring_held(last, rows):
+    """The position a ring of ``rows`` rows holds in each row once
+    position ``last`` (B,) is written: the newest one ``<= last``
+    congruent to the row; negative where nothing was written yet."""
+    r = jnp.arange(rows)[None, :]
+    return last[:, None] - (last[:, None] - r) % rows
+
+
+def ring_rows_scored(T: int, R: int) -> int:
+    """Key rows a query of a ring layer is scored against when T tokens
+    a row are fed: the ring alone for one, else its block's keys in
+    flight and the R before them (:func:`_ring_attention`)."""
+    return R if T == 1 else R + (R if T % R == 0 else T)
+
+
+def _ring_attention(q, k, v, ring_k, ring_v, starts, lengths, dtype):
+    """Sliding-window attention through a ring cache.
+
+    The ring (B, R, Hkv, D) holds position ``p`` in row ``p mod R``; R
+    is the window, so the ring is exactly the keys a query may see. The
+    T fed tokens of row ``b`` stand at positions ``starts[b] + t``, the
+    first ``lengths[b]`` of them real (a left-aligned prefix; the rest
+    is a bucket's padding and must not displace a real position).
+    Returns ``(out, ring_k, ring_v)``.
+
+    One token a row (a decode round) writes its row in place and reads
+    the R rows. Several (a prefill) attend the keys in flight in blocks
+    of R queries, each against its own and the previous block (the
+    ring's old content standing in before the first), so scores and
+    their temporaries follow T x 2R, not T x T; then the newest real
+    position of every residue goes to its row."""
+    B, T = q.shape[:2]
+    R = ring_k.shape[1]
+    if T == 1:
+        ring_k = _row_update(ring_k, k, starts % R)
+        ring_v = _row_update(ring_v, v, starts % R)
+        visible = _ring_held(starts, R) >= 0
+        out = _cache_attention(q, ring_k, ring_v, visible[:, None, :], dtype)
+        return out, ring_k, ring_v
+    fed = starts[:, None] + jnp.arange(T)[None]                  # (B, T)
+    k_all = jnp.concatenate([ring_k, k.astype(ring_k.dtype)], axis=1)
+    v_all = jnp.concatenate([ring_v, v.astype(ring_v.dtype)], axis=1)
+    pos_all = jnp.concatenate([_ring_held(starts - 1, R), fed], axis=1)
+    nb = T // R if T % R == 0 else 1
+    tb = T // nb
+
+    def windows(x):   # (B, R + T, ...) -> (B * nb, R + tb, ...)
+        if nb == 1:
+            return x
+        x = x.reshape((B, nb + 1, R) + x.shape[2:])
+        x = jnp.concatenate([x[:, :-1], x[:, 1:]], axis=2)
+        return x.reshape((B * nb, 2 * R) + x.shape[3:])
+
+    k_pos = windows(pos_all)[:, None, :]
+    q_pos = fed.reshape(B * nb, tb)[:, :, None]
+    band = (k_pos >= 0) & (k_pos <= q_pos) & (q_pos - k_pos < R)
+    out = _cache_attention(q.reshape((B * nb, tb) + q.shape[2:]),
+                           windows(k_all), windows(v_all), band, dtype)
+    out = out.reshape(q.shape)
+    newest = _ring_held(starts + lengths - 1, R) - starts[:, None]
+    fresh = (newest >= 0)[:, :, None, None]
+    take = jnp.clip(newest, 0, T - 1)[:, :, None, None]
+    ring_k = jnp.where(fresh, jnp.take_along_axis(k, take, axis=1), ring_k)
+    ring_v = jnp.where(fresh, jnp.take_along_axis(v, take, axis=1), ring_v)
+    return out, ring_k, ring_v
+
+
+def _blocked_cache_attention(q, k, v, pos_mask, dtype, block):
+    """:func:`_cache_attention` in blocks of ``block`` queries, one
+    after the other: the score temporaries are a block's, not all T
+    queries' (a query's softmax is over its own row, so nothing else
+    changes). T must be a multiple of ``block``."""
+    B, T = q.shape[:2]
+    nb = T // block
+
+    def split(x):   # (B|1, T, ...) -> (nb, B|1, block, ...)
+        x = x.reshape((x.shape[0], nb, block) + x.shape[2:])
+        return jnp.moveaxis(x, 1, 0)
+
+    out = jax.lax.map(
+        lambda a: _cache_attention(a[0], k, v, a[1], dtype),
+        (split(q), split(pos_mask)))
+    return jnp.moveaxis(out, 0, 1).reshape(q.shape)
+
+
 class MultiHeadAttention(nn.Module):
     num_heads: int
     head_dim: int
@@ -223,12 +308,26 @@ class MultiHeadAttention(nn.Module):
     # latency. quantize_model_params merges float q/k/v kernels into
     # the fused layout.
     fused_qkv: bool = False
+    # sliding window: a query sees its ``window`` newest keys, itself
+    # among them (0: every key before it). The decode cache of such a
+    # layer is a ring of ``window`` rows a sequence, position p in row
+    # p mod window, whatever length the cache is sized for
+    # (:func:`_ring_attention`).
+    window: int = 0
+    # RMSNorm over each head's dims of q and of k, before any rotation:
+    # one gain vector of head_dim for all heads (``q_norm/scale``,
+    # ``k_norm/scale``)
+    qk_norm: bool = False
+    norm_eps: float = 1e-5
+    # a cached prefill's scores in blocks of this many queries (0: all
+    # at once): bounds the (heads, T, S) float32 temporaries
+    query_block: int = 0
 
     @nn.compact
     def __call__(self, x, mask: Optional[jax.Array] = None,
                  decode: bool = False,
                  cache_positions: Optional[jax.Array] = None,
-                 lora=None):
+                 lora=None, lengths: Optional[jax.Array] = None):
         """``decode=True`` enables the autoregressive KV cache (flax
         "cache" collection): initialize by calling ``model.init`` with a
         (B, max_len) input and ``decode=True`` — that sizes the cache —
@@ -258,7 +357,13 @@ class MultiHeadAttention(nn.Module):
         so cached KV rows embed the adapter's deltas — which is why the
         prefix cache namespaces its content addresses by adapter id. A
         zero-B adapter contributes an exact-0.0 delta: adding it leaves
-        greedy decode token-identical to running without a bank."""
+        greedy decode token-identical to running without a bank.
+
+        ``lengths`` (B,) int32, ring caches only: how many of the T fed
+        tokens of each row are real (a left-aligned prefix; default all
+        T). Padding written by absolute position lands past a row's
+        end and is masked until overwritten; in a ring it would
+        displace a real position, so it is not written."""
         kv_heads = self.num_kv_heads or self.num_heads
         if self.quantized:
             if self.use_bias:
@@ -289,6 +394,16 @@ class MultiHeadAttention(nn.Module):
             a_q, b_q, a_v, b_v = lora
             q = q + lora_delta(x, a_q, b_q)
             v = v + lora_delta(x, a_v, b_v)
+        if self.qk_norm:
+            # (the models package imports this module)
+            from pytorch_distributed_nn_tpu.models.llama import RMSNorm
+
+            norm = lambda name: RMSNorm(  # noqa: E731
+                eps=self.norm_eps, dtype=self.dtype,
+                param_dtype=self.param_dtype, name=name)
+            q, k = norm("q_norm")(q), norm("k_norm")(k)
+        if self.window and not self.causal:
+            raise ValueError("a sliding window is causal")
         if decode and not self.causal:
             raise ValueError("decode cache requires causal attention")
         if decode and mask is not None:
@@ -367,8 +482,13 @@ class MultiHeadAttention(nn.Module):
                     "('compute', 'int8')"
                 )
             int8_cache = self.cache_dtype == "int8"
+            if self.window and int8_cache:
+                raise ValueError("a ring cache is kept in the compute "
+                                 "dtype; cache_dtype='int8' has no ring")
             init_k = nn.initializers.zeros
-            kv_shape = (B, T, kv_heads, self.head_dim)
+            # init sizes the cache from the (B, max_len) input; a window
+            # layer's is its ring, whatever max_len
+            kv_shape = (B, self.window or T, kv_heads, self.head_dim)
             cached_k = self.variable(
                 "cache", "cached_key", init_k, None, kv_shape,
                 jnp.int8 if int8_cache else k.dtype,
@@ -423,7 +543,15 @@ class MultiHeadAttention(nn.Module):
                 k_pos = jnp.arange(S)[None, None, :]
                 q_pos = positions[:, :, None]
                 pos_mask = k_pos <= q_pos  # (B|1, T, S)
-                if int8_cache:
+                if self.window:
+                    # the ring has its own rows and mask; one path for
+                    # both modes: the shared index is every row's start
+                    out, cached_k.value, cached_v.value = _ring_attention(
+                        q, k, v, cached_k.value, cached_v.value,
+                        jnp.broadcast_to(positions[:, 0], (B,)),
+                        jnp.full((B,), T, jnp.int32) if lengths is None
+                        else lengths.astype(jnp.int32), self.dtype)
+                elif int8_cache:
                     kq_new, ks_new = _quantize_kv(k)
                     vq_new, vs_new = _quantize_kv(v)
                     cached_k.value = write(cached_k.value, kq_new)
@@ -438,16 +566,30 @@ class MultiHeadAttention(nn.Module):
                 else:
                     cached_k.value = write(cached_k.value, k)
                     cached_v.value = write(cached_v.value, v)
-                    out = _cache_attention(
-                        q, cached_k.value, cached_v.value, pos_mask,
-                        self.dtype,
-                    )
+                    if self.query_block and T > self.query_block \
+                            and T % self.query_block == 0:
+                        out = _blocked_cache_attention(
+                            q, cached_k.value, cached_v.value, pos_mask,
+                            self.dtype, self.query_block)
+                    else:
+                        out = _cache_attention(
+                            q, cached_k.value, cached_v.value, pos_mask,
+                            self.dtype,
+                        )
         else:
             if self.rotary:
                 q, k = rotary_embedding(q, k, theta=self.rope_theta)
                 q, k = q.astype(self.dtype), k.astype(self.dtype)
+            impl = self.impl
+            if self.window:
+                if mask is not None:
+                    raise ValueError("a window layer takes no padding "
+                                     "mask; feed it decode=True")
+                t = jnp.arange(x.shape[1])
+                mask = (t[:, None] - t[None, :] < self.window)[None]
+                impl = "xla"   # the band is a mask, which flash lacks
             out = dot_product_attention(q, k, v, causal=self.causal,
-                                        impl=self.impl, mask=mask)
+                                        impl=impl, mask=mask)
         if self.quantized:
             return Int8DenseGeneral(
                 x.shape[-1], axis=(-2, -1), name="out", dtype=self.dtype,

@@ -43,6 +43,15 @@ class DeviceCounters:
                 "moe_held_experts_touched_total",
                 "held experts some token picked, summed over executions",
                 labels=labels),
+            "attn_rows_attended_total": reg.counter(
+                "attn_rows_attended_total",
+                "cached key rows inside the masks of real queries",
+                labels=labels + ("attn",)),
+            "attn_rows_read_total": reg.counter(
+                "attn_rows_read_total",
+                "cached key rows the program scored for real queries "
+                "(a window layer's ring, or a row's whole length)",
+                labels=labels + ("attn",)),
         }
         self._targets = [(made[name], labels) for name, labels in names]
         self._last = np.zeros((len(names),), np.uint32)

@@ -215,6 +215,15 @@ class HeldExpertsMoE(nn.Module):
     are left out: under expert parallelism they arrive through the
     combine all-to-all, which one rank alone does not run.
 
+    That is LongCat-Flash's router, the defaults. The router of the
+    DeepSeek-V3 line (K-EXAONE's) differs in three fields:
+    ``scoring="sigmoid"`` makes the scores ``p = sigmoid(float32(x) W_r)``;
+    ``renormalize=True`` divides a token's weights by the sum of its
+    ``k`` picked scores, *all* of them, held here or not (every rank
+    computes the same denominator, so the ranks' parts still add up),
+    ``w_e = scaling * p_e / (sum_{e' in S} p_e' + 1e-20)``; and
+    ``routed_scaling`` is its 2.5.
+
     Nothing is dropped and no row's result depends on its batch
     neighbours, so the layer serves through a decode cache (``MoEMLP``'s
     capacity routing cannot). Cost follows the token-expert pairs routed
@@ -244,6 +253,8 @@ class HeldExpertsMoE(nn.Module):
     mlp_dim: int = 2048
     k: int = 12
     routed_scaling: float = 1.0
+    scoring: str = "softmax"      # or "sigmoid"
+    renormalize: bool = False     # weights over the sum of all k picks
     ep_size: int = 1
     ep_rank: int = 0
     token_block: int = 128
@@ -283,12 +294,18 @@ class HeldExpertsMoE(nn.Module):
         if self.is_initializing():
             return x, jnp.zeros((4,), jnp.uint32)
 
-        probs = jax.nn.softmax(logits, axis=-1)
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router scoring {self.scoring!r}")
+        probs = jax.nn.softmax(logits, axis=-1) \
+            if self.scoring == "softmax" else jax.nn.sigmoid(logits)
         choose = probs
         if self.has_variable("buffers", "selection_bias"):
             choose = probs + self.get_variable("buffers", "selection_bias")
         _, idx = jax.lax.top_k(choose, self.k)                    # (N, k)
-        w = jnp.take_along_axis(probs, idx, axis=1) * self.routed_scaling
+        w = jnp.take_along_axis(probs, idx, axis=1)
+        if self.renormalize:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w * self.routed_scaling
         w = jnp.where(real[:, None], w, 0.0)
 
         # zero-compute experts: the token itself, where it lives

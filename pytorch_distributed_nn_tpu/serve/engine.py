@@ -86,6 +86,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import logging
 import time
 from typing import Optional
 
@@ -116,6 +117,8 @@ from pytorch_distributed_nn_tpu.serve.scheduler import (
     Scheduler,
     branch_seq_ids,
 )
+
+log = logging.getLogger(__name__)
 
 # TTFT spans queueing (ms..s under load); per-token latency is ms-scale
 _TTFT_BUCKETS = (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
@@ -434,6 +437,22 @@ class ServingEngine:
             block_size=block_size,
         )
         self._cache = _fresh_cache(model, max_slots, self.max_seq_len)
+        # a model may declare cache leaves that are rings (a sliding
+        # window's ``(slots, window, ...)``, row = position mod window)
+        # beside the rows-by-position ones. Such a model gets no prefix
+        # cache and no block store: a hit of n rows needs a window
+        # layer's rows [n - window, n), which a retiring sequence no
+        # longer holds except at its very end, and a store page for
+        # every block of every layer would cost what the ring saved.
+        # ``_save_blocks`` / ``_restore_blocks`` slice every leaf at
+        # j * block_size and must never see a ring.
+        rings = getattr(model, "ring_cache_leaves", tuple)()
+        self._has_rings = bool(rings)
+        if prefix_cache and self._has_rings:
+            log.info("%s declares %d ring cache leaves: no prefix cache "
+                     "and no block store; every admission prefills from "
+                     "position 0", type(model).__name__, len(rings))
+            prefix_cache = False
         if prefix_cache:
             self.prefix_cache: Optional[PrefixCache] = PrefixCache(
                 pool, max_rows=self.max_seq_len, tag=tag)
@@ -1068,6 +1087,14 @@ class ServingEngine:
             self._cache, self._store, bs,
             np.int32(slot), padded, np.int32(nb))
 
+    def _refuse_rings(self, what: str) -> None:
+        if self._has_rings:
+            raise ValueError(
+                f"{what}: {type(self.model).__name__} keeps ring cache "
+                f"leaves (a sliding window's rows, position mod window); "
+                f"its engine has no block store, and blocks sliced by "
+                f"absolute position cannot carry a ring")
+
     def export_blocks(self, table):
         """Host-side copy of physical store blocks ``table`` (leading
         axis = position in the streamed chain) — the transfer SOURCE of
@@ -1077,6 +1104,7 @@ class ServingEngine:
         eviction cannot recycle them before the peer's write lands.
         Non-block leaves (ndim < 2 scalars) ship as empty placeholders
         so the pytree structure round-trips."""
+        self._refuse_rings("export_blocks")
         idx = jnp.asarray(np.asarray(table, np.int32))
         return jax.tree.map(
             lambda s: np.asarray(s[idx]) if s.ndim >= 2
@@ -1090,6 +1118,7 @@ class ServingEngine:
         at the adopted ids. Already-resident blocks dedup by digest and
         are not rewritten. Returns blocks written; 0 when this engine
         has no prefix cache or the pool had no headroom to adopt."""
+        self._refuse_rings("ingest_blocks")
         if self.prefix_cache is None or self._store is None:
             return 0
         plan = self.prefix_cache.ingest(tokens, adapter)

@@ -16,6 +16,10 @@ _LONGCAT = dict(num_layers=2, d_model=32, num_heads=2, mlp_dim=64,
                 expert_mlp_dim=16, num_experts=8, num_zero_experts=4,
                 moe_topk=2)
 
+_KEXAONE = dict(num_layers=5, d_model=32, num_heads=4, num_kv_heads=2,
+                head_dim=8, mlp_dim=64, vocab_size=101, window=4,
+                expert_mlp_dim=16, num_experts=8, moe_topk=2)
+
 TINY = {
     "mlp": dict(),
     "lenet": dict(),
@@ -32,6 +36,8 @@ TINY = {
                 patch_size=4),
     "longcat_flash": dict(_LONGCAT),
     "longcat_flash_ep32": dict(_LONGCAT, num_experts=64),  # 2 of 64 held
+    "k_exaone": dict(_KEXAONE),
+    "k_exaone_ep8": dict(_KEXAONE, num_experts=16),  # 2 of 16 held
 }
 
 IMAGE_INPUT = {
