@@ -133,8 +133,23 @@ def _row_update(buf, new, starts):
     """Per-row cache write: row ``i`` of ``new`` (T leading tokens)
     lands at ``buf[i, starts[i]:starts[i]+T]``. The continuous-batching
     primitive — each sequence in the batch advances at its own index
-    instead of the shared scalar ``cache_index``. vmap over the batch
-    dim keeps it one fused scatter, no host loop."""
+    instead of the shared scalar ``cache_index``. ``starts`` (B,) int32
+    is never negative; a live row's window lies inside the row, and a
+    live row gets the same bytes whichever way it is written.
+
+    One token a row (every decode round) is one indexed scatter a
+    leaf, which the TPU runs as one in-place fusion. A row whose start
+    is out of range (a stopped row at ``max_seq_len``) is not written
+    at all: the row is dead, and its next occupant's prefill overwrites
+    or masks it. Several tokens a row (a prefill) are one
+    ``dynamic_update_slice`` a row, which clamps an out-of-range window
+    as a whole onto the row's end. The TPU compiles that form to a
+    serial loop over the batch rows: one iteration for the engine's
+    prefill, but B iterations a leaf a round if a decode round used it."""
+    if new.shape[1] == 1:
+        return buf.at[jnp.arange(buf.shape[0]), starts].set(
+            new[:, 0], mode="drop", unique_indices=True,
+            indices_are_sorted=True)
     return jax.vmap(
         lambda b, n, s: jax.lax.dynamic_update_slice(
             b, n, (s,) + (0,) * (b.ndim - 1))

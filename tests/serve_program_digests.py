@@ -6,8 +6,11 @@ new family must leave alone.
 commit before K-EXAONE came (ISSUE 33): a Mistral-shaped and a
 LongCat-shaped model's ``_serve_prefill`` and ``_serve_step`` lower to
 the same text, byte for byte, with the window, the ring, the q/k norm
-and the router's new fields in the shared modules as without them. A PR
-that means to change those programs writes the file anew and says so:
+and the router's new fields in the shared modules as without them.
+The two step programs were written anew by ISSUE 34, which meant to
+change them (a decode round's cache write is one scatter a leaf); the
+two prefill programs are still those of that commit. A PR that means
+to change those programs writes the file anew and says so:
 
     JAX_PLATFORMS=cpu python tests/serve_program_digests.py > tests/data/serve_program_digests.json
 

@@ -1,4 +1,5 @@
-"""Mosaic compiles of the main-path kernels at real widths, without a
+"""Mosaic compiles of the main-path kernels at real widths, and the
+serve step's cache write as the chip's compiler leaves it, without a
 chip.
 
 The TPU's compiler is installed and compiles for a chip that is
@@ -240,3 +241,97 @@ def test_dp_explicit_step_reduces_leaves_in_place_on_four_chips(topo):
     assert max(flat, default=0) <= 30522  # the largest rank-1 leaf
     # and no gradient-sized rank-1 buffer anywhere in the program
     assert max(map(int, re.findall(r"= f32\[(\d+)\]", text))) < 1 << 20
+
+
+# served families at small depth but the published cache-leaf widths
+# (heads of 128; MLA's latent 512 and rotated key 64; a ring of 128)
+_SERVED = {
+    "llama": ("llama3_8b", dict(
+        vocab_size=1024, num_layers=2, d_model=512, num_heads=4,
+        num_kv_heads=2, head_dim=128, mlp_dim=1024, rope_theta=1e6)),
+    "longcat": ("longcat_flash", dict(
+        vocab_size=1024, num_layers=1, d_model=512, num_heads=4,
+        num_kv_heads=4, mlp_dim=1024, q_lora_rank=256, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        expert_mlp_dim=256, num_experts=8, num_zero_experts=4, moe_topk=2,
+        routed_scaling=6.0, ep_size=2, ep_rank=0)),
+    "k_exaone": ("k_exaone", dict(
+        vocab_size=1024, num_layers=4, d_model=512, num_heads=4,
+        num_kv_heads=2, head_dim=128, mlp_dim=1024, window=128,
+        expert_mlp_dim=256, num_experts=8, moe_topk=2, ep_size=2,
+        ep_rank=0)),
+}
+
+
+def _loop_over_rows(buf, new, starts):
+    """``_row_update`` as it was for any number of tokens a row."""
+    return jax.vmap(
+        lambda b, n, s: jax.lax.dynamic_update_slice(
+            b, n, (s,) + (0,) * (b.ndim - 1))
+    )(buf, new, starts)
+
+
+@pytest.mark.parametrize("family", list(_SERVED))
+def test_serve_step_writes_cache_rows_without_a_loop(topo, monkeypatch,
+                                                     family):
+    """A decode round's cache write on the chip: the compiled
+    ``_serve_step`` (8 slots x 256) holds no ``while`` that came from a
+    scatter — for a Llama no ``while`` at all; the expert loops of the
+    other two stay — and donates its cache as before: aliased bytes and
+    temporaries no larger than with the write as a ``vmap`` of
+    ``dynamic_update_slice``, which this TPU runs as a serial loop over
+    the slots, two a layer."""
+    import re
+
+    from pytorch_distributed_nn_tpu.config import ModelConfig
+    from pytorch_distributed_nn_tpu.inference.generate import init_cache
+    from pytorch_distributed_nn_tpu.models import get_model
+    from pytorch_distributed_nn_tpu.nn import attention, mla
+    from pytorch_distributed_nn_tpu.serve import engine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    name, extra = _SERVED[family]
+    model = get_model(ModelConfig(name=name, dtype="float32",
+                                  compute_dtype="bfloat16",
+                                  extra=dict(extra)))
+    slots = 8
+
+    def on(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on(jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+        train=False))["params"])
+    cache = on(jax.eval_shape(lambda: init_cache(model, slots, 256)))
+    state = on([jax.ShapeDtypeStruct((slots,), dt) for dt in
+                (jnp.int32, jnp.int32, jnp.bool_, jnp.int32)]
+               + [jax.ShapeDtypeStruct((), jnp.int32)])
+
+    def compiled():
+        # a function of its own each time: the trace of the last one
+        # is not reused, so the patched write below is read
+        step = jax.jit(lambda *a: engine._serve_step.__wrapped__(*a),
+                       static_argnums=(0,), donate_argnums=(2,))
+        return step.lower(model, params, cache, *state).compile()
+
+    def loops(text):
+        return re.findall(r"^\s*%?while[.\d]* = .*?op_name=\"([^\"]*)\"",
+                          text, re.M)
+
+    now = compiled()
+    from_scatter = [op for op in loops(now.as_text())
+                    if op.endswith("/scatter")]
+    assert not from_scatter, from_scatter
+    if family == "llama":
+        assert " while(" not in now.as_text()
+
+    monkeypatch.setattr(attention, "_row_update", _loop_over_rows)
+    monkeypatch.setattr(mla, "_row_update", _loop_over_rows)
+    before = compiled()
+    leaves = sum(a.ndim >= 3 for a in jax.tree.leaves(cache))
+    assert sum(op.endswith("/scatter")
+               for op in loops(before.as_text())) == leaves  # the witness
+    was, is_ = before.memory_analysis(), now.memory_analysis()
+    assert is_.alias_size_in_bytes == was.alias_size_in_bytes
+    assert is_.temp_size_in_bytes <= was.temp_size_in_bytes

@@ -1,4 +1,5 @@
-"""Int8 KV-cache decode (nn/attention.py cache_dtype="int8").
+"""The per-row cache write (``_row_update``) against a plain loop, and
+int8 KV-cache decode (nn/attention.py cache_dtype="int8").
 
 Three layers of oracle:
 1. the scale-folding identity — int8-cache attention must equal the
@@ -23,6 +24,7 @@ from pytorch_distributed_nn_tpu.models import get_model
 from pytorch_distributed_nn_tpu.nn.attention import (
     _cache_attention,
     _quantize_kv,
+    _row_update,
     dot_product_attention,
 )
 
@@ -30,6 +32,76 @@ from pytorch_distributed_nn_tpu.nn.attention import (
 def _small_extra(cache_dtype="compute"):
     return dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
                 mlp_dim=128, vocab_size=97, cache_dtype=cache_dtype)
+
+
+def _write_rows_one_by_one(buf, new, starts, drop_out_of_range):
+    """The reference: one ``dynamic_update_slice`` a row, which clamps
+    the window onto the row's end; or no write at all for a row whose
+    start is out of range."""
+    S = buf.shape[1]
+    for i, s in enumerate(np.asarray(starts).tolist()):
+        if drop_out_of_range and not 0 <= s < S:
+            continue
+        row = jax.lax.dynamic_update_slice(
+            buf[i], new[i], (s,) + (0,) * (buf.ndim - 2))
+        buf = buf.at[i].set(row)
+    return buf
+
+
+# a leaf's shape past (B, S): K/V heads, MLA's latent, the int8 scales
+_LEAVES = {"kv": (2, 4), "latent": (6,), "scales": (2,)}
+_ROW = 8   # positions a row
+# per-row starts for B = 3; "dead" holds a stopped row at max_seq_len
+# and one further out, beside a live row
+_STARTS = {"zero": [0, 0, 0], "ragged": [5, 0, 3],
+           "last": [_ROW - 1] * 3, "dead": [_ROW, 2, _ROW + 5]}
+
+
+@pytest.mark.parametrize("starts", list(_STARTS))
+@pytest.mark.parametrize("B", [1, 3])
+@pytest.mark.parametrize("T", [1, 3])
+@pytest.mark.parametrize("leaf,dtype", [
+    ("kv", jnp.bfloat16), ("kv", jnp.int8), ("latent", jnp.bfloat16),
+    ("scales", jnp.float32)])
+def test_row_update_matches_per_row_writes(leaf, dtype, T, B, starts):
+    """Under ``jit`` with the buffer donated, every leaf shape that goes
+    through ``_row_update``: a live row gets the bytes a per-row
+    ``dynamic_update_slice`` gives it and nothing else moves. The stated
+    rule for a row out of range: one token a row is not written (a
+    scatter drops it), several are clamped onto the row's end as a
+    whole (a prefill's window)."""
+    rng = np.random.RandomState(7)
+
+    def draw(shape):
+        x = rng.randint(-100, 100, shape) if dtype == jnp.int8 \
+            else rng.randn(*shape)
+        return jnp.asarray(x).astype(dtype)
+
+    buf = draw((B, _ROW) + _LEAVES[leaf])
+    new = draw((B, T) + _LEAVES[leaf])
+    at = jnp.asarray(_STARTS[starts][:B], jnp.int32)
+    if starts != "dead":
+        at = jnp.minimum(at, _ROW - T)   # a live window lies in the row
+    want = _write_rows_one_by_one(buf, new, at, drop_out_of_range=T == 1)
+    got = jax.jit(_row_update, donate_argnums=0)(buf + 0, new, at)
+    assert got.dtype == buf.dtype and got.shape == buf.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_row_update_ring_rows_past_one_wrap():
+    """The ring's call: positions past one wrap land in row
+    ``position mod R``, each slot at its own."""
+    rng = np.random.RandomState(8)
+    B, R = 3, 4
+    ring = jnp.asarray(rng.randn(B, R, 2, 4), jnp.float32)
+    k = jnp.asarray(rng.randn(B, 1, 2, 4), jnp.float32)
+    positions = jnp.asarray([R + 1, 3 * R, 2 * R - 1], jnp.int32)
+    got = np.asarray(jax.jit(_row_update, donate_argnums=0)(
+        ring + 0, k, positions % R))
+    want = np.asarray(ring).copy()
+    for i, row in enumerate([1, 0, R - 1]):
+        want[i, row] = np.asarray(k)[i, 0]
+    np.testing.assert_array_equal(got, want)
 
 
 def test_quantize_kv_roundtrip():
