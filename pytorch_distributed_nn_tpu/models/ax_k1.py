@@ -49,14 +49,18 @@ from pytorch_distributed_nn_tpu.nn.attention import (
     yarn_mscale,
 )
 from pytorch_distributed_nn_tpu.nn.dtypes import get_policy
-from pytorch_distributed_nn_tpu.nn.mla import MLAttention
+from pytorch_distributed_nn_tpu.nn.mla import (
+    MLAttention,
+    expanded_rows_read,
+)
 from pytorch_distributed_nn_tpu.parallel.expert import HeldExpertsMoE
 
 # what a layer counts in one program execution, over real tokens only:
 # HeldExpertsMoE's routing counts (none in a dense layer) with the
 # groups a token's picks fell in, then the latent rows inside the real
-# queries' masks and the latent rows the program scored for them (the
-# row's whole length)
+# queries' masks and the latent rows the program read for them (a
+# decode round the row's whole length, a prefill the key blocks each
+# query's block visits)
 COUNTERS = ("moe_calls_total", "moe_picks_total", "moe_held_pairs_total",
             "moe_held_experts_touched_total", "moe_pick_groups_total",
             "attn_rows_attended_total", "attn_rows_read_total")
@@ -108,7 +112,8 @@ class AXK1Block(nn.Module):
                              else "axk1/mla_prefill"):
             h = x + attn(
                 norm("input_norm")(x), decode=decode,
-                cache_positions=positions[:, 0] if decode else None)
+                cache_positions=positions[:, 0] if decode else None,
+                token_mask=real)
         u = norm("post_attn_norm")(h)
         if self.mlp_dim:
             with jax.named_scope("axk1/dense_ffn"):
@@ -138,8 +143,10 @@ class AXK1Block(nn.Module):
         if not decode or self.is_initializing():
             return out, None
         rows = attn.get_variable("cache", "cached_latent").shape[1]
+        read = real.sum() * rows if T == 1 \
+            else expanded_rows_read(positions, real, rows)
         return out, jnp.concatenate([routing, jnp.stack([
-            jnp.where(real, positions + 1, 0).sum(), real.sum() * rows,
+            jnp.where(real, positions + 1, 0).sum(), read,
         ]).astype(jnp.uint32)])
 
 

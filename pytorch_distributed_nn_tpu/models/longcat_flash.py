@@ -130,7 +130,8 @@ class LongcatFlashBlock(nn.Module):
                     # sec. 7). Any weights may be loaded.
                     q_up_name="q_b_out", dtype=self.dtype,
                     param_dtype=self.param_dtype, name=name,
-                )(y, decode=decode, cache_positions=cache_positions)
+                )(y, decode=decode, cache_positions=cache_positions,
+                  token_mask=token_mask)
 
         h = x + mla("attn0", norm("input_norm0")(x))
         a = norm("post_attn_norm0")(h)

@@ -21,9 +21,13 @@ published sizes, where 64 heads of K and V would be 20,480). Two paths
 read that one cache:
 
 - **expanded** (prefill, and the uncached forward): K and V are
-  expanded from the latent for every cached position and queries go
-  through in blocks of :data:`QUERY_BLOCK`, so that the float32 scores
-  are ``heads x QUERY_BLOCK x positions`` and never ``heads x T x T``;
+  expanded from the latent once a call and the attention runs
+  blockwise (:mod:`ops.pallas.prefix_attention`): a tile of float32
+  scores, ``QUERY_BLOCK x KEY_BLOCK`` a head, lives under a running
+  maximum and denominator and is never written out, and a block of
+  queries visits only the key blocks at or under its largest position
+  (a Pallas kernel on a TPU, the same recurrence in ``jax.numpy``
+  elsewhere);
 - **absorbed** (a decode round, one new token a row): ``W_kvb``'s key
   half is folded into the query and its value half into the output, so
   the round reads the latent once for all heads and never expands it:
@@ -50,11 +54,16 @@ from pytorch_distributed_nn_tpu.nn.attention import (
     _row_update,
     rotary_embedding,
 )
+from pytorch_distributed_nn_tpu.ops.pallas.prefix_attention import (
+    prefix_attention,
+    rows_visited,
+)
 
 
-# queries a block of the expanded path: at 64 heads and 4,096 cached
-# positions the float32 scores of one block are 0.5 GB, not 4.3
+# the expanded path's tiles (queries x keys a head), chosen on the chip
+# at 64 heads of 192 / 128 (PERF.md sec. 6 has the table)
 QUERY_BLOCK = 512
+KEY_BLOCK = 1024
 
 
 def _deinterleave(x):
@@ -68,46 +77,47 @@ def _masked_softmax(scores, visible, dtype):
     return jax.nn.softmax(scores, axis=-1).astype(dtype)
 
 
+def _seen_from(q_pos, real):
+    """Positions with the padding's at -1: a query that sees nothing."""
+    return q_pos if real is None else jnp.where(real, q_pos, -1)
+
+
 def expanded_attention(q_nope, q_rope, latent, rope_key, w_kvb, q_pos, *,
-                       scale: float, query_block: int = QUERY_BLOCK):
+                       scale: float, real=None,
+                       query_block: int = QUERY_BLOCK,
+                       key_block: int = KEY_BLOCK):
     """Attention with K and V expanded from the latent.
 
     q_nope (B, T, H, dn), q_rope (B, T, H, dr); latent (B, S, r) and
     rope_key (B, S, dr) are the keys' side (the cache, or the T tokens
     themselves); w_kvb (r, H, dn + dv); q_pos (B, T) absolute positions:
-    key s is visible to query t iff ``s <= q_pos[b, t]``. Returns
+    key s is visible to query t iff ``s <= q_pos[b, t]``, and no row
+    past a query block's largest position is read. ``real`` (B, T) bool
+    marks the fed tokens that are tokens: the rest (a prompt's padding)
+    attend to nothing and their rows of the result are zeros. Returns
     (B, T, H, dv) in q's dtype."""
-    B, T, H, dn = q_nope.shape
-    S, dtype = latent.shape[1], q_nope.dtype
-    kv = jnp.einsum("bsr,rhk->bshk", latent, w_kvb,
+    B, S, dtype = latent.shape[0], latent.shape[1], q_nope.dtype
+    H, dn = q_nope.shape[2:]
+    kv = jnp.einsum("bsr,rhk->bhsk", latent, w_kvb,
                     preferred_element_type=jnp.float32).astype(dtype)
-    k_nope, v = kv[..., :dn], kv[..., dn:]
-    k_pos = jnp.arange(S)[None, None, :]
+    # the two score terms as one product: the rotated key is every
+    # head's
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+        rope_key[:, None], (B, H, S, rope_key.shape[-1]))], axis=-1)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1).transpose(0, 2, 1, 3)
+    out = prefix_attention(q, k, kv[..., dn:], _seen_from(q_pos, real),
+                           scale=scale, block_q=query_block,
+                           block_k=key_block)
+    return out.transpose(0, 2, 1, 3)
 
-    def block(args):
-        qn, qr, pos = args   # (B, tb, H, dn), (B, tb, H, dr), (B, tb)
-        s = jnp.einsum("bthk,bshk->bhts", qn, k_nope,
-                       preferred_element_type=jnp.float32)
-        s += jnp.einsum("bthk,bsk->bhts", qr, rope_key,
-                        preferred_element_type=jnp.float32)
-        p = _masked_softmax(s * scale, (k_pos <= pos[:, :, None])[:, None],
-                            dtype)
-        return jnp.einsum("bhts,bshk->bthk", p, v,
-                          preferred_element_type=jnp.float32).astype(dtype)
 
-    if T <= query_block:
-        return block((q_nope, q_rope, q_pos))
-    nb = -(-T // query_block)
-    pad = nb * query_block - T
-
-    def split(x):   # (B, T, ...) -> (nb, B, tb, ...)
-        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        x = x.reshape((B, nb, query_block) + x.shape[2:])
-        return jnp.moveaxis(x, 1, 0)
-
-    out = jax.lax.map(block, (split(q_nope), split(q_rope), split(q_pos)))
-    out = jnp.moveaxis(out, 0, 1).reshape((B, nb * query_block) + out.shape[3:])
-    return out[:, :T]
+def expanded_rows_read(q_pos, real, S: int):
+    """Key rows :func:`expanded_attention` reads for the real queries
+    of a call at its own tiles, summed: for each, the rows of the key
+    blocks its query block visits (the rows inside its mask are
+    ``q_pos + 1``)."""
+    return jnp.where(real, rows_visited(_seen_from(q_pos, real), S,
+                                        QUERY_BLOCK, KEY_BLOCK), 0).sum()
 
 
 def absorbed_attention(q_nope, q_rope, latent, rope_key, w_kvb, q_pos, *,
@@ -142,7 +152,9 @@ class MLAttention(nn.Module):
     is then required: row i's tokens land at its own depth, as in
     :class:`nn.attention.MultiHeadAttention`'s per-row mode (the model
     above keeps the one shared index for the callers that have none).
-    One fed token a row takes the absorbed path, more the expanded."""
+    One fed token a row takes the absorbed path, more the expanded,
+    where ``token_mask`` (B, T) bool, if given, marks the fed tokens
+    that are tokens: a prompt's padding attends to nothing."""
 
     num_heads: int
     q_lora_rank: int
@@ -163,7 +175,8 @@ class MLAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, decode: bool = False,
-                 cache_positions: Optional[jax.Array] = None):
+                 cache_positions: Optional[jax.Array] = None,
+                 token_mask: Optional[jax.Array] = None):
         B, T, d = x.shape
         H, r = self.num_heads, self.kv_lora_rank
         dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
@@ -226,10 +239,11 @@ class MLAttention(nn.Module):
                 else:
                     out = expanded_attention(
                         q_nope, q_rope, c_lat.value, c_key.value, w_kvb,
-                        positions, scale=scale)
+                        positions, scale=scale, real=token_mask)
         else:
             out = expanded_attention(q_nope, q_rope, latent, rope_key,
-                                     w_kvb, positions, scale=scale)
+                                     w_kvb, positions, scale=scale,
+                                     real=token_mask)
         return nn.DenseGeneral(
             d, axis=(-2, -1), use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, name="out")(out)

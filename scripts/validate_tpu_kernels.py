@@ -327,13 +327,46 @@ def check_bn_stats() -> bool:
     return ok
 
 
+def check_prefix_attention() -> bool:
+    """MLA's prefill kernel against the jax.numpy recurrence at the
+    served size (64 heads of 192 / 128, bf16, a row of 8,192): a whole
+    prompt with a padded tail, and a suffix behind 6,144 restored rows."""
+    import functools
+
+    from pytorch_distributed_nn_tpu.nn import mla
+    from pytorch_distributed_nn_tpu.ops.pallas import prefix_attention as pa
+
+    if jax.default_backend() != "tpu":
+        print("prefix_attention: skipped (the kernel needs the chip)")
+        return True
+    ok = True
+    ks = jax.random.split(jax.random.key(8), 3)
+    k = jax.random.normal(ks[1], (1, 64, 8192, 192), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, 64, 8192, 128), jnp.bfloat16)
+    kw = dict(scale=192 ** -0.5, block_q=mla.QUERY_BLOCK,
+              block_k=mla.KEY_BLOCK)
+    for T, first, real in [(8192, 0, 6528), (512, 6144, 512)]:
+        q = jax.random.normal(ks[0], (1, 64, T, 192), jnp.bfloat16)
+        pos = jnp.where(jnp.arange(T) < real, first + jnp.arange(T), -1)[None]
+        got, want = (jax.jit(functools.partial(run, **kw))(q, k, v, pos)
+                     for run in (pa._pallas, pa._blockwise))
+        err = float(jnp.abs(got.astype(jnp.float32)
+                            - want.astype(jnp.float32)).max())
+        line_ok = err < 2e-2
+        ok &= line_ok
+        print(f"prefix_attention T{T} at {first}, {real} real: "
+              f"max_err={err:.2e} {'OK' if line_ok else 'FAIL'}")
+    return ok
+
+
 def main() -> int:
     print(f"backend: {jax.default_backend()} devices: {jax.devices()}")
     if jax.default_backend() != "tpu":
         print("WARNING: not on TPU — validating fallbacks only")
     ok = (check_flash() & check_flash_grad() & check_quantize()
           & check_int8_matmul() & check_ring_block() & check_ring_bwd()
-          & check_long_context() & check_bn_stats())
+          & check_long_context() & check_bn_stats()
+          & check_prefix_attention())
     print("ALL OK" if ok else "FAILURES")
     return 0 if ok else 1
 

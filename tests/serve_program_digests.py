@@ -8,9 +8,13 @@ LongCat-shaped model's ``_serve_prefill`` and ``_serve_step`` lower to
 the same text, byte for byte, with the window, the ring, the q/k norm
 and the router's new fields in the shared modules as without them.
 The two step programs were written anew by ISSUE 34, which meant to
-change them (a decode round's cache write is one scatter a leaf); the
-two prefill programs are still those of that commit. A PR that means
-to change those programs writes the file anew and says so:
+change them (a decode round's cache write is one scatter a leaf).
+``longcat.prefill`` was written anew by ISSUE 36, which meant to change
+it (MLA's expanded path runs blockwise, ``ops/pallas/prefix_attention``;
+that one line: the ``trees`` entries are still those of ISSUE 35's
+commit, and the uncached forward's logits moved by 1e-6); the Mistral
+and K-EXAONE prefill programs are still those of the first commit. A PR
+that means to change those programs writes the file anew and says so:
 
     JAX_PLATFORMS=cpu python tests/serve_program_digests.py > tests/data/serve_program_digests.json
 

@@ -669,9 +669,13 @@ def test_served_with_counters_as_the_reference_counts(rank1):
                     "latent") == pre
         assert read("attn_rows_attended_total", "decode", layer,
                     "latent") == both - pre
-        # a decode round scores the row's whole length
+        # a decode round scores the row's whole length; a prefill the
+        # key tiles its query tiles visit: here one, the bucket's rows
         assert read("attn_rows_read_total", "decode", layer,
                     "latent") == fed * 64
+        assert read("attn_rows_read_total", "prefill", layer,
+                    "latent") == sum(len(p) * engine_mod._bucket_len(len(p))
+                                     for p in prompts)
         if dense:
             assert read("moe_calls_total", "decode", layer) == 0
             assert read("moe_pick_groups_total", "decode", layer) == 0
@@ -713,7 +717,8 @@ def test_shared_modules_leave_the_other_families_programs_alone(family,
     name, ``rotary_embedding``'s ``freqs`` and ``HeldExpertsMoE``'s
     groups are fields and arguments whose other values LongCat and
     K-EXAONE pass or leave: their serve programs lower to the text they
-    lowered to at commit 6d4c912, before ISSUE 35
+    lowered to at commit 6d4c912, before ISSUE 35, but for LongCat's
+    prefill, which ISSUE 36 meant to change
     (``tests/serve_program_digests.py`` says how the file was made)."""
     text = serve_program_digests.lowered(family, program)
     assert hashlib.sha256(text.encode()).hexdigest() \

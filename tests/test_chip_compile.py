@@ -120,6 +120,25 @@ def _ring_block(bh, tl, D):
     return build
 
 
+def _prefix_attention(T, S):
+    """MLA's expanded path at the served tiles: 64 heads of 192 / 128."""
+    from pytorch_distributed_nn_tpu.nn import mla
+    from pytorch_distributed_nn_tpu.ops.pallas import prefix_attention as pa
+
+    def build(arg):
+        def run(q, k, v, pos):
+            bq, bk = pa.tiles(T, S, mla.QUERY_BLOCK, mla.KEY_BLOCK)
+            assert pa._kernel_tiles(192, 128, bq, bk)
+            return pa._pallas(q, k, v, pos, scale=192 ** -0.5, block_q=bq,
+                              block_k=bk)
+
+        return run, [arg((1, 64, T, 192), jnp.bfloat16),
+                     arg((1, 64, S, 192), jnp.bfloat16),
+                     arg((1, 64, S, 128), jnp.bfloat16),
+                     arg((1, T), jnp.int32)], 1
+    return build
+
+
 CASES = {
     # Llama-3-8B's head layout (32 q / 8 kv heads of 128), long context
     "flash_fwd_bwd_d128_gqa_T8192": _flash(32, 8, 8192, 128, True),
@@ -140,6 +159,12 @@ CASES = {
     "bn_stats_dot_c256": _bn_stats((128, 56, 56, 256), True),
     # one ring step of a 32k sequence over four devices
     "ring_block_Tl8192_d128": _ring_block(8, 8192, 128),
+    # A.X-K1's whole prompt and its suffix behind restored rows against
+    # a row of 8,192; LongCat's smallest and largest buckets
+    "prefix_attention_mla_T8192_S8192": _prefix_attention(8192, 8192),
+    "prefix_attention_mla_T512_S8192": _prefix_attention(512, 8192),
+    "prefix_attention_mla_T256_S256": _prefix_attention(256, 256),
+    "prefix_attention_mla_T4096_S4096": _prefix_attention(4096, 4096),
 }
 
 
@@ -341,3 +366,49 @@ def test_serve_step_writes_cache_rows_without_a_loop(topo, monkeypatch,
     was, is_ = before.memory_analysis(), now.memory_analysis()
     assert is_.alias_size_in_bytes == was.alias_size_in_bytes
     assert is_.temp_size_in_bytes <= was.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("family,T,S,kernels", [
+    ("longcat", 2048, 2048, 2),     # two attentions a layer, T = S
+    ("ax_k1", 512, 8192, 2),        # a suffix against the cell's row
+    ("llama", 2048, 2048, 0),       # nn/attention.py: not this routine
+])
+def test_serve_prefill_keeps_mla_scores_in_the_core(topo, monkeypatch,
+                                                    family, T, S, kernels):
+    """The compiled ``_serve_prefill`` of the latent-attention families
+    holds one Pallas call an attention and no float32 tensor of scores
+    ``heads x queries x row``; the dispatcher asks for the backend, and
+    this test answers for the chip."""
+    import re
+
+    from pytorch_distributed_nn_tpu.config import ModelConfig
+    from pytorch_distributed_nn_tpu.inference.generate import init_cache
+    from pytorch_distributed_nn_tpu.models import get_model
+    from pytorch_distributed_nn_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    name, extra = _SERVED[family]
+    model = get_model(ModelConfig(name=name, dtype="float32",
+                                  compute_dtype="bfloat16",
+                                  extra=dict(extra)))
+
+    def on(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on(jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+        train=False))["params"])
+    cache = on(jax.eval_shape(lambda: init_cache(model, 1, S)))
+    one = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    prefill = jax.jit(lambda *a: engine._serve_prefill.__wrapped__(*a),
+                      static_argnums=(0,), donate_argnums=(2,))
+    text = prefill.lower(
+        model, params, cache,
+        jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=one_chip),
+        one, one).compile().as_text()
+    assert text.count(KERNEL) == kernels
+    heads = extra["num_heads"]
+    scores = set(re.findall(rf"f32\[[\d,]*,(?:{T}|512),{S}\]", text))
+    assert bool(scores) == (kernels == 0), scores

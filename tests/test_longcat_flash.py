@@ -131,11 +131,11 @@ def test_published_defaults_are_the_registered_sizes():
     assert (whole.ep_size, share.ep_size, share.ep_rank) == (1, 32, 0)
 
 
-@pytest.mark.parametrize("block", [4, 16, 64])
-def test_expanded_attention_in_query_blocks_is_the_unblocked_one(block):
-    """Blocks of queries bound the score temporaries and change nothing:
-    a query's softmax is over its own row (tolerance: the same float32
-    sums, 1e-6)."""
+@pytest.mark.parametrize("tiles", [(4, 1024), (16, 8), (64, 16)])
+def test_expanded_attention_in_query_blocks_is_the_unblocked_one(tiles):
+    """Tiles of queries and keys bound the score temporaries and change
+    nothing: a query's softmax is over its own row, whatever the blocks
+    it is summed in (tolerance: the same float32 sums, 1e-6)."""
     k = jax.random.split(jax.random.key(3), 5)
     B, T, H, dn, dr, dv, r = 2, 40, 4, 16, 8, 16, 16
     args = (jax.random.normal(k[0], (B, T, H, dn)),
@@ -144,9 +144,10 @@ def test_expanded_attention_in_query_blocks_is_the_unblocked_one(block):
             jax.random.normal(k[3], (B, T, dr)),
             jax.random.normal(k[4], (r, H, dn + dv)) / 4,
             jnp.broadcast_to(jnp.arange(T)[None], (B, T)))
-    whole = mla.expanded_attention(*args, scale=24 ** -0.5, query_block=T)
+    whole = mla.expanded_attention(*args, scale=24 ** -0.5, query_block=T,
+                                   key_block=T)
     parts = mla.expanded_attention(*args, scale=24 ** -0.5,
-                                   query_block=block)
+                                   query_block=tiles[0], key_block=tiles[1])
     assert np.abs(np.asarray(whole - parts)).max() < 1e-6
 
 

@@ -1,0 +1,219 @@
+"""The blockwise attention of MLA's expanded path
+(``ops/pallas/prefix_attention``): the Pallas kernel in interpret mode
+and the ``jax.numpy`` recurrence, each against the dense masked softmax
+on small MLA shapes (a qk width that differs from the v width), and the
+arithmetic that says which key rows a query block visits. The compiled
+kernel at the served sizes is in ``tests/test_chip_compile.py`` (no
+chip) and, against the recurrence, at the end of this file (chip only:
+``tests/conftest.py`` holds pytest to the CPU, so on the chip run it
+with ``python -m pytest --noconftest tests/test_prefix_attention.py -k
+compiled``; ``scripts/validate_tpu_kernels.py`` makes the same
+comparison beside the other kernels').
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pytorch_distributed_nn_tpu.nn import mla
+from pytorch_distributed_nn_tpu.ops.pallas import prefix_attention as pa
+
+H, DK, DV = 4, 24, 16
+SCALE = DK ** -0.5
+EXECUTIONS = {
+    "kernel": functools.partial(pa._pallas, interpret=True),
+    "jax_numpy": pa._blockwise,
+}
+
+
+def _dense(q, k, v, q_pos, scale=SCALE):
+    """The plain form: every score, one softmax a query."""
+    s = jnp.einsum("bhtd,bhsd->bhts", q, k) * scale
+    seen = jnp.arange(k.shape[2])[None, None, None, :] \
+        <= q_pos[:, None, :, None]
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+    return jnp.einsum("bhts,bhsd->bhtd", p, v)
+
+
+def _operands(B, T, S, key=0):
+    ks = jax.random.split(jax.random.key(key), 3)
+    return (jax.random.normal(ks[0], (B, H, T, DK)),
+            jax.random.normal(ks[1], (B, H, S, DK)),
+            jax.random.normal(ks[2], (B, H, S, DV)))
+
+
+def _case(name):
+    """``(q, k, v, q_pos, (block_q, block_k), real queries (B, T))``."""
+    if name == "whole":                     # T = S, offset 0
+        q, k, v = _operands(2, 32, 32)
+        pos = jnp.broadcast_to(jnp.arange(32)[None], (2, 32))
+        return q, k, v, pos, (8, 8), pos >= 0
+    if name == "suffix_rows_differ":        # T < S, an offset a row
+        q, k, v = _operands(2, 16, 48, 1)
+        pos = jnp.asarray([[20], [7]]) + jnp.arange(16)[None]
+        return q, k, v, pos, (8, 16), pos >= 0
+    if name == "ragged":                    # neither in whole tiles
+        q, k, v = _operands(2, 27, 43, 2)
+        pos = jnp.asarray([[13], [0]]) + jnp.arange(27)[None]
+        return q, k, v, pos, (8, 16), pos >= 0
+    if name == "padded_tail":               # queries that are no tokens
+        q, k, v = _operands(2, 32, 32, 3)
+        real = jnp.arange(32)[None] < jnp.asarray([[19], [8]])
+        pos = jnp.where(real, jnp.arange(32)[None], -1)
+        return q, k, v, pos, (8, 8), real
+    if name == "nan_past_the_prefix":       # the row's tail is never read
+        q, k, v = _operands(2, 16, 64, 4)
+        pos = jnp.asarray([[17], [3]]) + jnp.arange(16)[None]
+        k = k.at[:, :, 33:].set(jnp.nan)    # 32 is the last row seen
+        v = v.at[:, :, 33:].set(jnp.nan)
+        return q, k, v, pos, (8, 8), pos >= 0
+    raise KeyError(name)
+
+
+CASES = ("whole", "suffix_rows_differ", "ragged", "padded_tail",
+         "nan_past_the_prefix")
+
+
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("execution", list(EXECUTIONS))
+def test_blockwise_is_the_dense_masked_softmax(execution, case):
+    """Online softmax over key blocks at or under the diagonal against
+    the dense form: the same float32 sums in another order (2e-6 on
+    outputs of size ~1). A query that is no token gets zeros; NaN past
+    the visible prefix reaches nothing."""
+    q, k, v, pos, (bq, bk), real = _case(case)
+    got = pa._in_whole_tiles(EXECUTIONS[execution], q, k, v, pos,
+                             scale=SCALE, block_q=bq, block_k=bk)
+    finite = jnp.nan_to_num(k), jnp.nan_to_num(v)
+    want = _dense(q, *finite, jnp.maximum(pos, 0))
+    assert got.shape == want.shape and got.dtype == q.dtype
+    assert bool(jnp.isfinite(got).all())
+    gap = jnp.abs(got - want).max(axis=(1, 3))
+    assert float(jnp.where(real, gap, 0).max()) < 2e-6
+    assert float(jnp.where(real, 0, jnp.abs(got).max(axis=(1, 3))).max()) == 0
+
+
+@pytest.mark.parametrize("tiles", [(8, 8), (16, 8), (8, 32), (32, 16)])
+def test_kernel_is_the_recurrence_in_bf16(tiles):
+    """The two executions on bf16 operands (float32 maximum, denominator
+    and accumulator in both): the oracle's numbers to bf16's rounding
+    of an output of size ~1."""
+    q, k, v = (x.astype(jnp.bfloat16) for x in _operands(1, 32, 64, 5))
+    pos = 30 + jnp.arange(32)[None]
+    got, want = (run(q, k, v, pos, scale=SCALE, block_q=tiles[0],
+                     block_k=tiles[1]) for run in EXECUTIONS.values())
+    assert got.dtype == jnp.bfloat16
+    assert float(jnp.abs(got.astype(jnp.float32)
+                         - want.astype(jnp.float32)).max()) < 2e-2
+
+
+def _latent_operands(B, T, S, dn=16, dr=8, dv=16, r=16, key=6):
+    ks = jax.random.split(jax.random.key(key), 5)
+    return (jax.random.normal(ks[0], (B, T, H, dn)),
+            jax.random.normal(ks[1], (B, T, H, dr)),
+            jax.random.normal(ks[2], (B, S, r)),
+            jax.random.normal(ks[3], (B, S, dr)),
+            jax.random.normal(ks[4], (r, H, dn + dv)) / 4)
+
+
+@pytest.mark.parametrize("tiles", [(512, 1024), (8, 16), (5, 7)])
+def test_expanded_attention_is_the_two_term_dense_form(tiles):
+    """``expanded_attention`` (one product over ``[nope | rope]``, K and
+    V expanded once) against the two score terms written out, for a
+    suffix at a different offset a row with a padded tail."""
+    q_nope, q_rope, latent, rope_key, w_kvb = _latent_operands(2, 12, 40)
+    real = jnp.arange(12)[None] < jnp.asarray([[12], [7]])
+    pos = jnp.asarray([[25], [4]]) + jnp.arange(12)[None]
+    got = mla.expanded_attention(
+        q_nope, q_rope, latent, rope_key, w_kvb, pos, scale=24 ** -0.5,
+        real=real, query_block=tiles[0], key_block=tiles[1])
+    kv = jnp.einsum("bsr,rhk->bshk", latent, w_kvb)
+    s = jnp.einsum("bthk,bshk->bhts", q_nope, kv[..., :16]) \
+        + jnp.einsum("bthk,bsk->bhts", q_rope, rope_key)
+    seen = jnp.arange(40)[None, None, None, :] <= pos[:, None, :, None]
+    p = jax.nn.softmax(jnp.where(seen, s * 24 ** -0.5, -1e30), axis=-1)
+    want = jnp.einsum("bhts,bshk->bthk", p, kv[..., 16:])
+    assert got.shape == (2, 12, H, 16)
+    assert float(jnp.where(real[..., None, None],
+                           jnp.abs(got - want), 0).max()) < 2e-6
+    assert float(jnp.where(real[..., None, None], 0, got).max()) == 0
+
+
+def test_a_whole_prompt_visits_136_of_256_tile_pairs():
+    """The arithmetic of the counter ``attn_rows_read_total`` for a
+    prefill: 8,192 positions in tiles of 512 x 512 visit the tiles at
+    or under the diagonal; at 1,024 keys a tile a query block reads to
+    the end of the tile its last position lies in; a suffix behind
+    6,144 restored rows reads those and its own tile; padding reads
+    nothing."""
+    whole = jnp.arange(8192)[None]
+    assert int(pa.rows_visited(whole, 8192, 512, 512).sum()) \
+        == 136 * 512 * 512
+    rows = np.asarray(pa.rows_visited(whole, 8192, 512, 1024))[0]
+    assert rows[0] == rows[1023] == 1024 and rows[1024] == 2048
+    assert rows[-1] == 8192
+    suffix = 6144 + jnp.arange(512)[None]
+    assert set(np.asarray(pa.rows_visited(suffix, 8192, 512, 1024))[0]) \
+        == {7168}
+    # a prompt of 6,524 in a bucket of 8,192: the blocks of padding
+    # visit nothing, the last real block reads up to its last token
+    real = whole < 6524
+    read = int(mla.expanded_rows_read(whole, real, 8192))
+    assert read == 512 * 1024 * (1 + 1 + 2 + 2 + 3 + 3 + 4 + 4 + 5 + 5
+                                 + 6 + 6) + (6524 - 6144) * 7168
+    attended = 6524 * 6525 // 2
+    assert 0.85 < attended / read < 0.87
+    # a row cache shorter than a key tile is read whole, no further
+    assert int(mla.expanded_rows_read(jnp.arange(16)[None],
+                                      jnp.arange(16)[None] < 9, 64)) \
+        == 9 * 64
+
+
+def test_which_execution_a_shape_takes(monkeypatch):
+    """The dispatcher picks by what it can observe: off a TPU the
+    recurrence; on one, the kernel where the tiles lay out (the served
+    shapes) and the recurrence where they do not."""
+    assert pa._kernel_tiles(192, 128, 512, 1024)       # the served form
+    assert pa._kernel_tiles(192, 128, 256, 256)        # a bucket of 256
+    assert not pa._kernel_tiles(192, 128, 50, 50)      # T = 50, uncached
+    assert not pa._kernel_tiles(24, 16, 8, 8)          # this file's sizes
+    calls = []
+    monkeypatch.setattr(pa, "_pallas", lambda *a, **k: calls.append(
+        "kernel") or pa._blockwise(*a, **k))
+    q, k, v = _operands(1, 8, 8)
+    pa.prefix_attention(q, k, v, jnp.arange(8)[None], scale=SCALE,
+                        block_q=512, block_k=1024)
+    assert calls == []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pa.prefix_attention(q, k, v, jnp.arange(8)[None], scale=SCALE,
+                        block_q=512, block_k=1024)
+    assert calls == []
+    ks = jax.random.split(jax.random.key(7), 3)
+    pa.prefix_attention(
+        jax.random.normal(ks[0], (1, 1, 32, 8)),
+        jax.random.normal(ks[1], (1, 1, 128, 8)),
+        jax.random.normal(ks[2], (1, 1, 128, 128)),
+        96 + jnp.arange(32)[None], scale=1.0, block_q=16, block_k=128)
+    assert calls == ["kernel"]
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="the compiled kernel needs the chip")
+@pytest.mark.parametrize("T,first", [(8192, 0), (512, 6144)])
+def test_compiled_kernel_is_the_recurrence_at_the_served_size(T, first):
+    """64 heads of 192 / 128 in bf16 against 8,192 rows, a whole prompt
+    and a suffix behind 6,144 restored rows, at the served tiles."""
+    ks = jax.random.split(jax.random.key(8), 3)
+    q = jax.random.normal(ks[0], (1, 64, T, 192), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, 64, 8192, 192), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, 64, 8192, 128), jnp.bfloat16)
+    pos = first + jnp.arange(T)[None]
+    kw = dict(scale=192 ** -0.5, block_q=mla.QUERY_BLOCK,
+              block_k=mla.KEY_BLOCK)
+    got = jax.jit(functools.partial(pa._pallas, **kw))(q, k, v, pos)
+    want = jax.jit(functools.partial(pa._blockwise, **kw))(q, k, v, pos)
+    assert float(jnp.abs(got.astype(jnp.float32)
+                         - want.astype(jnp.float32)).max()) < 2e-2
