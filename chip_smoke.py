@@ -98,32 +98,28 @@ def emit(phase: str, **fields) -> None:
 
 
 class Meter:
-    """Compile seconds and persistent-cache traffic, from JAX's own
-    monitoring events; ``since()`` gives one phase's share."""
-
-    _COMPILE = "/jax/core/compile/backend_compile_duration"
-    _HIT = "/jax/compilation_cache/cache_hits"
-    _MISS = "/jax/compilation_cache/cache_misses"
+    """Compile seconds and persistent-cache traffic, from the program's
+    own ``jax.monitoring`` listener (obs/jitwatch.py); ``since()`` gives
+    one phase's share."""
 
     def __init__(self, cache_dir: str | None) -> None:
-        import jax.monitoring as mon
+        from pytorch_distributed_nn_tpu.obs import jitwatch
 
+        jitwatch.install()
+        self._totals = jitwatch.process_totals
         self.cache_dir = cache_dir
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
 
-    def _on_duration(self, name: str, secs: float, **_) -> None:
-        if name == self._COMPILE:
-            self.compile_s += secs
+    @property
+    def compile_s(self) -> float:
+        return self._totals()["compile_s"]
 
-    def _on_event(self, name: str, **_) -> None:
-        if name == self._HIT:
-            self.hits += 1
-        elif name == self._MISS:
-            self.misses += 1
+    @property
+    def hits(self) -> int:
+        return self._totals()["cache_hits"]
+
+    @property
+    def misses(self) -> int:
+        return self._totals()["cache_misses"]
 
     def cache_entries(self) -> int:
         d = self.cache_dir

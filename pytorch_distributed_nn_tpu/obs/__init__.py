@@ -9,7 +9,12 @@ heartbeats) with:
 - :mod:`obs.span` — ``with obs.span("data/next_batch"): ...`` Chrome
   trace events per host, free when disabled;
 - :mod:`obs.goodput` — per-step wall-time decomposition into
-  data/compute/collective/checkpoint/eval/other;
+  data/compute/collective/checkpoint/eval/other, and the serve loop's
+  whole-thread tally (phases that partition its wall time, one record
+  a round: :func:`serve_loop_records`);
+- :mod:`obs.jitwatch` — the process's one ``jax.monitoring`` listener:
+  which call traced, lowered or compiled, on which thread, and what
+  the garbage collector held;
 - :mod:`obs.runtime_gauges` — mesh topology + heartbeat state gauges;
 - :mod:`obs.aggregate` — cross-host snapshot aggregation through the
   native store;
@@ -57,7 +62,8 @@ heartbeats) with:
 - :mod:`obs.xray` — anomaly-triggered device profiling (ISSUE 10):
   bounded, rate-limited ``jax.profiler`` captures (page/interval/
   on-demand triggers), per-op MFU/roofline attribution, compile
-  telemetry feeding the ``recompile_storm`` detector, and the
+  telemetry (fed by :mod:`obs.jitwatch`) for the ``recompile_storm``
+  detector, and the
   ``bench.py --ledger`` perf-regression gate; inert unless
   ``TPUNN_XRAY`` is set.
 
@@ -71,6 +77,7 @@ heartbeats) with:
 from pytorch_distributed_nn_tpu.obs import audit  # noqa: F401
 from pytorch_distributed_nn_tpu.obs import critpath  # noqa: F401
 from pytorch_distributed_nn_tpu.obs import flight  # noqa: F401
+from pytorch_distributed_nn_tpu.obs import jitwatch  # noqa: F401
 from pytorch_distributed_nn_tpu.obs import meter  # noqa: F401
 from pytorch_distributed_nn_tpu.obs import stats  # noqa: F401
 from pytorch_distributed_nn_tpu.obs import trace  # noqa: F401
@@ -83,6 +90,7 @@ from pytorch_distributed_nn_tpu.obs.goodput import (  # noqa: F401
     PHASES,
     GoodputMeter,
     StepBreakdown,
+    serve_loop_records,
 )
 from pytorch_distributed_nn_tpu.obs.registry import (  # noqa: F401
     Counter,

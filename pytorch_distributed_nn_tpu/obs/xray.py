@@ -29,9 +29,9 @@ post-mortem rings (obs/flight.py + forensics). This module answers
    capture is never empty. Rendered by ``scripts/obs_xray.py`` and
    ``scripts/obs_report.py --xray``.
 
-3. **Compile telemetry** — when armed, a DEBUG log watch on jax's
-   dispatch logger turns every ``Finished XLA compilation of
-   jit(<fn>)`` line into ``xray_compiles_total`` /
+3. **Compile telemetry** — when armed, every backend compile the
+   process's ``jax.monitoring`` listener hears (:mod:`obs.jitwatch`,
+   which names the function) becomes ``xray_compiles_total`` /
    ``xray_compile_seconds`` updates, a ``xray/compile`` flight event,
    and a :func:`watchtower.on_compile` feed — the ``recompile_storm``
    detector names the function that keeps re-tracing mid-run.
@@ -69,7 +69,7 @@ import threading
 import time
 from typing import Callable, Sequence
 
-from pytorch_distributed_nn_tpu.obs import flight
+from pytorch_distributed_nn_tpu.obs import flight, jitwatch
 from pytorch_distributed_nn_tpu.obs.registry import get_registry
 from pytorch_distributed_nn_tpu.obs.stats import mad, median
 
@@ -509,45 +509,6 @@ def render_op_table(att: dict, *, top: int = 12) -> str:
 
 
 # ---------------------------------------------------------------------------
-# Compile telemetry: jax dispatch-log watch
-# ---------------------------------------------------------------------------
-
-# jax logs "Finished XLA compilation of jit(<fn>) in <secs> sec" (and
-# "Finished tracing + transforming <fn> for pjit in ...") at DEBUG on
-# its dispatch logger; the duration-only jax.monitoring events carry no
-# function name, so the log line is the only place both live together.
-_COMPILE_LOGGER = "jax._src.dispatch"
-_COMPILE_MSG_RE = re.compile(
-    r"Finished XLA compilation of (.+?) in ([0-9.eE+-]+) sec")
-
-
-class _CompileLogHandler(logging.Handler):
-    """Tap + relay. Installing the tap forces the dispatch logger down to
-    DEBUG and cuts propagation (else arming xray would spray every jax
-    compile line onto the app's console); records at or above the
-    logger's previous effective level are relayed to root so warnings
-    still surface exactly as before."""
-
-    def __init__(self, engine: "XrayEngine",
-                 relay_level: int = logging.WARNING) -> None:
-        super().__init__(level=logging.DEBUG)
-        self._engine = engine
-        self._relay_level = relay_level
-
-    def emit(self, record: logging.LogRecord) -> None:  # noqa: A003
-        try:
-            m = _COMPILE_MSG_RE.search(record.getMessage())
-            if m:
-                self._engine._on_compile(m.group(1), float(m.group(2)))
-            if record.levelno >= self._relay_level:
-                root = logging.getLogger()
-                if root.isEnabledFor(record.levelno):
-                    root.handle(record)
-        except Exception:  # a telemetry tap must never break dispatch
-            pass
-
-
-# ---------------------------------------------------------------------------
 # The engine
 # ---------------------------------------------------------------------------
 
@@ -572,9 +533,6 @@ class XrayEngine:
         # compile telemetry
         self.compile_counts: dict[str, int] = {}
         self.compile_seconds_total = 0.0
-        self._compile_handler: _CompileLogHandler | None = None
-        self._compile_prev_level: int | None = None
-        self._compile_prev_propagate: bool = True
         self._active: dict | None = None
         self._last_capture_t: float | None = None
         self._n_started = 0
@@ -737,35 +695,21 @@ class XrayEngine:
     # -- compile telemetry -----------------------------------------------
 
     def _install_compile_watch(self) -> None:
-        """DEBUG log watch on jax's dispatch logger (idempotent)."""
-        if self._compile_handler is not None:
-            return
-        lg = logging.getLogger(_COMPILE_LOGGER)
-        self._compile_prev_level = lg.level
-        self._compile_prev_propagate = lg.propagate
-        self._compile_handler = _CompileLogHandler(
-            self, relay_level=lg.getEffectiveLevel())
-        lg.addHandler(self._compile_handler)
-        lg.propagate = False
-        if lg.getEffectiveLevel() > logging.DEBUG:
-            lg.setLevel(logging.DEBUG)
+        """Hear the process's jit listener (idempotent)."""
+        jitwatch.add_sink(self._on_jit_event)
 
     def _uninstall_compile_watch(self) -> None:
-        if self._compile_handler is None:
-            return
-        lg = logging.getLogger(_COMPILE_LOGGER)
-        lg.removeHandler(self._compile_handler)
-        if self._compile_prev_level is not None:
-            lg.setLevel(self._compile_prev_level)
-        lg.propagate = self._compile_prev_propagate
-        self._compile_handler = None
+        jitwatch.remove_sink(self._on_jit_event)
+
+    def _on_jit_event(self, stage: str, fun: str, seconds: float) -> None:
+        if stage == "compile":
+            self._on_compile(fun, seconds)
 
     def _on_compile(self, name: str, seconds: float) -> None:
-        """One observed XLA compilation (from the log watch, or fed
+        """One observed XLA compilation (from the jit listener, or fed
         directly in tests): counters, a flight breadcrumb, and the
         watchtower recompile_storm feed."""
-        if name.startswith("jit(") and name.endswith(")"):
-            name = name[4:-1]
+        name = jitwatch.bare_name(name)
         with self._lock:
             self.compile_counts[name] = (
                 self.compile_counts.get(name, 0) + 1)
@@ -783,7 +727,7 @@ class XrayEngine:
     # -- teardown ---------------------------------------------------------
 
     def close(self, t: float | None = None) -> None:
-        """Disarm: finish any open capture and restore jax's logger."""
+        """Disarm: finish any open capture and leave the jit listener."""
         if self._active is not None:
             self._finish(time.time() if t is None else t)
         self._uninstall_compile_watch()

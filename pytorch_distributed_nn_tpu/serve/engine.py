@@ -80,6 +80,12 @@ queue-depth / KV-utilization gauges, one flight-ring ``serve`` event
 per decode round (a wedged loop is visible to the doctor as a stalled
 round counter), per-request retroactive spans when tracing is on, and
 per-request ``serve_request`` JSONL records through MetricsLogger.
+The loop accounts for its own thread (``ServingEngine.loop``, an
+:class:`obs.GoodputMeter` over the serve loop's phases): every
+instant from its first round on belongs to one phase, each phase's
+boundary also writes the span, and every round leaves a record
+(:func:`obs.serve_loop_records`) that names what the thread traced,
+lowered or compiled in it and what the collector took.
 """
 
 from __future__ import annotations
@@ -103,6 +109,8 @@ from pytorch_distributed_nn_tpu.nn.lora import num_adapters
 from pytorch_distributed_nn_tpu.obs import (
     audit,
     flight,
+    goodput,
+    jitwatch,
     meter,
     trace,
     watchtower,
@@ -497,6 +505,27 @@ class ServingEngine:
         self._flight: collections.deque = collections.deque()
         self._round_no = 0  # the newest dispatched round's number
         self._t_fetched = 0.0  # when the last fetch returned
+        # when _decode_round began to wait for the chip: what came
+        # before is the round's dispatch, what follows its fetch
+        self._t_fetch = 0.0
+        # of the admissions since the last round: the wait for a
+        # prefill's first token, and the thread's seconds on a core
+        # (the round's record carries both: an admission that is long
+        # and is neither waited for the chip, for a lock or for a core)
+        self._first_token_wait = 0.0
+        self._admit_cpu = 0.0
+        # the loop's own account of its thread, from its first round
+        # (or InferenceServer.start) on, on the clock the engine's
+        # other stamps use
+        jitwatch.install()
+        self.loop = goodput.GoodputMeter(
+            goodput.SERVE_PHASES, goodput.SERVE_SPANS, cat="app",
+            clock=time.monotonic, rounds=True, idle="parked",
+            counter=obs.get_registry().counter(
+                "serve_loop_seconds_total",
+                "seconds of the serve loop's thread by phase "
+                "(exclusive; they sum to its wall time)",
+                labels=("phase",)))
         self._overlapped = 0  # rounds dispatched over an unfetched one
         # the programs' ``lora`` argument: the bank and each slot's
         # adapter id (written with the slot state above), or None, and
@@ -608,6 +637,9 @@ class ServingEngine:
         was nothing to do (caller may sleep/park)."""
         sched = self.scheduler
         sched.round += 1
+        loop = self.loop
+        if not loop.running:
+            loop.start()
         with obs.span("serve/round", round=sched.round) as rnd:
             # chaos tenant_flood: synthetic burst traffic lands through the
             # REAL submit path (quota checks, DRR queues, reject counters)
@@ -620,9 +652,10 @@ class ServingEngine:
                 self._g_occ.set(0)
                 rnd.set(occ=0)
                 return changed
-            with obs.span("serve/decode"):
+            with loop.phase("dispatch") as dec:
                 host_tok, dt = self._decode_round()
-            with obs.span("serve/round_host") as host_span:
+                dec.set(dispatch_us=dec.split("fetch", self._t_fetch))
+            with loop.phase("round_host") as host_span:
                 self.round_seconds.append(dt)
                 self._h_tok.observe(dt)
                 if self._flight:
@@ -672,7 +705,15 @@ class ServingEngine:
                 retired = self._collect(host_tok, live)
                 if sched.round % _COUNTER_ROUNDS == 0:
                     self.publish_device_counters()
+                    loop.publish()
                 host_span.set(retired=retired)
+            wait, self._first_token_wait = self._first_token_wait, 0.0
+            if wait:
+                cpu, self._admit_cpu = self._admit_cpu, 0.0
+                loop.lap(sched.round, occ=occ, first_token_wait_s=wait,
+                         admit_cpu_s=cpu)
+            else:
+                loop.lap(sched.round, occ=occ)
             rnd.set(occ=self.active_slots)
             return True
 
@@ -710,10 +751,17 @@ class ServingEngine:
         free = [i for i, s in enumerate(self._slots) if s is None]
         if not free:
             return False
-        admitted = self.scheduler.next_admissions(len(free))
+        sched = self.scheduler
+        with self.loop.phase("next_admissions") as nxt:
+            admitted = sched.next_admissions(len(free))
+            nxt.set(queued=sched.last_queued, admitted=len(admitted),
+                    lock_wait_us=sched.last_lock_wait_us)
         if not admitted:
             return False
-        with obs.span("serve/admit", n=len(admitted)):
+        with self.loop.phase("admit", n=len(admitted)):
+            # once a pass, not a round: this clock costs 5 us on the
+            # chip's host, where it ticks every 10 ms
+            cpu0 = time.thread_time()
             filled = []
             for req in admitted:
                 # a branched request claims one row per branch (the
@@ -726,6 +774,7 @@ class ServingEngine:
             self._retire_finished()
             self._write_slots(filled)
             self._pending_logprob.clear()
+            self._admit_cpu += time.thread_time() - cpu0
         return True
 
     def _row_lora(self, adapter: int):
@@ -792,14 +841,14 @@ class ServingEngine:
                       tokens=T, padded=t_pad, cached=m, row_len=pad):
             tokens = np.zeros((1, t_pad), np.int32)
             tokens[0, :T] = suffix  # left-ALIGNED (pad tail is masked)
-            with obs.span("serve/fresh_cache"):
+            with jitwatch.dispatch_span("serve/fresh_cache"):
                 row_cache = _fresh_cache(self.model, 1, pad)
             if m > 0:
                 nb = len(match.restore_blocks)
                 table = np.zeros((self._blocks_per_seq,), np.int32)
                 table[:nb] = match.restore_blocks
                 t_restore = time.monotonic()
-                with obs.span("serve/restore", blocks=nb):
+                with jitwatch.dispatch_span("serve/restore", blocks=nb):
                     row_cache = _restore_blocks(
                         row_cache, self._store, bs, table, np.int32(nb))
                 trace.on_segment(req.trace, "restore", t_restore,
@@ -817,14 +866,17 @@ class ServingEngine:
             h["step"][slots] = req.decode_step0
             h["logprob"][slots] = 0.0
             rows = {k: v[slots] for k, v in h.items()} if sampled else None
-            with obs.span("serve/prefill", request=req.request_id,
-                          prompt_len=L, cached=m):
+            with jitwatch.dispatch_span(
+                    "serve/prefill", request=req.request_id,
+                    prompt_len=L, cached=m):
                 tok0, row_cache, drawn = _serve_prefill(
                     self.model, self.params, row_cache,
                     jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
                     jnp.asarray([m], jnp.int32),
                     self._row_lora(req.adapter), rows)
+                t_wait = time.monotonic()
                 firsts = [int(t) for t in np.asarray(tok0)]
+                self._first_token_wait += time.monotonic() - t_wait
                 if sampled:
                     h["logprob"][slots] = np.asarray(drawn["logprob"])
             if match is not None:
@@ -845,7 +897,8 @@ class ServingEngine:
                 self._h_ttft_tenant.observe(ttft, tenant=req.tenant)
             sids = branch_seq_ids(req)
             totals = self._counter_leaf
-            with obs.span("serve/insert_row", rows=len(slots)):
+            with jitwatch.dispatch_span("serve/insert_row",
+                                        rows=len(slots)):
                 for k, slot in enumerate(slots):
                     # one program for every row of a model without totals
                     self._cache = _insert_row(
@@ -880,7 +933,9 @@ class ServingEngine:
         device->host fetch). Dispatches until one round is in flight
         beyond the oldest, then fetches the oldest: ``(its tokens, its
         seconds)``, the seconds from the later of its dispatch and the
-        previous fetch's return to this fetch's return."""
+        previous fetch's return to this fetch's return. ``_t_fetch`` is
+        when the waiting began: ``step()`` splits the call there into
+        the host's dispatch and the wait for the chip."""
         t0 = time.monotonic()
         # chaos slow@/crash@/preempt@ key on the decode round the way
         # they key on the training step; inside the timed window so an
@@ -901,6 +956,7 @@ class ServingEngine:
             self._round_no += 1
             unfetched.append((nxt, t0, given))
         nxt, dispatched, _ = unfetched.popleft()
+        self._t_fetch = time.monotonic()
         host_tok = np.asarray(nxt)
         now = time.monotonic()
         dt = now - max(dispatched, self._t_fetched)
@@ -990,7 +1046,10 @@ class ServingEngine:
                 # final flush BEFORE retire: the closing chunk must be in
                 # the stream when done.set() wakes the client
                 self._emit_chunk(s, final=True)
-                self.scheduler.retire(req, np.asarray(s.tokens, np.int32))
+                with obs.span("serve/release", blocks=s.depth
+                              // self.scheduler.pool.block_size):
+                    self.scheduler.retire(
+                        req, np.asarray(s.tokens, np.int32))
                 flight.record("serve", "retire", step=self.scheduler.round,
                               note=f"{req.request_id} tokens={s.emitted}")
                 self._finish_record(req, s)
@@ -1316,4 +1375,12 @@ class ServingEngine:
         )
         if self.prefix_cache is not None:
             out.update(self.prefix_cache.stats())
+        # the loop thread's own account: seconds by phase since its
+        # first round, and its three longest rounds with what filled them
+        loop = self.loop
+        loop.publish()
+        out["loop"] = loop.summary()
+        out["longest_rounds"] = [
+            goodput.describe_round(r, loop.t_start or 0.0)
+            for r in list(loop.longest)]
         return out

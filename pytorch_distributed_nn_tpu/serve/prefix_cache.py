@@ -61,6 +61,7 @@ import numpy as np
 
 from pytorch_distributed_nn_tpu.obs import flight
 from pytorch_distributed_nn_tpu.obs.registry import get_registry
+from pytorch_distributed_nn_tpu.obs.span import span
 from pytorch_distributed_nn_tpu.runtime import chaos
 from pytorch_distributed_nn_tpu.serve.kv_pool import KVPool
 
@@ -244,7 +245,9 @@ class PrefixCache:
         with self._lock:
             if chaos.on_prefix_evict():
                 self._evict_locked(1)
-            match = self._match_locked(prompt, adapter)
+            with span("serve/prefix_match") as sp:
+                match = self._match_locked(prompt, adapter)
+                sp.set(blocks=len(match.blocks))
             if match.tail is not None:
                 self.pool.pin(match.tail)
             need = (self.pool.blocks_for(total_tokens)
@@ -257,10 +260,11 @@ class PrefixCache:
                 # rest of this very reservation, it left ``reserve`` a
                 # block the index no longer knew ("the prefix index is
                 # stale", which killed the serve loop)
-                mine = [b for b in match.blocks if self.pool.pin(b)]
-                self._evict_locked(short)
-                for b in mine:
-                    self.pool.unpin(b)
+                with span("serve/evict") as sp:
+                    mine = [b for b in match.blocks if self.pool.pin(b)]
+                    sp.set(blocks=self._evict_locked(short))
+                    for b in mine:
+                        self.pool.unpin(b)
             if not self.pool.reserve(seq_id, total_tokens,
                                      shared=match.blocks):
                 if match.tail is not None:

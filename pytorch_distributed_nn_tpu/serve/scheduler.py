@@ -210,6 +210,9 @@ class Scheduler:
         self._live: dict[str, int] = {}  # tenant -> queued + running
         self.round = 0  # advanced by the engine, one per decode round
         self.draining = False
+        # of the last next_admissions pass (the engine's span reads them)
+        self.last_lock_wait_us = 0
+        self.last_queued = 0
         self.metrics = None  # MetricsLogger; set by the owning engine
         reg = get_registry()
         self._c_requests = reg.counter(
@@ -452,6 +455,11 @@ class Scheduler:
         admitted: list[Request] = []
         now = time.monotonic()
         with self._lock:
+            # what the pass found: the engine's serve/next_admissions
+            # span says how long it waited for submitters and how many
+            # requests stood in line
+            self.last_lock_wait_us = int((time.monotonic() - now) * 1e6)
+            self.last_queued = self._queued
             while (self._queued and free_slots > 0
                    and len(admitted) < self.max_prefills_per_round):
                 # front of the rotation with work; ring stays put so

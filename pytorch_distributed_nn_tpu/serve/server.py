@@ -32,6 +32,7 @@ Synthetic clients, both canonical load shapes:
 
 from __future__ import annotations
 
+import logging
 import random
 import threading
 import time
@@ -39,11 +40,12 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
-from pytorch_distributed_nn_tpu import obs
 from pytorch_distributed_nn_tpu.obs import flight, watchtower
 from pytorch_distributed_nn_tpu.runtime import failure
 from pytorch_distributed_nn_tpu.serve.engine import ServingEngine
 from pytorch_distributed_nn_tpu.serve.scheduler import Request
+
+log = logging.getLogger(__name__)
 
 
 class InferenceServer:
@@ -71,6 +73,10 @@ class InferenceServer:
 
     def _loop(self) -> None:
         flight.record("serve", "server_start")
+        # the engine's account of this thread runs from here to the end
+        # of the drain: the idle waits are a phase of it
+        tally = self.engine.loop
+        tally.start()
         while not self._stop.is_set():
             if failure.preempt_requested():
                 self.preempted = True
@@ -80,10 +86,12 @@ class InferenceServer:
             else:
                 # park until a submit wakes us (bounded so stop/SIGTERM
                 # polls stay live even with no traffic)
-                with obs.span("serve/parked"):
+                with tally.phase("parked"):
                     self._wake.wait(self.idle_wait_s)
                 self._wake.clear()
         self.engine.drain()
+        tally.stop()
+        tally.publish()
         self._drained.set()
         flight.record("serve", "server_stop",
                       note="preempt" if self.preempted else "stop")
@@ -96,6 +104,7 @@ class InferenceServer:
             self._thread.join(timeout)
             if self._thread.is_alive():
                 raise TimeoutError("serve loop did not drain in time")
+            log.info("serve loop: %s", self.engine.loop.report())
 
     def join_drained(self, timeout: float = 60.0) -> bool:
         """Block until the loop has drained (SIGTERM path)."""

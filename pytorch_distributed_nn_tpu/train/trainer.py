@@ -119,6 +119,8 @@ class Trainer:
         # unified telemetry (obs/): goodput meter + registry instruments
         # feeding the JSONL stream and the Prometheus exposition
         self.goodput = obs.GoodputMeter()
+        # which call traced, lowered or compiled, on which thread
+        obs.jitwatch.install()
         _reg = obs.get_registry()
         self._c_steps = _reg.counter(
             "train_steps_total", "optimizer steps completed")
@@ -220,6 +222,7 @@ class Trainer:
 
     def train(self, steps: int | None = None) -> list[StepRecord]:
         cfg = self.cfg
+        obs.jitwatch.mark_loop_thread()  # its events are the loop's
         if steps is None:
             # default = the REMAINING budget: a resumed run finishes at
             # cfg.steps total, it doesn't run cfg.steps more (the LR
@@ -379,6 +382,7 @@ class Trainer:
         win = self.goodput.window_summary()
         if self.metrics is not None:
             self.metrics.emit("goodput", step=step, **win)
+        obs.jitwatch.publish()  # the collector's counters
         runtime_gauges.update_heartbeat_gauges()
         reg = obs.get_registry()
         gp_gauge = reg.gauge("goodput_frac",

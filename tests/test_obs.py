@@ -68,7 +68,8 @@ def test_histogram_buckets_cumulative(registry):
 
 _PROM_LINE = re.compile(
     r"^(# (HELP|TYPE) [a-zA-Z_:][a-zA-Z0-9_:]* .*"
-    r"|[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^}]*\})? -?[0-9eE+.naif]+)$"
+    # a value may carry a negative exponent (a counter of microseconds)
+    r"|[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^}]*\})? -?[0-9eE+.naif-]+)$"
 )
 
 

@@ -36,6 +36,8 @@ REQUESTS = ((5, 3), (19, 2))
 # each span's parent on its thread (the span that directly encloses it)
 PARENTS = {
     "serve/round": (None,),
+    "serve/next_admissions": ("serve/round",),
+    "serve/prefix_match": ("serve/next_admissions",),
     "serve/admit": ("serve/round",),
     "serve/prefill_into": ("serve/admit",),
     "serve/fresh_cache": ("serve/prefill_into",),
@@ -45,6 +47,7 @@ PARENTS = {
     "serve/decode": ("serve/round",),
     "serve/round_host": ("serve/round",),
     "serve/retire": ("serve/admit", "serve/round_host"),
+    "serve/release": ("serve/retire",),
 }
 
 
@@ -243,6 +246,28 @@ def test_engine_span_in_profiler_trace(traced, name):
             list(range(1, len(stats) + 1))
         assert all(0 <= st["occ"] <= 4 for st in stats)
         assert stats[0]["occ"] == 1     # two admitted, one retired
+    elif name == "serve/next_admissions":
+        # opened whether or not a request is admitted, closed before
+        # serve/admit opens; the three admissions are two passes
+        assert [st["admitted"] for st in stats if st["admitted"]] == [2, 1]
+        assert len(stats) > 2
+        assert all(st["queued"] >= st["admitted"] and st["lock_wait_us"] >= 0
+                   for st in stats)
+        ends = [e for n, _, e, _ in traced["engine"]
+                if n == "serve/next_admissions"]
+        admits = [s for n, s, _, _ in traced["engine"]
+                  if n == "serve/admit"]
+        assert all(any(e <= a for e in ends) for a in admits)
+    elif name == "serve/prefix_match":
+        # one a reservation; the third request shares its first block
+        assert [st["blocks"] for st in stats] == [0, 0, 1]
+    elif name == "serve/release":
+        # full blocks the retiring sequence donates: 19 + 2 - 1 rows
+        # (the two-token budget ends first), 5 + 3 - 1, 19 + 2 - 1
+        assert [st["blocks"] for st in stats] == [1, 0, 1]
+    elif name == "serve/decode":
+        # the host's part of the call, before it waits for the chip
+        assert all(0 <= st["dispatch_us"] for st in stats)
     elif name == "serve/admit":
         assert [st["n"] for st in stats] == [2, 1]
     elif name == "serve/prefill_into":
@@ -372,31 +397,48 @@ def test_decode_round_is_the_parents_byte_for_byte():
     greedy batch is read off its lowered text (test_quality.py), not
     off this source."""
     src = inspect.getsource(ServingEngine._decode_round)
-    assert "obs.span" not in src
+    assert "obs.span" not in src and ".phase(" not in src
     assert src.count("_serve_step(") == 1
+    assert src.count("np.asarray(") == 1   # one fetch
     assert src.count("_write_rows(") == src.count("_insert_row(") == 0
 
 
 def test_unarmed_spans_of_a_decode_round_cost_under_20_us():
-    """A plain decode round enters four spans (round, decode,
-    round_host, retire) and sets three late arguments."""
+    """A plain decode round enters five spans (round, next_admissions,
+    decode, round_host, retire), three of them through the loop's
+    tally, which also splits the decode call where the wait begins,
+    sets seven late arguments and leaves one round record."""
+    from pytorch_distributed_nn_tpu.obs import goodput
+
+    tally = goodput.GoodputMeter(
+        goodput.SERVE_PHASES, goodput.SERVE_SPANS, clock=time.monotonic,
+        rounds=True)
+    tally.start()
+
     def rounds(n):
         for i in range(n):
             with obs.span("serve/round", round=i) as rnd:
-                with obs.span("serve/decode"):
-                    pass
-                with obs.span("serve/round_host") as host:
+                with tally.phase("next_admissions") as nxt:
+                    nxt.set(queued=0, admitted=0, lock_wait_us=0)
+                with tally.phase("dispatch") as dec:
+                    dec.set(dispatch_us=dec.split("fetch",
+                                                  time.monotonic()))
+                with tally.phase("round_host") as host:
                     with obs.span("serve/retire") as sp:
                         sp.set(n=0)
                     host.set(retired=0)
+                tally.lap(i, occ=3)
                 rnd.set(occ=3)
 
     rounds(1000)
+    # the best of many short stretches: one of 500 rounds is shorter
+    # than a scheduler's slice, so some run undisturbed beside the other
+    # workers of a loaded test machine
     best = float("inf")
-    for _ in range(5):
+    for _ in range(50):
         t = time.perf_counter()
-        rounds(5000)
-        best = min(best, (time.perf_counter() - t) / 5000)
+        rounds(500)
+        best = min(best, (time.perf_counter() - t) / 500)
     assert best < 20e-6, f"{best * 1e6:.1f} us a round"
 
 

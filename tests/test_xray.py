@@ -429,54 +429,65 @@ def test_on_compile_counts_and_breadcrumbs(tmp_path):
     assert "train_step" in crumbs[0]["note"]
 
 
-def test_compile_log_watch_parses_jax_dispatch_lines(tmp_path):
+def test_compile_watch_hears_the_jit_listener_by_function(tmp_path):
+    """A jitted call that finds no executable reaches the armed engine
+    through the process's one ``jax.monitoring`` listener, under the
+    function's own name; tracing and lowering are not compilations."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_nn_tpu.obs import jitwatch
+
     eng = _engine("profiler=0", tmp_path)
     eng._install_compile_watch()
     try:
-        lg = logging.getLogger("jax._src.dispatch")
-        lg.debug("Finished XLA compilation of jit(train_step) in "
-                 "0.731 sec")
-        lg.debug("Finished tracing + transforming train_step for pjit "
-                 "in 0.1 sec")  # not a compilation line: ignored
-        assert eng.compile_counts == {"train_step": 1}
-        assert eng.compile_seconds_total == pytest.approx(0.731)
+        @jax.jit
+        def _xray_probe_step(x):
+            return x * 3 + 1
+
+        _xray_probe_step(jnp.arange(5.0)).block_until_ready()
+        assert eng.compile_counts["_xray_probe_step"] == 1
+        assert eng.compile_seconds_total > 0
+        # the second call finds its executable: no event
+        _xray_probe_step(jnp.arange(5.0)).block_until_ready()
+        assert eng.compile_counts["_xray_probe_step"] == 1
+        traced = [e for e in jitwatch.events()
+                  if e.fun == "_xray_probe_step"]
+        assert {e.stage for e in traced} == {"trace", "lower", "compile"}
+        crumbs = [e for e in flight.get_recorder().snapshot()
+                  if e["kind"] == "xray" and e["op"] == "compile"]
+        assert any("_xray_probe_step" in c["note"] for c in crumbs)
     finally:
         eng._uninstall_compile_watch()
 
 
-def test_compile_watch_keeps_console_quiet_but_relays_warnings(tmp_path):
-    """Arming xray forces the dispatch logger to DEBUG; that must not
-    spray jax's compile chatter onto the app's console (propagation is
-    cut while the tap is installed), while WARNING+ records still reach
-    root handlers."""
+def test_compile_watch_leaves_jax_logging_alone_and_disarms(tmp_path):
+    """Arming xray touches neither level nor propagation of jax's
+    dispatch logger (the log tap is gone), and a disarmed engine hears
+    nothing more while the listener stays for the next one."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_nn_tpu.obs import jitwatch
+
     lg = logging.getLogger("jax._src.dispatch")
-    prev_propagate, prev_level = lg.propagate, lg.level
+    before = (lg.propagate, lg.level, list(lg.handlers))
     eng = _engine("profiler=0", tmp_path)
     eng._install_compile_watch()
+    eng._install_compile_watch()   # idempotent: one sink
+    assert (lg.propagate, lg.level, list(lg.handlers)) == before
+    eng._uninstall_compile_watch()
+    jax.jit(lambda x: x - 7)(jnp.arange(3.0)).block_until_ready()
+    assert eng.compile_counts == {}
+    assert jitwatch.installed()
+    other = _engine("profiler=0", tmp_path)
+    other._install_compile_watch()
     try:
-        assert lg.propagate is False
-        relayed: list[logging.LogRecord] = []
-
-        class _Sink(logging.Handler):
-            def emit(self, record):
-                relayed.append(record)
-
-        root = logging.getLogger()
-        sink = _Sink(level=logging.DEBUG)
-        root.addHandler(sink)
-        try:
-            lg.debug("Finished XLA compilation of jit(noisy) in 0.5 sec")
-            lg.warning("compile cache disabled")
-        finally:
-            root.removeHandler(sink)
-        msgs = [r.getMessage() for r in relayed]
-        assert "compile cache disabled" in msgs
-        assert not any("noisy" in m for m in msgs)
-        assert eng.compile_counts == {"noisy": 1}
+        jax.jit(lambda x: x - 9)(jnp.arange(3.0)).block_until_ready()
+        assert sum(other.compile_counts.values()) >= 1
+        assert eng.compile_counts == {}
     finally:
-        eng._uninstall_compile_watch()
-    assert lg.propagate is prev_propagate
-    assert lg.level == prev_level
+        other._uninstall_compile_watch()
 
 
 def test_real_jit_compile_is_observed(tmp_path):
