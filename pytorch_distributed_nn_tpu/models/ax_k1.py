@@ -49,10 +49,8 @@ from pytorch_distributed_nn_tpu.nn.attention import (
     yarn_mscale,
 )
 from pytorch_distributed_nn_tpu.nn.dtypes import get_policy
-from pytorch_distributed_nn_tpu.nn.mla import (
-    MLAttention,
-    expanded_rows_read,
-)
+from pytorch_distributed_nn_tpu.nn.mla import MLAttention
+from pytorch_distributed_nn_tpu.ops.pallas.prefix_attention import rows_read
 from pytorch_distributed_nn_tpu.parallel.expert import HeldExpertsMoE
 
 # what a layer counts in one program execution, over real tokens only:
@@ -144,7 +142,7 @@ class AXK1Block(nn.Module):
             return out, None
         rows = attn.get_variable("cache", "cached_latent").shape[1]
         read = real.sum() * rows if T == 1 \
-            else expanded_rows_read(positions, real, rows)
+            else rows_read(positions, real, rows)
         return out, jnp.concatenate([routing, jnp.stack([
             jnp.where(real, positions + 1, 0).sum(), read,
         ]).astype(jnp.uint32)])

@@ -50,20 +50,21 @@ from pytorch_distributed_nn_tpu.models.llama import RMSNorm
 from pytorch_distributed_nn_tpu.models.longcat_flash import KINDS, SwiGLU
 from pytorch_distributed_nn_tpu.nn.attention import (
     MultiHeadAttention,
+    prefill_in_tiles,
     ring_rows_scored,
 )
 from pytorch_distributed_nn_tpu.nn.dtypes import get_policy
+from pytorch_distributed_nn_tpu.ops.pallas.prefix_attention import rows_read
 from pytorch_distributed_nn_tpu.parallel.expert import HeldExpertsMoE
 
 # what a layer counts in one program execution, over real tokens only:
 # HeldExpertsMoE's routing counts (none in a dense layer), then the key
 # rows inside the real queries' masks and the key rows the program
-# scored for them (the ring, or the row's whole length)
+# read for them (the ring; a full layer's row whole, or in a blockwise
+# prefill the key tiles each query's tile visits)
 COUNTERS = ("moe_calls_total", "moe_picks_total", "moe_held_pairs_total",
             "moe_held_experts_touched_total", "attn_rows_attended_total",
             "attn_rows_read_total")
-# a global layer's cached prefill scores this many queries at a time
-QUERY_BLOCK = 512
 
 
 class KExaoneBlock(nn.Module):
@@ -98,7 +99,7 @@ class KExaoneBlock(nn.Module):
             num_kv_heads=self.num_kv_heads, causal=True,
             rotary=self.window > 0, rope_theta=self.rope_theta,
             impl="auto", use_bias=False, window=self.window,
-            qk_norm=True, norm_eps=self.norm_eps, query_block=QUERY_BLOCK,
+            qk_norm=True, norm_eps=self.norm_eps,
             dtype=self.dtype, param_dtype=self.param_dtype, name="attn")
         with jax.named_scope("kexaone/attn_window" if self.window
                              else "kexaone/attn_full"):
@@ -139,8 +140,14 @@ class KExaoneBlock(nn.Module):
         else:
             inside, scored = positions + 1, rows
         n_real = real.sum()
+        # a full layer's blockwise prefill reads the key tiles its
+        # queries' tiles visit; every other call the rows it scores for
+        # each real query
         return out, jnp.concatenate([routing, jnp.stack([
-            jnp.where(real, inside, 0).sum(), n_real * scored,
+            jnp.where(real, inside, 0).sum(),
+            rows_read(positions, real, rows)
+            if not self.window and prefill_in_tiles(T, rows)
+            else n_real * scored,
         ]).astype(jnp.uint32)])
 
 

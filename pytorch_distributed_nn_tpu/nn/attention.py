@@ -19,6 +19,10 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from pytorch_distributed_nn_tpu.nn.quantized import Int8DenseGeneral
+from pytorch_distributed_nn_tpu.ops.pallas.prefix_attention import (
+    prefix_attention,
+    seen_from,
+)
 
 
 def yarn_frequencies(dim: int, theta: float, factor: float,
@@ -310,22 +314,40 @@ def _ring_attention(q, k, v, ring_k, ring_v, starts, lengths, dtype):
     return out, ring_k, ring_v
 
 
-def _blocked_cache_attention(q, k, v, pos_mask, dtype, block):
-    """:func:`_cache_attention` in blocks of ``block`` queries, one
-    after the other: the score temporaries are a block's, not all T
-    queries' (a query's softmax is over its own row, so nothing else
-    changes). T must be a multiple of ``block``."""
+# scores a head (queries x keys) up to which the dense routine is not
+# slower than the blockwise one for a prefill over rows by position: its
+# three transposes and the kernel's launch are then most of its time
+# (on the chip at 32 / 8 heads of 128, us a layer, dense | blockwise:
+# 32 x 32 15 | 17, 128 x 128 22 | 36, 512 x 512 78 | 116, 1,024 x 1,024
+# 649 | 241; PERF.md sec. 6)
+DENSE_SCORES = 512 * 512
+
+
+def prefill_in_tiles(T: int, S: int) -> bool:
+    """Whether T fed tokens a row against S rows by position attend
+    blockwise (:func:`_prefill_attention`): several tokens whose dense
+    scores would be large. A decode round and a small bucket keep the
+    dense routine over the whole row (:func:`_cache_attention`)."""
+    return T > 1 and T * S > DENSE_SCORES
+
+
+def _prefill_attention(q, k, v, positions, lengths=None):
+    """Several tokens a row against rows by position, blockwise
+    (:mod:`ops.pallas.prefix_attention`: float32 scores a tile at a
+    time that are never written out, no key tile past a query tile's
+    largest position read; the K/V head of a group serves its query
+    heads). q (B, T, H, D); k/v (B, S, Hkv, D), the cache with the fed
+    tokens written; ``positions`` (B|1, T), where each fed token
+    stands; ``lengths`` (B,), if given, how many of a row's are real:
+    the others see nothing and get zeros. Returns (B, T, H, D)."""
     B, T = q.shape[:2]
-    nb = T // block
-
-    def split(x):   # (B|1, T, ...) -> (nb, B|1, block, ...)
-        x = x.reshape((x.shape[0], nb, block) + x.shape[2:])
-        return jnp.moveaxis(x, 1, 0)
-
-    out = jax.lax.map(
-        lambda a: _cache_attention(a[0], k, v, a[1], dtype),
-        (split(q), split(pos_mask)))
-    return jnp.moveaxis(out, 0, 1).reshape(q.shape)
+    q_pos = jnp.broadcast_to(positions, (B, T))
+    if lengths is not None:
+        q_pos = seen_from(q_pos, jnp.arange(T)[None] < lengths[:, None])
+    heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    return heads_first(prefix_attention(
+        heads_first(q), heads_first(k), heads_first(v), q_pos,
+        scale=q.shape[-1] ** -0.5))
 
 
 class MultiHeadAttention(nn.Module):
@@ -372,9 +394,6 @@ class MultiHeadAttention(nn.Module):
     # ``k_norm/scale``)
     qk_norm: bool = False
     norm_eps: float = 1e-5
-    # a cached prefill's scores in blocks of this many queries (0: all
-    # at once): bounds the (heads, T, S) float32 temporaries
-    query_block: int = 0
 
     @nn.compact
     def __call__(self, x, mask: Optional[jax.Array] = None,
@@ -412,11 +431,14 @@ class MultiHeadAttention(nn.Module):
         zero-B adapter contributes an exact-0.0 delta: adding it leaves
         greedy decode token-identical to running without a bank.
 
-        ``lengths`` (B,) int32, ring caches only: how many of the T fed
-        tokens of each row are real (a left-aligned prefix; default all
-        T). Padding written by absolute position lands past a row's
-        end and is masked until overwritten; in a ring it would
-        displace a real position, so it is not written."""
+        ``lengths`` (B,) int32: how many of the T fed tokens of each
+        row are real (a left-aligned prefix; default all T). Padding
+        written by absolute position lands past a row's end and is
+        masked until overwritten; in a ring it would displace a real
+        position, so it is not written. A blockwise prefill over rows
+        by position (:func:`prefill_in_tiles`) lets the padding attend
+        to nothing (its rows of the result are zeros); the dense routine
+        and the int8 cache take no notice."""
         kv_heads = self.num_kv_heads or self.num_heads
         if self.quantized:
             if self.use_bias:
@@ -619,11 +641,10 @@ class MultiHeadAttention(nn.Module):
                 else:
                     cached_k.value = write(cached_k.value, k)
                     cached_v.value = write(cached_v.value, v)
-                    if self.query_block and T > self.query_block \
-                            and T % self.query_block == 0:
-                        out = _blocked_cache_attention(
-                            q, cached_k.value, cached_v.value, pos_mask,
-                            self.dtype, self.query_block)
+                    if prefill_in_tiles(T, S):
+                        out = _prefill_attention(
+                            q, cached_k.value, cached_v.value, positions,
+                            lengths)
                     else:
                         out = _cache_attention(
                             q, cached_k.value, cached_v.value, pos_mask,

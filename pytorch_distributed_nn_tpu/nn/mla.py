@@ -55,15 +55,11 @@ from pytorch_distributed_nn_tpu.nn.attention import (
     rotary_embedding,
 )
 from pytorch_distributed_nn_tpu.ops.pallas.prefix_attention import (
+    KEY_BLOCK,
+    QUERY_BLOCK,
     prefix_attention,
-    rows_visited,
+    seen_from,
 )
-
-
-# the expanded path's tiles (queries x keys a head), chosen on the chip
-# at 64 heads of 192 / 128 (PERF.md sec. 6 has the table)
-QUERY_BLOCK = 512
-KEY_BLOCK = 1024
 
 
 def _deinterleave(x):
@@ -75,11 +71,6 @@ def _deinterleave(x):
 def _masked_softmax(scores, visible, dtype):
     scores = jnp.where(visible, scores, -1e30)
     return jax.nn.softmax(scores, axis=-1).astype(dtype)
-
-
-def _seen_from(q_pos, real):
-    """Positions with the padding's at -1: a query that sees nothing."""
-    return q_pos if real is None else jnp.where(real, q_pos, -1)
 
 
 def expanded_attention(q_nope, q_rope, latent, rope_key, w_kvb, q_pos, *,
@@ -105,19 +96,10 @@ def expanded_attention(q_nope, q_rope, latent, rope_key, w_kvb, q_pos, *,
     k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
         rope_key[:, None], (B, H, S, rope_key.shape[-1]))], axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1).transpose(0, 2, 1, 3)
-    out = prefix_attention(q, k, kv[..., dn:], _seen_from(q_pos, real),
+    out = prefix_attention(q, k, kv[..., dn:], seen_from(q_pos, real),
                            scale=scale, block_q=query_block,
                            block_k=key_block)
     return out.transpose(0, 2, 1, 3)
-
-
-def expanded_rows_read(q_pos, real, S: int):
-    """Key rows :func:`expanded_attention` reads for the real queries
-    of a call at its own tiles, summed: for each, the rows of the key
-    blocks its query block visits (the rows inside its mask are
-    ``q_pos + 1``)."""
-    return jnp.where(real, rows_visited(_seen_from(q_pos, real), S,
-                                        QUERY_BLOCK, KEY_BLOCK), 0).sum()
 
 
 def absorbed_attention(q_nope, q_rope, latent, rope_key, w_kvb, q_pos, *,

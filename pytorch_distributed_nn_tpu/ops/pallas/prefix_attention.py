@@ -9,7 +9,9 @@ maximum and denominator (online softmax), and a query block visits only
 the key blocks at or under its largest position: nothing past the
 visible prefix is multiplied, and a tile of scores never outlives the
 step that made it. The qk width may differ from the v width (MLA: 192
-and 128). Forward only.
+and 128), and K and V may have fewer heads than q (grouped queries: H =
+G x Hkv, K/V head ``h // G`` serves query head ``h``, nothing is
+repeated). Forward only.
 
 One routine in two executions: :func:`_pallas` (a TPU kernel: the tile
 lives in VMEM, memory sees q, K, V and the output only) and
@@ -33,9 +35,15 @@ log = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 STAT_LANES = 128   # minor dim of the m / l scratch: one whole lane tile
-# heads a grid step: a tile of one head is a few microseconds of matrix
-# work, little over the step's own overhead (1 -> 4 heads: -18 % at
-# 512 x 512 on the chip, PERF.md sec. 6)
+# the tiles (queries x keys a head), chosen on the chip at 64 heads of
+# 192 / 128 and checked again at 32 / 8 heads of 128 (PERF.md sec. 6 has
+# the tables)
+QUERY_BLOCK = 512
+KEY_BLOCK = 1024
+# query heads a grid step: a tile of one head is a few microseconds of
+# matrix work, little over the step's own overhead (1 -> 4 heads: -18 %
+# at 512 x 512 on the chip, PERF.md sec. 6). Grouped, they are whole
+# groups or a part of one (four of Mistral's: one K/V head a step)
 HEADS_A_STEP = 4
 # four heads' double-buffered tiles at 512 x 1,024 pass the compiler's
 # 16 MiB default; a v5e core has 128 MiB
@@ -72,6 +80,21 @@ def rows_visited(q_pos, S: int, block_q: int, block_k: int):
     return jnp.repeat(rows, bq, axis=1)[:, :T]
 
 
+def seen_from(q_pos, real):
+    """Positions with the padding's at -1: a query that sees nothing."""
+    return q_pos if real is None else jnp.where(real, q_pos, -1)
+
+
+def rows_read(q_pos, real, S: int, block_q: int = QUERY_BLOCK,
+              block_k: int = KEY_BLOCK):
+    """Key rows the routine reads for the ``real`` (B, T) queries of a
+    call whose other queries stand at -1 (:func:`seen_from`), summed:
+    for each, the rows of the key blocks its query block visits (the
+    rows inside its mask are ``q_pos + 1``)."""
+    return jnp.where(real, rows_visited(seen_from(q_pos, real), S, block_q,
+                                        block_k), 0).sum()
+
+
 def _update(s, v, m_prev, l_prev, acc_prev):
     """One key block into the running softmax: float32 scores ``s``
     (..., tq, tk) (masked entries at :data:`NEG_INF`), ``v`` (..., tk,
@@ -91,12 +114,17 @@ def _update(s, v, m_prev, l_prev, acc_prev):
 def _blockwise(q, k, v, q_pos, *, scale: float, block_q: int, block_k: int):
     """The recurrence in ``jax.numpy``: a scan over key blocks inside a
     map over query blocks, a key block past the query block's largest
-    position skipped. T and S in whole blocks, positions under S."""
-    B, H, T, _ = q.shape
-    S, dv = k.shape[2], v.shape[-1]
+    position skipped. T and S in whole blocks, positions under S. The
+    G query heads of a K/V head are G times the query rows of that
+    head, each block of them at the same positions."""
+    B, Hq, Tq, _ = q.shape
+    H, S, dv = k.shape[1], k.shape[2], v.shape[-1]
+    G = Hq // H
+    T = G * Tq
+    q = q.reshape(B, H, T, -1)
     nq = T // block_q
-    _, his = block_bounds(q_pos, S, block_q)
-    pos = q_pos.astype(jnp.int32)
+    pos = jnp.concatenate([q_pos.astype(jnp.int32)] * G, axis=1)
+    _, his = block_bounds(pos, S, block_q)
 
     def query_block(args):
         qb, pb, hi = args   # (B, H, bq, dk), (B, bq), (B,)
@@ -131,7 +159,7 @@ def _blockwise(q, k, v, q_pos, *, scale: float, block_q: int, block_k: int):
     out = jax.lax.map(query_block, (
         jnp.moveaxis(q.reshape(B, H, nq, block_q, -1), 2, 0),
         jnp.moveaxis(pos.reshape(B, nq, block_q), 1, 0), his.T))
-    return jnp.moveaxis(out, 0, 2).reshape(B, H, T, dv)
+    return jnp.moveaxis(out, 0, 2).reshape(B, Hq, Tq, dv)
 
 
 def _kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
@@ -142,11 +170,13 @@ def _kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
     and ``hi`` (scalar prefetch, (B, blocks)) bound the query block's
     positions: a key block past ``hi`` is neither fetched (the index
     map repeats the last visited block) nor multiplied; one at or under
-    ``lo`` is visible whole and needs no mask."""
+    ``lo`` is visible whole and needs no mask. The step's query heads
+    share its K/V heads in order, ``group`` to one."""
     b, qi, kj = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     lo, hi = lo_ref[b, qi], hi_ref[b, qi]
     first = kj * block_k
     heads, block_q = q_ref.shape[0], q_ref.shape[1]
+    group = heads // k_ref.shape[0]
 
     @pl.when(kj == 0)
     def _init():
@@ -162,9 +192,9 @@ def _kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
             v_seen = first + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, 1), 0) <= hi
         for h in range(heads):
-            v = v_ref[h]
+            v = v_ref[h // group]
             s = jax.lax.dot_general(
-                q_ref[h], k_ref[h], (((1,), (1,)), ((), ())),
+                q_ref[h], k_ref[h // group], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             if masked:
                 s = jnp.where(visible, s, NEG_INF)
@@ -197,7 +227,12 @@ def _pallas(q, k, v, q_pos, *, scale: float, block_q: int, block_k: int,
     otherwise, compile cache or not."""
     B, H, T, dk = q.shape
     S, dv = k.shape[2], v.shape[-1]
-    heads = next(n for n in range(min(heads, H), 0, -1) if H % n == 0)
+    G = H // k.shape[1]
+    # whole groups a step, or a part of one: its K/V heads are whole
+    heads = next(n for n in range(min(heads, H), 0, -1)
+                 if H % n == 0 and (n % G == 0 or G % n == 0))
+    kv_heads = max(heads // G, 1)
+    steps = G * kv_heads // heads    # grid steps that share K/V heads
     nq, nk = T // block_q, S // block_k
     lo, hi = block_bounds(q_pos, S, block_q)
     pos = q_pos.astype(jnp.int32)[..., None]
@@ -207,7 +242,7 @@ def _pallas(q, k, v, q_pos, *, scale: float, block_q: int, block_k: int,
 
     def kv_map(b, h, qi, kj, lo_ref, hi_ref):
         seen = jnp.maximum(hi_ref[b, qi], 0) // block_k
-        return (b, h, jnp.minimum(kj, seen), 0)
+        return (b, h // steps if steps > 1 else h, jnp.minimum(kj, seen), 0)
 
     visited = T * S // (2 if T == S else 1)   # score entries, roughly
     return pl.pallas_call(
@@ -217,8 +252,8 @@ def _pallas(q, k, v, q_pos, *, scale: float, block_q: int, block_k: int,
             grid=(B, H // heads, nq, nk),
             in_specs=[
                 pl.BlockSpec((None, heads, block_q, dk), q_map),
-                pl.BlockSpec((None, heads, block_k, dk), kv_map),
-                pl.BlockSpec((None, heads, block_k, dv), kv_map),
+                pl.BlockSpec((None, kv_heads, block_k, dk), kv_map),
+                pl.BlockSpec((None, kv_heads, block_k, dv), kv_map),
                 pl.BlockSpec((None, block_q, 1),
                              lambda b, h, qi, kj, lo_ref, hi_ref:
                              (b, qi, 0)),
@@ -236,8 +271,9 @@ def _pallas(q, k, v, q_pos, *, scale: float, block_q: int, block_k: int,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         cost_estimate=pl.CostEstimate(
             flops=2 * B * H * visited * (dk + dv),
-            bytes_accessed=(B * H * T * (dk + dv)
-                            + B * H * visited // block_q * (dk + dv))
+            bytes_accessed=(B * H * T * (dk + dv)   # a K/V tile a step
+                            + B * H // heads * kv_heads
+                            * visited // block_q * (dk + dv))
             * q.dtype.itemsize,
             transcendentals=B * H * visited),
         interpret=interpret,
@@ -252,9 +288,10 @@ def _kernel_tiles(dk: int, dv: int, bq: int, bk: int) -> bool:
 
 
 @functools.lru_cache(maxsize=None)
-def _log_execution(execution: str, shape: tuple, S: int, bq: int, bk: int):
-    log.info("prefix_attention: %s, q %s against %d keys in tiles of "
-             "%d x %d", execution, shape, S, bq, bk)
+def _log_execution(execution: str, shape: tuple, kv_heads: int, S: int,
+                   bq: int, bk: int):
+    log.info("prefix_attention: %s, q %s against %d keys of %d heads in "
+             "tiles of %d x %d", execution, shape, S, kv_heads, bq, bk)
 
 
 def _in_whole_tiles(run, q, k, v, q_pos, *, scale: float, block_q: int,
@@ -271,19 +308,23 @@ def _in_whole_tiles(run, q, k, v, q_pos, *, scale: float, block_q: int,
                scale=scale, block_q=block_q, block_k=block_k)[:, :, :T]
 
 
-def prefix_attention(q, k, v, q_pos, *, scale: float, block_q: int,
-                     block_k: int):
-    """q (B, H, T, dk), k (B, H, S, dk), v (B, H, S, dv), q_pos (B, T):
-    key s is visible to query t iff ``s <= q_pos[b, t]``; a query at a
-    negative position sees nothing and gets zeros. Returns (B, H, T,
-    dv) in q's dtype. On a TPU with tiles the kernel can lay out, the
-    kernel; otherwise the same recurrence in ``jax.numpy``. Logs once a
-    shape which of the two a program lowered with."""
+def prefix_attention(q, k, v, q_pos, *, scale: float,
+                     block_q: int = QUERY_BLOCK, block_k: int = KEY_BLOCK):
+    """q (B, H, T, dk), k (B, Hkv, S, dk), v (B, Hkv, S, dv) with H a
+    multiple of Hkv (K/V head ``h // (H / Hkv)`` is query head h's),
+    q_pos (B, T): key s is visible to query t iff ``s <= q_pos[b, t]``;
+    a query at a negative position sees nothing and gets zeros. Returns
+    (B, H, T, dv) in q's dtype. On a TPU with tiles the kernel can lay
+    out, the kernel; otherwise the same recurrence in ``jax.numpy``.
+    Logs once a shape which of the two a program lowered with."""
     T, S = q.shape[2], k.shape[2]
+    if q.shape[1] % k.shape[1] or k.shape[:3] != v.shape[:3]:
+        raise ValueError(f"{q.shape[1]} query heads over K {k.shape} and "
+                         f"V {v.shape}: not whole groups")
     bq, bk = tiles(T, S, block_q, block_k)
     kernel = jax.default_backend() == "tpu" \
         and _kernel_tiles(q.shape[-1], v.shape[-1], bq, bk)
     _log_execution("Pallas kernel" if kernel else "jax.numpy",
-                   tuple(q.shape), S, bq, bk)
+                   tuple(q.shape), k.shape[1], S, bq, bk)
     return _in_whole_tiles(_pallas if kernel else _blockwise, q, k, v,
                            q_pos, scale=scale, block_q=bq, block_k=bk)

@@ -328,12 +328,13 @@ def check_bn_stats() -> bool:
 
 
 def check_prefix_attention() -> bool:
-    """MLA's prefill kernel against the jax.numpy recurrence at the
-    served size (64 heads of 192 / 128, bf16, a row of 8,192): a whole
-    prompt with a padded tail, and a suffix behind 6,144 restored rows."""
+    """The prefill kernel against the jax.numpy recurrence at the served
+    sizes in bf16: MLA's 64 heads of 192 / 128 against a row of 8,192 (a
+    whole prompt with a padded tail, a suffix behind 6,144 restored
+    rows), and grouped queries of 128 / 128 against a row of 4,096
+    (Mistral's 32 heads to 8, K-EXAONE's 64 to 8 with a padded tail)."""
     import functools
 
-    from pytorch_distributed_nn_tpu.nn import mla
     from pytorch_distributed_nn_tpu.ops.pallas import prefix_attention as pa
 
     if jax.default_backend() != "tpu":
@@ -341,21 +342,25 @@ def check_prefix_attention() -> bool:
         return True
     ok = True
     ks = jax.random.split(jax.random.key(8), 3)
-    k = jax.random.normal(ks[1], (1, 64, 8192, 192), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (1, 64, 8192, 128), jnp.bfloat16)
-    kw = dict(scale=192 ** -0.5, block_q=mla.QUERY_BLOCK,
-              block_k=mla.KEY_BLOCK)
-    for T, first, real in [(8192, 0, 6528), (512, 6144, 512)]:
-        q = jax.random.normal(ks[0], (1, 64, T, 192), jnp.bfloat16)
+    for H, Hkv, dk, S, T, first, real in [
+            (64, 64, 192, 8192, 8192, 0, 6528),
+            (64, 64, 192, 8192, 512, 6144, 512),
+            (32, 8, 128, 4096, 4096, 0, 4096),
+            (64, 8, 128, 4096, 2048, 1024, 1500)]:
+        q = jax.random.normal(ks[0], (1, H, T, dk), jnp.bfloat16)
+        k = jax.random.normal(ks[1], (1, Hkv, S, dk), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (1, Hkv, S, 128), jnp.bfloat16)
         pos = jnp.where(jnp.arange(T) < real, first + jnp.arange(T), -1)[None]
+        kw = dict(scale=dk ** -0.5, block_q=min(pa.QUERY_BLOCK, T),
+                  block_k=pa.KEY_BLOCK)
         got, want = (jax.jit(functools.partial(run, **kw))(q, k, v, pos)
                      for run in (pa._pallas, pa._blockwise))
         err = float(jnp.abs(got.astype(jnp.float32)
                             - want.astype(jnp.float32)).max())
         line_ok = err < 2e-2
         ok &= line_ok
-        print(f"prefix_attention T{T} at {first}, {real} real: "
-              f"max_err={err:.2e} {'OK' if line_ok else 'FAIL'}")
+        print(f"prefix_attention {H}/{Hkv} heads T{T} at {first}, {real} "
+              f"real: max_err={err:.2e} {'OK' if line_ok else 'FAIL'}")
     return ok
 
 

@@ -12,9 +12,17 @@ change them (a decode round's cache write is one scatter a leaf).
 ``longcat.prefill`` was written anew by ISSUE 36, which meant to change
 it (MLA's expanded path runs blockwise, ``ops/pallas/prefix_attention``;
 that one line: the ``trees`` entries are still those of ISSUE 35's
-commit, and the uncached forward's logits moved by 1e-6); the Mistral
-and K-EXAONE prefill programs are still those of the first commit. A PR
-that means to change those programs writes the file anew and says so:
+commit, and the uncached forward's logits moved by 1e-6). ISSUE 39 put
+the blockwise routine behind ``nn/attention.MultiHeadAttention``'s
+cached prefill too, grouped-query heads and all, and the file stayed as
+it was, every line: a bucket whose dense scores are small
+(``nn/attention.prefill_in_tiles``: up to 512 x 512 a head, this file's
+16 x 16 among them) keeps the dense routine, which was not slower there
+on the chip, so the Mistral and K-EXAONE prefill programs are still
+those of the first commit; at ``G = 1`` the routine lowers to ISSUE
+36's text, so ``longcat.prefill`` is still ISSUE 36's. What the served
+sizes compile to is ``tests/test_chip_compile.py``'s to hold. A PR that
+means to change those programs writes the file anew and says so:
 
     JAX_PLATFORMS=cpu python tests/serve_program_digests.py > tests/data/serve_program_digests.json
 

@@ -120,21 +120,23 @@ def _ring_block(bh, tl, D):
     return build
 
 
-def _prefix_attention(T, S):
-    """MLA's expanded path at the served tiles: 64 heads of 192 / 128."""
-    from pytorch_distributed_nn_tpu.nn import mla
+def _prefix_attention(T, S, heads=(64, 64), widths=(192, 128)):
+    """A prefill's attention at the served tiles: MLA's expanded path
+    (64 heads of 192 / 128) unless told otherwise."""
     from pytorch_distributed_nn_tpu.ops.pallas import prefix_attention as pa
+
+    (H, Hkv), (dk, dv) = heads, widths
 
     def build(arg):
         def run(q, k, v, pos):
-            bq, bk = pa.tiles(T, S, mla.QUERY_BLOCK, mla.KEY_BLOCK)
-            assert pa._kernel_tiles(192, 128, bq, bk)
-            return pa._pallas(q, k, v, pos, scale=192 ** -0.5, block_q=bq,
+            bq, bk = pa.tiles(T, S, pa.QUERY_BLOCK, pa.KEY_BLOCK)
+            assert pa._kernel_tiles(dk, dv, bq, bk)
+            return pa._pallas(q, k, v, pos, scale=dk ** -0.5, block_q=bq,
                               block_k=bk)
 
-        return run, [arg((1, 64, T, 192), jnp.bfloat16),
-                     arg((1, 64, S, 192), jnp.bfloat16),
-                     arg((1, 64, S, 128), jnp.bfloat16),
+        return run, [arg((1, H, T, dk), jnp.bfloat16),
+                     arg((1, Hkv, S, dk), jnp.bfloat16),
+                     arg((1, Hkv, S, dv), jnp.bfloat16),
                      arg((1, T), jnp.int32)], 1
     return build
 
@@ -165,6 +167,17 @@ CASES = {
     "prefix_attention_mla_T512_S8192": _prefix_attention(512, 8192),
     "prefix_attention_mla_T256_S256": _prefix_attention(256, 256),
     "prefix_attention_mla_T4096_S4096": _prefix_attention(4096, 4096),
+    # grouped queries of 128 / 128. Mistral's 32 to 8: the documents'
+    # largest bucket, chat's largest (the smallest that runs in tiles),
+    # a suffix; K-EXAONE's 64 to 8, a step's four heads half a group
+    "prefix_attention_gqa4_T4096_S4096": _prefix_attention(
+        4096, 4096, (32, 8), (128, 128)),
+    "prefix_attention_gqa4_T1024_S1024": _prefix_attention(
+        1024, 1024, (32, 8), (128, 128)),
+    "prefix_attention_gqa4_T512_S4096": _prefix_attention(
+        512, 4096, (32, 8), (128, 128)),
+    "prefix_attention_gqa8_T2048_S2048": _prefix_attention(
+        2048, 2048, (64, 8), (128, 128)),
 }
 
 
@@ -368,25 +381,44 @@ def test_serve_step_writes_cache_rows_without_a_loop(topo, monkeypatch,
     assert is_.temp_size_in_bytes <= was.temp_size_in_bytes
 
 
+def _dense_prefill(q, k, v, positions, lengths=None):
+    """``nn/attention._prefill_attention`` as the dense routine it
+    replaced: every score of the row at once."""
+    from pytorch_distributed_nn_tpu.nn import attention
+
+    seen = jnp.arange(k.shape[1])[None, None, :] <= positions[:, :, None]
+    return attention._cache_attention(q, k, v, seen, q.dtype)
+
+
 @pytest.mark.parametrize("family,T,S,kernels", [
     ("longcat", 2048, 2048, 2),     # two attentions a layer, T = S
     ("ax_k1", 512, 8192, 2),        # a suffix against the cell's row
-    ("llama", 2048, 2048, 0),       # nn/attention.py: not this routine
+    ("llama", 2048, 2048, 2),       # rows by position, two layers
+    ("llama", 512, 4096, 2),        # a suffix behind restored rows
+    ("k_exaone", 2048, 2048, 1),    # LLLG: one full layer; the rings'
+                                    # band is not this routine
+    ("llama_dense", 2048, 2048, 0),  # the witness: the dense routine
 ])
-def test_serve_prefill_keeps_mla_scores_in_the_core(topo, monkeypatch,
-                                                    family, T, S, kernels):
+def test_serve_prefill_keeps_scores_in_the_core(topo, monkeypatch,
+                                                family, T, S, kernels):
     """The compiled ``_serve_prefill`` of the latent-attention families
-    holds one Pallas call an attention and no float32 tensor of scores
-    ``heads x queries x row``; the dispatcher asks for the backend, and
-    this test answers for the chip."""
+    and of the rows-by-position ones holds one Pallas call an attention
+    and no float32 tensor of scores ``heads x queries x row`` (with the
+    dense routine put back it holds them: the pattern finds them); the
+    dispatcher asks for the backend, and this test answers for the
+    chip."""
     import re
 
     from pytorch_distributed_nn_tpu.config import ModelConfig
     from pytorch_distributed_nn_tpu.inference.generate import init_cache
     from pytorch_distributed_nn_tpu.models import get_model
+    from pytorch_distributed_nn_tpu.nn import attention
     from pytorch_distributed_nn_tpu.serve import engine
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if family == "llama_dense":
+        family = "llama"
+        monkeypatch.setattr(attention, "_prefill_attention", _dense_prefill)
     one_chip = SingleDeviceSharding(topo.devices[0])
     name, extra = _SERVED[family]
     model = get_model(ModelConfig(name=name, dtype="float32",
@@ -409,6 +441,5 @@ def test_serve_prefill_keeps_mla_scores_in_the_core(topo, monkeypatch,
         jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=one_chip),
         one, one).compile().as_text()
     assert text.count(KERNEL) == kernels
-    heads = extra["num_heads"]
     scores = set(re.findall(rf"f32\[[\d,]*,(?:{T}|512),{S}\]", text))
     assert bool(scores) == (kernels == 0), scores

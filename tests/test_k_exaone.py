@@ -232,21 +232,27 @@ def test_ring_prefill_leaves_padding_out_of_the_ring():
             assert np.array_equal(np.asarray(ring_k[b, r]), want)
 
 
-@pytest.mark.parametrize("block", [4, 16])
-def test_cached_prefill_in_query_blocks_is_the_unblocked_one(block):
-    """Blocks of queries bound the score temporaries of a full layer's
-    prefill and change nothing: a query's softmax is over its own row
-    (1e-6: the same float32 sums)."""
+@pytest.mark.parametrize("lengths", [None, (29, 7)])
+def test_cached_prefill_in_tiles_is_the_dense_one(lengths):
+    """A full layer's prefill (8 query heads to a K/V head, no
+    rotation, a suffix behind 3 filled rows) runs blockwise and changes
+    nothing: a query's softmax is over its own row (1e-6: the same
+    float32 sums in tiles). Given ``lengths``, a bucket's padding
+    attends to nothing and its rows of the result are zeros."""
     ks = jax.random.split(jax.random.key(9), 3)
     B, T, S = 2, 32, 48
-    q = jax.random.normal(ks[0], (B, T, 4, 8))
+    q = jax.random.normal(ks[0], (B, T, 16, 8))
     k = jax.random.normal(ks[1], (B, S, 2, 8))
     v = jax.random.normal(ks[2], (B, S, 2, 8))
-    mask = jnp.arange(S)[None, None, :] <= (3 + jnp.arange(T))[None, :, None]
+    positions = (3 + jnp.arange(T))[None]
+    mask = jnp.arange(S)[None, None, :] <= positions[:, :, None]
     whole = attention._cache_attention(q, k, v, mask, jnp.float32)
-    parts = attention._blocked_cache_attention(q, k, v, mask, jnp.float32,
-                                               block)
-    assert np.abs(np.asarray(whole - parts)).max() < 1e-6
+    tiled = attention._prefill_attention(
+        q, k, v, positions, None if lengths is None else jnp.asarray(lengths))
+    real = np.arange(T)[None] < np.asarray(lengths or (T, T))[:, None]
+    gap = np.abs(np.asarray(whole - tiled)).max(axis=(2, 3))
+    assert gap[real].max() < 1e-6
+    assert np.abs(np.asarray(tiled))[~real].max(initial=0) == 0
 
 
 @pytest.mark.parametrize("max_len", [16, 64, 512])
@@ -575,6 +581,12 @@ def test_served_by_the_engine_with_counters_as_the_reference_counts(rank1):
         # a decode round scores the ring, or the row's whole length
         assert read("attn_rows_read_total", "decode", layer, kind) \
             == fed * (WINDOW if window else 64)
+        if not window:
+            # a full layer's prefill reads the key tiles its real
+            # queries' tiles visit: at these sizes the bucket's one
+            from pytorch_distributed_nn_tpu.serve.engine import _bucket_len
+            assert read("attn_rows_read_total", "prefill", layer, kind) \
+                == sum(len(p) * _bucket_len(len(p)) for p in prompts)
         if dense:
             assert read("moe_calls_total", "decode", layer) == 0
             continue

@@ -211,3 +211,96 @@ def test_decode_matches_full_context_logits():
         decode=True, mutable=["cache"])
     np.testing.assert_allclose(np.asarray(logits), np.asarray(full),
                                rtol=0.1, atol=0.05)
+
+
+def _attention_module():
+    from pytorch_distributed_nn_tpu.nn.attention import MultiHeadAttention
+
+    return MultiHeadAttention(num_heads=4, head_dim=8, num_kv_heads=2,
+                              causal=True, rotary=True, use_bias=False)
+
+
+_ROWS = 640   # 512 fed tokens against them are scores worth tiling
+
+
+def _filled_cache(attn, x, fill):
+    """Parameters, and a (B, _ROWS) cache with ``fill`` tokens a row
+    fed."""
+    B, d = x.shape[0], x.shape[-1]
+    variables = attn.init(jax.random.key(0), jnp.zeros((B, _ROWS, d)),
+                          decode=True)
+    _, mutated = attn.apply(variables, x[:, :fill], decode=True,
+                            mutable=["cache"])
+    return variables["params"], mutated["cache"]
+
+
+@pytest.mark.parametrize("mode", ["per_row", "shared_index"])
+def test_prefill_behind_filled_rows_is_the_dense_masked_softmax(
+        monkeypatch, mode):
+    """Several tokens a row (512: grouped-query, rotary, a nonzero
+    start: 100 rows filled, in per-row mode the second row rewinds to
+    37) through the blockwise routine against the dense masked softmax
+    over the whole row cache, which is what ran here before: 1e-5 in
+    float32."""
+    from pytorch_distributed_nn_tpu.nn import attention
+
+    attn = _attention_module()
+    x = jax.random.normal(jax.random.key(1), (2, 612, 32))
+    params, cache = _filled_cache(attn, x, 100)
+    assert attention.prefill_in_tiles(512, _ROWS)
+    kw = dict(cache_positions=jnp.asarray([100, 37])) \
+        if mode == "per_row" else {}
+    entered = []
+    blockwise = attention._prefill_attention
+    monkeypatch.setattr(
+        attention, "_prefill_attention",
+        lambda *a: entered.append(1) or blockwise(*a))
+
+    def prefill():
+        return attn.apply({"params": params, "cache": cache}, x[:, 100:],
+                          decode=True, mutable=["cache"], **kw)
+
+    def dense(q, k, v, positions, lengths=None):
+        seen = jnp.arange(k.shape[1])[None, None, :] \
+            <= positions[:, :, None]
+        return _cache_attention(q, k, v, seen, q.dtype)
+
+    got, got_cache = prefill()
+    assert entered == [1]
+    monkeypatch.setattr(attention, "_prefill_attention", dense)
+    want, want_cache = prefill()
+    assert float(jnp.abs(want).mean()) > 0.05
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    for a, b in zip(jax.tree.leaves(got_cache), jax.tree.leaves(want_cache)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_one_token_a_row_lowers_as_before_the_blockwise_prefill(monkeypatch):
+    """A decode round (T = 1) keeps the dense routine over the row
+    cache: its lowered text is the one the commit before the blockwise
+    prefill lowered (sha256 taken there; it depends on the installed
+    JAX, as ``tests/data/serve_program_digests.json`` does), and the
+    prefill's routine is never entered."""
+    import hashlib
+
+    from pytorch_distributed_nn_tpu.nn import attention
+
+    def never(*a, **k):
+        raise AssertionError("a decode round took the prefill's routine")
+
+    attn = _attention_module()
+    x = jax.random.normal(jax.random.key(1), (2, 12, 32))
+    variables = attn.init(jax.random.key(0), jnp.zeros((2, 24, 32)),
+                          decode=True)
+    params, cache = variables["params"], variables["cache"]
+    monkeypatch.setattr(attention, "_prefill_attention", never)
+    with jax.default_matmul_precision(None):
+        text = jax.jit(lambda p, c, x, at: attn.apply(
+            {"params": p, "cache": c}, x, decode=True, mutable=["cache"],
+            cache_positions=at)).lower(
+                params, cache, x[:, 5:6], jnp.asarray([5, 2])).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == _ONE_TOKEN_TEXT
+
+
+_ONE_TOKEN_TEXT = (
+    "b8f227485b2fd75e7c01d8e97672fb8504913d36edac921c3302df0d4ae2cc27")

@@ -1,7 +1,10 @@
-"""The blockwise attention of MLA's expanded path
-(``ops/pallas/prefix_attention``): the Pallas kernel in interpret mode
-and the ``jax.numpy`` recurrence, each against the dense masked softmax
-on small MLA shapes (a qk width that differs from the v width), and the
+"""The blockwise attention of a prefill (``ops/pallas/prefix_attention``:
+MLA's expanded path, and the rows-by-position cache of
+``nn/attention.MultiHeadAttention``): the Pallas kernel in interpret
+mode and the ``jax.numpy`` recurrence, each against the dense masked
+softmax on small MLA shapes (a qk width that differs from the v width)
+and on grouped-query ones (4 and 8 query heads a K/V head, where
+``nn/attention._cache_attention`` is the dense form), and the
 arithmetic that says which key rows a query block visits. The compiled
 kernel at the served sizes is in ``tests/test_chip_compile.py`` (no
 chip) and, against the recurrence, at the end of this file (chip only:
@@ -18,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from pytorch_distributed_nn_tpu.nn import mla
+from pytorch_distributed_nn_tpu.nn import attention, mla
 from pytorch_distributed_nn_tpu.ops.pallas import prefix_attention as pa
 
 H, DK, DV = 4, 24, 16
@@ -38,11 +41,21 @@ def _dense(q, k, v, q_pos, scale=SCALE):
     return jnp.einsum("bhts,bhsd->bhtd", p, v)
 
 
-def _operands(B, T, S, key=0):
+def _operands(B, T, S, key=0, heads=(H, H), widths=(DK, DV)):
     ks = jax.random.split(jax.random.key(key), 3)
-    return (jax.random.normal(ks[0], (B, H, T, DK)),
-            jax.random.normal(ks[1], (B, H, S, DK)),
-            jax.random.normal(ks[2], (B, H, S, DV)))
+    return (jax.random.normal(ks[0], (B, heads[0], T, widths[0])),
+            jax.random.normal(ks[1], (B, heads[1], S, widths[0])),
+            jax.random.normal(ks[2], (B, heads[1], S, widths[1])))
+
+
+def _dense_grouped(q, k, v, q_pos, scale):
+    """The dense routine of a decode cache, which keeps K and V grouped
+    (rows by position, heads second to last)."""
+    rows = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    seen = jnp.arange(k.shape[2])[None, None, :] <= q_pos[:, :, None]
+    assert scale == q.shape[-1] ** -0.5   # the routine's own
+    return rows(attention._cache_attention(rows(q), rows(k), rows(v), seen,
+                                           q.dtype))
 
 
 def _case(name):
@@ -70,11 +83,36 @@ def _case(name):
         k = k.at[:, :, 33:].set(jnp.nan)    # 32 is the last row seen
         v = v.at[:, :, 33:].set(jnp.nan)
         return q, k, v, pos, (8, 8), pos >= 0
+    # grouped queries, dk = dv = 16: G query heads to a K/V head
+    if name == "gqa4_whole":                # 8 / 2 heads, T = S
+        q, k, v = _operands(2, 32, 32, 10, (8, 2), (16, 16))
+        pos = jnp.broadcast_to(jnp.arange(32)[None], (2, 32))
+        return q, k, v, pos, (8, 8), pos >= 0
+    if name == "gqa8_whole":                # 8 / 1: the step's heads
+        q, k, v = _operands(1, 32, 32, 11, (8, 1), (16, 16))   # are half
+        pos = jnp.arange(32)[None]                             # a group
+        return q, k, v, pos, (8, 16), pos >= 0
+    if name == "gqa4_suffix_behind_restored":   # T < S, an offset a row
+        q, k, v = _operands(2, 16, 48, 12, (8, 2), (16, 16))
+        pos = jnp.asarray([[32], [5]]) + jnp.arange(16)[None]
+        return q, k, v, pos, (8, 16), pos >= 0
+    if name == "gqa8_padded_tail_ragged":   # off the tiles, padding at -1
+        q, k, v = _operands(2, 27, 43, 13, (16, 2), (16, 16))
+        real = jnp.arange(27)[None] < jnp.asarray([[21], [6]])
+        pos = jnp.where(real, jnp.asarray([[13], [0]])
+                        + jnp.arange(27)[None], -1)
+        return q, k, v, pos, (8, 16), real
+    if name == "gqa2_three_kv_heads":       # 6 / 3: a step is one group
+        q, k, v = _operands(1, 16, 32, 14, (6, 3), (16, 16))
+        pos = 9 + jnp.arange(16)[None]
+        return q, k, v, pos, (8, 8), pos >= 0
     raise KeyError(name)
 
 
 CASES = ("whole", "suffix_rows_differ", "ragged", "padded_tail",
-         "nan_past_the_prefix")
+         "nan_past_the_prefix", "gqa4_whole", "gqa8_whole",
+         "gqa4_suffix_behind_restored", "gqa8_padded_tail_ragged",
+         "gqa2_three_kv_heads")
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -83,12 +121,15 @@ def test_blockwise_is_the_dense_masked_softmax(execution, case):
     """Online softmax over key blocks at or under the diagonal against
     the dense form: the same float32 sums in another order (2e-6 on
     outputs of size ~1). A query that is no token gets zeros; NaN past
-    the visible prefix reaches nothing."""
+    the visible prefix reaches nothing. Grouped, the dense form is the
+    decode cache's own routine, which repeats nothing either."""
     q, k, v, pos, (bq, bk), real = _case(case)
+    scale = q.shape[-1] ** -0.5
     got = pa._in_whole_tiles(EXECUTIONS[execution], q, k, v, pos,
-                             scale=SCALE, block_q=bq, block_k=bk)
+                             scale=scale, block_q=bq, block_k=bk)
     finite = jnp.nan_to_num(k), jnp.nan_to_num(v)
-    want = _dense(q, *finite, jnp.maximum(pos, 0))
+    dense = _dense if k.shape[1] == q.shape[1] else _dense_grouped
+    want = dense(q, *finite, jnp.maximum(pos, 0), scale)
     assert got.shape == want.shape and got.dtype == q.dtype
     assert bool(jnp.isfinite(got).all())
     gap = jnp.abs(got - want).max(axis=(1, 3))
@@ -96,12 +137,15 @@ def test_blockwise_is_the_dense_masked_softmax(execution, case):
     assert float(jnp.where(real, 0, jnp.abs(got).max(axis=(1, 3))).max()) == 0
 
 
+@pytest.mark.parametrize("heads", [(H, H), (8, 2), (8, 1)],
+                         ids=["g1", "g4", "g8"])
 @pytest.mark.parametrize("tiles", [(8, 8), (16, 8), (8, 32), (32, 16)])
-def test_kernel_is_the_recurrence_in_bf16(tiles):
+def test_kernel_is_the_recurrence_in_bf16(tiles, heads):
     """The two executions on bf16 operands (float32 maximum, denominator
     and accumulator in both): the oracle's numbers to bf16's rounding
     of an output of size ~1."""
-    q, k, v = (x.astype(jnp.bfloat16) for x in _operands(1, 32, 64, 5))
+    q, k, v = (x.astype(jnp.bfloat16)
+               for x in _operands(1, 32, 64, 5, heads))
     pos = 30 + jnp.arange(32)[None]
     got, want = (run(q, k, v, pos, scale=SCALE, block_q=tiles[0],
                      block_k=tiles[1]) for run in EXECUTIONS.values())
@@ -161,13 +205,13 @@ def test_a_whole_prompt_visits_136_of_256_tile_pairs():
     # a prompt of 6,524 in a bucket of 8,192: the blocks of padding
     # visit nothing, the last real block reads up to its last token
     real = whole < 6524
-    read = int(mla.expanded_rows_read(whole, real, 8192))
+    read = int(pa.rows_read(whole, real, 8192))
     assert read == 512 * 1024 * (1 + 1 + 2 + 2 + 3 + 3 + 4 + 4 + 5 + 5
                                  + 6 + 6) + (6524 - 6144) * 7168
     attended = 6524 * 6525 // 2
     assert 0.85 < attended / read < 0.87
     # a row cache shorter than a key tile is read whole, no further
-    assert int(mla.expanded_rows_read(jnp.arange(16)[None],
+    assert int(pa.rows_read(jnp.arange(16)[None],
                                       jnp.arange(16)[None] < 9, 64)) \
         == 9 * 64
 
@@ -177,6 +221,7 @@ def test_which_execution_a_shape_takes(monkeypatch):
     recurrence; on one, the kernel where the tiles lay out (the served
     shapes) and the recurrence where they do not."""
     assert pa._kernel_tiles(192, 128, 512, 1024)       # the served form
+    assert pa._kernel_tiles(128, 128, 512, 1024)       # grouped heads
     assert pa._kernel_tiles(192, 128, 256, 256)        # a bucket of 256
     assert not pa._kernel_tiles(192, 128, 50, 50)      # T = 50, uncached
     assert not pa._kernel_tiles(24, 16, 8, 8)          # this file's sizes
@@ -198,21 +243,35 @@ def test_which_execution_a_shape_takes(monkeypatch):
         jax.random.normal(ks[2], (1, 1, 128, 128)),
         96 + jnp.arange(32)[None], scale=1.0, block_q=16, block_k=128)
     assert calls == ["kernel"]
+    # one level up, which routine a cached call over rows by position
+    # takes, by its shape alone: a decode round and a bucket whose dense
+    # scores are small (up to 512 x 512 a head: where the dense routine
+    # was not slower on the chip) keep the dense routine
+    for T, S, tiles in [(1, 4096, False), (1, 1 << 20, False),
+                        (32, 32, False), (64, 64, False),
+                        (512, 512, False), (16, 4096, False),
+                        (1024, 1024, True), (512, 4096, True),
+                        (4096, 4096, True), (16, 32768, True)]:
+        assert attention.prefill_in_tiles(T, S) == tiles, (T, S)
 
 
 @pytest.mark.skipif(jax.default_backend() != "tpu",
                     reason="the compiled kernel needs the chip")
-@pytest.mark.parametrize("T,first", [(8192, 0), (512, 6144)])
-def test_compiled_kernel_is_the_recurrence_at_the_served_size(T, first):
-    """64 heads of 192 / 128 in bf16 against 8,192 rows, a whole prompt
-    and a suffix behind 6,144 restored rows, at the served tiles."""
+@pytest.mark.parametrize("heads,dk,S,T,first", [
+    ((64, 64), 192, 8192, 8192, 0), ((64, 64), 192, 8192, 512, 6144),
+    ((32, 8), 128, 4096, 4096, 0), ((64, 8), 128, 4096, 2048, 1024)])
+def test_compiled_kernel_is_the_recurrence_at_the_served_size(heads, dk, S,
+                                                              T, first):
+    """In bf16 at the served tiles: 64 heads of 192 / 128 against 8,192
+    rows, a whole prompt and a suffix behind 6,144 restored rows; 32 and
+    64 query heads of 128 / 128 to 8 K/V heads against 4,096 rows."""
     ks = jax.random.split(jax.random.key(8), 3)
-    q = jax.random.normal(ks[0], (1, 64, T, 192), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (1, 64, 8192, 192), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (1, 64, 8192, 128), jnp.bfloat16)
+    q = jax.random.normal(ks[0], (1, heads[0], T, dk), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, heads[1], S, dk), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, heads[1], S, 128), jnp.bfloat16)
     pos = first + jnp.arange(T)[None]
-    kw = dict(scale=192 ** -0.5, block_q=mla.QUERY_BLOCK,
-              block_k=mla.KEY_BLOCK)
+    kw = dict(scale=dk ** -0.5, block_q=pa.QUERY_BLOCK,
+              block_k=pa.KEY_BLOCK)
     got = jax.jit(functools.partial(pa._pallas, **kw))(q, k, v, pos)
     want = jax.jit(functools.partial(pa._blockwise, **kw))(q, k, v, pos)
     assert float(jnp.abs(got.astype(jnp.float32)

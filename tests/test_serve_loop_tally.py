@@ -34,6 +34,11 @@ def _fresh(monkeypatch):
     chaos.reset()
     flight.reset_recorder(enabled=True)
     obs.reset_registry()
+    if jitwatch.installed():
+        # the listener's function names are the registry's labels and go
+        # with them: a worker that ran other files first may have used
+        # up all MAX_FUNS, and ``_serve_prefill`` would read "other"
+        jitwatch._watch.funs.clear()
     obs.disable_tracing()
     yield
     chaos.reset()
