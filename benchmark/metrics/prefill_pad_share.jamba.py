@@ -1,0 +1,12 @@
+"""``prefill_pad_share.jamba``
+
+1 - sum(``tokens``) / sum(``padded``) over the ``serve/prefill_into``
+spans in the trace: prompt positions computed for padding. A scan pays
+for them as attention's tiles do not.
+"""
+
+from benchmark.lib import host_spans
+
+
+def read(run: dict):
+    return host_spans.prefill_pad_share_pct(run)

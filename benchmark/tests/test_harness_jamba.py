@@ -1,0 +1,127 @@
+"""A whole closed-loop run of a tiny Jamba on the CPU.
+
+Beside ``test_harness_axk1.py``, for the fifth served family and the
+first whose cache holds recurrent state: the run comes out ``correct``,
+its int8 control does not, and neither does a run whose SSM state is
+zeroed when a prefilled row joins the batch, nor one whose decode rounds
+let inactive rows' state wander into the next admission (the insert made
+to skip the state leaves). ``serving.program_model`` passes a model eight
+sizes and no more, so the sizes it does not pass (the layer period, the
+state-space sizes) are the defaults of a tiny model registered for the
+length of a test.
+"""
+
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark import run as bench_run
+from pytorch_distributed_nn_tpu import models, obs
+from pytorch_distributed_nn_tpu.models.jamba import Jamba
+from pytorch_distributed_nn_tpu.nn.dtypes import get_policy
+from pytorch_distributed_nn_tpu.serve import engine as engine_mod
+
+DATA = Path(__file__).parent / "data"
+
+
+@pytest.fixture(autouse=True)
+def _tiny_model_registered(monkeypatch):
+    """In the registry for one test and out again: another file's test
+    of what ``available_models()`` lists may share this session."""
+    monkeypatch.setitem(models._REGISTRY, "jamba_tiny_for_tests", _tiny)
+    # the readers sum the process's counters: a run of the benchmark is
+    # a process of its own, a test is not
+    obs.reset_registry()
+    yield
+    obs.reset_registry()
+
+
+def _tiny(cfg):
+    policy = get_policy(cfg.dtype, cfg.compute_dtype)
+    e = cfg.extra
+    return Jamba(
+        vocab_size=e["vocab_size"], num_layers=e["num_layers"],
+        d_model=e["d_model"], num_heads=e["num_heads"],
+        num_kv_heads=e["num_kv_heads"], mlp_dim=e["mlp_dim"],
+        norm_eps=e["norm_eps"], attn_layer_period=3, attn_layer_offset=1,
+        mamba_dt_rank=8, dtype=policy.compute_dtype,
+        param_dtype=policy.param_dtype)
+
+
+def _serve(seconds: float, **kw):
+    return bench_run.run_cell(
+        workload="tiny_jamba", config_file=DATA / "tiny_jamba.json",
+        traffic_file=DATA / "tiny_chat64.json",
+        cell_file=DATA / "cells" / "tiny_jamba.json", chips=1,
+        seed=2**31 + 40, seconds=seconds, traced=False, check_device=False,
+        **kw)
+
+
+def test_closed_loop_cell_is_correct_and_its_control_is_not():
+    run = _serve(2.0, control=True)
+    assert run["correct"], run["check"]
+    assert not run["control"]["correct"], run["control"]
+    line = bench_run.result_line(
+        run, [dict(name=n, unit="x") for n in (
+            "serve_throughput", "setup_s", "state_bytes_share.jamba",
+            "decode_round_p50.jamba", "prefill_share.jamba",
+            "peak_hbm_share.jamba")], traced=False)
+    m = {k: v["value"] for k, v in line["metrics"].items()}
+    assert line["correct"] and line["failed"] == 0
+    assert m["serve_throughput"] > 0
+    # four rows' state in four layers beside ~118 K parameters
+    assert 10 < m["state_bytes_share.jamba"] < 60
+    # the traced-only readers say nothing in an untraced run
+    assert bench_run.read_metrics(
+        [dict(name=n, unit="%") for n in (
+            "decode_hbm_share.jamba", "prefill_flops_share.jamba",
+            "prefill_pad_share.jamba")], run) == {}
+
+
+def _without(leaf: str):
+    """``_insert_row`` that leaves the named state leaf of the slot as
+    its last occupant left it."""
+    insert = engine_mod._insert_row
+
+    def faulty(batch_cache, row_cache, slot, **kw):
+        # (a copy: the insert donates the batch cache)
+        kept = jax.tree_util.tree_map_with_path(
+            lambda path, x: jnp.copy(x)
+            if getattr(path[-1], "key", "") == leaf else None, batch_cache)
+        out = insert(batch_cache, row_cache, slot, **kw)
+        return jax.tree_util.tree_map_with_path(
+            lambda path, new, old: old
+            if getattr(path[-1], "key", "") == leaf else new, out, kept,
+            is_leaf=lambda x: x is None)
+    return faulty
+
+
+@pytest.mark.parametrize("fault", ["state_zeroed", "state_not_inserted",
+                                   "tail_not_inserted"])
+def test_a_state_that_does_not_reach_its_slot_is_not_correct(
+        monkeypatch, fault):
+    """The SSM state zeroed at the insert; the insert made to skip the
+    SSM state (a slot keeps what its last occupant left); the same for
+    the convolution's carried inputs."""
+    insert = engine_mod._insert_row
+
+    def zeroed(batch_cache, row_cache, slot, **kw):
+        row_cache = jax.tree_util.tree_map_with_path(
+            lambda path, x: jnp.zeros_like(x)
+            if getattr(path[-1], "key", "") == "ssm_state" else x,
+            row_cache)
+        return insert(batch_cache, row_cache, slot, **kw)
+
+    def tamper(engine):
+        del engine
+        monkeypatch.setattr(engine_mod, "_insert_row", {
+            "state_zeroed": zeroed,
+            "state_not_inserted": _without("ssm_state"),
+            "tail_not_inserted": _without("conv_tail")}[fault])
+    try:
+        run = _serve(2.0, tamper=tamper)
+    finally:
+        monkeypatch.undo()
+    assert not run["correct"], run["check"]

@@ -26,6 +26,7 @@ def get_model(cfg: ModelConfig):
     from pytorch_distributed_nn_tpu.models import (  # noqa: F401
         ax_k1,
         bert,
+        jamba,
         k_exaone,
         lenet,
         llama,
@@ -57,6 +58,7 @@ def available_models() -> list[str]:
     from pytorch_distributed_nn_tpu.models import (  # noqa: F401
         ax_k1,
         bert,
+        jamba,
         k_exaone,
         lenet,
         llama,

@@ -15,7 +15,7 @@ sees a sliding window of ``window`` keys; a *global* layer sees every
 key before it and rotates nothing. Served, a local layer's cache is a
 ring of ``window`` rows a sequence and a global layer's a row for every
 position (:class:`nn.attention.MultiHeadAttention`): two kinds of cache
-leaf in one engine, which :meth:`KExaone.ring_cache_leaves` declares.
+leaf in one engine, which :meth:`KExaone.leaves_not_by_position` declares.
 
 The first ``first_k_dense`` layers have a dense SwiGLU FFN; the others a
 mixture of ``num_experts`` routed experts, ``moe_topk`` picks a token by
@@ -204,14 +204,16 @@ class KExaone(nn.Module):
             for i, (_, window, _) in enumerate(self._layers())
             for name in COUNTERS)
 
-    def ring_cache_leaves(self) -> tuple:
-        """Paths in the ``cache`` collection of the leaves that are
-        rings: ``(slots, window, ...)``, row = position mod window, not
-        rows by absolute position. The serving engine keeps no prefix
-        store for a model that has any (serve/engine.py says why)."""
-        return tuple((name, "attn", leaf)
-                     for name, window, _ in self._layers() if window
-                     for leaf in ("cached_key", "cached_value"))
+    def leaves_not_by_position(self) -> dict:
+        """``{what they are: their paths in the ``cache`` collection}`` of
+        the leaves that are not rows by absolute position. Here the
+        rings: ``(slots, window, ...)``, row = position mod window. The
+        serving engine keeps no prefix store for a model that has any
+        (serve/engine.py says why)."""
+        return {"ring (a sliding window's rows, position mod window)": tuple(
+            (name, "attn", leaf)
+            for name, window, _ in self._layers() if window
+            for leaf in ("cached_key", "cached_value"))}
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False,

@@ -1,7 +1,8 @@
 """Counters a model sums on the device, published to the registry.
 
 A served model may count what only its own program sees (a mixture of
-experts: which experts its tokens picked) without a fetch of its own
+experts: which experts its tokens picked; a state-space layer: the
+positions that advanced its state) without a fetch of its own
 every round. It keeps one 1-D ``uint32`` leaf of running totals in its
 ``cache`` collection, which every program execution adds to on the
 device, and declares it: ``device_counter_leaf`` is the leaf's path in
@@ -48,6 +49,13 @@ class DeviceCounters:
                 "distinct expert groups a real token's picks fell in, "
                 "summed over tokens (group-limited routing)",
                 labels=labels),
+            "ssm_calls_total": reg.counter(
+                "ssm_calls_total", "executions of a state-space layer",
+                labels=labels),
+            "ssm_tokens_total": reg.counter(
+                "ssm_tokens_total",
+                "real positions that advanced a state-space layer's "
+                "state", labels=labels),
             "attn_rows_attended_total": reg.counter(
                 "attn_rows_attended_total",
                 "cached key rows inside the masks of real queries",

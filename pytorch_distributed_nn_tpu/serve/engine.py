@@ -23,6 +23,27 @@ length), and the compiler orders the same sums differently. Every
 divergence seen was a choice between logits less than half a bf16 step
 apart; chip_smoke.py gates on that margin.
 
+What the cache holds is the model's. Most leaves are *rows by absolute
+position*, ``(slots, max_seq_len, ...)``: keys and values, or a latent
+attention's compressed rows; position p of a sequence is row p, so a
+prefix of a sequence is a prefix of its rows, which is what the prefix
+cache and the block store stand on. A model may declare leaves that are
+not (``leaves_not_by_position()``): a *ring*, ``(slots, window, ...)``,
+a sliding window's rows with position p in row p mod window
+(K-EXAONE's window layers), and a *state*, ``(slots, ...)`` with no
+position axis at all: the running value of a recurrence, one a sequence
+whatever its length (Jamba's Mamba layers: the SSM state and the
+convolution's last inputs). Both are written by the model's own
+programs and copied into a slot whole by :func:`_insert_row`, like any
+leaf; neither can be cut into blocks of positions, so an engine whose
+model declares any keeps no prefix cache and no block store and refuses
+block export and ingest (``__init__`` says why). A state differs from
+a ring in one thing the model must see to: a ring row written by a
+padded position is masked until overwritten, a state advanced by one is
+wrong for good, so such a model takes the ``token_mask``
+(:func:`_mask_kw`) and holds its state through every position that is
+not real: a prefill bucket's padding, a decode round's retired row.
+
 There are two model programs, :func:`_serve_prefill` and
 :func:`_serve_step`, and what the engine holds and the batch contains
 decides their form: each takes ``lora`` (the bank and the rows' adapter
@@ -445,22 +466,46 @@ class ServingEngine:
             block_size=block_size,
         )
         self._cache = _fresh_cache(model, max_slots, self.max_seq_len)
-        # a model may declare cache leaves that are rings (a sliding
-        # window's ``(slots, window, ...)``, row = position mod window)
-        # beside the rows-by-position ones. Such a model gets no prefix
-        # cache and no block store: a hit of n rows needs a window
-        # layer's rows [n - window, n), which a retiring sequence no
-        # longer holds except at its very end, and a store page for
-        # every block of every layer would cost what the ring saved.
-        # ``_save_blocks`` / ``_restore_blocks`` slice every leaf at
-        # j * block_size and must never see a ring.
-        rings = getattr(model, "ring_cache_leaves", tuple)()
-        self._has_rings = bool(rings)
-        if prefix_cache and self._has_rings:
-            log.info("%s declares %d ring cache leaves: no prefix cache "
-                     "and no block store; every admission prefills from "
-                     "position 0", type(model).__name__, len(rings))
+        # a model may declare cache leaves that are not rows by absolute
+        # position (``leaves_not_by_position()``: what they are, and
+        # their paths): a sliding window's *ring* ``(slots, window,
+        # ...)``, row = position mod window, or a recurrence's *state*
+        # ``(slots, ...)``, one value a sequence whatever its length.
+        # Such a model gets no prefix cache and no block store. A hit
+        # of n rows needs a window layer's rows [n - window, n), which a
+        # retiring sequence no longer holds except at its very end, and
+        # a recurrence's state as it stood after exactly n tokens, which
+        # a sequence holds only while it is there; a store page for
+        # every block of every layer would cost what the ring or the
+        # state saved. ``_save_blocks`` / ``_restore_blocks`` slice
+        # every leaf at j * block_size and must never see either.
+        # ``_insert_row`` copies such a leaf like any other: the insert
+        # overwrites all of the slot's.
+        kinds = getattr(model, "leaves_not_by_position", dict)()
+        self._not_by_position = "; ".join(
+            f"{len(paths)} {kind}" for kind, paths in kinds.items() if paths)
+        if prefix_cache and self._not_by_position:
+            log.info("%s declares cache leaves that are not rows by "
+                     "position (%s): no prefix cache and no block store; "
+                     "every admission prefills from position 0",
+                     type(model).__name__, self._not_by_position)
             prefix_cache = False
+        # what the batch cache holds, by how its leaves are addressed
+        # (the slots' counters and indices, of one axis or none, aside)
+        g_cache = obs.get_registry().gauge(
+            "serve_cache_bytes", "bytes of the batch cache in leaves that "
+            "are rows by absolute position, and in leaves that are not "
+            "(rings, recurrent state)", labels=("leaves",))
+        declared = {p for paths in kinds.values() for p in paths}
+        held = {"by_position": 0, "not_by_position": 0}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                self._cache)[0]:
+            if leaf.ndim >= 2:
+                name = tuple(getattr(k, "key", k) for k in path)
+                held["not_by_position" if name in declared
+                     else "by_position"] += leaf.nbytes
+        for leaves, n in held.items():
+            g_cache.set(n, leaves=leaves)
         if prefix_cache:
             self.prefix_cache: Optional[PrefixCache] = PrefixCache(
                 pool, max_rows=self.max_seq_len, tag=tag)
@@ -1146,13 +1191,14 @@ class ServingEngine:
             self._cache, self._store, bs,
             np.int32(slot), padded, np.int32(nb))
 
-    def _refuse_rings(self, what: str) -> None:
-        if self._has_rings:
+    def _refuse_blocks(self, what: str) -> None:
+        if self._not_by_position:
             raise ValueError(
-                f"{what}: {type(self.model).__name__} keeps ring cache "
-                f"leaves (a sliding window's rows, position mod window); "
-                f"its engine has no block store, and blocks sliced by "
-                f"absolute position cannot carry a ring")
+                f"{what}: {type(self.model).__name__} keeps cache leaves "
+                f"that are not rows by position "
+                f"({self._not_by_position}); its engine has no block "
+                f"store, and blocks sliced by absolute position cannot "
+                f"carry them")
 
     def export_blocks(self, table):
         """Host-side copy of physical store blocks ``table`` (leading
@@ -1163,7 +1209,7 @@ class ServingEngine:
         eviction cannot recycle them before the peer's write lands.
         Non-block leaves (ndim < 2 scalars) ship as empty placeholders
         so the pytree structure round-trips."""
-        self._refuse_rings("export_blocks")
+        self._refuse_blocks("export_blocks")
         idx = jnp.asarray(np.asarray(table, np.int32))
         return jax.tree.map(
             lambda s: np.asarray(s[idx]) if s.ndim >= 2
@@ -1177,7 +1223,7 @@ class ServingEngine:
         at the adopted ids. Already-resident blocks dedup by digest and
         are not rewritten. Returns blocks written; 0 when this engine
         has no prefix cache or the pool had no headroom to adopt."""
-        self._refuse_rings("ingest_blocks")
+        self._refuse_blocks("ingest_blocks")
         if self.prefix_cache is None or self._store is None:
             return 0
         plan = self.prefix_cache.ingest(tokens, adapter)

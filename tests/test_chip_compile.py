@@ -141,6 +141,23 @@ def _prefix_attention(T, S, heads=(64, 64), widths=(192, 128)):
     return build
 
 
+def _mamba_scan(T):
+    """A Mamba mixer's prefill scan at Jamba2-3B's widths: no kernel of
+    its own (XLA's), compiled so that the chip's compiler has seen the
+    loop over positions with its ``(16, 5120)`` float32 carry."""
+    from pytorch_distributed_nn_tpu.nn import mamba
+
+    def build(arg):
+        def run(h, dt, c, b, co, a):
+            return mamba.selective_scan(h, dt, c, b, co, a)
+
+        f32 = jnp.float32
+        return run, [arg((1, 16, 5120), f32), arg((1, T, 5120), f32),
+                     arg((1, T, 5120), f32), arg((1, T, 16), f32),
+                     arg((1, T, 16), f32), arg((16, 5120), f32)], 0
+    return build
+
+
 CASES = {
     # Llama-3-8B's head layout (32 q / 8 kv heads of 128), long context
     "flash_fwd_bwd_d128_gqa_T8192": _flash(32, 8, 8192, 128, True),
@@ -178,6 +195,13 @@ CASES = {
         512, 4096, (32, 8), (128, 128)),
     "prefix_attention_gqa8_T2048_S2048": _prefix_attention(
         2048, 2048, (64, 8), (128, 128)),
+    # Jamba's 20 to 1: the chat cell's largest bucket and the smallest
+    # that runs in tiles; and its mixers' scan at that bucket
+    "prefix_attention_gqa20_T4096_S4096": _prefix_attention(
+        4096, 4096, (20, 1), (128, 128)),
+    "prefix_attention_gqa20_T1024_S1024": _prefix_attention(
+        1024, 1024, (20, 1), (128, 128)),
+    "mamba_scan_T4096": _mamba_scan(4096),
 }
 
 

@@ -262,7 +262,9 @@ def test_a_window_layers_cache_is_its_ring_whatever_the_length(max_len):
     model declares the rings, and no other leaf."""
     model = _model(4, 1)
     cache = gen.init_cache(model, 3, max_len)
-    rings = set(model.ring_cache_leaves())
+    (kind, paths), = model.leaves_not_by_position().items()
+    assert kind.startswith("ring")
+    rings = set(paths)
     assert len(rings) == 2 * 6   # key and value of six window layers
     for name, window, _ in model._layers():
         for leaf in ("cached_key", "cached_value"):
@@ -623,13 +625,15 @@ def test_a_ring_model_gets_no_prefix_cache_and_says_so_once(rank1, caplog):
     _, model, params = rank1
     with caplog.at_level("INFO", logger=engine_mod.log.name):
         engine = ServingEngine(model, params, max_slots=2, max_seq_len=32)
-    said = [r for r in caplog.records if "ring cache" in r.getMessage()]
+    said = [r for r in caplog.records
+            if "not rows by position" in r.getMessage()]
     assert len(said) == 1 and "no prefix cache" in said[0].getMessage()
+    assert "12 ring" in said[0].getMessage()
     assert engine.prefix_cache is None and engine._store is None
     assert engine.scheduler.prefix_cache is None
-    with pytest.raises(ValueError, match="ring cache"):
+    with pytest.raises(ValueError, match="12 ring"):
         engine.export_blocks([0])
-    with pytest.raises(ValueError, match="ring cache"):
+    with pytest.raises(ValueError, match="12 ring"):
         engine.ingest_blocks(np.arange(16), None)
 
     mc = ModelConfig(name="llama3_8b", dtype="float32",
@@ -641,7 +645,7 @@ def test_a_ring_model_gets_no_prefix_cache_and_says_so_once(rank1, caplog):
                     train=False)["params"]
     plain = ServingEngine(llama, lp, max_slots=2, max_seq_len=32)
     assert plain.prefix_cache is not None and plain._store is not None
-    assert not plain._has_rings
+    assert not plain._not_by_position
 
 
 # -- the other families' programs ------------------------------------------

@@ -46,6 +46,9 @@ TINY = {
     "k_exaone_ep8": dict(_KEXAONE, num_experts=16),  # 2 of 16 held
     "ax_k1": dict(_AXK1),
     "ax_k1_ep16": dict(_AXK1, num_experts=32),  # 2 of 32 held
+    "jamba": dict(num_layers=4, d_model=32, num_heads=4, num_kv_heads=1,
+                  mlp_dim=64, vocab_size=101, attn_layer_period=2,
+                  attn_layer_offset=1, mamba_dt_rank=4),
 }
 
 IMAGE_INPUT = {
