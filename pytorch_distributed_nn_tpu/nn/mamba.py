@@ -37,13 +37,20 @@ padded to 128, eight times the memory and the traffic.
 
 from __future__ import annotations
 
+import functools
+import logging
 import math
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-# positions of a prefill's recurrence unrolled into one loop iteration
+from pytorch_distributed_nn_tpu.obs.registry import get_registry
+from pytorch_distributed_nn_tpu.ops.pallas import selective_scan as kernel
+
+log = logging.getLogger(__name__)
+
+# positions of the recurrence unrolled into one iteration of ``lax.scan``
 # (the state stays on the core between them): read on the chip at
 # Jamba2-3B's widths (PERF.md sec. 4)
 SCAN_UNROLL = 16
@@ -71,30 +78,67 @@ def a_log_init(key, shape, dtype=jnp.float32):
         jnp.arange(1, shape[1] + 1, dtype=jnp.float32), shape)).astype(dtype)
 
 
-def selective_scan(h, dt, c, b, c_out, a, unroll: int = SCAN_UNROLL):
+@functools.lru_cache(maxsize=None)
+def _log_execution(execution: str, state: tuple, T: int, how: str):
+    log.info("selective_scan: %s, a state %s through %d positions, %s",
+             execution, state, T, how)
+
+
+def selective_scan(h, dt, c, b, c_out, a, unroll: int = SCAN_UNROLL,
+                   differentiable: bool = True):
     """``h_t = exp(dt_t a) h_{t-1} + (dt_t c_t) b_t``; ``y_t = h_t . c_out_t``.
 
-    h (B, N, D) float32, the state before the first position; dt, c
-    (B, T, D) float32; b, c_out (B, T, N) float32; a (N, D) float32.
-    Returns ``(y (B, T, D), h after the last position)``. The recurrence
-    runs a position a step (``lax.scan``, ``unroll`` positions an
-    iteration; one position is the step itself, with no loop): on the
-    chip a step over ``(16, 5120)`` takes ~1 us, and the parallel form
-    (an associative scan over the pairs ``(exp(dt a), dt c b)`` inside
-    chunks of 64 to 1,024 positions) measured 2 to 18 times slower,
-    several passes over ``(chunk, 16, 5120)`` float32 (PERF.md sec. 4).
-    A position whose ``dt`` is 0 holds ``h`` exactly."""
+    h (B, N, D) float32, the state before the first position; dt (B, T,
+    D) float32; c (B, T, D) in the serving type (widened here: the same
+    values); b, c_out (B, T, N) float32; a (N, D) float32. Returns ``(y
+    (B, T, D) float32, h after the last position)``. A position whose
+    ``dt`` is 0 holds ``h`` exactly.
+
+    One recurrence, a position after a position, in two executions
+    picked by what the call can observe. On a TPU, for more than one
+    position, shapes the kernel can lay out and a caller that will not
+    differentiate the call (the kernel brings no VJP): the Pallas kernel
+    of :mod:`ops.pallas.selective_scan`, the state on the core through a
+    chunk of positions, the call padded to whole chunks with ``dt = 0``.
+    Otherwise ``lax.scan``, ``unroll`` positions an iteration: the CPU's
+    path and the kernel's oracle, ~0.9 us a position on the chip, the
+    state through memory every iteration. One position, the decode
+    round, is the step itself with no loop. (The parallel form, an
+    associative scan over the pairs ``(exp(dt a), dt c b)`` inside
+    chunks of 64 to 1,024 positions, measured 2 to 18 times slower than
+    the loop, several passes over ``(chunk, 16, 5120)`` float32: PERF.md
+    sec. 4.) Logs once a shape which execution a program lowered with,
+    and counts the calls of each in ``selective_scan_calls_total``."""
     def step(h, xs):
         dt_t, c_t, b_t, co_t = xs              # (B, D) twice, (B, N) twice
         h = jnp.exp(dt_t[:, None, :] * a) * h \
             + (dt_t * c_t)[:, None, :] * b_t[:, :, None]
         return h, jnp.einsum("bnd,bn->bd", h, co_t)
 
-    if dt.shape[1] == 1:
-        h, y = step(h, (dt[:, 0], c[:, 0], b[:, 0], c_out[:, 0]))
+    T, D = dt.shape[1:]
+    if T == 1:
+        h, y = step(h, (dt[:, 0], c[:, 0].astype(jnp.float32), b[:, 0],
+                        c_out[:, 0]))
         return y[:, None], h
+    chunk, lanes = kernel.tiles(T, D)
+    on_core = not differentiable and jax.default_backend() == "tpu" \
+        and kernel.kernel_tiles(a.shape[0], D)
+    execution = "Pallas kernel" if on_core else "lax.scan"
+    _log_execution(execution, tuple(h.shape), T,
+                   f"chunks of {chunk} x {lanes} lanes" if on_core
+                   else f"{unroll} an iteration")
+    get_registry().counter(
+        "selective_scan_calls_total", "prefill recurrences a program was "
+        "traced with, by execution", ("execution",)).inc(execution=execution)
+    if on_core:
+        rows = lambda x: jnp.pad(  # noqa: E731
+            x, ((0, 0), (0, -T % chunk), (0, 0)))
+        y, h = kernel.scan(h, rows(dt), rows(c), rows(b), rows(c_out), a,
+                           chunk=chunk, lanes=lanes)
+        return y[:, :T], h
     h, y = jax.lax.scan(
-        step, h, tuple(jnp.moveaxis(x, 1, 0) for x in (dt, c, b, c_out)),
+        step, h, tuple(jnp.moveaxis(x, 1, 0) for x in
+                       (dt, c.astype(jnp.float32), b, c_out)),
         unroll=unroll)
     return jnp.moveaxis(y, 0, 1), h
 
@@ -188,8 +232,10 @@ class MambaMixer(nn.Module):
         dt = jnp.where(real[:, :, None], nn.softplus(dt_proj(r)), 0.0)
         a = -jnp.exp(a_log.astype(jnp.float32)).T                # (N, D)
         c32 = c.astype(jnp.float32)
-        # a decode round (T = 1) is one step over every row
-        y, h = selective_scan(h, dt, c32, b, c_out, a)
+        # a decode round (T = 1) is one step over every row; a call that
+        # keeps a cache is a served one, which nothing differentiates
+        y, h = selective_scan(h, dt, c, b, c_out, a,
+                              differentiable=not decode)
         y = y + skip.astype(jnp.float32) * c32
         if decode and not self.is_initializing():
             state.value = h

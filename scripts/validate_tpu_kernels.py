@@ -364,6 +364,44 @@ def check_prefix_attention() -> bool:
     return ok
 
 
+def check_selective_scan() -> bool:
+    """The Mamba prefill's kernel against ``lax.scan`` at Jamba2-3B's
+    widths, ``c`` in bf16: the chat cell's smallest bucket with a padded
+    tail (the state held bit for bit through it), a ragged call through
+    the dispatcher's padding, and 1,024 positions from a state that is
+    not zero."""
+    import functools
+
+    from pytorch_distributed_nn_tpu.nn import mamba
+
+    if jax.default_backend() != "tpu":
+        print("selective_scan: skipped (the kernel needs the chip)")
+        return True
+    ok = True
+    ks = jax.random.split(jax.random.key(9), 6)
+    a = -jnp.exp(jax.random.normal(ks[4], (16, 5120)))
+    h = jax.random.normal(ks[5], (1, 16, 5120))
+    for T, real in [(128, 77), (300, 300), (1024, 1024)]:
+        dt = jnp.where(jnp.arange(T)[None, :, None] < real, jax.nn.softplus(
+            jax.random.normal(ks[0], (1, T, 5120)) - 2.0), 0.0)
+        c = jax.random.normal(ks[1], (1, T, 5120), jnp.bfloat16)
+        b, co = (jax.random.normal(k, (1, T, 16)) for k in ks[2:4])
+        (y, h1), (want_y, want_h) = (
+            jax.jit(functools.partial(mamba.selective_scan,
+                                      differentiable=differentiable))(
+                h, dt, c, b, co, a) for differentiable in (False, True))
+        _, held = mamba.selective_scan(
+            h, *(x[:, :real] for x in (dt, c, b, co)), a,
+            differentiable=False)
+        err = float(jnp.abs(y - want_y).max() / jnp.abs(want_y).max())
+        err_h = float(jnp.abs(h1 - want_h).max())
+        line_ok = err < 1e-5 and err_h < 1e-5 and bool((held == h1).all())
+        ok &= line_ok
+        print(f"selective_scan T{T}, {real} real: y rel_err={err:.2e} "
+              f"h max_err={err_h:.2e} {'OK' if line_ok else 'FAIL'}")
+    return ok
+
+
 def main() -> int:
     print(f"backend: {jax.default_backend()} devices: {jax.devices()}")
     if jax.default_backend() != "tpu":
@@ -371,7 +409,7 @@ def main() -> int:
     ok = (check_flash() & check_flash_grad() & check_quantize()
           & check_int8_matmul() & check_ring_block() & check_ring_bwd()
           & check_long_context() & check_bn_stats()
-          & check_prefix_attention())
+          & check_prefix_attention() & check_selective_scan())
     print("ALL OK" if ok else "FAILURES")
     return 0 if ok else 1
 

@@ -141,20 +141,39 @@ def _prefix_attention(T, S, heads=(64, 64), widths=(192, 128)):
     return build
 
 
+def _mamba_operands(arg, T, c_dtype):
+    f32 = jnp.float32
+    return [arg((1, 16, 5120), f32), arg((1, T, 5120), f32),
+            arg((1, T, 5120), c_dtype), arg((1, T, 16), f32),
+            arg((1, T, 16), f32), arg((16, 5120), f32)]
+
+
 def _mamba_scan(T):
-    """A Mamba mixer's prefill scan at Jamba2-3B's widths: no kernel of
-    its own (XLA's), compiled so that the chip's compiler has seen the
-    loop over positions with its ``(16, 5120)`` float32 carry."""
+    """A Mamba mixer's scan at Jamba2-3B's widths as ``lax.scan`` (the
+    dispatcher sees the CPU here): what a shape off the kernel's tiling
+    and an uncached call still run, compiled so that the chip's compiler
+    has seen the loop over positions with its ``(16, 5120)`` float32
+    carry."""
     from pytorch_distributed_nn_tpu.nn import mamba
 
     def build(arg):
-        def run(h, dt, c, b, co, a):
-            return mamba.selective_scan(h, dt, c, b, co, a)
+        return mamba.selective_scan, _mamba_operands(arg, T, jnp.float32), 0
+    return build
 
-        f32 = jnp.float32
-        return run, [arg((1, 16, 5120), f32), arg((1, T, 5120), f32),
-                     arg((1, T, 5120), f32), arg((1, T, 16), f32),
-                     arg((1, T, 16), f32), arg((16, 5120), f32)], 0
+
+def _selective_scan(T):
+    """A prefill's recurrence as the kernel, at the tiles
+    ``nn/mamba.selective_scan`` runs it in and ``c`` in bf16 as served."""
+    from pytorch_distributed_nn_tpu.ops.pallas import selective_scan as ss
+
+    def build(arg):
+        chunk, lanes = ss.tiles(T, 5120)
+        assert ss.kernel_tiles(16, 5120)
+
+        def run(*xs):
+            return ss.scan(*xs, chunk=chunk, lanes=lanes)
+
+        return run, _mamba_operands(arg, T, jnp.bfloat16), 1
     return build
 
 
@@ -196,11 +215,14 @@ CASES = {
     "prefix_attention_gqa8_T2048_S2048": _prefix_attention(
         2048, 2048, (64, 8), (128, 128)),
     # Jamba's 20 to 1: the chat cell's largest bucket and the smallest
-    # that runs in tiles; and its mixers' scan at that bucket
+    # that runs in tiles; its mixers' scan as the kernel at the smallest
+    # bucket (one chunk) and the largest, and as the loop that stays
     "prefix_attention_gqa20_T4096_S4096": _prefix_attention(
         4096, 4096, (20, 1), (128, 128)),
     "prefix_attention_gqa20_T1024_S1024": _prefix_attention(
         1024, 1024, (20, 1), (128, 128)),
+    "selective_scan_T128": _selective_scan(128),
+    "selective_scan_T4096": _selective_scan(4096),
     "mamba_scan_T4096": _mamba_scan(4096),
 }
 
