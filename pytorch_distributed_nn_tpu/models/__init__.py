@@ -34,6 +34,7 @@ def get_model(cfg: ModelConfig):
         mlp,
         moe_lm,
         resnet,
+        sdar_moe,
         transformer_lm,
         vit,
     )
@@ -66,6 +67,7 @@ def available_models() -> list[str]:
         mlp,
         moe_lm,
         resnet,
+        sdar_moe,
         transformer_lm,
         vit,
     )

@@ -187,7 +187,8 @@ def _row_update(buf, new, starts):
     ``dynamic_update_slice`` a row, which clamps an out-of-range window
     as a whole onto the row's end. The TPU compiles that form to a
     serial loop over the batch rows: one iteration for the engine's
-    prefill, but B iterations a leaf a round if a decode round used it."""
+    prefill, but B iterations a leaf a round if a decode round used it
+    (a block decoder's round writes with :func:`_block_update`)."""
     if new.shape[1] == 1:
         return buf.at[jnp.arange(buf.shape[0]), starts].set(
             new[:, 0], mode="drop", unique_indices=True,
@@ -196,6 +197,16 @@ def _row_update(buf, new, starts):
         lambda b, n, s: jax.lax.dynamic_update_slice(
             b, n, (s,) + (0,) * (b.ndim - 1))
     )(buf, new, starts)
+
+
+def _block_update(buf, new, starts):
+    """:func:`_row_update` for a round that feeds every row a block of
+    a few positions (a block decoder's): one indexed scatter a leaf, as
+    for one token a row, a position out of range dropped by itself (a
+    stopped row at ``max_seq_len``)."""
+    return buf.at[jnp.arange(buf.shape[0])[:, None],
+                  starts[:, None] + jnp.arange(new.shape[1])[None]].set(
+        new, mode="drop", unique_indices=True, indices_are_sorted=True)
 
 
 def _quantize_kv(x):
@@ -394,6 +405,13 @@ class MultiHeadAttention(nn.Module):
     # ``k_norm/scale``)
     qk_norm: bool = False
     norm_eps: float = 1e-5
+    # causal by blocks (a block-diffusion decoder's mask, decode cache
+    # only): a query at position p sees every key of its own block of
+    # ``see_block`` positions and of every block before it, up to
+    # ``p // see_block * see_block + see_block - 1``; rotation keeps p.
+    # 0 or 1: causal. T == see_block fed tokens a row are a decode round
+    # over every slot, written by one scatter a leaf.
+    see_block: int = 0
 
     @nn.compact
     def __call__(self, x, mask: Optional[jax.Array] = None,
@@ -608,6 +626,8 @@ class MultiHeadAttention(nn.Module):
                     positions = starts[:, None] + jnp.arange(T)[None]
 
                     def write(buf, new):
+                        if T == self.see_block:   # a block round
+                            return _block_update(buf, new, starts)
                         return _row_update(buf, new, starts)
                 if self.rotary:
                     q, k = rotary_embedding(q, k, theta=self.rope_theta,
@@ -616,7 +636,15 @@ class MultiHeadAttention(nn.Module):
                 # attend to the filled prefix: k_pos <= this row's q_pos
                 # (per-row rows are left-aligned, so slot == position)
                 k_pos = jnp.arange(S)[None, None, :]
-                q_pos = positions[:, :, None]
+                seen = positions  # the last key position a query sees
+                if self.see_block > 1:
+                    if self.window or int8_cache:
+                        raise ValueError(
+                            "a mask by blocks is built for rows by "
+                            "position in the compute dtype")
+                    seen = positions // self.see_block * self.see_block \
+                        + self.see_block - 1
+                q_pos = seen[:, :, None]
                 pos_mask = k_pos <= q_pos  # (B|1, T, S)
                 if self.window:
                     # the ring has its own rows and mask; one path for
@@ -643,7 +671,7 @@ class MultiHeadAttention(nn.Module):
                     cached_v.value = write(cached_v.value, v)
                     if prefill_in_tiles(T, S):
                         out = _prefill_attention(
-                            q, cached_k.value, cached_v.value, positions,
+                            q, cached_k.value, cached_v.value, seen,
                             lengths)
                     else:
                         out = _cache_attention(
@@ -662,7 +690,15 @@ class MultiHeadAttention(nn.Module):
                 t = jnp.arange(x.shape[1])
                 mask = (t[:, None] - t[None, :] < self.window)[None]
                 impl = "xla"   # the band is a mask, which flash lacks
-            out = dot_product_attention(q, k, v, causal=self.causal,
+            causal = self.causal
+            if self.see_block > 1:
+                if mask is not None:
+                    raise ValueError("a mask by blocks takes no other")
+                t = jnp.arange(x.shape[1])
+                mask = (t[None, :] <= t[:, None] // self.see_block
+                        * self.see_block + self.see_block - 1)[None]
+                impl, causal = "xla", False
+            out = dot_product_attention(q, k, v, causal=causal,
                                         impl=impl, mask=mask)
         if self.quantized:
             return Int8DenseGeneral(

@@ -56,6 +56,20 @@ class DeviceCounters:
                 "ssm_tokens_total",
                 "real positions that advanced a state-space layer's "
                 "state", labels=labels),
+            "block_forwards_total": reg.counter(
+                "block_forwards_total",
+                "forwards of a block of positions by a block decoder's "
+                "round (a live row a round)"),
+            "block_commits_total": reg.counter(
+                "block_commits_total",
+                "block forwards that found no position masked and "
+                "committed the block"),
+            "block_positions_unmasked_total": reg.counter(
+                "block_positions_unmasked_total",
+                "masked positions that took a token in a denoising step"),
+            "block_tokens_emitted_total": reg.counter(
+                "block_tokens_emitted_total",
+                "tokens a commit handed to its request"),
             "attn_rows_attended_total": reg.counter(
                 "attn_rows_attended_total",
                 "cached key rows inside the masks of real queries",

@@ -269,6 +269,13 @@ class HeldExpertsMoE(nn.Module):
     ep_size: int = 1
     ep_rank: int = 0
     token_block: int = 128
+    # one loop over the held experts in the program, expert j's column
+    # block taken by ``dynamic_slice``, in place of a loop a held expert
+    # unrolled into it: what a layer that holds many experts (128) costs
+    # to compile falls with the text (PERF.md sec. 6 has both forms'
+    # compile and round times). Off: the program of the layers that hold
+    # 12 or 16 is as it was.
+    rolled: bool = False
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -352,14 +359,23 @@ class HeldExpertsMoE(nn.Module):
         order = jnp.argsort(~member, axis=0, stable=True).astype(jnp.int32)
         order = jnp.pad(order, ((0, n_pad - N), (0, 0)))
 
+        def cols(w, j, width):
+            """Expert j's column block: a static slice for a Python j,
+            a dynamic one inside the rolled loop."""
+            if isinstance(j, int):
+                return w[:, j * width:(j + 1) * width]
+            return jax.lax.dynamic_slice_in_dim(w, j * width, width, axis=1)
+
         def run_expert(j, out):
             def one_block(b, out):
-                rows = jax.lax.dynamic_slice(order[:, j], (b * tb,), (tb,))
+                mine = order[:, j] if isinstance(j, int) else \
+                    jax.lax.dynamic_index_in_dim(order, j, 1, keepdims=False)
+                rows = jax.lax.dynamic_slice(mine, (b * tb,), (tb,))
                 live = b * tb + jnp.arange(tb) < counts[j]
                 xb = a[rows]
-                wg = w_gate[:, j * ff:(j + 1) * ff].astype(self.dtype)
-                wu = w_up[:, j * ff:(j + 1) * ff].astype(self.dtype)
-                wd = w_down[:, j * d:(j + 1) * d].astype(self.dtype)
+                wg = cols(w_gate, j, ff).astype(self.dtype)
+                wu = cols(w_up, j, ff).astype(self.dtype)
+                wd = cols(w_down, j, d).astype(self.dtype)
                 h = nn.silu(xb @ wg) * (xb @ wu)
                 yb = jnp.dot(h, wd, preferred_element_type=jnp.float32)
                 wt = jnp.where(live, w_held[rows, j], 0.0)
@@ -368,8 +384,11 @@ class HeldExpertsMoE(nn.Module):
             blocks = (counts[j] + tb - 1) // tb
             return jax.lax.fori_loop(0, blocks, one_block, out)
 
-        for j in range(held):
-            out = run_expert(j, out)
+        if self.rolled:
+            out = jax.lax.fori_loop(0, held, run_expert, out)
+        else:
+            for j in range(held):
+                out = run_expert(j, out)
 
         n_real = real.sum()
         stats = jnp.stack([

@@ -1,7 +1,8 @@
 """Continuous-batching decode engine.
 
 The data plane of the serving stack: a dense batched KV cache of
-``max_slots`` rows, stepped one token per round for every active row,
+``max_slots`` rows, stepped one token per round for every active row
+(a block decoder's: a block of positions, see below),
 with finished rows retired *mid-batch* and newly admitted requests
 prefilled into the freed rows — the batch never drains to admit work.
 Policy (who gets in, who waits) is the scheduler's
@@ -96,6 +97,24 @@ across rounds, and the one device->host fetch per call (the tokens the
 host must see to stream them and to retire rows) is a single
 ``np.asarray`` of a (slots,) array.
 
+A *block decoder* (a model that declares ``block_decoding()``:
+SDAR's block diffusion, ``models/sdar_moe.py``) changes what a round
+is and nothing of the above. Its round (:func:`_block_round`, under
+``_serve_step``'s name) forwards a block of ``B`` positions a row at
+the row's committed depth and then, row by row, either unmasks some of
+the block's positions (a denoising step: no token comes of it) or, the
+block being whole, commits it: up to ``B`` tokens for the request at
+once. So a round gives a row none or up to a block of tokens, the one
+fetch is a (slots, B + 1) array (the tokens and how many), a row's
+rounds to go are its blocks left times a block's steps plus one, a
+prefill fills the prompt's whole blocks and yields no first token (the
+prompt's tail opens the first block, written with the slot's state),
+and time to first token is the first block's commit. The three rules
+hold as they stand: the device stops a row in the round that hands out
+its last token; only an admission writes slot state
+(``_write_block_rows``); a round knows its rows. ``docs/sdar.md`` has
+the pieces and what is refused.
+
 Observability: TTFT + per-token latency histograms, batch-occupancy /
 queue-depth / KV-utilization gauges, one flight-ring ``serve`` event
 per decode round (a wedged loop is visible to the doctor as a stalled
@@ -172,6 +191,48 @@ def _mask_kw(model, mask) -> dict:
         if getattr(model, "takes_token_mask", False) else {}
 
 
+def _block_of(model):
+    """What a block decoder declares (``block_decoding()``: positions a
+    block, denoising steps, the rule that unmasks, its threshold, the
+    mask token), or None for a model that emits a token a round."""
+    declared = getattr(model, "block_decoding", None)
+    return None if declared is None else declared()
+
+
+def _block_steps(masked: int, block: int, steps: int) -> int:
+    """Denoising forwards that unmask ``masked`` positions of a block
+    of ``block`` planned in ``steps``: step t takes ``block // steps``,
+    one more in the first ``block % steps`` (the family's
+    ``get_num_transfer_tokens``), fewer if fewer are masked. Exact for
+    the rules that take just that many, an upper bound for
+    ``low_confidence_dynamic``."""
+    t = 0
+    while masked > 0:
+        masked -= block // steps + (t < block % steps)
+        t += 1
+    return t
+
+
+def _block_rounds(prompt_len: int, new_tokens: int, block: int,
+                  steps: int) -> int:
+    """Rounds a block decoder's row needs at most: its first block's
+    steps and commit (the prompt's tail known from the start), then
+    those of every further block that holds one of its tokens."""
+    tail = prompt_len % block
+    return _block_steps(block - tail, block, steps) + 1 \
+        + (-(-(tail + new_tokens) // block) - 1) \
+        * (_block_steps(block, block, steps) + 1)
+
+
+def _idle_block_state(slots: int, block: int) -> tuple:
+    """A block decoder's slot state with no row live: ``(out, place)``
+    as :func:`_block_round` takes them."""
+    idle = jnp.zeros((slots,), jnp.int32)
+    return (jnp.zeros((slots, block + 1), jnp.int32),
+            dict(depth=idle, masked=jnp.zeros((slots, block), bool),
+                 step=idle, skip=idle))
+
+
 def _apply_prefill_at(model, params, cache, tokens, lengths, starts,
                       **extra):
     """Ragged prefill with a per-row cache-write offset: row i's KV
@@ -229,6 +290,14 @@ def _serve_prefill(model, params, cache, tokens, lengths, starts,
     the one prompt's logits fan into ``n`` first tokens. An absent
     argument is an empty pytree: the program lowers as if the argument
     had never been written, and its output is absent too."""
+    if _block_of(model) is not None:
+        # a block decoder's prefill fills rows and yields no token: its
+        # first block, the prompt's tail among it, is the rounds'
+        # (nor a logit: the hidden states come back, the head is skipped)
+        _, cache = _apply_prefill_at(
+            model, params, cache, tokens, lengths, starts,
+            return_hidden=True, **(lora or {}))
+        return None, cache, sampling
     next_logits, cache = _apply_prefill_at(
         model, params, cache, tokens, lengths, starts, **(lora or {}))
     if sampling is None:
@@ -260,7 +329,16 @@ def _serve_step(model, params, cache, last_tok, lengths, active, remaining,
     tokens computes nothing for a finished row. ``lora`` and absent
     arguments as in :func:`_serve_prefill`, with ``adapter_ids``
     (slots,). Returns ``(tokens, lengths, active, remaining, cache,
-    sampling)``."""
+    sampling)``.
+
+    A block decoder (:func:`_block_of`) gets :func:`_block_round` under
+    this program's name, with a block's state where a row's last token
+    and depth stand; the branch is taken while tracing, on the static
+    ``model``, so no other model's program holds a line of it."""
+    block = _block_of(model)
+    if block is not None:
+        return _block_round(model, block, params, cache, last_tok, lengths,
+                            active, remaining, eos, lora, sampling)
     logits, cache = _apply_decode_ragged(
         model, params, cache, last_tok, lengths, **(lora or {}),
         **_mask_kw(model, active[:, None]))
@@ -273,6 +351,138 @@ def _serve_step(model, params, cache, last_tok, lengths, active, remaining,
     alive = active & (remaining > 1) & (nxt != eos)
     remaining = jnp.where(active, remaining - 1, remaining)
     return nxt, lengths, alive, remaining, cache, sampling
+
+
+def _block_round(model, block, params, cache, out, place, active, remaining,
+                 eos, lora, sampling):
+    """One round of a block decoder over all slots: a forward of every
+    row's block of ``B`` positions at its committed depth, and then,
+    row by row, a *denoising step* or a *commit*.
+
+    ``out`` (slots, B + 1) int32 is both state and what the host
+    fetches: the block's tokens and, last, how many tokens the round
+    handed to the request (a row that committed holds those tokens,
+    left-aligned, where its block stood: the next block starts all
+    masked and its tokens mean nothing). ``place`` is the rest of a
+    row's state: ``depth`` (slots,) the rows committed, a multiple of
+    B; ``masked`` (slots, B) which positions of the block are unknown
+    (they are fed ``mask_token_id``; a boolean, not a comparison of
+    ids: the mask token in a prompt is a token); ``step`` the
+    denoising steps the block has had; ``skip`` the leading positions
+    that are the prompt's tail and are not handed out.
+
+    Position p's own row of logits scores position p. A row with a
+    masked position takes a step: ``x0`` is the best token but the mask
+    token (or the row's draw), ``conf`` its probability, and ``n_t`` of
+    the masked positions take their ``x0`` (:func:`_block_steps`'s
+    schedule): the leftmost (``sequential``), the most confident
+    (``low_confidence_static``), or every one over
+    ``confidence_threshold`` if those are at least ``n_t``
+    (``low_confidence_dynamic``). A row with none masked has just
+    written the finished block's keys and values: the commit. Its depth
+    moves on by B, the positions past ``skip`` are handed out up to the
+    row's budget or its first ``eos``, and a fresh block begins. Every
+    forward writes the block's rows and the next overwrites them, the
+    commit last; nothing reads a row past the block. Rows at different
+    steps, a row that commits beside one that steps, a stopped row
+    flowing through: one program. Returns as :func:`_serve_step`."""
+    B, S = block["block_length"], block["denoising_steps"]
+    mask_id, rule = block["mask_token_id"], block["remasking"]
+    tok, masked, step = out[:, :B], place["masked"], place["step"]
+    depth, skip = place["depth"], place["skip"]
+    logits, mutated = model.apply(
+        {"params": params, "cache": cache},
+        jnp.where(masked, mask_id, tok), train=False, decode=True,
+        mutable=["cache"], cache_positions=depth, block_round=True,
+        token_mask=jnp.broadcast_to(active[:, None], tok.shape),
+        **(lora or {}))
+    V = logits.shape[-1]
+    allowed = jnp.where(jnp.arange(V) == mask_id, -jnp.inf, logits)
+    if sampling is None:
+        x0 = jnp.argmax(allowed, axis=-1).astype(jnp.int32)
+    else:
+        # a row's spec for each of its positions; the key is the row's
+        # at this round, the position folded in
+        per = lambda v: jnp.repeat(v, B)  # noqa: E731
+        keys = jax.vmap(lambda k: jax.vmap(
+            lambda i: jax.random.fold_in(k, i))(jnp.arange(B)))(
+            decoding.row_keys(sampling["seed"], sampling["branch"],
+                              sampling["step"]))
+        x0 = decoding.sample_rows(
+            allowed.reshape(-1, V), per(sampling["temp"]),
+            per(sampling["top_k"]), per(sampling["top_p"]),
+            keys.reshape((-1,) + keys.shape[2:])
+        ).astype(jnp.int32).reshape(tok.shape)
+        sampling = dict(sampling, step=jnp.where(
+            active, sampling["step"] + 1, sampling["step"]))
+    n_t = B // S + (step < B % S)
+    if rule == "sequential":
+        rank = jnp.cumsum(masked, axis=-1) - 1
+    else:   # by falling confidence, the leftmost of equals first
+        conf = jnp.exp(
+            jnp.take_along_axis(logits, x0[..., None], axis=-1)[..., 0]
+            - jax.nn.logsumexp(logits, axis=-1))
+        order = jnp.argsort(-jnp.where(masked, conf, -1.0), axis=-1,
+                            stable=True)
+        rank = jnp.argsort(order, axis=-1)
+    take = masked & (rank < n_t[:, None])
+    if rule == "low_confidence_dynamic":
+        sure = masked & (conf > block["confidence_threshold"])
+        take = jnp.where((sure.sum(axis=-1) >= n_t)[:, None], sure, take)
+    commit = active & ~masked.any(axis=-1)
+    take &= (active & ~commit)[:, None]
+    place_i = jnp.arange(B)[None]
+    n_out = jnp.minimum(B - skip, remaining)
+    stops = (tok == eos) & (place_i >= skip[:, None]) \
+        & (place_i < (skip + n_out)[:, None])
+    stopped = stops.any(axis=-1)
+    n_out = jnp.where(stopped, jnp.argmax(stops, axis=-1) - skip + 1, n_out)
+    n_out = jnp.where(commit, n_out, 0)
+    handed = jnp.take_along_axis(
+        tok, jnp.minimum(skip[:, None] + place_i, B - 1), axis=1)
+    out = jnp.concatenate([
+        jnp.where(commit[:, None], handed, jnp.where(take, x0, tok)),
+        n_out[:, None]], axis=1)
+    remaining = remaining - n_out
+    alive = jnp.where(commit, (remaining > 0) & ~stopped, active)
+    place = dict(
+        depth=jnp.where(commit, depth + B, depth),
+        masked=jnp.where(commit[:, None], True, masked & ~take),
+        step=jnp.where(commit, 0, jnp.where(active, step + 1, step)),
+        skip=jnp.where(commit, 0, skip))
+    cache = model.add_block_counts(mutated["cache"], jnp.stack([
+        active.sum(), commit.sum(), take.sum(), n_out.sum()]))
+    return out, place, alive, remaining, cache, sampling
+
+
+@jax.jit
+def _write_block_rows(out, place, active, remaining, rows, tails,
+                      adapter_ids=None, sampling=None, mirror=None):
+    """:func:`_write_rows` for a block decoder: a written row gets its
+    first block. ``rows`` (5, slots) int32 is the host's ``(written,
+    prompt positions in the block, depth, remaining, adapter)`` and
+    ``tails`` (slots, B) the block's tokens, the prompt's tail first;
+    the positions after it are masked."""
+    written = rows[0] > 0
+    B = tails.shape[1]
+    out = jnp.where(written[:, None],
+                    jnp.pad(tails, ((0, 0), (0, 1))), out)
+    place = dict(
+        depth=jnp.where(written, rows[2], place["depth"]),
+        masked=jnp.where(written[:, None],
+                         jnp.arange(B)[None] >= rows[1][:, None],
+                         place["masked"]),
+        step=jnp.where(written, 0, place["step"]),
+        skip=jnp.where(written, rows[1], place["skip"]))
+    state = (out, place, jnp.where(written, rows[3] > 0, active),
+             jnp.where(written, rows[3], remaining))
+    if adapter_ids is not None:
+        adapter_ids = jnp.where(written, rows[4], adapter_ids)
+    if sampling is not None:
+        sampling = {k: jnp.where(written, v, sampling[k])
+                    if k in ("step", "logprob") else v
+                    for k, v in mirror.items()}
+    return state, adapter_ids, sampling
 
 
 @jax.jit
@@ -398,18 +608,22 @@ class _Slot:
     """Host-side mirror of one batch row (= one decode branch)."""
 
     __slots__ = ("req", "emitted", "tokens", "depth", "cached",
-                 "seq_id", "branch", "step0", "streamed", "first_round")
+                 "seq_id", "branch", "step0", "streamed", "first_round",
+                 "rounds")
 
-    def __init__(self, req: Request, first_token: int, depth: int,
+    def __init__(self, req: Request, first_token: Optional[int], depth: int,
                  first_round: int, cached: int = 0, seq_id: str = "",
-                 branch: int = 0):
+                 branch: int = 0, rounds: int = 0):
         self.req = req
         # the first decode round (by the engine's count of dispatches)
         # that holds this row: an earlier round, still unfetched when
         # the row was admitted, has the slot's last occupant's token
         self.first_round = first_round
-        self.tokens = [int(first_token)]
-        self.emitted = 1
+        # a block decoder's row starts with no token (its prefill yields
+        # none) and knows the rounds it needs at most: ``rounds``
+        self.tokens = [] if first_token is None else [int(first_token)]
+        self.emitted = len(self.tokens)
+        self.rounds = rounds
         self.depth = depth  # cache rows filled (prompt + emitted - 1)
         self.cached = cached  # prompt tokens restored from prefix cache
         # Prism: which pool sequence this row extends (== request_id
@@ -461,6 +675,24 @@ class ServingEngine:
         # (nn/lora.py); requests pick an adapter at submit and each
         # batch row applies its own deltas in the shared forward
         self.lora_bank = lora_bank
+        # a block decoder (``block_decoding()``): a round rewrites a
+        # block of positions a row and hands out none or up to a block
+        # of tokens (:func:`_block_round`). Under its mask by blocks a
+        # prefix-cache page's rows depend on nothing after the page
+        # only if pages are whole blocks
+        self._block = _block_of(model)
+        # what a block decoder's serve/decode span says beside the rest
+        self._span_block = {}
+        if self._block is not None:
+            B = self._block["block_length"]
+            self._span_block = {"block": B}
+            if block_size % B or self.max_seq_len % B:
+                raise ValueError(
+                    f"{type(model).__name__} decodes blocks of {B} "
+                    f"positions under a mask by blocks: block_size "
+                    f"{block_size} and max_seq_len {self.max_seq_len} "
+                    f"must be multiples of it (a prefix-cache page has "
+                    f"to hold whole blocks, a row's last block to fit)")
         pool = KVPool(
             num_blocks=max_slots * (-(-self.max_seq_len // block_size)),
             block_size=block_size,
@@ -541,6 +773,12 @@ class ServingEngine:
                          jnp.zeros((max_slots,), jnp.int32),
                          jnp.zeros((max_slots,), bool),
                          jnp.zeros((max_slots,), jnp.int32))
+        if self._block is not None:
+            # a block's state where a row's last token and depth stand
+            # (_block_round says what each holds)
+            self._d_slots = (
+                *_idle_block_state(max_slots, self._block["block_length"]),
+                *self._d_slots[2:])
         self._d_eos = jnp.asarray(
             -1 if eos_token is None else eos_token, jnp.int32)
         # decode rounds dispatched and not yet fetched, oldest first:
@@ -603,6 +841,7 @@ class ServingEngine:
         self.round_seconds: list[float] = []
         self.completed: list[dict] = []
         self._occ_sum = 0  # sum of per-round active-slot counts
+        self._tokens_emitted = 0  # prefills' first tokens and rounds' tokens
         reg = obs.get_registry()
         self._h_ttft = reg.histogram(
             "serve_ttft_seconds", "submit -> first token",
@@ -654,6 +893,12 @@ class ServingEngine:
                 f"adapter {adapter} requested but the engine has no "
                 f"LoRA bank (pass lora_bank= to ServingEngine)")
         spec = kw.get("decode")
+        if self._block is not None and getattr(spec, "branches", 1) > 1:
+            raise ValueError(
+                f"n > 1 branches: {type(self.model).__name__} decodes by "
+                f"blocks, and a prefill that yields no logits has none "
+                f"to fan into branches (each branch would need its own "
+                f"first block)")
         if spec is not None \
                 and getattr(spec, "branches", 1) > self.max_slots:
             raise ValueError(
@@ -699,7 +944,8 @@ class ServingEngine:
                 return changed
             with loop.phase("dispatch") as dec:
                 host_tok, dt = self._decode_round()
-                dec.set(dispatch_us=dec.split("fetch", self._t_fetch))
+                dec.set(dispatch_us=dec.split("fetch", self._t_fetch),
+                        **self._span_block)
             with loop.phase("round_host") as host_span:
                 self.round_seconds.append(dt)
                 self._h_tok.observe(dt)
@@ -715,7 +961,6 @@ class ServingEngine:
                         if s is not None and s.first_round <= fetched]
                 occ = len(live)
                 self._g_occ.set(occ)
-                self._c_tokens.inc(occ)
                 self._occ_sum += occ
                 flight.record("serve", "decode_round", step=sched.round,
                               note=f"occ={occ}/{self.max_slots}")
@@ -747,7 +992,8 @@ class ServingEngine:
                         [s.req.tenant for _, s in live
                          if s.req.tenant != audit.SHADOW_TENANT],
                         self.flops_per_token())
-                retired = self._collect(host_tok, live)
+                retired, emitted = self._collect(host_tok, live)
+                self._count_tokens(emitted)
                 if sched.round % _COUNTER_ROUNDS == 0:
                     self.publish_device_counters()
                     loop.publish()
@@ -755,10 +1001,10 @@ class ServingEngine:
             wait, self._first_token_wait = self._first_token_wait, 0.0
             if wait:
                 cpu, self._admit_cpu = self._admit_cpu, 0.0
-                loop.lap(sched.round, occ=occ, first_token_wait_s=wait,
-                         admit_cpu_s=cpu)
+                loop.lap(sched.round, occ=occ, emitted=emitted,
+                         first_token_wait_s=wait, admit_cpu_s=cpu)
             else:
-                loop.lap(sched.round, occ=occ)
+                loop.lap(sched.round, occ=occ, emitted=emitted)
             rnd.set(occ=self.active_slots)
             return True
 
@@ -837,6 +1083,11 @@ class ServingEngine:
         engine's own state is not touched, so a replica may be warmed
         while its driver loop idles."""
         cache = _fresh_cache(self.model, self.max_slots, self.max_seq_len)
+        if self._block is not None:
+            # what a block decoder prefills of a prompt: its whole blocks
+            B = self._block["block_length"]
+            prompt_lens = [int(p) // B * B for p in prompt_lens
+                           if int(p) >= B]
         for plen in prompt_lens:
             pad = min(_bucket_len(int(plen)), self.max_seq_len)
             _, row, _ = _serve_prefill(
@@ -849,11 +1100,19 @@ class ServingEngine:
             cache = _insert_row(cache, row, 0, totals=self._counter_leaf,
                                 count=True)
         idle = jnp.zeros((self.max_slots,), jnp.int32)
-        state, _, _ = _write_rows(
-            idle, idle, jnp.zeros((self.max_slots,), bool), idle,
-            np.zeros((5, self.max_slots), np.int32),
-            None if self._lora is None else self._lora["adapter_ids"],
-            None, None)  # every argument, as _write_slots passes them
+        ids = None if self._lora is None else self._lora["adapter_ids"]
+        rows = np.zeros((5, self.max_slots), np.int32)
+        # every argument, as _write_slots passes them
+        if self._block is not None:
+            B = self._block["block_length"]
+            state, _, _ = _write_block_rows(
+                *_idle_block_state(self.max_slots, B),
+                jnp.zeros((self.max_slots,), bool), idle, rows,
+                np.zeros((self.max_slots, B), np.int32), ids, None, None)
+        else:
+            state, _, _ = _write_rows(
+                idle, idle, jnp.zeros((self.max_slots,), bool), idle,
+                rows, ids, None, None)
         nxt, *_ = _serve_step(self.model, self.params, cache, *state,
                               self._d_eos, self._lora, None)
         np.asarray(nxt)  # block until compiled + executed
@@ -871,9 +1130,21 @@ class ServingEngine:
         match = req.prefix_match
         m = match.tokens if match is not None else 0
         bs = self.scheduler.pool.block_size
-        suffix = np.asarray(req.prompt[m:], np.int32)
-        T = len(suffix)  # >= 1: PrefixCache caps matches at L - 1
-        t_pad = min(_bucket_len(T), self.max_seq_len - m)
+        blk = self._block
+        end = L  # the prompt positions the prefill fills
+        if blk is not None:
+            # a block decoder prefills the prompt's whole blocks (its
+            # tail opens the first block of the rounds) and resumes a
+            # restored prefix at a whole block: the rows of a block cut
+            # short were computed with what followed it there
+            B = blk["block_length"]
+            end = L // B * B
+            m = min(m // B * B, end)
+        suffix = np.asarray(req.prompt[m:end], np.int32)
+        # >= 1 (PrefixCache caps matches at L - 1) but for a block
+        # decoder whose whole blocks are all restored, or that has none
+        T = len(suffix)
+        t_pad = min(_bucket_len(T), self.max_seq_len - m) if T else 0
         # row-cache length must hold BOTH the restored blocks and the
         # suffix writes: a dynamic_update_slice whose start exceeds the
         # buffer silently clamps (corrupting neighbor rows), so pad is
@@ -886,8 +1157,10 @@ class ServingEngine:
                       tokens=T, padded=t_pad, cached=m, row_len=pad):
             tokens = np.zeros((1, t_pad), np.int32)
             tokens[0, :T] = suffix  # left-ALIGNED (pad tail is masked)
-            with jitwatch.dispatch_span("serve/fresh_cache"):
-                row_cache = _fresh_cache(self.model, 1, pad)
+            row_cache = None  # nothing to fill and nothing restored
+            if T or m:
+                with jitwatch.dispatch_span("serve/fresh_cache"):
+                    row_cache = _fresh_cache(self.model, 1, pad)
             if m > 0:
                 nb = len(match.restore_blocks)
                 table = np.zeros((self._blocks_per_seq,), np.int32)
@@ -911,51 +1184,53 @@ class ServingEngine:
             h["step"][slots] = req.decode_step0
             h["logprob"][slots] = 0.0
             rows = {k: v[slots] for k, v in h.items()} if sampled else None
+            firsts = [None] * len(slots)
             with jitwatch.dispatch_span(
                     "serve/prefill", request=req.request_id,
                     prompt_len=L, cached=m):
-                tok0, row_cache, drawn = _serve_prefill(
-                    self.model, self.params, row_cache,
-                    jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
-                    jnp.asarray([m], jnp.int32),
-                    self._row_lora(req.adapter), rows)
-                t_wait = time.monotonic()
-                firsts = [int(t) for t in np.asarray(tok0)]
-                self._first_token_wait += time.monotonic() - t_wait
-                if sampled:
-                    h["logprob"][slots] = np.asarray(drawn["logprob"])
+                if T:
+                    tok0, row_cache, drawn = _serve_prefill(
+                        self.model, self.params, row_cache,
+                        jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
+                        jnp.asarray([m], jnp.int32),
+                        self._row_lora(req.adapter), rows)
+                if blk is None:
+                    t_wait = time.monotonic()
+                    firsts = [int(t) for t in np.asarray(tok0)]
+                    self._first_token_wait += time.monotonic() - t_wait
+                    if sampled:
+                        h["logprob"][slots] = np.asarray(drawn["logprob"])
             if match is not None:
                 # restored rows are copied out; the COW tail pin can drop
                 self.prefix_cache.finish_restore(match)
                 req.prefix_match = None
-            now = time.monotonic()
-            req.t_first_token = now
-            # TTFT is charged from the logical request's ORIGINAL arrival
-            # (t_origin: set by the fleet on resubmitted legs), and only
-            # when THIS leg delivers the first token — a disagg decode leg
-            # or a post-first-token failover re-admission arrives with
-            # t_first_origin already set and must not observe again (the
-            # capacity sim's accounting, now pinned for the live fleet too)
-            if req.t_first_origin == 0.0:
-                ttft = now - (req.t_origin or req.t_submit)
-                self._h_ttft.observe(ttft)
-                self._h_ttft_tenant.observe(ttft, tenant=req.tenant)
+            req.t_prefilled = time.monotonic()
+            if blk is None:
+                self._first_token(req, req.t_prefilled)
+            else:
+                rounds = _block_rounds(L, req.max_new_tokens, B,
+                                       blk["denoising_steps"])
             sids = branch_seq_ids(req)
             totals = self._counter_leaf
             with jitwatch.dispatch_span("serve/insert_row",
                                         rows=len(slots)):
                 for k, slot in enumerate(slots):
                     # one program for every row of a model without totals
-                    self._cache = _insert_row(
-                        self._cache, row_cache, slot, totals=totals,
-                        count=k == 0 or totals is None)
-                    s = _Slot(req, firsts[k], depth=L,
-                              first_round=self._round_no + 1, cached=m,
-                              seq_id=sids[k], branch=k)
+                    if row_cache is not None:
+                        self._cache = _insert_row(
+                            self._cache, row_cache, slot, totals=totals,
+                            count=k == 0 or totals is None)
+                    # (a block decoder's row: no token yet, ``depth``
+                    # the same count of the host's, prompt + emitted - 1)
+                    s = _Slot(req, firsts[k], depth=L if blk is None
+                              else L - 1, first_round=self._round_no + 1,
+                              cached=m, seq_id=sids[k], branch=k,
+                              rounds=0 if blk is None else rounds)
                     self._slots[slot] = s
                     self._n_sampled += sampled
                     self._pending_logprob[slot] = float(h["logprob"][slot])
-                    self._c_tokens.inc()  # the prefill-produced first token
+                    # the prefill-produced first token
+                    self._count_tokens(s.emitted)
                     flight.record("serve", "admit", step=self.scheduler.round,
                                   note=f"{sids[k]} slot={slot} L={L} "
                                        f"cached={m}")
@@ -970,6 +1245,26 @@ class ServingEngine:
                 meter.on_prefill(req.request_id, req.tenant,
                                  new_tokens=T, cached_tokens=m,
                                  flops_per_token=self.flops_per_token())
+
+    def _count_tokens(self, n: int) -> None:
+        """``n`` tokens given: a prefill's first, a round's."""
+        self._c_tokens.inc(n)
+        self._tokens_emitted += n
+
+    def _first_token(self, req: Request, now: float) -> None:
+        """Stamp a request's first token (a block decoder's: its first
+        block's commit) and observe its TTFT. TTFT is charged from the
+        logical request's ORIGINAL arrival (t_origin: set by the fleet
+        on resubmitted legs), and only when THIS leg delivers the first
+        token — a disagg decode leg or a post-first-token failover
+        re-admission arrives with t_first_origin already set and must
+        not observe again (the capacity sim's accounting, now pinned
+        for the live fleet too)."""
+        req.t_first_token = now
+        if req.t_first_origin == 0.0:
+            ttft = now - (req.t_origin or req.t_submit)
+            self._h_ttft.observe(ttft)
+            self._h_ttft_tenant.observe(ttft, tenant=req.tenant)
 
     def _decode_round(self):
         """THE hot loop body (see module docstring for the lint
@@ -1013,17 +1308,56 @@ class ServingEngine:
         (an eos the host has not seen yet may still stop it: the round
         dispatched for it then computes nothing and is fetched like any
         other). A row admitted after a round's dispatch is not in it."""
+        if self._block is not None:
+            # a block decoder's row counts rounds, not tokens: blocks
+            # left times the steps a block takes plus its commit (at
+            # most: ``low_confidence_dynamic`` may take fewer)
+            return any(s is not None
+                       and self._round_no - (s.first_round - 1) < s.rounds
+                       for s in self._slots)
         fetched = self._round_no - len(self._flight)
         return any(
             s is not None and s.emitted + self._round_no
             - max(fetched, s.first_round - 1) < s.req.max_new_tokens
             for s in self._slots)
 
-    def _collect(self, host_tok: np.ndarray, live: list) -> int:
+    def _collect_blocks(self, host_out: np.ndarray, live: list) -> tuple:
+        """:meth:`_collect` for a block decoder: ``host_out`` (slots,
+        B + 1) holds, for a row that committed, the tokens the commit
+        handed out and, last, how many (none for a row that took a
+        denoising step)."""
+        if chaos.on_flip_token(self.replica_index, self.scheduler.round):
+            raise RuntimeError(
+                f"chaos flip@: {type(self.model).__name__} decodes by "
+                f"blocks; the drill rewrites one fetched token on the "
+                f"device, and a block's tokens there are not the fetched "
+                f"ones (a flip would have to rewrite a committed row's "
+                f"keys and values)")
+        emitted = 0
+        for i, s in live:
+            n = int(host_out[i, -1])
+            if not n:
+                continue
+            if not s.emitted:
+                self._first_token(s.req, time.monotonic())
+            s.tokens.extend(int(t) for t in host_out[i, :n])
+            s.emitted += n
+            s.depth += n
+            emitted += n
+            self.scheduler.pool.extend(s.seq_id, s.depth)
+            if s.req.stream is not None and \
+                    len(s.tokens) - s.streamed >= self.stream_chunk_tokens:
+                self._emit_chunk(s)
+        return self._retire_finished(), emitted
+
+    def _collect(self, host_tok: np.ndarray, live: list) -> tuple:
         """Fold one round's tokens into the host mirrors of its rows
         (``live``: slot and mirror of every row the round held) and
         retire rows that hit eos or budget, which the device stopped in
-        that round. Returns retired count."""
+        that round. Returns the retired count and the tokens the round
+        gave."""
+        if self._block is not None:
+            return self._collect_blocks(host_tok, live)
         # chaos flip@replica=K: perturb ONE fetched token (first active
         # slot) this round — a silent corruption: the wrong id flows
         # into the slot mirror, the JSONL record, and the fingerprint
@@ -1061,12 +1395,12 @@ class ServingEngine:
                 self._h_sampling["logprob"][flipped] = np.asarray(
                     self._d_sampling["logprob"])[flipped]
             self._write_slots([flipped])
-        return retired
+        return retired, len(live)
 
     def _done(self, s: _Slot) -> bool:
         if s.emitted >= s.req.max_new_tokens:
             return True
-        return self.eos_token is not None and \
+        return self.eos_token is not None and bool(s.tokens) and \
             s.tokens[-1] == self.eos_token
 
     def _retire_finished(self) -> int:
@@ -1224,6 +1558,13 @@ class ServingEngine:
         are not rewritten. Returns blocks written; 0 when this engine
         has no prefix cache or the pool had no headroom to adopt."""
         self._refuse_blocks("ingest_blocks")
+        if self._block is not None \
+                and len(tokens) % self._block["block_length"]:
+            raise ValueError(
+                f"ingest_blocks: {len(tokens)} tokens end inside a block "
+                f"of {self._block['block_length']}; the rows of a block "
+                f"under way were written by a denoising step, not by its "
+                f"commit, and cannot be taken for the sequence's")
         if self.prefix_cache is None or self._store is None:
             return 0
         plan = self.prefix_cache.ingest(tokens, adapter)
@@ -1256,8 +1597,10 @@ class ServingEngine:
         # request.
         waterfall = dict(
             queued_s=round(max(req.t_admit - req.t_submit, 0.0), 6),
-            prefill_s=round(max(req.t_first_token - req.t_admit, 0.0),
-                            6),
+            # (a block decoder's first token is its first commit, some
+            # rounds after its prefill)
+            prefill_s=round(max((req.t_prefilled or req.t_first_token)
+                                - req.t_admit, 0.0), 6),
             decode_s=round(max(decode, 0.0), 6),
             round_submitted=req.round_submitted,
             round_admitted=req.round_admitted,
@@ -1358,19 +1701,36 @@ class ServingEngine:
         sampling rows while a sampled row is live."""
         rows = np.zeros((5, self.max_slots), np.int32)
         rows[0, slots] = 1
+        blk = self._block
+        if blk is not None:
+            B = blk["block_length"]
+            tails = np.zeros((self.max_slots, B), np.int32)
         for i in slots:
             s = self._slots[i]
-            if s is not None:
-                rows[1:, i] = (s.tokens[-1], s.depth,
-                               s.req.max_new_tokens - s.emitted,
+            if s is None:
+                continue
+            if blk is not None:
+                # the row's first block: the prompt's tail, then masks;
+                # its RNG step counts the row's rounds
+                L = len(s.req.prompt)
+                tails[i, :L % B] = s.req.prompt[L // B * B:]
+                rows[1:, i] = (L % B, L // B * B, s.req.max_new_tokens,
                                s.req.adapter)
-                # a row's RNG step is step0 + emitted: recomputable
-                # here by design, so a flip drill's write mid-stream
-                # cannot skew the device's counter
-                self._h_sampling["step"][i] = s.step0 + s.emitted
+                self._h_sampling["step"][i] = s.step0
+                continue
+            rows[1:, i] = (s.tokens[-1], s.depth,
+                           s.req.max_new_tokens - s.emitted,
+                           s.req.adapter)
+            # a row's RNG step is step0 + emitted: recomputable
+            # here by design, so a flip drill's write mid-stream
+            # cannot skew the device's counter
+            self._h_sampling["step"][i] = s.step0 + s.emitted
         sampled = self._n_sampled > 0
-        self._d_slots, ids, drawn = _write_rows(
-            *self._d_slots, rows,
+        # (positional, as warmup() passes them: a keyword is another
+        # program to jax.jit)
+        self._d_slots, ids, drawn = (_write_rows if blk is None
+                                     else _write_block_rows)(
+            *self._d_slots, rows, *(() if blk is None else (tails,)),
             None if self._lora is None else self._lora["adapter_ids"],
             self._d_sampling if sampled else None,
             self._h_sampling if sampled else None)
@@ -1415,6 +1775,10 @@ class ServingEngine:
             rounds_overlapped=self._overlapped,
             requests_done=len(self.completed),
             tokens_out=int(sum(r["new_tokens"] for r in self.completed)),
+            # every token the engine gave (a prefill's first, a round's
+            # one a row or a block decoder's up to a block a row), the
+            # unfinished requests' too
+            tokens_emitted=self._tokens_emitted,
             occupancy=occ,
             kv_util=self.scheduler.pool.utilization(),
             queue_depth=self.scheduler.queue_depth,

@@ -106,6 +106,10 @@ class Request:
     t_admit: float = 0.0
     t_first_token: float = 0.0
     t_done: float = 0.0
+    # when the prefill had filled the request's rows: its first token's
+    # time but for a block decoder, whose first token is its first
+    # block's commit, some rounds later
+    t_prefilled: float = 0.0
     # logical-request origin times (fleet-level): a resubmitted leg —
     # failover re-admission or a disagg decode-leg rewrite — carries
     # the ORIGINAL arrival in t_origin (0.0: this leg is the arrival)

@@ -489,3 +489,60 @@ def test_serve_prefill_keeps_scores_in_the_core(topo, monkeypatch,
     assert text.count(KERNEL) == kernels
     scores = set(re.findall(rf"f32\[[\d,]*,(?:{T}|512),{S}\]", text))
     assert bool(scores) == (kernels == 0), scores
+
+
+@pytest.mark.parametrize("program", ["step", 64, 1024])
+def test_sdar_serve_programs_fit_the_chip_at_the_cells_size(topo, program):
+    """``sdar_30b_a3b_seq2`` as its cell runs it (7 layers, every one of the
+    128 experts of a layer, the whole vocabulary, bf16; 64 slots x 2,048
+    positions): the block round and the smallest and largest prefill
+    buckets compile for the described chip and fit its 16 GB beside
+    what they are given, the round's cache donated. The held experts'
+    loop is one loop a layer in the program (``rolled``): unrolled, 128
+    a layer, this compile takes 110 s where it takes 6."""
+    from pytorch_distributed_nn_tpu.config import ModelConfig
+    from pytorch_distributed_nn_tpu.inference.generate import init_cache
+    from pytorch_distributed_nn_tpu.models import get_model
+    from pytorch_distributed_nn_tpu.serve import engine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    model = get_model(ModelConfig(name="sdar_30b_a3b_seq2", dtype="bfloat16",
+                                  extra=dict(num_layers=7)))
+    slots, rows, B = 64, 2048, model.block_decoding()["block_length"]
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def on(tree):
+        return jax.tree.map(lambda a: arg(a.shape, a.dtype), tree)
+
+    params = on(jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+        train=False))["params"])
+    if program == "step":
+        cache = on(jax.eval_shape(lambda: init_cache(model, slots, rows)))
+        compiled = jax.jit(
+            lambda *a: engine._serve_step.__wrapped__(*a),
+            static_argnums=(0,), donate_argnums=(2,)).lower(
+            model, params, cache, arg((slots, B + 1)),
+            dict(depth=arg((slots,)), masked=arg((slots, B), jnp.bool_),
+                 step=arg((slots,)), skip=arg((slots,))),
+            arg((slots,), jnp.bool_), arg((slots,)), arg(())).compile()
+        text = compiled.as_text()
+        # one loop over the experts a layer and its loop over blocks of
+        # tokens, not 128 of them; the cache written by scatters, no loop
+        # over the slots
+        assert text.count(" while(") <= 2 * 7
+    else:
+        cache = on(jax.eval_shape(lambda: init_cache(model, 1, program)))
+        compiled = jax.jit(
+            lambda *a: engine._serve_prefill.__wrapped__(*a),
+            static_argnums=(0,), donate_argnums=(2,)).lower(
+            model, params, cache, arg((1, program)), arg((1,)),
+            arg((1,))).compile()
+    m = compiled.memory_analysis()
+    held = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    assert held < 16.0e9, held
+    if program == "step":
+        assert m.alias_size_in_bytes > 1.8e9   # the cache, in place

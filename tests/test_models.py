@@ -26,6 +26,10 @@ _AXK1 = dict(num_layers=3, d_model=32, num_heads=2, mlp_dim=64,
              expert_mlp_dim=16, num_experts=8, moe_topk=2, n_group=4,
              topk_group=2, rope_original_positions=8)
 
+_SDAR = dict(num_layers=2, d_model=32, num_heads=4, num_kv_heads=2,
+             head_dim=8, vocab_size=101, expert_mlp_dim=16, num_experts=8,
+             moe_topk=2, mask_token_id=100)
+
 TINY = {
     "mlp": dict(),
     "lenet": dict(),
@@ -49,6 +53,8 @@ TINY = {
     "jamba": dict(num_layers=4, d_model=32, num_heads=4, num_kv_heads=1,
                   mlp_dim=64, vocab_size=101, attn_layer_period=2,
                   attn_layer_offset=1, mamba_dt_rank=4),
+    "sdar_moe": dict(_SDAR),
+    "sdar_30b_a3b_seq2": dict(_SDAR),   # the cell's steps: 2, sequential
 }
 
 IMAGE_INPUT = {
