@@ -142,10 +142,7 @@ class SdarMoeBlock(nn.Module):
                 num_experts=self.num_experts, mlp_dim=self.expert_mlp_dim,
                 k=self.moe_topk, scoring="softmax", renormalize=True,
                 ep_size=self.ep_size, ep_rank=self.ep_rank,
-                # every expert of a layer is held: one loop over them in
-                # the program, not 128 (PERF.md sec. 6 has both forms)
-                rolled=True, dtype=self.dtype,
-                param_dtype=self.param_dtype,
+                dtype=self.dtype, param_dtype=self.param_dtype,
                 name="moe")(norm("post_attn_norm")(h), token_mask=real)
         out = h + f
         if not decode or self.is_initializing():

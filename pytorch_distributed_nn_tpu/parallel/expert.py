@@ -39,7 +39,9 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from pytorch_distributed_nn_tpu.obs.registry import get_registry
 from pytorch_distributed_nn_tpu.ops import collectives as cc
+from pytorch_distributed_nn_tpu.ops.pallas import grouped_experts as kernel
 from pytorch_distributed_nn_tpu.runtime.mesh import AXIS_EXPERT
 
 
@@ -196,6 +198,97 @@ class MoEMLP(nn.Module):
         return out.reshape(B, S, d)
 
 
+# held experts from which a layer's pairs go through ``grouped_experts``
+GROUPED_FROM = 32
+
+
+def experts_grouped(held: int) -> bool:
+    """Whether a layer that holds ``held`` experts computes its pairs
+    sorted by expert in one routine (:mod:`ops.pallas.grouped_experts`)
+    or in a loop an expert unrolled into the program, each reading its
+    static column block. A rule on ``held`` alone.
+
+    Measured at SDAR's widths (d 2048, ff 768, 8 picks of 128, a round
+    of 256 positions, a rank's share held; ms a layer on a v5e, the
+    unrolled loop against the kernel, and the seconds the first call
+    took to compile; PERF.md sec. 6, PR 43)::
+
+        held    16      32      64      128
+        loop    0.556   1.085   2.196   4.300    3.9 - 28.9 s
+        kernel  0.414   0.647   1.093   1.927    1.1 - 2.5 s
+
+    The loop costs ~34 us an expert whatever it holds and its text
+    grows with ``held`` (896 ``while``s in SDAR's seven-layer program:
+    110 s to compile where the grouped form takes 6, PR 42); the kernel
+    26 us at 16 experts and 15 at 128, so it is ahead on every line,
+    the first too. The boundary stands at 32 all the same: the layers
+    that hold 12 to 16 are LongCat's, K-EXAONE's and A.X-K1's, at other
+    widths (LongCat's expert is 75 MB where SDAR's is 9.4: blocks that
+    must go in chunks), in programs their cells were measured with and
+    ``tests/data/serve_program_digests.json`` pins. Moving them is a
+    claim of its own, judged on those three cells (ROADMAP S12 (c))."""
+    return held >= GROUPED_FROM
+
+
+def _count_execution(execution: str):
+    """One more expert layer a program was traced with."""
+    get_registry().counter(
+        "held_experts_calls_total", "expert layers a program was traced "
+        "with, by execution", ("execution",)).inc(execution=execution)
+
+
+def _unrolled_experts(a, out, member, w_held, counts, w_gate, w_up, w_down,
+                      *, token_block: int, dtype):
+    """``out`` (N, d) float32 with every held expert's term added: a
+    loop a held expert in the program's text, each over the blocks of
+    ``token_block`` tokens that picked it, its column block a static
+    slice."""
+    (N, d), held, ff = a.shape, counts.shape[0], w_down.shape[0]
+    tb = min(token_block, N)
+    n_pad = -(-N // tb) * tb
+    # column j: the tokens that picked expert j first, in token order
+    order = jnp.argsort(~member, axis=0, stable=True).astype(jnp.int32)
+    order = jnp.pad(order, ((0, n_pad - N), (0, 0)))
+
+    def cols(w, j, width):
+        return w[:, j * width:(j + 1) * width]
+
+    def run_expert(j, out):
+        def one_block(b, out):
+            rows = jax.lax.dynamic_slice(order[:, j], (b * tb,), (tb,))
+            live = b * tb + jnp.arange(tb) < counts[j]
+            xb = a[rows]
+            wg = cols(w_gate, j, ff).astype(dtype)
+            wu = cols(w_up, j, ff).astype(dtype)
+            wd = cols(w_down, j, d).astype(dtype)
+            h = nn.silu(xb @ wg) * (xb @ wu)
+            yb = jnp.dot(h, wd, preferred_element_type=jnp.float32)
+            wt = jnp.where(live, w_held[rows, j], 0.0)
+            return out.at[rows].add(yb * wt[:, None])
+
+        blocks = (counts[j] + tb - 1) // tb
+        return jax.lax.fori_loop(0, blocks, one_block, out)
+
+    for j in range(held):
+        out = run_expert(j, out)
+    return out
+
+
+def _grouped_experts(a, out, expert, here, w, counts, w_gate, w_up, w_down,
+                     *, num_experts: int, dtype):
+    """``out`` with the picks computed here added, sorted by expert in
+    one routine. ``expert`` (N, k) is each pick's held expert where
+    ``here`` (N, k)."""
+    (N, d), k = a.shape, expert.shape[1]
+    held, ff = counts.shape[0], w_down.shape[0]
+    a = a.astype(dtype)
+    _count_execution(kernel.execution(N, k, num_experts, d, ff, a.dtype,
+                                      w_gate.dtype)[0])
+    return out + kernel.grouped_experts(
+        a, jnp.where(here, expert, held).astype(jnp.int32), w, counts,
+        w_gate, w_up, w_down, num_experts=num_experts)
+
+
 class HeldExpertsMoE(nn.Module):
     """Dropless top-k MoE as ONE rank of an expert-parallel deployment.
 
@@ -234,19 +327,31 @@ class HeldExpertsMoE(nn.Module):
     Nothing is dropped and no row's result depends on its batch
     neighbours, so the layer serves through a decode cache (``MoEMLP``'s
     capacity routing cannot). Cost follows the token-expert pairs routed
-    here, not tokens x experts held: for each held expert the tokens that
-    picked it are gathered in blocks of ``token_block`` rows and a loop
-    whose trip count is that expert's number of blocks runs the three
-    matrix products, so an expert no token picked is not read. (That
-    loop has a data-dependent length: forward only.)
+    here, not tokens x experts held, in one of two forms that
+    :func:`experts_grouped` picks by ``held`` alone. A layer that holds
+    few experts (12 to 16 of LongCat's, K-EXAONE's, A.X-K1's): for each
+    held expert the tokens that picked it are gathered in blocks of
+    ``token_block`` rows and a loop whose trip count is that expert's
+    number of blocks runs the three matrix products, a loop an expert
+    in the program's text. A layer that holds many (SDAR's 128): the
+    pairs are sorted by expert, each expert's rows padded to whole
+    tiles, and one routine walks the live tiles
+    (:func:`ops.pallas.grouped_experts.grouped_experts`: a Pallas kernel
+    on a TPU, a ``fori_loop`` elsewhere). In both an expert no token
+    picked is not read, and the trip count is the data's: forward only.
 
     Expert kernels are laid side by side with the contracted axis
     first, ``(d, held * ff)`` and ``(ff, held * d)``: expert j's matrix
-    is the static, tile-aligned column block ``[j * ff, (j + 1) * ff)``,
-    which XLA reads in place as the product's operand (stacked as
-    ``(d, held, ff)`` the TPU's tiled layout interleaves the experts and
-    every use copies the 25 MB matrix out first), and an initialiser that
-    scales by ``shape[0]`` scales by the fan-in.
+    is the tile-aligned column block ``[j * ff, (j + 1) * ff)``. Both
+    forms read it in place: the unrolled loop as a static slice that XLA
+    takes as the product's operand, the kernel through its index maps,
+    which hand a grid step block ``j`` of the columns for the expert
+    ``j`` the step's tile belongs to. (Stacked as ``(d, held, ff)`` the
+    TPU's tiled layout interleaves the experts and every use copies the
+    25 MB matrix out first; a ``dynamic_slice`` of the side-by-side
+    form inside a rolled loop copied each expert's 9.4 MB too, PERF.md
+    sec. 6, PR 43.) An initialiser that scales by ``shape[0]`` scales
+    by the fan-in.
 
     ``token_mask`` (B, T) bool marks the real tokens (not the padding of
     a bucketed prefill, not a retired decode row): the others reach no
@@ -268,14 +373,7 @@ class HeldExpertsMoE(nn.Module):
     topk_group: int = 1           # groups a token may pick from
     ep_size: int = 1
     ep_rank: int = 0
-    token_block: int = 128
-    # one loop over the held experts in the program, expert j's column
-    # block taken by ``dynamic_slice``, in place of a loop a held expert
-    # unrolled into it: what a layer that holds many experts (128) costs
-    # to compile falls with the text (PERF.md sec. 6 has both forms'
-    # compile and round times). Off: the program of the layers that hold
-    # 12 or 16 is as it was.
-    rolled: bool = False
+    token_block: int = 128     # rows a block of the unrolled loop
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -353,42 +451,15 @@ class HeldExpertsMoE(nn.Module):
         w_held = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)
         member = hit.any(axis=1)                                  # (N, held)
         counts = member.sum(axis=0).astype(jnp.int32)             # (held,)
-        tb = min(self.token_block, N)
-        n_pad = -(-N // tb) * tb
-        # column j: the tokens that picked expert j first, in token order
-        order = jnp.argsort(~member, axis=0, stable=True).astype(jnp.int32)
-        order = jnp.pad(order, ((0, n_pad - N), (0, 0)))
-
-        def cols(w, j, width):
-            """Expert j's column block: a static slice for a Python j,
-            a dynamic one inside the rolled loop."""
-            if isinstance(j, int):
-                return w[:, j * width:(j + 1) * width]
-            return jax.lax.dynamic_slice_in_dim(w, j * width, width, axis=1)
-
-        def run_expert(j, out):
-            def one_block(b, out):
-                mine = order[:, j] if isinstance(j, int) else \
-                    jax.lax.dynamic_index_in_dim(order, j, 1, keepdims=False)
-                rows = jax.lax.dynamic_slice(mine, (b * tb,), (tb,))
-                live = b * tb + jnp.arange(tb) < counts[j]
-                xb = a[rows]
-                wg = cols(w_gate, j, ff).astype(self.dtype)
-                wu = cols(w_up, j, ff).astype(self.dtype)
-                wd = cols(w_down, j, d).astype(self.dtype)
-                h = nn.silu(xb @ wg) * (xb @ wu)
-                yb = jnp.dot(h, wd, preferred_element_type=jnp.float32)
-                wt = jnp.where(live, w_held[rows, j], 0.0)
-                return out.at[rows].add(yb * wt[:, None])
-
-            blocks = (counts[j] + tb - 1) // tb
-            return jax.lax.fori_loop(0, blocks, one_block, out)
-
-        if self.rolled:
-            out = jax.lax.fori_loop(0, held, run_expert, out)
+        if experts_grouped(held):
+            out = _grouped_experts(
+                a, out, idx - first, hit.any(axis=2), w, counts, w_gate,
+                w_up, w_down, num_experts=self.num_experts, dtype=self.dtype)
         else:
-            for j in range(held):
-                out = run_expert(j, out)
+            _count_execution("unrolled_loop")
+            out = _unrolled_experts(
+                a, out, member, w_held, counts, w_gate, w_up, w_down,
+                token_block=self.token_block, dtype=self.dtype)
 
         n_real = real.sum()
         stats = jnp.stack([

@@ -492,19 +492,23 @@ def test_serve_prefill_keeps_scores_in_the_core(topo, monkeypatch,
 
 
 @pytest.mark.parametrize("program", ["step", 64, 1024])
-def test_sdar_serve_programs_fit_the_chip_at_the_cells_size(topo, program):
+def test_sdar_serve_programs_fit_the_chip_at_the_cells_size(
+        topo, monkeypatch, program):
     """``sdar_30b_a3b_seq2`` as its cell runs it (7 layers, every one of the
     128 experts of a layer, the whole vocabulary, bf16; 64 slots x 2,048
     positions): the block round and the smallest and largest prefill
     buckets compile for the described chip and fit its 16 GB beside
-    what they are given, the round's cache donated. The held experts'
-    loop is one loop a layer in the program (``rolled``): unrolled, 128
-    a layer, this compile takes 110 s where it takes 6."""
+    what they are given, the round's cache donated. A layer's held
+    experts are one ``grouped_experts`` call (``ops/pallas``: the
+    dispatcher asks for the backend, and this test answers for the
+    chip) and no loop: a loop an expert unrolled into the text, 128 a
+    layer, this compile takes 110 s where it takes 6."""
     from pytorch_distributed_nn_tpu.config import ModelConfig
     from pytorch_distributed_nn_tpu.inference.generate import init_cache
     from pytorch_distributed_nn_tpu.models import get_model
     from pytorch_distributed_nn_tpu.serve import engine
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     one_chip = SingleDeviceSharding(topo.devices[0])
     model = get_model(ModelConfig(name="sdar_30b_a3b_seq2", dtype="bfloat16",
                                   extra=dict(num_layers=7)))
@@ -528,11 +532,11 @@ def test_sdar_serve_programs_fit_the_chip_at_the_cells_size(topo, program):
             dict(depth=arg((slots,)), masked=arg((slots, B), jnp.bool_),
                  step=arg((slots,)), skip=arg((slots,))),
             arg((slots,), jnp.bool_), arg((slots,)), arg(())).compile()
+        # one kernel a layer for its experts and no loop around it; the
+        # cache written by scatters, no loop over the slots
         text = compiled.as_text()
-        # one loop over the experts a layer and its loop over blocks of
-        # tokens, not 128 of them; the cache written by scatters, no loop
-        # over the slots
-        assert text.count(" while(") <= 2 * 7
+        assert text.count(KERNEL) == 7
+        assert text.count(" while(") == 0
     else:
         cache = on(jax.eval_shape(lambda: init_cache(model, 1, program)))
         compiled = jax.jit(
@@ -540,6 +544,12 @@ def test_sdar_serve_programs_fit_the_chip_at_the_cells_size(topo, program):
             static_argnums=(0,), donate_argnums=(2,)).lower(
             model, params, cache, arg((1, program)), arg((1,)),
             arg((1,))).compile()
+        # a block decoder's prefill fills rows and yields no token: the
+        # last layer's experts feed nothing and are not in the program;
+        # a blockwise attention a layer where the bucket's scores would
+        # be large
+        assert compiled.as_text().count(KERNEL) == (13 if program == 1024
+                                                    else 6)
     m = compiled.memory_analysis()
     held = m.argument_size_in_bytes + m.temp_size_in_bytes \
         + m.output_size_in_bytes - m.alias_size_in_bytes
