@@ -25,8 +25,10 @@ logits predicts position ``p`` itself, and a block of ``B`` positions
 is written by *denoising*: its unknown positions are fed the embedding
 of ``mask_token_id``, a forward of the block against the committed rows
 before it proposes a token for each, some of them are taken, and the
-forward repeats until none is masked; one more forward of the finished
-block, the *commit*, leaves its keys and values in the cache. What a
+forward repeats until none is masked. The finished block's keys and
+values are those of a forward of its final tokens, the *commit*, which
+the engine makes no forward of its own: the forward that takes the next
+block's first step feeds the finished block before it. What a
 served model of this kind tells its engine is :meth:`SdarMoe
 .block_decoding`; the round that does it is the engine's
 (``serve/engine.py``, ``_block_round``). The architecture, not the
@@ -54,18 +56,20 @@ from pytorch_distributed_nn_tpu.models.llama import RMSNorm
 from pytorch_distributed_nn_tpu.models.longcat_flash import KINDS
 from pytorch_distributed_nn_tpu.nn.attention import (
     MultiHeadAttention,
-    prefill_in_tiles,
+    cache_rows_read,
 )
 from pytorch_distributed_nn_tpu.nn.dtypes import get_policy
-from pytorch_distributed_nn_tpu.ops.pallas.prefix_attention import rows_read
 from pytorch_distributed_nn_tpu.parallel.expert import HeldExpertsMoE
 
 # what the engine's round counts, over its live rows: forwards of a
-# block (a row a round), those that were commits, masked positions that
-# took a token, tokens handed to a request
+# block (a row a round), those that unmasked nothing and only committed
+# (none since a commit rides the next block's first step), masked
+# positions that took a token, tokens handed to a request, forwards that
+# wrote a finished block's rows beside a step of the next
 BLOCK_COUNTERS = ("block_forwards_total", "block_commits_total",
                   "block_positions_unmasked_total",
-                  "block_tokens_emitted_total")
+                  "block_tokens_emitted_total",
+                  "block_commits_fused_total")
 REMASKING = ("sequential", "low_confidence_static",
              "low_confidence_dynamic")
 
@@ -147,15 +151,11 @@ class SdarMoeBlock(nn.Module):
         out = h + f
         if not decode or self.is_initializing():
             return out, None
-        rows = attn.get_variable("cache", "cached_key").shape[1]
         B = max(self.block_length, 1)
         seen = positions // B * B + B - 1
-        # a blockwise prefill reads the key tiles its queries' tiles
-        # visit; every other call the whole row for each real query
         return out, jnp.concatenate([c[jnp.asarray([0, 2, 3])], jnp.stack([
             jnp.where(real, seen + 1, 0).sum(),
-            rows_read(seen, real, rows) if prefill_in_tiles(T, rows)
-            else real.sum() * rows,
+            cache_rows_read(attn, T, seen, real),
         ]).astype(jnp.uint32)])
 
 
@@ -222,7 +222,7 @@ class SdarMoe(nn.Module):
     @staticmethod
     def add_block_counts(cache, counts):
         """``cache`` with the engine's :data:`BLOCK_COUNTERS` of one
-        round, ``counts`` (4,), added to the leaf's last entries."""
+        round, ``counts`` (one each), added to the leaf's last entries."""
         leaf = cache["device_counters"]
         n = len(BLOCK_COUNTERS)
         return {**cache, "device_counters": leaf.at[-n:].add(
@@ -232,14 +232,19 @@ class SdarMoe(nn.Module):
     def __call__(self, tokens, *, train: bool = False,
                  decode: bool = False, last_only: bool = False,
                  return_hidden: bool = False, cache_positions=None,
-                 token_mask=None, block_round: bool = False):
+                 token_mask=None, block_round: bool = False,
+                 head_rows=None):
         """As :class:`models.llama.Llama` (``last_only``,
         ``return_hidden``, ``cache_positions``), but row ``t`` of the
         result scores position ``t`` itself. ``token_mask`` (B, T) bool
         marks the real tokens, a left-aligned prefix of each row: the
         rest reach no expert and no counter. ``block_round`` says the
         call is the engine's round over every slot (counted as kind
-        ``decode``; any other cached call is a ``prefill``)."""
+        ``decode``; any other cached call is a ``prefill``), and
+        ``head_rows`` (B, K) int32 which of a sequence's T rows reach
+        the final norm and the head (all of them by default): a round
+        scores a row's open block and not the finished one it feeds
+        before it."""
         del train   # no dropout, no auxiliary loss: the forward is one
         B, T = tokens.shape
         x = TokenTable(self.vocab_size, self.d_model,
@@ -282,6 +287,8 @@ class SdarMoe(nn.Module):
                     jnp.concatenate(counts))
         if last_only:
             x = x[:, -1:]
+        if head_rows is not None:
+            x = jnp.take_along_axis(x, head_rows[..., None], axis=1)
         x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
                     param_dtype=self.param_dtype, name="final_norm")(x)
         if return_hidden:

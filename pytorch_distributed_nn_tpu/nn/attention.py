@@ -21,6 +21,10 @@ from flax import linen as nn
 from pytorch_distributed_nn_tpu.nn.quantized import Int8DenseGeneral
 from pytorch_distributed_nn_tpu.ops.pallas.prefix_attention import (
     prefix_attention,
+    round_attention,
+    round_key_block,
+    round_rows_read,
+    rows_read,
     seen_from,
 )
 
@@ -361,6 +365,59 @@ def _prefill_attention(q, k, v, positions, lengths=None):
         scale=q.shape[-1] ** -0.5))
 
 
+def _round_attention(q, k, v, seen, lengths, dtype):
+    """A block decoder's round against its cache: q (B, T, H, D), a
+    block or two of fed positions a row; k, v (B, S, Hkv * D), the flat
+    rows such a cache holds (``see_block``), the fed positions written;
+    ``seen`` (B, T) the last key each query sees; ``lengths`` (B,) how
+    many of a row's queries are real. On a TPU in bf16
+    (:func:`round_key_block`) one kernel a layer, whose grid step is a
+    row and a key block: the G query heads of a K/V head times the T
+    positions are that head's query rows, a row's key blocks past what
+    it sees are not read, a query that is not real sees nothing and
+    gets zeros. Anywhere else the dense routine over the whole row,
+    as every other decode round. Returns (B, T, H, D)."""
+    B, T, H, D = q.shape
+    S, kv_heads = k.shape[1], k.shape[2] // D
+    G = H // kv_heads
+    block_k = round_key_block(T * G, S, D, k.dtype)
+    if not block_k:
+        heads = lambda x: x.reshape(B, S, kv_heads, D)  # noqa: E731
+        return _cache_attention(
+            q, heads(k), heads(v),
+            jnp.arange(S)[None, None, :] <= seen[:, :, None], dtype)
+    if lengths is not None:
+        seen = seen_from(seen, jnp.arange(T)[None] < lengths[:, None])
+    grouped = lambda x: x.reshape(  # noqa: E731
+        B, x.shape[1], x.shape[2] // G, G, D).transpose(0, 2, 1, 3, 4)
+    out = round_attention(
+        grouped(q).reshape(B, kv_heads, T * G, D), k, v,
+        jnp.repeat(seen, G, axis=1), scale=D ** -0.5, block_k=block_k)
+    return out.reshape(B, kv_heads, T, G, D).transpose(
+        0, 2, 1, 3, 4).reshape(B, T, H, D).astype(dtype)
+
+
+def cache_rows_read(attn, T: int, seen, real):
+    """Key rows a cached call of ``attn`` (a bound
+    :class:`MultiHeadAttention` over rows by position) that feeds T
+    tokens a row reads for its ``real`` (B, T) queries, summed, each
+    seeing up to ``seen`` (B, T): the key tiles its query tiles visit
+    (a blockwise prefill), the key blocks up to the last position a
+    row's real queries see (:func:`_round_attention`'s kernel), or the
+    row's whole length a query (the dense routine)."""
+    key = attn.get_variable("cache", "cached_key")
+    S = key.shape[1]
+    if prefill_in_tiles(T, S):
+        return rows_read(seen, real, S)
+    if attn.is_block_round(T):
+        block_k = round_key_block(
+            T * attn.num_heads // (attn.num_kv_heads or attn.num_heads),
+            S, attn.head_dim, key.dtype)
+        if block_k:
+            return round_rows_read(seen, real, S, block_k)
+    return real.sum() * S
+
+
 class MultiHeadAttention(nn.Module):
     num_heads: int
     head_dim: int
@@ -409,9 +466,19 @@ class MultiHeadAttention(nn.Module):
     # only): a query at position p sees every key of its own block of
     # ``see_block`` positions and of every block before it, up to
     # ``p // see_block * see_block + see_block - 1``; rotation keeps p.
-    # 0 or 1: causal. T == see_block fed tokens a row are a decode round
-    # over every slot, written by one scatter a leaf.
+    # 0 or 1: causal. A block or two of fed tokens a row (T of
+    # ``see_block`` or twice that) are a decode round over every slot,
+    # written by one scatter a leaf and attended by
+    # :func:`_round_attention`; for its kernel's sake the cache of such
+    # a layer holds a position's K/V heads side by side in one flat row,
+    # ``(B, S, Hkv * D)``, which every routine but that one reshapes.
     see_block: int = 0
+
+    @nn.nowrap
+    def is_block_round(self, T: int) -> bool:
+        """Whether T fed tokens a row are a block decoder's round."""
+        return self.see_block > 1 and T in (self.see_block,
+                                            2 * self.see_block)
 
     @nn.compact
     def __call__(self, x, mask: Optional[jax.Array] = None,
@@ -582,6 +649,8 @@ class MultiHeadAttention(nn.Module):
             # init sizes the cache from the (B, max_len) input; a window
             # layer's is its ring, whatever max_len
             kv_shape = (B, self.window or T, kv_heads, self.head_dim)
+            if self.see_block > 1:
+                kv_shape = (B, T, kv_heads * self.head_dim)
             cached_k = self.variable(
                 "cache", "cached_key", init_k, None, kv_shape,
                 jnp.int8 if int8_cache else k.dtype,
@@ -626,7 +695,9 @@ class MultiHeadAttention(nn.Module):
                     positions = starts[:, None] + jnp.arange(T)[None]
 
                     def write(buf, new):
-                        if T == self.see_block:   # a block round
+                        # a block round: a row's open block, and the
+                        # finished one before it or as many dead positions
+                        if self.is_block_round(T):
                             return _block_update(buf, new, starts)
                         return _row_update(buf, new, starts)
                 if self.rotary:
@@ -667,16 +738,26 @@ class MultiHeadAttention(nn.Module):
                         vscale=v_scale.value,
                     )
                 else:
-                    cached_k.value = write(cached_k.value, k)
-                    cached_v.value = write(cached_v.value, v)
+                    flat = by_head = lambda x: x  # noqa: E731
+                    if self.see_block > 1:   # a position's heads in a row
+                        flat = lambda x: x.reshape(  # noqa: E731
+                            x.shape[:2] + (-1,))
+                        by_head = lambda x: x.reshape(  # noqa: E731
+                            x.shape[:2] + (kv_heads, self.head_dim))
+                    cached_k.value = write(cached_k.value, flat(k))
+                    cached_v.value = write(cached_v.value, flat(v))
                     if prefill_in_tiles(T, S):
                         out = _prefill_attention(
+                            q, by_head(cached_k.value),
+                            by_head(cached_v.value), seen, lengths)
+                    elif self.is_block_round(T):
+                        out = _round_attention(
                             q, cached_k.value, cached_v.value, seen,
-                            lengths)
+                            lengths, self.dtype)
                     else:
                         out = _cache_attention(
-                            q, cached_k.value, cached_v.value, pos_mask,
-                            self.dtype,
+                            q, by_head(cached_k.value),
+                            by_head(cached_v.value), pos_mask, self.dtype,
                         )
         else:
             if self.rotary:

@@ -62,14 +62,19 @@ class DeviceCounters:
                 "round (a live row a round)"),
             "block_commits_total": reg.counter(
                 "block_commits_total",
-                "block forwards that found no position masked and "
-                "committed the block"),
+                "block forwards that found no position masked and only "
+                "committed the block (none: see "
+                "block_commits_fused_total)"),
             "block_positions_unmasked_total": reg.counter(
                 "block_positions_unmasked_total",
                 "masked positions that took a token in a denoising step"),
             "block_tokens_emitted_total": reg.counter(
                 "block_tokens_emitted_total",
-                "tokens a commit handed to its request"),
+                "tokens a block's last step handed to its request"),
+            "block_commits_fused_total": reg.counter(
+                "block_commits_fused_total",
+                "block forwards that wrote a finished block's keys and "
+                "values beside a denoising step of the next block"),
             "attn_rows_attended_total": reg.counter(
                 "attn_rows_attended_total",
                 "cached key rows inside the masks of real queries",

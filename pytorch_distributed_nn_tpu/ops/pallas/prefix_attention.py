@@ -19,6 +19,14 @@ lives in VMEM, memory sees q, K, V and the output only) and
 the path of a shape the kernel cannot tile, and the kernel's oracle).
 :func:`prefix_attention` picks by what it can observe, the backend and
 the shapes.
+
+:func:`round_attention` is the same recurrence for a decode round that
+feeds every row of a batched cache a few positions (a block decoder's:
+a block or two): the keys and values are read where the cache holds
+them, rows by position with a position's heads side by side, a grid
+step a row and a key block, and a row's key blocks past its last
+visible position are not read, where the dense routine scores every
+row's whole padded length and writes the float32 scores out.
 """
 
 from __future__ import annotations
@@ -48,6 +56,11 @@ HEADS_A_STEP = 4
 # four heads' double-buffered tiles at 512 x 1,024 pass the compiler's
 # 16 MiB default; a v5e core has 128 MiB
 VMEM_LIMIT_BYTES = 64 * 2 ** 20
+# key rows a grid step of :func:`round_attention`: a step moves a
+# block of K and of V for every head, 2 KB a row at 4 heads of 128 in
+# bf16, so 512 rows are 1 MB and ~1.3 us of the chip's bandwidth over a
+# step's ~0.35 us, and a row reads half a block past what it has filled
+ROUND_KEY_BLOCKS = (512, 256, 128)
 
 
 def tiles(T: int, S: int, block_q: int, block_k: int) -> tuple:
@@ -328,3 +341,121 @@ def prefix_attention(q, k, v, q_pos, *, scale: float,
                    tuple(q.shape), k.shape[1], S, bq, bk)
     return _in_whole_tiles(_pallas if kernel else _blockwise, q, k, v,
                            q_pos, scale=scale, block_q=bq, block_k=bk)
+
+
+def _round_kernel(hi_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
+                  m_scr, l_scr, acc_scr, *, scale: float, block_k: int):
+    """One (row, kj) grid step of :func:`round_attention`: every K/V
+    head's tile of the key block, cut from the block's lanes, against
+    that head's query rows. ``hi`` (scalar prefetch, (B,)) is the last
+    position any query of the row sees: a key block past it is neither
+    fetched nor multiplied, and a row with none (``hi`` < 0) costs its
+    steps' overhead alone."""
+    b, kj = pl.program_id(0), pl.program_id(1)
+    hi = hi_ref[b]
+    first = kj * block_k
+    heads, rows, d = q_ref.shape
+
+    @pl.when(kj == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(first <= hi)
+    def _visit():
+        k_pos = first + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_k), 1)
+        visible = k_pos <= pos_ref[...]
+        v_seen = first + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, 1), 0) <= hi
+        for h in range(heads):
+            v = v_ref[:, h * d:(h + 1) * d]
+            s = jax.lax.dot_general(
+                q_ref[h], k_ref[:, h * d:(h + 1) * d],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            m, l, acc = _update(
+                jnp.where(visible, s, NEG_INF),
+                jnp.where(v_seen, v, jnp.zeros_like(v)),
+                m_scr[h][:, :1], l_scr[h][:, :1], acc_scr[h])
+            m_scr[h] = jnp.broadcast_to(m, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l, l_scr.shape[1:])
+            acc_scr[h] = acc
+
+    @pl.when(kj == pl.num_programs(1) - 1)
+    def _emit():
+        sees = pos_ref[...] >= 0
+        for h in range(heads):
+            out = acc_scr[h] / jnp.maximum(l_scr[h][:, :1], 1e-30)
+            o_ref[h] = jnp.where(sees, out, 0).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_k",
+                                             "interpret"))
+def round_attention(q, k, v, q_pos, *, scale: float, block_k: int,
+                    interpret: bool = False):
+    """Attention of a decode round over a batched cache, as a kernel.
+    q (B, Hkv, R, d): a row's R query rows a K/V head (its fed
+    positions times the query heads of the group, in any order); k, v
+    (B, S, Hkv * d): the cache as it lies, rows by position, a
+    position's K/V heads side by side, S in whole blocks of ``block_k``
+    (:func:`round_key_block`); q_pos (B, R): key s is visible to query
+    row r iff ``s <= q_pos[b, r]``, a negative position sees nothing and
+    gets zeros. Returns (B, Hkv, R, d) in q's dtype."""
+    B, H, R, d = q.shape
+    S = k.shape[1]
+    pos = jnp.minimum(q_pos.astype(jnp.int32), S - 1)
+
+    def row_map(b, kj, hi_ref):
+        return (b, 0, 0, 0)
+
+    def kv_map(b, kj, hi_ref):
+        return (b, jnp.minimum(kj, jnp.maximum(hi_ref[b], 0) // block_k), 0)
+
+    return pl.pallas_call(
+        functools.partial(_round_kernel, scale=scale, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, S // block_k),
+            in_specs=[
+                pl.BlockSpec((None, H, R, d), row_map),
+                pl.BlockSpec((None, block_k, H * d), kv_map),
+                pl.BlockSpec((None, block_k, H * d), kv_map),
+                pl.BlockSpec((None, R, 1),
+                             lambda b, kj, hi_ref: (b, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, H, R, d), row_map),
+            scratch_shapes=[
+                pltpu.VMEM((H, R, STAT_LANES), jnp.float32),
+                pltpu.VMEM((H, R, STAT_LANES), jnp.float32),
+                pltpu.VMEM((H, R, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, H, R, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="round_attention",
+    )(pos.max(axis=-1), q, k, v, pos[..., None])
+
+
+def round_key_block(R: int, S: int, d: int, dtype) -> int:
+    """The key rows a grid step of :func:`round_attention` takes for R
+    query rows a K/V head of width d against S cached rows, or 0 where
+    the kernel is not the routine: off a TPU, off bf16, or off the
+    tiles (whole registers of query rows, whole lane tiles of a head,
+    whole blocks of keys)."""
+    if jax.default_backend() != "tpu" or R % 16 or d % 128 \
+            or jnp.dtype(dtype) != jnp.bfloat16:
+        return 0
+    return next((n for n in ROUND_KEY_BLOCKS if S % n == 0), 0)
+
+
+def round_rows_read(q_pos, real, S: int, block_k: int):
+    """Key rows :func:`round_attention` reads for the ``real`` (B, T)
+    queries of a round, summed: for each, the key blocks up to the last
+    position its row's real queries see (the rows inside its own mask
+    are ``q_pos + 1``)."""
+    hi = jnp.where(real, jnp.minimum(q_pos, S - 1), -1).max(axis=-1)
+    return (real.sum(axis=-1)
+            * jnp.where(hi < 0, 0, (hi // block_k + 1) * block_k)).sum()

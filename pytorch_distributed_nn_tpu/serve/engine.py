@@ -100,18 +100,29 @@ host must see to stream them and to retire rows) is a single
 A *block decoder* (a model that declares ``block_decoding()``:
 SDAR's block diffusion, ``models/sdar_moe.py``) changes what a round
 is and nothing of the above. Its round (:func:`_block_round`, under
-``_serve_step``'s name) forwards a block of ``B`` positions a row at
-the row's committed depth and then, row by row, either unmasks some of
-the block's positions (a denoising step: no token comes of it) or, the
-block being whole, commits it: up to ``B`` tokens for the request at
-once. So a round gives a row none or up to a block of tokens, the one
-fetch is a (slots, B + 1) array (the tokens and how many), a row's
-rounds to go are its blocks left times a block's steps plus one, a
-prefill fills the prompt's whole blocks and yields no first token (the
-prompt's tail opens the first block, written with the slot's state),
-and time to first token is the first block's commit. The three rules
-hold as they stand: the device stops a row in the round that hands out
-its last token; only an admission writes slot state
+``_serve_step``'s name) forwards every row's open block of ``B``
+positions and unmasks some of them (a denoising step). The step that
+leaves none masked hands the block out, up to ``B`` tokens for the
+request at once, and the row then *owes* the block's rows: what the
+cache holds of it was computed while some of its positions were still
+fed the mask token. The next round's forward of that row feeds the owed
+block's final tokens and the fresh block, all masked, behind it, ``2B``
+positions under the mask by blocks, so the owed rows are written in the
+forward that takes the fresh block's first step: a block costs its
+steps and no forward of its own to commit it, and a request's last
+block is never committed at all. Rows at different steps share one
+program, so every row feeds ``2B`` positions; a row that owes nothing
+feeds its open block and ``B`` positions that are not real. So a round
+gives a row none or up to a block of tokens, the one fetch is a
+(slots, B + 1) array (the tokens and how many), a row's rounds to go
+are its blocks left times a block's steps, a prefill fills the prompt's
+whole blocks and yields no first token (the prompt's tail opens the
+first block, written with the slot's state), and time to first token is
+the first block's last step. A row that retires owes its last block:
+the rows written for good end at that block's start, and a retire saves
+the pages wholly under it (:meth:`ServingEngine._donate_blocks`). The
+three rules hold as they stand: the device stops a row in the round
+that hands out its last token; only an admission writes slot state
 (``_write_block_rows``); a round knows its rows. ``docs/sdar.md`` has
 the pieces and what is refused.
 
@@ -216,12 +227,13 @@ def _block_steps(masked: int, block: int, steps: int) -> int:
 def _block_rounds(prompt_len: int, new_tokens: int, block: int,
                   steps: int) -> int:
     """Rounds a block decoder's row needs at most: its first block's
-    steps and commit (the prompt's tail known from the start), then
-    those of every further block that holds one of its tokens."""
+    steps (the prompt's tail known from the start), then those of every
+    further block that holds one of its tokens. No block has a round of
+    its own to commit it."""
     tail = prompt_len % block
-    return _block_steps(block - tail, block, steps) + 1 \
+    return _block_steps(block - tail, block, steps) \
         + (-(-(tail + new_tokens) // block) - 1) \
-        * (_block_steps(block, block, steps) + 1)
+        * _block_steps(block, block, steps)
 
 
 def _idle_block_state(slots: int, block: int) -> tuple:
@@ -230,7 +242,8 @@ def _idle_block_state(slots: int, block: int) -> tuple:
     idle = jnp.zeros((slots,), jnp.int32)
     return (jnp.zeros((slots, block + 1), jnp.int32),
             dict(depth=idle, masked=jnp.zeros((slots, block), bool),
-                 step=idle, skip=idle))
+                 step=idle, skip=idle, owes=jnp.zeros((slots,), bool),
+                 owed=jnp.zeros((slots, block), jnp.int32)))
 
 
 def _apply_prefill_at(model, params, cache, tokens, lengths, starts,
@@ -356,45 +369,68 @@ def _serve_step(model, params, cache, last_tok, lengths, active, remaining,
 def _block_round(model, block, params, cache, out, place, active, remaining,
                  eos, lora, sampling):
     """One round of a block decoder over all slots: a forward of every
-    row's block of ``B`` positions at its committed depth, and then,
-    row by row, a *denoising step* or a *commit*.
+    row's open block of ``B`` positions, with the finished block before
+    it if the row still *owes* that block's keys and values, and then,
+    row by row, a *denoising step*.
 
     ``out`` (slots, B + 1) int32 is both state and what the host
-    fetches: the block's tokens and, last, how many tokens the round
-    handed to the request (a row that committed holds those tokens,
-    left-aligned, where its block stood: the next block starts all
-    masked and its tokens mean nothing). ``place`` is the rest of a
-    row's state: ``depth`` (slots,) the rows committed, a multiple of
-    B; ``masked`` (slots, B) which positions of the block are unknown
-    (they are fed ``mask_token_id``; a boolean, not a comparison of
-    ids: the mask token in a prompt is a token); ``step`` the
-    denoising steps the block has had; ``skip`` the leading positions
-    that are the prompt's tail and are not handed out.
+    fetches: the open block's tokens and, last, how many tokens the
+    round handed to the request (a row whose block became whole holds
+    those tokens, left-aligned, where its block stood: the next block
+    starts all masked and its tokens mean nothing). ``place`` is the
+    rest of a row's state: ``depth`` (slots,) where the open block
+    starts, a multiple of B; ``masked`` (slots, B) which positions of
+    the block are unknown (they are fed ``mask_token_id``; a boolean,
+    not a comparison of ids: the mask token in a prompt is a token);
+    ``step`` the denoising steps the block has had; ``skip`` the
+    leading positions that are the prompt's tail and are not handed
+    out; ``owes`` (slots,) whether the block before the open one is
+    whole but not written, and ``owed`` (slots, B) its final tokens.
 
-    Position p's own row of logits scores position p. A row with a
-    masked position takes a step: ``x0`` is the best token but the mask
-    token (or the row's draw), ``conf`` its probability, and ``n_t`` of
-    the masked positions take their ``x0`` (:func:`_block_steps`'s
-    schedule): the leftmost (``sequential``), the most confident
-    (``low_confidence_static``), or every one over
+    Every row feeds ``2B`` positions, one static shape. A row that owes
+    feeds the owed block and the open one behind it from ``depth - B``:
+    under the mask by blocks the owed block's queries see nothing of
+    the open one and the open one sees the owed one whole, so the
+    forward writes the owed block's rows from its final tokens (the
+    *commit*) and takes the open block's step at once. A row that owes
+    nothing (the step after, or a first block behind a prefill) feeds
+    its open block from ``depth`` and ``B`` positions that are not real
+    (``token_mask``, a left-aligned prefix as ever: they reach no
+    expert and no counter, and what they write lies past the block,
+    where nothing reads before a later forward overwrites it, or past
+    ``max_seq_len``, where it is dropped). Only the open block's ``B``
+    rows reach the head.
+
+    Position p's own row of logits scores position p. ``x0`` is the
+    best token but the mask token (or the row's draw), ``conf`` its
+    probability, and ``n_t`` of the masked positions take their ``x0``
+    (:func:`_block_steps`'s schedule): the leftmost (``sequential``),
+    the most confident (``low_confidence_static``), or every one over
     ``confidence_threshold`` if those are at least ``n_t``
-    (``low_confidence_dynamic``). A row with none masked has just
-    written the finished block's keys and values: the commit. Its depth
-    moves on by B, the positions past ``skip`` are handed out up to the
-    row's budget or its first ``eos``, and a fresh block begins. Every
-    forward writes the block's rows and the next overwrites them, the
-    commit last; nothing reads a row past the block. Rows at different
-    steps, a row that commits beside one that steps, a stopped row
-    flowing through: one program. Returns as :func:`_serve_step`."""
+    (``low_confidence_dynamic``). A block that a step leaves whole is
+    handed out in that round: the positions past ``skip``, up to the
+    row's budget or its first ``eos``; the row's depth moves on by B, a
+    fresh block begins, and the row owes the whole one unless it
+    stopped (a stopped row's last block is never written: the retire
+    saves no page that holds it). Every forward writes the open block's
+    rows and the next overwrites them, the owed write last. Rows at
+    different steps, a row that owes beside one that does not, a
+    stopped row flowing through: one program. Returns as
+    :func:`_serve_step`."""
     B, S = block["block_length"], block["denoising_steps"]
     mask_id, rule = block["mask_token_id"], block["remasking"]
     tok, masked, step = out[:, :B], place["masked"], place["step"]
-    depth, skip = place["depth"], place["skip"]
+    depth, skip, owes = place["depth"], place["skip"], place["owes"]
+    both = jnp.concatenate(
+        [place["owed"], jnp.where(masked, mask_id, tok)], axis=1)
+    owing = owes[:, None]
     logits, mutated = model.apply(
         {"params": params, "cache": cache},
-        jnp.where(masked, mask_id, tok), train=False, decode=True,
-        mutable=["cache"], cache_positions=depth, block_round=True,
-        token_mask=jnp.broadcast_to(active[:, None], tok.shape),
+        jnp.where(owing, both, jnp.roll(both, B, axis=1)), train=False,
+        decode=True, mutable=["cache"],
+        cache_positions=jnp.where(owes, depth - B, depth), block_round=True,
+        token_mask=active[:, None] & (owing | (jnp.arange(2 * B) < B)),
+        head_rows=jnp.where(owing, B, 0) + jnp.arange(B),
         **(lora or {}))
     V = logits.shape[-1]
     allowed = jnp.where(jnp.arange(V) == mask_id, -jnp.inf, logits)
@@ -402,7 +438,7 @@ def _block_round(model, block, params, cache, out, place, active, remaining,
         x0 = jnp.argmax(allowed, axis=-1).astype(jnp.int32)
     else:
         # a row's spec for each of its positions; the key is the row's
-        # at this round, the position folded in
+        # at this round, the position's place in its block folded in
         per = lambda v: jnp.repeat(v, B)  # noqa: E731
         keys = jax.vmap(lambda k: jax.vmap(
             lambda i: jax.random.fold_in(k, i))(jnp.arange(B)))(
@@ -429,29 +465,32 @@ def _block_round(model, block, params, cache, out, place, active, remaining,
     if rule == "low_confidence_dynamic":
         sure = masked & (conf > block["confidence_threshold"])
         take = jnp.where((sure.sum(axis=-1) >= n_t)[:, None], sure, take)
-    commit = active & ~masked.any(axis=-1)
-    take &= (active & ~commit)[:, None]
+    take &= active[:, None]
+    tok = jnp.where(take, x0, tok)
+    whole = active & ~(masked & ~take).any(axis=-1)
     place_i = jnp.arange(B)[None]
     n_out = jnp.minimum(B - skip, remaining)
     stops = (tok == eos) & (place_i >= skip[:, None]) \
         & (place_i < (skip + n_out)[:, None])
     stopped = stops.any(axis=-1)
     n_out = jnp.where(stopped, jnp.argmax(stops, axis=-1) - skip + 1, n_out)
-    n_out = jnp.where(commit, n_out, 0)
+    n_out = jnp.where(whole, n_out, 0)
     handed = jnp.take_along_axis(
         tok, jnp.minimum(skip[:, None] + place_i, B - 1), axis=1)
     out = jnp.concatenate([
-        jnp.where(commit[:, None], handed, jnp.where(take, x0, tok)),
-        n_out[:, None]], axis=1)
+        jnp.where(whole[:, None], handed, tok), n_out[:, None]], axis=1)
     remaining = remaining - n_out
-    alive = jnp.where(commit, (remaining > 0) & ~stopped, active)
-    place = dict(
-        depth=jnp.where(commit, depth + B, depth),
-        masked=jnp.where(commit[:, None], True, masked & ~take),
-        step=jnp.where(commit, 0, jnp.where(active, step + 1, step)),
-        skip=jnp.where(commit, 0, skip))
+    alive = jnp.where(whole, (remaining > 0) & ~stopped, active)
     cache = model.add_block_counts(mutated["cache"], jnp.stack([
-        active.sum(), commit.sum(), take.sum(), n_out.sum()]))
+        active.sum(), (active & ~masked.any(axis=-1)).sum(), take.sum(),
+        n_out.sum(), (active & owes).sum()]))
+    place = dict(
+        depth=jnp.where(whole, depth + B, depth),
+        masked=jnp.where(whole[:, None], True, masked & ~take),
+        step=jnp.where(whole, 0, jnp.where(active, step + 1, step)),
+        skip=jnp.where(whole, 0, skip),
+        owes=whole & alive,
+        owed=jnp.where(whole[:, None], tok, place["owed"]))
     return out, place, alive, remaining, cache, sampling
 
 
@@ -462,7 +501,8 @@ def _write_block_rows(out, place, active, remaining, rows, tails,
     first block. ``rows`` (5, slots) int32 is the host's ``(written,
     prompt positions in the block, depth, remaining, adapter)`` and
     ``tails`` (slots, B) the block's tokens, the prompt's tail first;
-    the positions after it are masked."""
+    the positions after it are masked. The row owes nothing: the blocks
+    before its first are the prefill's."""
     written = rows[0] > 0
     B = tails.shape[1]
     out = jnp.where(written[:, None],
@@ -473,7 +513,8 @@ def _write_block_rows(out, place, active, remaining, rows, tails,
                          jnp.arange(B)[None] >= rows[1][:, None],
                          place["masked"]),
         step=jnp.where(written, 0, place["step"]),
-        skip=jnp.where(written, rows[1], place["skip"]))
+        skip=jnp.where(written, rows[1], place["skip"]),
+        owes=place["owes"] & ~written, owed=place["owed"])
     state = (out, place, jnp.where(written, rows[3] > 0, active),
              jnp.where(written, rows[3], remaining))
     if adapter_ids is not None:
@@ -1253,7 +1294,7 @@ class ServingEngine:
 
     def _first_token(self, req: Request, now: float) -> None:
         """Stamp a request's first token (a block decoder's: its first
-        block's commit) and observe its TTFT. TTFT is charged from the
+        block's last step) and observe its TTFT. TTFT is charged from the
         logical request's ORIGINAL arrival (t_origin: set by the fleet
         on resubmitted legs), and only when THIS leg delivers the first
         token — a disagg decode leg or a post-first-token failover
@@ -1310,8 +1351,8 @@ class ServingEngine:
         other). A row admitted after a round's dispatch is not in it."""
         if self._block is not None:
             # a block decoder's row counts rounds, not tokens: blocks
-            # left times the steps a block takes plus its commit (at
-            # most: ``low_confidence_dynamic`` may take fewer)
+            # left times the steps a block takes (at most:
+            # ``low_confidence_dynamic`` may take fewer)
             return any(s is not None
                        and self._round_no - (s.first_round - 1) < s.rounds
                        for s in self._slots)
@@ -1323,9 +1364,9 @@ class ServingEngine:
 
     def _collect_blocks(self, host_out: np.ndarray, live: list) -> tuple:
         """:meth:`_collect` for a block decoder: ``host_out`` (slots,
-        B + 1) holds, for a row that committed, the tokens the commit
-        handed out and, last, how many (none for a row that took a
-        denoising step)."""
+        B + 1) holds, for a row whose block the round's step left
+        whole, the tokens handed out and, last, how many (none for a
+        row whose block still has a position masked)."""
         if chaos.on_flip_token(self.replica_index, self.scheduler.round):
             raise RuntimeError(
                 f"chaos flip@: {type(self.model).__name__} decodes by "
@@ -1512,7 +1553,17 @@ class ServingEngine:
         ``depth // block_size`` full blocks (depth = prompt + emitted
         - 1 = exactly the rows whose tokens the scheduler hands to
         release). Re-saving a block the radix already owns writes
-        bit-identical bytes — harmless."""
+        bit-identical bytes — harmless.
+
+        A block decoder's row retires owing its last block, the one
+        that holds row ``depth``: what the cache has of it a denoising
+        step wrote while some of its positions were still fed the mask
+        token, and no forward of its final tokens followed. The rows
+        written for good end at that block's start, ``depth // B * B``,
+        and the pages wholly under it are the same ``depth //
+        block_size``, a page being whole blocks (the constructor
+        refuses any other): the count is release's, and no saved page
+        holds a row of the owed block."""
         pool = self.scheduler.pool
         bs = pool.block_size
         table = pool.block_table(s.req.request_id)
@@ -1597,8 +1648,8 @@ class ServingEngine:
         # request.
         waterfall = dict(
             queued_s=round(max(req.t_admit - req.t_submit, 0.0), 6),
-            # (a block decoder's first token is its first commit, some
-            # rounds after its prefill)
+            # (a block decoder's first token is its first block's last
+            # step, some rounds after its prefill)
             prefill_s=round(max((req.t_prefilled or req.t_first_token)
                                 - req.t_admit, 0.0), 6),
             decode_s=round(max(decode, 0.0), 6),

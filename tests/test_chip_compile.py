@@ -496,13 +496,16 @@ def test_sdar_serve_programs_fit_the_chip_at_the_cells_size(
         topo, monkeypatch, program):
     """``sdar_30b_a3b_seq2`` as its cell runs it (7 layers, every one of the
     128 experts of a layer, the whole vocabulary, bf16; 64 slots x 2,048
-    positions): the block round and the smallest and largest prefill
+    positions): the block round (two blocks of positions a row: the
+    open one and the one it may owe) and the smallest and largest prefill
     buckets compile for the described chip and fit its 16 GB beside
     what they are given, the round's cache donated. A layer's held
     experts are one ``grouped_experts`` call (``ops/pallas``: the
     dispatcher asks for the backend, and this test answers for the
     chip) and no loop: a loop an expert unrolled into the text, 128 a
     layer, this compile takes 110 s where it takes 6."""
+    import re
+
     from pytorch_distributed_nn_tpu.config import ModelConfig
     from pytorch_distributed_nn_tpu.inference.generate import init_cache
     from pytorch_distributed_nn_tpu.models import get_model
@@ -530,13 +533,17 @@ def test_sdar_serve_programs_fit_the_chip_at_the_cells_size(
             static_argnums=(0,), donate_argnums=(2,)).lower(
             model, params, cache, arg((slots, B + 1)),
             dict(depth=arg((slots,)), masked=arg((slots, B), jnp.bool_),
-                 step=arg((slots,)), skip=arg((slots,))),
+                 step=arg((slots,)), skip=arg((slots,)),
+                 owes=arg((slots,), jnp.bool_), owed=arg((slots, B))),
             arg((slots,), jnp.bool_), arg((slots,)), arg(())).compile()
-        # one kernel a layer for its experts and no loop around it; the
-        # cache written by scatters, no loop over the slots
+        # one kernel a layer for its experts and one for its attention
+        # over the flat cache rows, no loop around either; the cache
+        # written by scatters, no loop over the slots; no float32 scores
+        # of a row's whole padded length
         text = compiled.as_text()
-        assert text.count(KERNEL) == 7
+        assert text.count(KERNEL) == 14
         assert text.count(" while(") == 0
+        assert not re.findall(rf"f32\[{slots},\d+,\d+,\d+,{rows}\]", text)
     else:
         cache = on(jax.eval_shape(lambda: init_cache(model, 1, program)))
         compiled = jax.jit(

@@ -154,6 +154,99 @@ def test_kernel_is_the_recurrence_in_bf16(tiles, heads):
                          - want.astype(jnp.float32)).max()) < 2e-2
 
 
+def _round_operands(dtype=jnp.float32, key=20):
+    """A block decoder's round at a small size: five slots of a cache of
+    64 rows, 4 query heads over 2 K/V heads of 16, 8 fed positions a
+    row (two blocks of 4). Row 0 owes (all 8 real, from 20), row 1 owes
+    nothing (4 real, from 36), row 2 is idle, row 3 stands at the
+    cache's last block, row 4 in its first; the rows past what a slot
+    has filled hold NaN. ``(q (B, T, H, D), k, v (B, S, Hkv * D), seen
+    (B, T), lengths (B,))``."""
+    B, T, S, heads, kv, d, blk = 5, 8, 64, 4, 2, 16, 4
+    ks = jax.random.split(jax.random.key(key), 3)
+    starts = jnp.asarray([20, 36, 0, 56, 0])
+    lengths = jnp.asarray([8, 4, 0, 8, 4])
+    fed = starts[:, None] + jnp.arange(T)[None]
+    seen = fed // blk * blk + blk - 1
+    filled = jnp.where(lengths > 0, starts + lengths, 0)
+    rows = jnp.arange(S)[None, :, None] < filled[:, None, None]
+    k, v = (jnp.where(rows, jax.random.normal(kk, (B, S, kv * d)), jnp.nan)
+            for kk in ks[1:])
+    q = jax.random.normal(ks[0], (B, T, heads, d))
+    return tuple(x.astype(dtype) for x in (q, k, v)) + (seen, lengths)
+
+
+@pytest.mark.parametrize("block_k", [8, 16, 64])
+def test_round_attention_is_the_dense_routine_on_what_a_row_sees(block_k):
+    """The round's kernel (interpret mode) on the cache as it lies, a
+    position's heads side by side, against the decode cache's dense
+    routine: the same float32 sums in another order. A row's key blocks
+    past its last visible position are not read (they hold NaN), a
+    query that is not real gets zeros, a row with none costs nothing."""
+    q, k, v, seen, lengths = _round_operands()
+    B, T, heads, d = q.shape
+    S, kv = k.shape[1], k.shape[2] // d
+    G = heads // kv
+    real = jnp.arange(T)[None] < lengths[:, None]
+    grouped = q.reshape(B, T, kv, G, d).transpose(0, 2, 1, 3, 4)
+    got = pa.round_attention(
+        grouped.reshape(B, kv, T * G, d), k, v,
+        jnp.repeat(jnp.where(real, seen, -1), G, axis=1), scale=d ** -0.5,
+        block_k=block_k, interpret=True)
+    got = got.reshape(B, kv, T, G, d).transpose(0, 2, 1, 3, 4) \
+        .reshape(q.shape)
+    by_head = lambda x: jnp.nan_to_num(x).reshape(B, S, kv, d)  # noqa: E731
+    want = attention._cache_attention(
+        q, by_head(k), by_head(v),
+        jnp.arange(S)[None, None, :] <= seen[:, :, None], q.dtype)
+    assert bool(jnp.isfinite(got).all())
+    gap = jnp.abs(got - want).max(axis=(2, 3))
+    assert float(jnp.where(real, gap, 0).max()) < 2e-6
+    assert float(jnp.where(real, 0, jnp.abs(got).max(axis=(2, 3))).max()) == 0
+
+
+def test_a_round_reads_the_key_blocks_up_to_what_a_row_sees():
+    """The arithmetic of ``attn_rows_read_total`` for a round through
+    the kernel: a real query is charged the key blocks up to the last
+    position its row's real queries see; a row with none reads none."""
+    _, _, _, seen, lengths = _round_operands()
+    real = jnp.arange(8)[None] < lengths[:, None]
+    # the five rows see up to 27, 39, nothing, 63 and 3
+    assert int(pa.round_rows_read(seen, real, 64, 16)) \
+        == 8 * 32 + 4 * 48 + 0 + 8 * 64 + 4 * 16
+    assert int(pa.round_rows_read(seen, real, 64, 64)) == 24 * 64
+
+
+def test_a_block_round_takes_the_kernel_on_a_tpu_in_bf16(monkeypatch):
+    """``nn/attention._round_attention`` asks the backend, the dtype and
+    the tiles (``round_key_block``): on the CPU the dense routine; told
+    it is a TPU, bf16 operands go through the kernel (here in interpret
+    mode, in key blocks of 16) and agree with the dense routine to
+    bf16's rounding."""
+    q, k, v, seen, lengths = _round_operands(jnp.bfloat16, 21)
+    q = jnp.repeat(q, 2, axis=2)      # 32 query rows a K/V head: whole
+    k, v = jnp.nan_to_num(k), jnp.nan_to_num(v)     # bf16 registers
+    assert pa.round_key_block(32, 64, 128, jnp.bfloat16) == 0
+    dense = attention._round_attention(q, k, v, seen, lengths, q.dtype)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pa, "ROUND_KEY_BLOCKS", (16,))
+    assert pa.round_key_block(32, 64, 128, jnp.bfloat16) == 16
+    assert pa.round_key_block(32, 64, 128, jnp.float32) == 0   # not bf16
+    assert pa.round_key_block(24, 64, 128, jnp.bfloat16) == 0  # registers
+    assert pa.round_key_block(32, 64, 16, jnp.bfloat16) == 0   # lane tiles
+    assert pa.round_key_block(32, 72, 128, jnp.bfloat16) == 0  # key blocks
+    asked = []
+    monkeypatch.setattr(attention, "round_key_block",
+                        lambda R, S, d, dtype: asked.append((R, S)) or 16)
+    monkeypatch.setattr(attention, "round_attention", functools.partial(
+        pa.round_attention, interpret=True))
+    got = attention._round_attention(q, k, v, seen, lengths, q.dtype)
+    assert asked == [(32, 64)] and got.dtype == jnp.bfloat16
+    real = jnp.arange(8)[None] < lengths[:, None]
+    gap = jnp.abs(got.astype(jnp.float32) - dense.astype(jnp.float32))
+    assert float(jnp.where(real[..., None, None], gap, 0).max()) < 2e-2
+
+
 def _latent_operands(B, T, S, dn=16, dr=8, dv=16, r=16, key=6):
     ks = jax.random.split(jax.random.key(key), 5)
     return (jax.random.normal(ks[0], (B, T, H, dn)),

@@ -107,20 +107,29 @@ def ref_forward(p, ids, block=B):
 
 
 def ref_generate(p, prompt, G, *, steps, rule, threshold=0.9, eos=None):
-    """The family's ``block_diffusion_generate`` a forward at a time:
-    ``(tokens, forwards, commits, unmasked)``."""
+    """The family's ``block_diffusion_generate`` a forward at a time, on
+    the engine's schedule: a block is handed out by the step that leaves
+    it whole, and its keys and values are written by the next forward of
+    the row, the one that takes the next block's first step (a forward
+    with no cache writes nothing, so here that forward is only counted:
+    ``fused``). No forward unmasks nothing and only commits (``commits``
+    stays 0), and the last block of a request is never committed.
+    ``(tokens, forwards, commits, unmasked, fused)``."""
     prompt = [int(t) for t in prompt]
     P = len(prompt)
     c = P // B * B
     done = prompt[:c]
     block = prompt[c:] + [None] * (B - (P - c))
-    out, forwards, commits, unmasked = [], 0, 0, 0
+    out, forwards, commits, unmasked, fused = [], 0, 0, 0, 0
+    owes = False
     while True:
         t = 0
         while any(x is None for x in block):
             ids = done + [MASK if x is None else x for x in block]
             logits = np.asarray(ref_forward(p, ids))[c:c + B]
             forwards += 1
+            fused += owes
+            owes = False
             open_ = logits.copy()
             open_[:, MASK] = -np.inf
             x0 = open_.argmax(-1)
@@ -139,16 +148,14 @@ def ref_generate(p, prompt, G, *, steps, rule, threshold=0.9, eos=None):
                 block[i] = int(x0[i])
             unmasked += len(take)
             t += 1
-        forwards += 1          # the commit
-        commits += 1
         for i, tok in enumerate(block):
             if c + i >= P and len(out) < G:
                 out.append(tok)
                 if tok == eos:
-                    return out, forwards, commits, unmasked
+                    return out, forwards, commits, unmasked, fused
         if len(out) >= G:
-            return out, forwards, commits, unmasked
-        done, block, c = done + block, [None] * B, c + B
+            return out, forwards, commits, unmasked, fused
+        done, block, c, owes = done + block, [None] * B, c + B, True
 
 
 def _engine(model, params, **kw):
@@ -168,6 +175,17 @@ def _prompt(n, seed=0):
     return np.random.default_rng([seed, n]).integers(0, MASK, size=(n,))
 
 
+def _block_counts(eng):
+    """The round's own counters as the registry has them: ``[forwards,
+    commits, positions unmasked, tokens emitted, fused commits]`` (a
+    counter that never moved is not in a snapshot)."""
+    eng.publish_device_counters()
+    reg = obs.get_registry().snapshot()
+    return [int(reg.get(f"block_{n}_total", 0)) for n in (
+        "forwards", "commits", "positions_unmasked", "tokens_emitted",
+        "commits_fused")]
+
+
 # -- the model --------------------------------------------------------------
 
 def test_forward_under_the_mask_by_blocks(params):
@@ -182,11 +200,16 @@ def test_forward_under_the_mask_by_blocks(params):
 
 def test_prefill_and_rounds_through_the_cache_equal_the_full_forward(params):
     """Rows [0, 8) prefilled, then the block at [8, 12) forwarded three
-    times (its tokens changing: two steps and the commit), then the
-    block at [12, 16): each against the full forward of what the rows
-    held at that moment."""
+    times (its tokens changing), then the block at [12, 16): each
+    against the full forward of what the rows held at that moment. Then
+    ``2B`` positions a row, as the engine's round feeds them: the block
+    at [12, 16) again with the next, all masked, behind it (a row that
+    owes), whose rows [12, 16) the following forward reads; and the
+    block at [16, 20) with four positions behind it that are not real
+    (a row that owes nothing), only the open block's rows reaching the
+    head."""
     model = _model()
-    ids = _prompt(16, 2)
+    ids = _prompt(20, 2)
     cache = init_cache(model, 2, 32)
 
     def fed(tokens, at, **kw):
@@ -206,20 +229,38 @@ def test_prefill_and_rounds_through_the_cache_equal_the_full_forward(params):
             fed(block, 8, block_round=True), ref_forward(params, seq)[8:],
             atol=2e-5)
     np.testing.assert_allclose(
-        fed(ids[12:16], 12, block_round=True), ref_forward(params, ids)[12:],
-        atol=2e-5)
+        fed(ids[12:16], 12, block_round=True),
+        ref_forward(params, ids[:16])[12:], atol=2e-5)
+    # the rows [12, 16) spoiled, as a denoising step leaves them
+    fed([ids[12], MASK, MASK, MASK], 12, block_round=True)
+    two = np.concatenate([ids[12:16], [MASK] * 4])
+    want = ref_forward(params, np.concatenate([ids[:12], two]))
+    np.testing.assert_allclose(fed(two, 12, block_round=True), want[12:],
+                               atol=2e-5)
+    np.testing.assert_allclose(
+        fed(two, 12, block_round=True,
+            head_rows=jnp.asarray([[4, 5, 6, 7]] * 2)), want[16:], atol=2e-5)
+    # the owed rows are the final tokens' now: the next block reads them
+    real = jnp.arange(8)[None] < jnp.asarray([[4], [4]])
+    np.testing.assert_allclose(
+        fed(np.concatenate([ids[16:20], [MASK] * 4]), 16, block_round=True,
+            token_mask=real, head_rows=jnp.asarray([[0, 1, 2, 3]] * 2)),
+        ref_forward(params, ids)[16:], atol=2e-5)
     totals = np.asarray(cache["device_counters"]).reshape(-1)
     names = model.device_counter_names()
     by = {(n, l.get("kind"), l.get("layer")): int(v)
           for (n, l), v in zip(names, totals)}
-    # one prefill of 2 rows x 8 and four rounds of 2 rows x 4 positions
+    # one prefill of 2 rows x 8, five rounds of 2 rows x 4 positions,
+    # two of 2 rows x 8 and one of 2 rows x 8 of which 4 are real
     assert by[("moe_calls_total", "prefill", "0")] == 1
-    assert by[("moe_calls_total", "decode", "2")] == 4
-    assert by[("moe_picks_total", "decode", "0")] == 4 * 2 * 4 * TOPK
-    assert by[("attn_rows_read_total", "decode", "1")] == 4 * 2 * 4 * 32
-    # a query of the block at [8, 12) sees 12 rows, of [12, 16) 16
+    assert by[("moe_calls_total", "decode", "2")] == 8
+    assert by[("moe_picks_total", "decode", "0")] == 2 * 40 * TOPK
+    assert by[("attn_rows_read_total", "decode", "1")] == 2 * 40 * 32
+    # a query of the block at [8, 12) sees 12 rows, of [12, 16) 16, of
+    # [16, 20) 20: counted a real query, so a row that feeds two blocks
+    # counts the rows before them twice over
     assert by[("attn_rows_attended_total", "decode", "1")] \
-        == 2 * 4 * (3 * 12 + 16)
+        == 2 * 4 * (3 * 12 + 2 * 16 + 2 * (16 + 20) + 20)
 
 
 # -- the engine's rounds ----------------------------------------------------
@@ -239,12 +280,13 @@ def test_served_tokens_are_the_reference_generators(params, rule, steps):
     want = [ref_generate(params, p, g, steps=steps, rule=rule)
             for p, g in jobs]
     assert served == [w[0] for w in want]
-    eng.publish_device_counters()
-    reg = obs.get_registry().snapshot()
-    got = [int(reg[f"block_{n}_total"]) for n in (
-        "forwards", "commits", "positions_unmasked", "tokens_emitted")]
+    got = _block_counts(eng)
     assert got == [sum(w[i] for w in want) for i in (1, 2, 3)] \
-        + [sum(g for _, g in jobs)]
+        + [sum(g for _, g in jobs), sum(w[4] for w in want)]
+    # no forward only commits; every block but a request's last is
+    # written beside the next one's first step
+    assert got[1] == 0 and got[4] == sum(
+        -(-(len(p) % B + g) // B) - 1 for p, g in jobs)
     assert eng.summary()["tokens_emitted"] == sum(g for _, g in jobs)
     if rule != "low_confidence_dynamic":
         # the rounds the host planned for are the rounds the rows took
@@ -252,20 +294,49 @@ def test_served_tokens_are_the_reference_generators(params, rule, steps):
                              for p, g in jobs)
 
 
-def test_rows_admitted_mid_flight_equal_their_solo_runs(params):
-    model = _model()
+@pytest.mark.parametrize("slots,steps,rule", [
+    (2, 2, "sequential"), (3, 2, "sequential"),
+    (3, 3, "low_confidence_static"), (3, 4, "low_confidence_dynamic")])
+def test_rows_admitted_mid_flight_equal_their_solo_runs(params, slots, steps,
+                                                        rule, monkeypatch):
+    """Prompts whose tails leave first blocks of one to four steps, so
+    the rows fall out of phase: in one round a row that owes its last
+    block, a row in the middle of one and a row admitted since the last
+    round, behind a prefill or (P 2: no whole block) none."""
+    model = _model(denoising_steps=steps, remasking=rule)
     jobs = [(_prompt(P, 3), G) for P, G in
-            [(5, 9), (12, 3), (7, 11), (2, 6), (9, 4), (6, 7)]]
-    together = _serve(_engine(model, params, max_slots=2), jobs)
+            [(5, 9), (12, 3), (7, 11), (2, 6), (9, 4), (6, 7), (11, 13)]]
+    obs.reset_registry()
+    eng = _engine(model, params, max_slots=slots)
+    seen = set()
+    step = engine._serve_step
+
+    def watched(model_, params_, cache, out, place, active, *rest):
+        seen.update(zip(np.asarray(active).tolist(),
+                        np.asarray(place["owes"]).tolist(),
+                        np.asarray(place["step"] > 0).tolist()))
+        return step(model_, params_, cache, out, place, active, *rest)
+    monkeypatch.setattr(engine, "_serve_step", watched)
+    together = _serve(eng, jobs)
+    # live rows that owe, that are in the middle of a block, and that
+    # open one owing nothing have all shared a program
+    assert {(True, True, False), (True, False, True),
+            (True, False, False)} <= seen
     alone = [_serve(_engine(model, params, max_slots=1), [job])[0]
              for job in jobs]
     assert together == alone
+    want = [ref_generate(params, p, g, steps=steps, rule=rule)
+            for p, g in jobs]
+    assert together == [w[0] for w in want]
+    got = _block_counts(eng)
+    assert (got[0], got[4]) == (sum(w[1] for w in want),
+                                sum(w[4] for w in want))
 
 
 def test_peaked_logits_unmask_a_block_in_one_dynamic_step(params):
     """A head scaled up makes every proposal's probability nearly one:
     ``low_confidence_dynamic`` takes the whole block in its first step,
-    two forwards a block, where the same model under
+    one forward a block, where the same model under
     ``low_confidence_static`` takes the four steps it planned."""
     sharp = jax.tree_util.tree_map_with_path(
         lambda path, x: x * 1e3 if "lm_head" in str(path) else x, params)
@@ -278,28 +349,71 @@ def test_peaked_logits_unmask_a_block_in_one_dynamic_step(params):
         want = [ref_generate(sharp, p, g, steps=4, rule=rule)
                 for p, g in jobs]
         assert served == [w[0] for w in want]
-        eng.publish_device_counters()
-        reg = obs.get_registry().snapshot()
-        counts[rule] = (int(reg["block_forwards_total"]),
-                        int(reg["block_commits_total"]))
-        assert counts[rule] == (sum(w[1] for w in want),
-                                sum(w[2] for w in want))
-    assert counts["low_confidence_dynamic"] == (2 * 5, 5)
-    assert counts["low_confidence_static"] == (5 * 5, 5)
+        got = _block_counts(eng)
+        counts[rule] = (got[0], got[1], got[4])
+        assert counts[rule] == tuple(sum(w[i] for w in want)
+                                     for i in (1, 2, 4))
+    # five blocks, of which three are followed by another of their row
+    assert counts["low_confidence_dynamic"] == (1 * 5, 0, 3)
+    assert counts["low_confidence_static"] == (4 * 5, 0, 3)
 
 
-def test_eos_inside_a_block_ends_the_request_there(params):
+@pytest.mark.parametrize("at", [5, 1, 9])
+def test_eos_inside_a_block_ends_the_request_there(params, at):
+    """``eos`` inside the second block, in the first block (which the
+    prompt's tail opens) and as a block's last position: the request
+    ends in the round whose step leaves that block whole, and its row
+    runs no further forward: none to commit the block, none of the
+    next."""
     model = _model()
     prompt = _prompt(6, 6)
     free = _serve(_engine(model, params), [(prompt, 12)])[0]
-    eos = free[5]                       # inside the second block
+    eos = free[at]
     cut = free.index(eos)
-    served = _serve(_engine(model, params, eos_token=eos),
-                    [(prompt, 12), (_prompt(9, 8), 3)])
-    assert served[0] == free[:cut + 1]
-    assert served[0] == ref_generate(params, prompt, 12, steps=2,
-                                     rule="sequential", eos=eos)[0]
-    assert len(served[1]) <= 3
+    obs.reset_registry()
+    eng = _engine(model, params, eos_token=eos)
+    served = _serve(eng, [(prompt, 12)])
+    want = ref_generate(params, prompt, 12, steps=2, rule="sequential",
+                        eos=eos)
+    assert served[0] == free[:cut + 1] == want[0]
+    assert _block_counts(eng) == [want[1], 0, want[3], cut + 1, want[4]]
+    # the first block's one step (two of its positions are the prompt's)
+    # and two a block after it, up to the block that holds the eos
+    assert want[1] == 1 + 2 * ((6 % B + cut) // B)
+    other = _serve(_engine(model, params, eos_token=eos),
+                   [(prompt, 12), (_prompt(9, 8), 3)])
+    assert other[0] == served[0] and len(other[1]) <= 3
+
+
+@pytest.mark.parametrize("P,G", [(6, 5), (6, 6), (8, 3), (7, 1)])
+def test_a_budget_that_ends_inside_a_block_ends_the_request_there(params,
+                                                                  P, G):
+    """The block that holds the budget's last token is stepped to its
+    end, handed out up to the budget and never committed."""
+    obs.reset_registry()
+    eng = _engine(_model(), params)
+    prompt = _prompt(P, 16)
+    served = _serve(eng, [(prompt, G)])[0]
+    want = ref_generate(params, prompt, G, steps=2, rule="sequential")
+    assert served == want[0] and len(served) == G
+    assert _block_counts(eng) == [want[1], 0, want[3], G, want[4]]
+    assert want[1] == engine._block_rounds(P, G, B, 2)
+
+
+@pytest.mark.parametrize("P,G", [(25, 7), (29, 3), (28, 4), (20, 12)])
+def test_a_row_at_the_end_of_its_cache_drops_the_dead_positions(params, P, G):
+    """A row whose open block is the cache's last, ``max_seq_len - B``,
+    and that owes nothing feeds ``B`` dead positions past the cache's
+    end: they are dropped, not wrapped onto the row's start nor onto the
+    next slot's, beside a row whose requests fill the same cache to the
+    end one after another."""
+    model = _model()
+    jobs = [(_prompt(P, 17), G), (_prompt(5, 18), 27), (_prompt(31, 19), 1),
+            (_prompt(P, 20), G)]
+    together = _serve(_engine(model, params, max_slots=2, max_seq_len=32),
+                      jobs)
+    assert together == [ref_generate(params, p, g, steps=2,
+                                     rule="sequential")[0] for p, g in jobs]
 
 
 def test_the_mask_token_inside_a_prompt_is_a_token(params):
@@ -312,6 +426,35 @@ def test_the_mask_token_inside_a_prompt_is_a_token(params):
     assert served == ref_generate(params, prompt, 6, steps=2,
                                   rule="sequential")[0]
     assert MASK not in served           # and it is never proposed
+
+
+@pytest.mark.parametrize("P,G", [(45, 20), (45, 19), (41, 24), (48, 17)])
+def test_a_retire_saves_the_pages_under_the_owed_block(params, P, G,
+                                                       monkeypatch):
+    """A row retires owing its last block, the one that holds position
+    ``P + G - 1``: the pages saved, and indexed, are those wholly under
+    that block's start, by one count, and a request that brings the
+    whole sequence as its prompt is served from them what the reference
+    generates: (45, 20) ends on a page's first position, (45, 19) and
+    (41, 24) on a page's last (that page holds the owed block and is not
+    saved), (48, 17) with the owed block the first of a page."""
+    model = _model()
+    eng = _engine(model, params, max_slots=2, max_seq_len=128)
+    saved = []
+    save = engine._save_blocks
+    monkeypatch.setattr(
+        engine, "_save_blocks",
+        lambda cache, store, bs, slot, table, n: saved.append(int(n))
+        or save(cache, store, bs, slot, table, n))
+    first = _prompt(P, 21)
+    served = _serve(eng, [(first, G)])[0]
+    written = (P + G - 1) // B * B          # the owed block's start
+    assert saved == [written // 16]
+    assert eng.prefix_cache.stats()["prefix_nodes"] == saved[0]
+    again = np.concatenate([first, served, _prompt(3, 22)])
+    assert _serve(eng, [(again, 9)])[0] == ref_generate(
+        params, again, 9, steps=2, rule="sequential")[0]
+    assert eng.completed[-1]["cached_tokens"] == written // 16 * 16
 
 
 def test_a_restored_prefix_serves_the_same_tokens(params):
