@@ -69,9 +69,9 @@ SERVE_INT8 = dict(
         "published",
     slots=8, max_seq=256, requests=8, max_new=16,
     min_prompt=20, max_prompt=60, rate_hz=8.0)
-# one-chip batch 128 (bench.py PER_CHIP_BATCH); the presets' own global
-# batches (1024, 256) are sized for eight chips, and ResNet's 1024 does
-# not load on one (14.0 GB program, PR 23). Its lr 0.1 goes with that
+# one-chip batch 128; the presets' own global batches (1024, 256) are
+# sized for eight chips, and ResNet's 1024 does not load on one
+# (14.0 GB program, PR 23). Its lr 0.1 goes with that
 # batch: at 128 it is scaled by 128/1024, without which the loss climbs
 # for the first thirty steps (seen on the chip, PR 23).
 TRAIN_RUNS = (
@@ -273,13 +273,13 @@ def phase_launcher(run: dict = TRAIN_RUNS[0], *, out: Path = OUT,
 # device
 # ---------------------------------------------------------------------------
 
-def phase_device(*, explicit_cpu_ok: bool = False):
+def phase_device():
     import jax
 
     from pytorch_distributed_nn_tpu.runtime.device import require_tpu
     from pytorch_distributed_nn_tpu.utils.flops import peak_flops_per_chip
 
-    dev = require_tpu(explicit_cpu_ok=explicit_cpu_ok)
+    dev = require_tpu()
     peak = peak_flops_per_chip(dev)  # raises for an unknown TPU kind
     ms = dev.memory_stats() or {}
     emit("device", platform=dev.platform, kind=dev.device_kind,
@@ -514,8 +514,8 @@ def build_llama(spec: dict):
         cfg.model.extra["quantized"] = True
     model = get_model(cfg.model)
     if spec["quantized"]:
-        # what bench.py and examples/int8_8b_inference.py do: there is
-        # no float 8B to quantize in a sealed machine
+        # what examples/int8_8b_inference.py does: there is no float
+        # 8B to quantize in a sealed machine
         params = synthetic_int8_params(
             model, jnp.zeros((1, 1), jnp.int32), seed=SEED)
     else:

@@ -20,9 +20,6 @@ Two paths shown:
 
 Run (small model so it works anywhere, incl. the CPU fallback):
     python examples/int8_8b_inference.py
-Real-8B benchmarks on a chip:
-    python bench.py --metric decode --real-8b-int8 [--kv-int8]
-    python bench.py --metric quality            # int8-vs-bf16 NLL delta
 """
 
 import sys

@@ -139,7 +139,7 @@ class TrainConfig:
     # transferred per dispatch — the production setting). N > 0 = cycle
     # a fixed pool of N device-resident batches inside the scan:
     # repeats data, which is wrong for real training but exactly what a
-    # device-rate benchmark wants (bench.py --multistep sets 4).
+    # device-rate benchmark wants.
     multistep_pool: int = 0
     log_every: int = 10
     eval_every: int = 0  # 0 = no eval; else eval every N steps

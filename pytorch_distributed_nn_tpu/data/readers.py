@@ -196,9 +196,7 @@ class ImageFolderDataset(ArraySampler):
     0 = decode inline, -1 = one per core capped at 16). Threads — not
     processes — because PIL/libjpeg releases the GIL for the decode and
     resize hot paths, so worker threads scale across cores without
-    pickling batches between processes (VERDICT r2 Missing #5; the
-    per-core decode rate is measured by ``bench.py --metric loader
-    --workers-sweep`` and recorded in BASELINE.md).
+    pickling batches between processes (VERDICT r2 Missing #5).
 
     ``root/train`` + ``root/val`` (each in class layout) are honored as
     the split when present — val/ becomes the eval stream; otherwise
@@ -293,7 +291,7 @@ class ImageFolderDataset(ArraySampler):
 
     def close(self) -> None:
         """Shut the decode pool down (idle threads otherwise persist
-        for the process lifetime — e.g. the bench worker sweep builds
+        for the process lifetime — e.g. a worker-count sweep builds
         one dataset per sweep point)."""
         if self._pool is not None:
             self._pool.shutdown(wait=False)

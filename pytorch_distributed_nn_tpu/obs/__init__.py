@@ -34,8 +34,8 @@ heartbeats) with:
   deterministic service model), judge each rung with the watchtower's
   burn-rate signal, and emit the max-sustainable-rate frontier, the
   goodput-saturation knee, and the "replicas needed per SLO per
-  traffic shape" planning report (``bench.py --capacity``,
-  ``scripts/obs_report.py --capacity``);
+  traffic shape" planning report (``scripts/obs_report.py
+  --capacity`` renders it);
 - :mod:`obs.trace` — Causeway distributed request tracing (ISSUE 16):
   per-request :class:`~obs.trace.TraceContext` minted at submit,
   propagated across scheduler transitions, prefill/decode legs, KV
@@ -63,15 +63,12 @@ heartbeats) with:
   bounded, rate-limited ``jax.profiler`` captures (page/interval/
   on-demand triggers), per-op MFU/roofline attribution, compile
   telemetry (fed by :mod:`obs.jitwatch`) for the ``recompile_storm``
-  detector, and the
-  ``bench.py --ledger`` perf-regression gate; inert unless
-  ``TPUNN_XRAY`` is set.
+  detector; inert unless ``TPUNN_XRAY`` is set.
 
 ``scripts/obs_report.py`` renders the JSONL/trace output;
 ``scripts/obs_doctor.py`` analyzes flight dumps;
 ``scripts/obs_watch.py`` tails/replays alerts and burn rates;
-``scripts/obs_xray.py`` renders capture attribution tables;
-``bench.py --goodput`` attaches the breakdown to benchmark records.
+``scripts/obs_xray.py`` renders capture attribution tables.
 """
 
 from pytorch_distributed_nn_tpu.obs import audit  # noqa: F401

@@ -19,12 +19,12 @@ Two ways to produce a rung's request stream:
   chaos ``kill_replica@`` faults with re-admission penalties). Pure in
   the trace: same spec + seed → byte-identical events → **identical
   capacity report**, with no accelerator in the loop. This is what
-  ``bench.py --capacity --selftest`` and tier-1 exercise, and what the
-  planning report defaults to.
+  tier-1 exercises (tests/test_skyline.py), and what the planning
+  report defaults to.
 - a real :class:`serve.fleet.Fleet` driven by
-  :func:`serve.traffic.replay_trace` (``bench.py --capacity``), whose
-  completion records feed the same judge, and whose service-time
-  parameters calibrate the simulator.
+  :func:`serve.traffic.replay_trace`, whose completion records feed
+  the same judge, and whose service-time parameters calibrate the
+  simulator.
 
 Chaos composes: the simulator accepts a ``TPUNN_CHAOS``-grammar spec
 (parsed by :func:`runtime.chaos.parse_spec` — the real grammar, not a
@@ -284,7 +284,7 @@ def simulate_autoscaled_fleet(
         tail_s: float = 10.0) -> dict:
     """:func:`simulate_fleet` with the replica set under closed-loop
     control — the no-backend validation path for Helm
-    (:mod:`serve.autoscale`, ``bench.py --autoscale --selftest``).
+    (:mod:`serve.autoscale`, tests/test_autoscale.py).
 
     ``controller`` is duck-typed (so this module never imports the
     autoscaler; serve code reaches obs, not the reverse):

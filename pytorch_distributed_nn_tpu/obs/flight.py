@@ -21,7 +21,7 @@ Cost model (why it can stay always-on):
   *trace* time — once per compiled program, not per step;
 - per-step cost is two ring appends (step marker + dispatch event): a
   lock acquire and a ``deque.append`` each, ~1 µs against millisecond
-  steps — not measurable in ``bench.py --goodput``;
+  steps;
 - the ring is bounded (``deque(maxlen=...)``), so memory is O(capacity)
   forever.
 

@@ -466,31 +466,3 @@ def describe_round(rec: dict, t_ref: float = 0.0) -> str:
                      f"{rec.get('admit_cpu_s', 0.0) * 1e3:.1f}]")
     return (f"round {rec['round']} at +{rec['t'] - t_ref:.1f} s: "
             f"{rec['busy_s'] * 1e3:.1f} ms = " + " ".join(parts))
-
-
-def restart_context() -> dict:
-    """Restart/backoff accounting to attach to a goodput summary: which
-    incarnation this process is (``TPUNN_RESTART``), whether a chaos
-    engine is armed, and — when the elastic agent shares this process's
-    registry (in-process ``launch()``) — the agent's restart, backoff,
-    and preemption gauges. Interrupted runs thereby account their lost
-    time instead of silently reporting only the surviving window."""
-    import os
-
-    from pytorch_distributed_nn_tpu.obs.registry import get_registry
-
-    out: dict = {"incarnation": int(os.environ.get("TPUNN_RESTART", "0")
-                                    or 0)}
-    try:  # lazy: goodput must not drag runtime/ in at import time
-        from pytorch_distributed_nn_tpu.runtime import chaos
-
-        out["chaos_enabled"] = chaos.enabled()
-    except Exception:  # pragma: no cover - import cycles in stubs
-        pass
-    snap = get_registry().snapshot()
-    for key in ("agent_incarnations_total", "agent_restarts_total",
-                "agent_preempt_restarts_total",
-                "agent_backoff_seconds_total"):
-        if key in snap:
-            out[key] = snap[key]
-    return out

@@ -36,19 +36,14 @@ post-mortem rings (obs/flight.py + forensics). This module answers
    and a :func:`watchtower.on_compile` feed — the ``recompile_storm``
    detector names the function that keeps re-tracing mid-run.
 
-The perf-regression ledger (:func:`check_ledger`) also lives here:
-``bench.py --ledger`` fits a per-metric noise band (median ± k·MAD
-over prior ``BENCH_r*.json`` records) and fails with a named
-regression when the newest record falls out of band.
-
 Hooks (:func:`on_step`, :func:`on_serve_round`, :func:`on_page`,
 :func:`on_wire_bytes`) follow the chaos/watchtower inert-when-unset
 contract — first statement is the ``_xray is None`` bail-out, AST-
 checked by tests/test_quality.py — so an unarmed run pays one ``None``
 check per step. Module import stays stdlib-only (jax, numpy and
 ops.collectives are imported lazily inside the functions that need
-them): the ledger and the capture-reading scripts must run on a dev
-box with nothing but the JSON artifacts.
+them): the capture-reading scripts must run on a dev box with nothing
+but the JSON artifacts.
 
 This module also absorbed ``utils/profiling.py`` (``xprof_trace``,
 ``collective_trace_seconds``, ``StepTimer``/``time_steps``,
@@ -71,7 +66,6 @@ from typing import Callable, Sequence
 
 from pytorch_distributed_nn_tpu.obs import flight, jitwatch
 from pytorch_distributed_nn_tpu.obs.registry import get_registry
-from pytorch_distributed_nn_tpu.obs.stats import mad, median
 
 log = logging.getLogger(__name__)
 
@@ -219,8 +213,7 @@ def collective_trace_seconds(log_dir: str,
     one device spent inside collectives. Async pairs (TPU
     'all-reduce-start'/'-done') both count — start covers the transfer
     window, done the wait — so the figure is an upper bound on wire
-    occupancy; the cross-check against analytic wire bytes in
-    ``bench.py --metric bus_bw`` reports both. Returns None when no
+    occupancy. Returns None when no
     trace file or no collective slices are found (e.g. world == 1 —
     XLA elides the collectives entirely)."""
     path = _newest_perfetto(log_dir)
@@ -740,155 +733,6 @@ class XrayEngine:
             "compile_seconds": self.compile_seconds_total,
             "paths": [c["dir"] for c in self.captures],
         }
-
-
-# ---------------------------------------------------------------------------
-# Perf-regression ledger (bench.py --ledger)
-# ---------------------------------------------------------------------------
-
-# substrings that mark a lower-is-better metric; everything else
-# (throughput, MFU, bandwidth, accuracy) regresses downward
-_LOWER_IS_BETTER = ("nll", "latency", "ttft", "_ms", " ms", "seconds",
-                    "cost")
-
-
-def metric_direction(name: str) -> str:
-    low = name.lower()
-    return ("lower" if any(s in low for s in _LOWER_IS_BETTER)
-            else "higher")
-
-
-def _parse_tail_metrics(tail) -> list[dict]:
-    """Benchmark records embedded in a record's captured-stdout
-    ``tail``. The driver parses ONE record per round into ``parsed``,
-    but a round that benches several series in one invocation (e.g.
-    ``--fleet`` emitting the thread-fleet AND the ``--fleet-procs`` /
-    ``--disagg`` series) prints one JSON line per series; this
-    recovers the rest so every emitted series joins the tracked
-    trajectory. Accepts both shapes MetricsLogger produces — the
-    event-wrapped ``{"event": "benchmark", ...}`` line and the bare
-    ``{"metric", "value", "unit", ...}`` record — and tolerates a
-    missing/garbled tail (older and synthetic records have none)."""
-    if isinstance(tail, str):
-        lines = tail.splitlines()
-    elif isinstance(tail, (list, tuple)):
-        lines = [str(x) for x in tail]
-    else:
-        return []
-    out = []
-    for ln in lines:
-        ln = ln.strip()
-        if not (ln.startswith("{") and '"metric"' in ln):
-            continue
-        try:
-            d = json.loads(ln)
-        except ValueError:
-            continue
-        if not isinstance(d, dict) \
-                or d.get("event") not in (None, "benchmark") \
-                or not isinstance(d.get("metric"), str) \
-                or not isinstance(d.get("value"), (int, float)):
-            continue
-        out.append({k: v for k, v in d.items()
-                    if k not in ("event", "time", "process")})
-    return out
-
-
-def load_bench_records(directory=".",
-                       pattern: str = "BENCH_r*.json") -> list[dict]:
-    """The BENCH_r*.json trajectory, ordered by round number ``n``.
-    Unreadable files are skipped (a torn write must not kill the
-    gate); records with ``parsed: null`` (failed runs) are kept so the
-    checker can report how many it ignored. Extra benchmark lines in
-    each record's stdout tail land in ``_tail_metrics`` so multi-series
-    rounds track every series they emitted."""
-    recs = []
-    for p in sorted(glob.glob(os.path.join(str(directory), pattern))):
-        try:
-            with open(p) as f:
-                rec = json.load(f)
-        except (OSError, ValueError):
-            continue
-        rec.setdefault("_path", p)
-        rec["_tail_metrics"] = _parse_tail_metrics(rec.get("tail"))
-        recs.append(rec)
-    recs.sort(key=lambda r: (int(r.get("n", 1 << 30)),
-                             str(r.get("_path", ""))))
-    return recs
-
-
-def fit_noise_band(values: Sequence[float], *, mad_k: float = 4.0,
-                   rel_floor: float = 0.05) -> dict:
-    """median ± max(k·MAD, rel_floor·|median|). The MAD term tracks the
-    observed run-to-run noise; the relative floor keeps a freakishly
-    quiet history (MAD ≈ 0 on 2-3 records) from flagging 1% jitter."""
-    vals = [float(v) for v in values]
-    med = median(vals)
-    spread = mad(vals, center=med)
-    half = max(mad_k * spread, rel_floor * abs(med))
-    return {"median": med, "mad": spread,
-            "lo": med - half, "hi": med + half}
-
-
-def check_ledger(records: list[dict], *, mad_k: float = 4.0,
-                 rel_floor: float = 0.05,
-                 min_history: int = 2) -> dict:
-    """The regression gate: per metric, fit the noise band over all
-    PRIOR parsed records and test the newest one against it (direction-
-    aware — throughput regresses below band, NLL/latency above). Named
-    verdicts; ``ok`` is False only on a confirmed regression."""
-    series: dict[str, list[tuple[int, float, str]]] = {}
-    skipped = 0
-    for rec in records:
-        entries = []
-        parsed = rec.get("parsed")
-        if (isinstance(parsed, dict)
-                and isinstance(parsed.get("value"), (int, float))):
-            entries.append(parsed)
-        # multi-series rounds: the driver's single `parsed` slot only
-        # holds one record; the rest ride in from the stdout tail
-        # (load_bench_records), deduped on the series name
-        seen = {str(e.get("metric", "unnamed")) for e in entries}
-        for extra in rec.get("_tail_metrics") or ():
-            if str(extra.get("metric", "unnamed")) not in seen:
-                entries.append(extra)
-                seen.add(str(extra.get("metric", "unnamed")))
-        if not entries:
-            skipped += 1
-            continue
-        for parsed in entries:
-            metric = str(parsed.get("metric", "unnamed"))
-            series.setdefault(metric, []).append(
-                (int(rec.get("n", -1)), float(parsed["value"]),
-                 str(rec.get("_path", ""))))
-    metrics = []
-    regressions = []
-    for metric in sorted(series):
-        pts = series[metric]
-        n, value, path = pts[-1]
-        prior = [v for _, v, _ in pts[:-1]]
-        entry: dict = {"metric": metric, "n": n, "value": value,
-                       "direction": metric_direction(metric),
-                       "history": len(prior), "path": path}
-        if len(prior) < min_history:
-            entry["status"] = "insufficient_history"
-            metrics.append(entry)
-            continue
-        band = fit_noise_band(prior, mad_k=mad_k, rel_floor=rel_floor)
-        entry.update(band)
-        bad = (value > band["hi"] if entry["direction"] == "lower"
-               else value < band["lo"])
-        entry["status"] = "regression" if bad else "ok"
-        if bad:
-            bound = band["hi" if entry["direction"] == "lower" else "lo"]
-            regressions.append(
-                f"{metric}: r{n} = {value:g} is outside the noise band "
-                f"(bound {bound:g}; median {band['median']:g}, "
-                f"MAD {band['mad']:g}, k={mad_k:g}, "
-                f"floor {rel_floor:.0%})")
-        metrics.append(entry)
-    return {"ok": not regressions, "metrics": metrics,
-            "regressions": regressions, "skipped_records": skipped}
 
 
 # ---------------------------------------------------------------------------

@@ -49,7 +49,7 @@ def _stat_subl(nq: int) -> int:
     TPU block tiling needs the last two block dims divisible by (8, 128)
     or equal to the array dims, so a (1, block_q) per-row block is
     illegal whenever nq > 1, and the whole (nq, block_q) plane OOMs the
-    16 MB scoped-vmem stack at T=512k (KERNELS_r03 first run: 2 MB x2
+    16 MB scoped-vmem stack at T=512k (2026-08-01, first run: 2 MB x2
     stats x double-buffering). Group-of-8 rows satisfies the sublane
     tile and keeps stat VMEM residency T-independent (8*block_q f32)."""
     return min(8, nq)

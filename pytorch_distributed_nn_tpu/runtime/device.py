@@ -6,9 +6,8 @@ test route, nothing for the chip. Three things do need code:
 
 - :func:`configure_compile_cache` — where JAX's persistent compilation
   cache lives. Every entry point calls it first;
-- :func:`require_tpu` — the one device check of the measuring entry
-  points (``bench.py``'s modes, ``chip_smoke.py``): no chip is an
-  error, never a CPU number;
+- :func:`require_tpu` — the device check of ``chip_smoke.py``: no chip
+  is an error, never a CPU number;
 - :func:`claim_chip` — a chip belongs to one process at a time, so a
   second process that wants it on the same host fails with a message
   instead of waiting inside backend initialisation.
@@ -44,15 +43,11 @@ def _cpu_asked_for() -> bool:
     return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
 
 
-def require_tpu(*, explicit_cpu_ok: bool = False) -> jax.Device:
-    """``jax.devices()[0]``, which must be a TPU. With
-    ``explicit_cpu_ok`` a CPU device passes when ``JAX_PLATFORMS=cpu``
-    asked for it by name (the tests' route through ``bench.py``); a CPU
-    that JAX fell back to is refused either way."""
+def require_tpu() -> jax.Device:
+    """``jax.devices()[0]``, which must be a TPU: a CPU is refused
+    whether ``JAX_PLATFORMS`` asked for it or JAX fell back to it."""
     dev = jax.devices()[0]
     if dev.platform == "tpu":
-        return dev
-    if explicit_cpu_ok and dev.platform == "cpu" and _cpu_asked_for():
         return dev
     raise RuntimeError(
         f"no TPU: jax.devices()[0] is {dev.platform}:{dev.device_kind} "

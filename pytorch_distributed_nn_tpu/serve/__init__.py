@@ -53,9 +53,8 @@ Layering (each module's docstring carries its own contract):
   helper (:func:`runtime.failure.store_call`), torn chunks re-pulled
   then degraded to a cold re-prefill — a request never wedges.
 
-CLI: ``scripts/serve.py``, ``scripts/fleet_deploy.py``; load test:
-``bench.py --serve`` / ``bench.py --fleet [--fleet-procs N]`` /
-``bench.py --fleet --disagg[-procs]``; docs: ``docs/serving.md``.
+CLI: ``scripts/serve.py``, ``scripts/fleet_deploy.py``; measured by
+``benchmark/run.py``'s serving cells; docs: ``docs/serving.md``.
 """
 
 from pytorch_distributed_nn_tpu.serve.autoscale import (  # noqa: F401

@@ -46,8 +46,7 @@ Env contract: ``TPUNN_AUTOSCALE=1`` arms the defaults;
 ``TPUNN_AUTOSCALE=max_replicas=6:burn_up=1.5`` overrides
 :class:`AutoscaleConfig` fields (``:``-separated ``key=value``; a
 typo'd key fails loudly, never silently scales nothing). Validation:
-``bench.py --autoscale`` (live fleet) and
-``bench.py --autoscale --selftest`` (simulated fleet, tier-1).
+tests/test_autoscale.py (simulated fleet, tier-1).
 """
 
 from __future__ import annotations
@@ -536,9 +535,9 @@ class FleetAutoscaler:
     :meth:`step` refreshes fleet-wide pressure from the router's own
     gauges, consults the watchtower's burn windows, and applies any
     resulting decision through :meth:`Fleet.scale_to`. Drive it from
-    the thread that owns the fleet (bench's replay tick, a serving
-    front-end's poll loop) — never from a replica worker, which must
-    not take the fleet lock."""
+    the thread that owns the fleet (a serving front-end's poll loop,
+    the process fleet's supervision pass) — never from a replica
+    worker, which must not take the fleet lock."""
 
     def __init__(self, fleet, scaler: Autoscaler) -> None:
         self.fleet = fleet

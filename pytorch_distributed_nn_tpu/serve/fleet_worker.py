@@ -48,8 +48,8 @@ exactly like the training agent does.
 
 Backends: ``stub`` decodes with :func:`serve.stub.stub_next_token`
 (deterministic, model-free — restart drills and tier-1); ``tiny``
-builds the same deterministic tiny model ``bench.py --serve-tiny``
-uses and drives a real :class:`serve.engine.ServingEngine`;
+builds a deterministic tiny model (:func:`build_tiny_model`) and
+drives a real :class:`serve.engine.ServingEngine`;
 ``preset`` builds a REAL model from a named :data:`config.PRESETS`
 entry (``--preset``, validated with an error naming every available
 preset) with optional Orbax params at ``--ckpt``, behind the same
@@ -157,8 +157,8 @@ class _StubBackend:
 
 class _EngineBackend:
     """A real :class:`serve.engine.ServingEngine` over the
-    deterministic tiny model (``bench.py``'s ``--serve-tiny`` shape):
-    same config, same seed-0 params in every process, so greedy decode
+    deterministic tiny model (:func:`build_tiny_model`): same
+    config, same seed-0 params in every process, so greedy decode
     is bit-identical across replicas and coordinator lives."""
 
     def __init__(self, *, max_slots: int, max_seq_len: int,
@@ -265,9 +265,9 @@ class _EngineBackend:
 
 def build_tiny_model():
     """The deterministic tiny decoder every process-backed replica
-    serves: the exact ``bench.py --serve-tiny`` shape with seed-0
-    init — identical params in every process by construction, so the
-    process fleet's greedy streams are bit-comparable to the threaded
+    serves: a 4-layer Llama at d_model 256 with seed-0 init —
+    identical params in every process by construction, so the process
+    fleet's greedy streams are bit-comparable to the threaded
     fleet's."""
     import jax
     import jax.numpy as jnp

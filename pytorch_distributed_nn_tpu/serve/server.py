@@ -22,7 +22,7 @@ Synthetic clients, both canonical load shapes:
 - :func:`open_loop_client` — requests arrive on their own schedule
   (a fixed metronome, or seeded exponential gaps — a true Poisson
   process) regardless of completions: the model of external traffic,
-  the one that can actually overload the server (bench.py --serve);
+  the one that can actually overload the server;
   richer shapes (diurnal, flash crowds, tenant mixes) live in
   :mod:`serve.traffic`;
 - :func:`closed_loop_client` — N users, each submits, waits, repeats:

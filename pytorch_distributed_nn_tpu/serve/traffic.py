@@ -465,19 +465,13 @@ def prompt_tokens(rec: dict, vocab_size: int) -> np.ndarray:
 
 def replay_trace(trace: list[dict], submit: Callable,
                  *, vocab_size: int, realtime: bool = True,
-                 time_scale: float = 1.0,
-                 on_tick: Optional[Callable] = None) -> list:
+                 time_scale: float = 1.0) -> list:
     """Drive a live service with a trace. ``submit(prompt, max_new)``
     adapts the target — ``lambda p, n: server.submit(p, n)`` or
     ``lambda p, n: fleet.submit(p, n)``. ``realtime=True`` sleeps to
     each record's arrival offset (``time_scale`` compresses/stretches
     the clock); ``realtime=False`` submits the backlog at once (the
-    saturation probe). ``on_tick(t)`` — called once per arrival with
-    the record's *virtual* trace time, before it submits — gives a
-    controller a deterministic clock on the replay thread (Helm's
-    ``FleetAutoscaler.step`` rides it in ``bench.py --autoscale``;
-    workers must never drive control themselves). Returns the submit
-    handles in trace order.
+    saturation probe). Returns the submit handles in trace order.
 
     Records carrying Prism decode keys (``temperature``/``n`` +
     ``decode_seed``, or ``stream``) submit with the matching
@@ -493,8 +487,6 @@ def replay_trace(trace: list[dict], submit: Callable,
             wait = float(rec["t"]) / time_scale - (time.monotonic() - t0)
             if wait > 0:
                 time.sleep(wait)
-        if on_tick is not None:
-            on_tick(float(rec["t"]))
         kw = {}
         if "temperature" in rec or "n" in rec:
             kw["decode"] = DecodeSpec(

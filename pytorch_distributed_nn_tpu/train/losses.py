@@ -194,8 +194,8 @@ def get_loss_fn(dataset_name: str, *, label_smoothing: float = 0.0):
 def model_nll(model, params, batches) -> float:
     """Teacher-forced mean per-token NLL of a causal LM over an
     iterable of (tokens, targets) batches — the whole-model quality
-    metric behind ``bench.py --metric quality`` (int8-vs-bf16 NLL
-    delta; VERDICT r4 Missing #3). Works for float and int8-quantized
+    metric (int8-vs-bf16 NLL delta, tests/test_quality.py; VERDICT r4
+    Missing #3). Works for float and int8-quantized
     param trees alike (the model's lm_head emits f32 logits either
     way). Perplexity = exp(return value).
 

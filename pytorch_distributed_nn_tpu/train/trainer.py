@@ -236,8 +236,7 @@ class Trainer:
             return self._train_loop(it, steps)
         finally:
             # join the prefetch producer: a daemon thread left blocked
-            # mid-queue-put at interpreter exit SIGABRTs (the same race
-            # bench.py's loader loop guards against)
+            # mid-queue-put at interpreter exit SIGABRTs
             it.close()
 
     def _train_loop(self, it, steps: int) -> list[StepRecord]:

@@ -51,17 +51,6 @@ class MetricsLogger:
         self._fh.write(json.dumps(rec) + "\n")
         self._fh.flush()
 
-    def emit_benchmark(self, metric: str, value: float, unit: str,
-                       vs_baseline: float | None = None,
-                       **extra: Any) -> dict:
-        """The BASELINE.json schema line the driver's bench harness
-        expects (plus any extra fields, e.g. mfu); returned so callers
-        can also print it bare."""
-        rec = {"metric": metric, "value": value, "unit": unit,
-               "vs_baseline": vs_baseline, **extra}
-        self.emit("benchmark", **rec)
-        return rec
-
     def close(self) -> None:
         if self._fh is not None and self._fh not in (sys.stdout,
                                                      sys.stderr):

@@ -6,7 +6,7 @@ collective-slice parser, ``bus_bandwidth``) are now part of the Xray
 subsystem (:mod:`pytorch_distributed_nn_tpu.obs.xray`), which adds
 anomaly-triggered capture, per-op attribution, and compile telemetry
 on top of them. This shim re-exports the original names so existing
-imports (bench.py, tests, notebooks) keep working unchanged.
+imports (scripts, tests, notebooks) keep working unchanged.
 """
 
 from __future__ import annotations

@@ -479,8 +479,8 @@ def print_capacity_table(events: list[dict], last: int,
                  if e.get("event") == "capacity_plan"), None)
     if not (rungs or fronts or plan):
         if requested:
-            print("\nno capacity events found (write them with "
-                  "bench.py --capacity --capacity-out FILE)")
+            print("\nno capacity events found (write a plan's "
+                  "obs.capacity.report_events, one JSON line each)")
         return False
 
     print("\n== capacity frontier (Skyline) ==")
@@ -555,8 +555,9 @@ def print_autoscale_table(events: list[dict], last: int,
             if e.get("event") == "autoscale_decision"]
     if not decs:
         if requested:
-            print("\nno autoscale decisions found (write them with "
-                  "bench.py --autoscale --autoscale-out FILE)")
+            print("\nno autoscale decisions found (write each line "
+                  "of Autoscaler.journal_jsonl() with "
+                  "event=autoscale_decision added)")
         return False
 
     print("\n== autoscale decisions (Helm) ==")

@@ -187,8 +187,8 @@ def _render(spans: list[dict], args) -> int:
 def _drill() -> tuple[list[dict], float]:
     """One traced request through a disaggregated fleet with the first
     KV transfer killed mid-stream. Returns (spans, measured e2e
-    seconds). The tiny 2-layer llama is the bench.py --fleet --disagg
-    --selftest shape: CPU-scale, seed-pinned, greedy."""
+    seconds). The tiny 2-layer llama is tests/test_disagg.py's:
+    CPU-scale, seed-pinned, greedy."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -16,7 +16,7 @@ Two modes over the same engine (:mod:`obs.watchtower`):
 A third mode audits Helm instead of the detectors:
 
 - **--autoscale**: shadow-replay a recorded decision journal
-  (``bench.py --autoscale --autoscale-out``) through the REAL policy:
+  (``Autoscaler.journal_jsonl()``) through the REAL policy:
   every ``autoscale_decision`` record carries its spec, evidence, and
   pre-decision state, so :func:`serve.autoscale.replay_decision`
   re-derives the verdict standalone and any divergence from what the

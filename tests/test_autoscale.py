@@ -6,6 +6,7 @@ shadow audit), armed-but-idle inertness, elastic ``Fleet.scale_to``
 with the warm-before-READY join gate, and the ``TPUNN_WATCH`` burn
 window configuration the loop reads."""
 
+import functools
 import json
 import subprocess
 import sys
@@ -246,50 +247,125 @@ def test_decide_flapping_load_never_scales():
 # convergence, replay
 # ---------------------------------------------------------------------------
 
-_TRAFFIC = ("diurnal@rps=5:duration_s=14:amplitude=0.3:period_s=14;"
-            "flash@at_s=4:peak=4:ramp_s=1:hold_s=3;"
-            "tenant@name=chat:weight=1:prompt_med=12:prompt_sigma=0.5"
-            ":prompt_max=40:out_med=8:out_sigma=0.4:out_max=16")
-_POLICY = ("min_replicas=1:max_replicas=5:up_consecutive=2"
-           ":down_consecutive=3:cooldown_up_s=1.5:cooldown_down_s=4"
-           ":eval_interval_s=1")
 _SVC = dict(slots=2, prefill_tps=400.0, decode_tps=30.0, max_wait_s=3.0)
 
+# Two loops. The short one: 14 s, one tenant, the forecast given. The
+# acceptance drill's: a 30 s diurnal with a flash crowd over t=8..16
+# and two tenants, room for a full scale-up -> hold -> scale-down
+# cycle, and no forecast: it is taken from a Skyline sweep of the same
+# service model. ``kill_s`` falls inside each one's flash crowd.
+_SHORT = dict(
+    traffic=("diurnal@rps=5:duration_s=14:amplitude=0.3:period_s=14;"
+             "flash@at_s=4:peak=4:ramp_s=1:hold_s=3;"
+             "tenant@name=chat:weight=1:prompt_med=12:prompt_sigma=0.5"
+             ":prompt_max=40:out_med=8:out_sigma=0.4:out_max=16"),
+    policy=("min_replicas=1:max_replicas=5:up_consecutive=2"
+            ":down_consecutive=3:cooldown_up_s=1.5:cooldown_down_s=4"
+            ":eval_interval_s=1"),
+    burn_fast_s=3.0, burn_slow_s=12.0, burn_min_events=4,
+    duration_s=14.0, tail_s=20.0, kill_s=6, forecast=2)
+_DRILL = dict(
+    traffic=("diurnal@rps=6:duration_s=30:amplitude=0.3:period_s=30;"
+             "flash@at_s=8:peak=5:ramp_s=2:hold_s=6;"
+             "tenant@name=chat:weight=3:prompt_med=12:prompt_sigma=0.5"
+             ":prompt_max=40:out_med=8:out_sigma=0.4:out_max=16;"
+             "tenant@name=batch:weight=1:prompt=zipf:prompt_a=1.5"
+             ":prompt_max=40:out_med=12:out_max=16"),
+    policy=("min_replicas=1:max_replicas=6:up_consecutive=2"
+            ":down_consecutive=4:cooldown_up_s=2:cooldown_down_s=6"
+            ":eval_interval_s=1"),
+    burn_fast_s=4.0, burn_slow_s=16.0, burn_min_events=5,
+    duration_s=30.0, tail_s=30.0, kill_s=10)
+_LOOPS = [pytest.param(_SHORT, id="short"),
+          pytest.param(_DRILL, id="drill")]
 
-def _closed_loop(kill=None, forecast=2):
-    wcfg = watchtower.WatchConfig(
-        ttft_slo_s=0.25, token_slo_s=0.1, burn_fast_s=3.0,
-        burn_slow_s=12.0, burn_threshold=2.0, burn_min_events=4)
-    tower = watchtower.Watchtower(wcfg, dump_on_page=False)
+
+@functools.cache
+def _drill_forecast():
+    plan = capacity.plan_capacity(
+        traffic.parse_spec(_DRILL["traffic"]),
+        replica_counts=(1, 2, 3, 4, 5, 6), rates=(0.5, 1.0, 1.5, 2.0),
+        make_run_rung=lambda n: capacity.simulated_run_rung(n, **_SVC),
+        seed=7)
+    needed = (plan["replicas_needed"].get("interactive")
+              or {}).get("replicas")
+    assert needed, \
+        f"forecast found no sustainable count: {plan['replicas_needed']}"
+    return needed
+
+
+def _forecast(loop):
+    return loop.get("forecast") or _drill_forecast()
+
+
+def _tower(loop):
+    return watchtower.Watchtower(watchtower.WatchConfig(
+        ttft_slo_s=0.25, token_slo_s=0.1, burn_fast_s=loop["burn_fast_s"],
+        burn_slow_s=loop["burn_slow_s"], burn_threshold=2.0,
+        burn_min_events=loop["burn_min_events"]), dump_on_page=False)
+
+
+def _closed_loop(kill=None, loop=_SHORT, tower=None):
     scaler = autoscale.Autoscaler(
-        autoscale.parse_spec(_POLICY), tower=tower, feed_tower=True,
-        forecast_replicas=forecast, spec=_POLICY)
-    trace = traffic.generate_trace(traffic.parse_spec(_TRAFFIC), seed=7)
+        autoscale.parse_spec(loop["policy"]),
+        tower=tower or _tower(loop), feed_tower=True,
+        forecast_replicas=_forecast(loop),
+        spec=loop["policy"])
+    trace = traffic.generate_trace(
+        traffic.parse_spec(loop["traffic"]), seed=7)
     rep = capacity.simulate_autoscaled_fleet(
         trace, controller=autoscale.SimController(scaler, target=1),
-        replicas=1, warmup_s=0.25, tick_s=0.5, duration_s=14.0,
-        tail_s=20.0, chaos_spec=kill, **_SVC)
+        replicas=1, warmup_s=0.25, tick_s=0.5,
+        duration_s=loop["duration_s"], tail_s=loop["tail_s"],
+        chaos_spec=kill, **_SVC)
     return scaler, rep
 
 
-def test_journal_is_byte_identical_and_loop_converges():
-    s1, r1 = _closed_loop()
-    s2, r2 = _closed_loop()
-    j = s1.journal_jsonl()
-    assert j and j == s2.journal_jsonl()
-    assert json.dumps(r1, sort_keys=True) == json.dumps(
-        r2, sort_keys=True)
-    ups = [d for d in s1.decisions if d.action == autoscale.SCALE_UP]
+@pytest.mark.parametrize("loop", _LOOPS)
+def test_journal_is_byte_identical_and_loop_converges(loop):
+    needed = _forecast(loop)
+    tw1 = _tower(loop)
+    s1, r1 = _closed_loop(loop=loop, tower=tw1)
+    s2, r2 = _closed_loop(loop=loop)
+    j1 = s1.journal_jsonl()
+    assert j1 and j1 == s2.journal_jsonl(), \
+        "decision journal not byte-identical twice in a row"
+    assert (json.dumps(r1, sort_keys=True)
+            == json.dumps(r2, sort_keys=True)), \
+        "autoscaled-fleet report not identical twice in a row"
+
+    ups = [d for d in s1.decisions
+           if d.action == autoscale.SCALE_UP]
     downs = [d for d in s1.decisions
              if d.action == autoscale.SCALE_DOWN]
-    assert ups and downs, (len(ups), len(downs))
+    assert ups and downs, \
+        f"no full cycle: ups={len(ups)} downs={len(downs)}"
     assert any(tag in ups[0].reason
-               for tag in ("burn", "queue", "kv")), ups[0].reason
-    assert r1["rejects"] == 0
+               for tag in ("burn", "queue", "kv")), \
+        f"first scale-up names no pressure evidence: {ups[0].reason}"
+    assert ups[0].t < downs[0].t, "scale-down preceded scale-up"
+    # the loop must keep pace with the pager: Helm's burn_up (1.0x)
+    # undercuts the pager's threshold (2.0x), so the first scale-up
+    # lands within one fast window of the first page, and once the
+    # last scale-up settles the page condition is extinguished for
+    # good — the pager re-arms and stays quiet
+    pages = [a for a in tw1.alerts if a.kind == "slo_burn_rate"
+             and a.severity == watchtower.PAGE]
+    if pages:
+        assert ups[0].t <= pages[0].t + loop["burn_fast_s"], \
+            f"Helm scaled at t={ups[0].t}, more than one fast window " \
+            f"after the page at t={pages[0].t}"
+        assert max(a.t for a in pages) <= ups[-1].t + loop["burn_slow_s"], \
+            f"pages kept firing after Helm settled: " \
+            f"{[round(a.t, 3) for a in pages]} vs last scale-up " \
+            f"t={ups[-1].t}"
+    assert r1["rejects"] == 0, \
+        f"rejects under closed-loop control: {r1['rejects']}"
     # scale-down floor == forecast: the loop lands within ±1 of Skyline
-    assert abs(r1["final_target"] - 2) <= 1, r1["final_target"]
+    assert abs(r1["final_target"] - needed) <= 1, \
+        f"steady state {r1['final_target']} vs forecast {needed}"
     # the journal carries the complete evidence snapshot per decision
-    rec = json.loads(j.splitlines()[0])
+    rec = json.loads(j1.splitlines()[0])
     assert set(rec) >= {"action", "reason", "evidence", "state",
                         "spec", "t", "seq", "from_replicas",
                         "to_replicas"}
@@ -298,28 +374,36 @@ def test_journal_is_byte_identical_and_loop_converges():
                                     "forecast_replicas"}
 
 
-def test_chaos_kill_mid_spike_is_absorbed_and_journaled():
-    """Replica 0 dies at t=6, mid-flash-crowd, while Helm is already
-    scaling into the spike: the drill must cost zero rejects, name the
+@pytest.mark.parametrize("loop", _LOOPS)
+def test_chaos_kill_mid_spike_is_absorbed_and_journaled(loop):
+    """Replica 0 dies mid-flash-crowd, while Helm is already scaling
+    into the spike: the drill must cost zero rejects, name the
     failover window, leave a visible trace in the journaled evidence,
     and still converge to the forecast."""
-    s_clean, _ = _closed_loop()
-    sk, rk = _closed_loop(kill="kill_replica@replica=0:after_s=6")
+    needed = _forecast(loop)
+    s_clean, _ = _closed_loop(loop=loop)
+    sk, rk = _closed_loop(
+        kill=f"kill_replica@replica=0:after_s={loop['kill_s']}",
+        loop=loop)
     wins = rk["failover_windows"]
-    assert any(w["replica"] == 0 and w["t_down"] == 6.0
-               for w in wins), wins
-    assert rk["rejects"] == 0
-    assert abs(rk["final_target"] - 2) <= 1
+    assert any(w["replica"] == 0 and w["t_down"] == loop["kill_s"]
+               and w.get("t_recovered") is not None
+               for w in wins), f"failover window unnamed: {wins}"
+    assert rk["rejects"] == 0, \
+        f"rejects during the kill drill: {rk['rejects']}"
+    assert abs(rk["final_target"] - needed) <= 1, \
+        f"no reconvergence after kill: {rk['final_target']}"
     assert sk.journal_jsonl() != s_clean.journal_jsonl(), \
         "kill drill left no trace in the decision journal"
 
 
-def test_every_journal_line_replays_standalone():
-    s, _ = _closed_loop()
-    for line in s.journal_jsonl().splitlines():
-        rec = json.loads(line)
+@pytest.mark.parametrize("loop", _LOOPS)
+def test_every_journal_line_replays_standalone(loop):
+    s, _ = _closed_loop(loop=loop)
+    for rec in map(json.loads, s.journal_jsonl().splitlines()):
         assert autoscale.replay_decision(rec) == (
-            rec["action"], rec["reason"], rec["to_replicas"])
+            rec["action"], rec["reason"], rec["to_replicas"]), \
+            f"journal line does not replay: {rec['seq']}"
 
 
 def test_tampered_journal_record_diverges_on_replay():

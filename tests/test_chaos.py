@@ -102,7 +102,7 @@ def test_hooks_are_noops_when_unset():
 
 
 def test_disabled_hook_overhead_is_negligible():
-    """bench --goodput A/B proxy: the disabled fast path is one global
+    """The disabled fast path is one global
     load + one comparison — 1M calls must stay far under any step
     budget (generous bound for loaded CI hosts)."""
     t0 = time.perf_counter()
@@ -317,32 +317,6 @@ def test_corrupt_ckpt_rank_filter(tmp_path):
 
     eng.checkpoint_saved(_Mgr(), 2)  # no-op: rank filter
     assert _chaos_ring_events() == []
-
-
-def test_goodput_restart_context(monkeypatch):
-    """bench.py --goodput satellite: the goodput record carries the
-    incarnation, the chaos arm state, and (when present in the
-    registry) the agent's restart/backoff gauges."""
-    from pytorch_distributed_nn_tpu.obs import runtime_gauges
-    from pytorch_distributed_nn_tpu.obs.goodput import restart_context
-
-    ctx = restart_context()
-    assert ctx["incarnation"] == 0
-    assert ctx["chaos_enabled"] is False
-    assert "agent_restarts_total" not in ctx  # no agent in this process
-
-    monkeypatch.setenv("TPUNN_RESTART", "2")
-    monkeypatch.setenv(chaos.ENV_CHAOS, "slow@rank=0:ms=1")
-    chaos.maybe_init(rank=0)
-    runtime_gauges.export_restart_gauges(
-        incarnations=3, restarts=2, preempt_restarts=1,
-        backoff_seconds_total=3.5, last_exit_code=43)
-    ctx = restart_context()
-    assert ctx["incarnation"] == 2
-    assert ctx["chaos_enabled"] is True
-    assert ctx["agent_restarts_total"] == 2.0
-    assert ctx["agent_preempt_restarts_total"] == 1.0
-    assert ctx["agent_backoff_seconds_total"] == 3.5
 
 
 # ---------------------------------------------------------------------------

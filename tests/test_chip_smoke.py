@@ -265,20 +265,15 @@ def test_compile_cache_env_reaches_a_fresh_process(tmp_path):
 # -- the shared device check -------------------------------------------------
 
 
-@pytest.mark.parametrize("platforms,explicit_cpu_ok,passes", [
-    ("cpu", True, True),        # a test asked for the CPU by name
-    ("cpu", False, False),      # chip_smoke: never
-    ("", True, False),          # unset: a CPU here is a fallback
-    ("tpu,cpu", True, False),   # the chip machine's setting, no chip
+@pytest.mark.parametrize("platforms", [
+    "cpu",      # asked for by name: still no chip
+    "",         # unset: a CPU here is a fallback
+    "tpu,cpu",  # the chip machine's setting, no chip
 ])
-def test_require_tpu(monkeypatch, platforms, explicit_cpu_ok, passes):
+def test_require_tpu(monkeypatch, platforms):
     monkeypatch.setenv("JAX_PLATFORMS", platforms)
-    if passes:
-        got = device.require_tpu(explicit_cpu_ok=explicit_cpu_ok)
-        assert got.platform == "cpu"
-    else:
-        with pytest.raises(RuntimeError, match="no TPU"):
-            device.require_tpu(explicit_cpu_ok=explicit_cpu_ok)
+    with pytest.raises(RuntimeError, match="no TPU"):
+        device.require_tpu()
 
 
 # -- one process for each chip -----------------------------------------------

@@ -64,6 +64,18 @@ def _golden(model, params, prompt, n):
         0, len(prompt):]
 
 
+def _unified(model, params, prompts, budgets):
+    """What ``Fleet(replicas=P+D)`` answers for the same workload: the
+    other reference a disaggregated fleet is held to."""
+    fleet = Fleet(model, params, replicas=3, max_slots=2,
+                  max_seq_len=64, block_size=16)
+    tickets = [fleet.submit(p, n) for p, n in zip(prompts, budgets)]
+    fleet.run_until_idle()
+    for t in tickets:
+        assert t.ok, (t.status, t.reject_reason)
+    return [list(t.tokens) for t in tickets]
+
+
 def _fleet_ring(op=None):
     evs = [e for e in flight.get_recorder().snapshot()
            if e["kind"] == "fleet"]
@@ -202,18 +214,18 @@ def test_on_transfer_is_inert_when_chaos_unset():
 # Fleet, synchronous drive (deterministic, no threads)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow  # ~10s: pays the serve jit warmup compile
 def test_disagg_sync_golden_streams_blocks_and_reuses_warmth(tiny_llama):
     """The acceptance criterion, sunny side: a disaggregated fleet's
     stitched greedy output is bit-identical to sequential ``generate``
-    (budget 1 included — it finalizes at the handoff without a decode
-    leg), the prompt's KV blocks travel through the collectives choke
-    point (wire bytes + flight ring for free), and a repeat prompt
-    lands on the already-warm decode replica without a second
-    transfer."""
+    and to the unified fleet (budget 1 included — it finalizes at the
+    handoff without a decode leg), the prompt's KV blocks travel
+    through the collectives choke point (wire bytes + flight ring for
+    free), and a repeat prompt lands on the already-warm decode
+    replica without a second transfer."""
     model, params = tiny_llama
     prompts = _prompts([34, 6, 37, 9], seed=7)
     budgets = [2, 8, 1, 6]
+    golden = _unified(model, params, prompts, budgets)
     with collectives.recording() as records:
         fleet = Fleet(model, params, prefill=1, decode=2, max_slots=2,
                       max_seq_len=64, block_size=16, max_queue=16)
@@ -224,6 +236,8 @@ def test_disagg_sync_golden_streams_blocks_and_reuses_warmth(tiny_llama):
             assert t.ok, (t.status, t.reject_reason)
             np.testing.assert_array_equal(
                 t.tokens, _golden(model, params, p, n))
+        got = [list(t.tokens) for t in tickets]
+        assert got == golden, f"disagg output diverged:\n{got}\n{golden}"
         # the long prompts (>= 2 full blocks) streamed their chains
         assert any(t["outcome"] == "ok" for t in fleet.transfers)
         n_before = len(fleet.transfers)
@@ -256,17 +270,17 @@ def test_disagg_sync_golden_streams_blocks_and_reuses_warmth(tiny_llama):
     assert roles == {"r0": "prefill", "r1": "decode", "r2": "decode"}
 
 
-@pytest.mark.slow  # ~10s: jit warmup + chaos drill
 def test_kill_transfer_failover_is_output_invariant(tiny_llama):
     """The acceptance criterion, rainy side: a source replica dying
     mid-transfer (chaos ``kill_transfer@``) burns the wire bytes, goes
     DEAD, and the decode leg re-prefills cold on a survivor — the
     stitched output does not change by a single token."""
     model, params = tiny_llama
-    chaos.maybe_init("kill_transfer@step=1", rank=0, incarnation=0,
-                     seed=0)
     prompts = _prompts([34, 6, 37, 9], seed=7)
     budgets = [2, 8, 3, 6]
+    golden = _unified(model, params, prompts, budgets)
+    chaos.maybe_init("kill_transfer@step=1", rank=0, incarnation=0,
+                     seed=0)
     fleet = Fleet(model, params, prefill=2, decode=2, max_slots=2,
                   max_seq_len=64, block_size=16, max_queue=16)
     tickets = [fleet.submit(p, n) for p, n in zip(prompts, budgets)]
@@ -275,6 +289,9 @@ def test_kill_transfer_failover_is_output_invariant(tiny_llama):
         assert t.ok, (t.status, t.reject_reason)
         np.testing.assert_array_equal(
             t.tokens, _golden(model, params, p, n))
+    got = [list(t.tokens) for t in tickets]
+    assert got == golden, \
+        f"kill_transfer broke bit-identity:\n{got}\n{golden}"
     assert any(t["outcome"] == "failed" for t in fleet.transfers), \
         "the drill must actually kill a transfer"
     reg = obs.get_registry()
@@ -283,5 +300,5 @@ def test_kill_transfer_failover_is_output_invariant(tiny_llama):
     # failed transfers still burned the wire: bytes are on the books
     failed = [t for t in fleet.transfers if t["outcome"] == "failed"]
     assert all(t["bytes"] > 0 for t in failed)
-    assert any("state:dead" in e["op"] for e in _fleet_ring()), \
+    assert any(e["op"] == "state:dead" for e in _fleet_ring()), \
         "the transfer source must be declared dead"

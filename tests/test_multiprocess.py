@@ -559,7 +559,13 @@ def test_injected_hang_dumps_flight_rings_and_doctor_names_rank(tmp_path):
         [str(script)],
         LaunchConfig(
             nprocs=2,
-            heartbeat_timeout_s=1.0,
+            # the detector's start-up grace IS this timeout: it has to
+            # cover a worker's imports (2 s on an idle host, several
+            # times that beside five other test workers), or both
+            # ranks read stale before either has a beat thread to
+            # serve the dump request. The hang itself is still found
+            # by the 0.5 s progress window at step 7.
+            heartbeat_timeout_s=15.0,
             heartbeat_interval_s=0.1,
             progress_timeout_s=0.5,
             flight_dir=str(tmp_path),

@@ -333,6 +333,21 @@ def test_detector_gauges(registry):
     assert snap['worker_missed_beats_total{rank="1"}'] == 1
 
 
+def test_restart_gauges(registry):
+    from pytorch_distributed_nn_tpu.obs import runtime_gauges
+
+    assert "agent_restarts_total" not in registry.snapshot()
+    runtime_gauges.export_restart_gauges(
+        incarnations=3, restarts=2, preempt_restarts=1,
+        backoff_seconds_total=3.5, last_exit_code=43, registry=registry)
+    snap = registry.snapshot()
+    assert snap["agent_incarnations_total"] == 3.0
+    assert snap["agent_restarts_total"] == 2.0
+    assert snap["agent_preempt_restarts_total"] == 1.0
+    assert snap["agent_backoff_seconds_total"] == 3.5
+    assert snap["agent_last_exit_code"] == 43.0
+
+
 def test_cross_host_aggregation(registry):
     store = _FakeStore()
     registry.counter("train_steps_total").inc(10)

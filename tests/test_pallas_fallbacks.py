@@ -160,7 +160,7 @@ def test_flash_kernels_grouped_stats_interpret(causal):
     (rows 8-11), plus GQA group addressing — the geometry where the
     qi % subl row store, the qi // subl group maps, and the causal
     clamp in stat_fix can all go wrong while every nq==1 test stays
-    green (KERNELS_r03: the per-qi-row variant only failed on chip)."""
+    green (2026-08-01: the per-qi-row variant only failed on chip)."""
     from pytorch_distributed_nn_tpu.ops.pallas.flash_attention import (
         _attention_reference,
         _flash_bhtd,

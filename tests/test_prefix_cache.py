@@ -440,6 +440,7 @@ def test_engine_golden_prefix_on_equals_off_equals_generate(tiny_llama):
     st = eng_on.prefix_cache.stats()
     assert st["prefix_hits"] >= len(wave2)
     assert st["prefix_tokens_saved"] >= 24 * len(wave2)
+    assert eng_on.summary()["prefix_hit_rate"] > 0, eng_on.summary()
     assert eng_off.prefix_cache is None
     # every completed request reports what it skipped
     cached = [c.get("cached_tokens", 0) for c in eng_on.completed]

@@ -29,6 +29,22 @@ SPEC = ("diurnal@rps=6:duration_s=8:amplitude=0.5:period_s=8;"
         ":prompt_max=40:out_med=12:out_max=16")
 
 
+# the capacity drill's: a shorter diurnal, the flash crowd holding
+# over t=3..4
+DRILL_SPEC = ("diurnal@rps=4:duration_s=6:amplitude=0.5:period_s=6;"
+              "flash@at_s=3:peak=3:ramp_s=1:hold_s=1;"
+              "tenant@name=chat:weight=3:prompt_med=12:prompt_sigma=0.5"
+              ":prompt_max=40:out_med=8:out_sigma=0.4:out_max=16;"
+              "tenant@name=batch:weight=1:prompt=zipf:prompt_a=1.5"
+              ":prompt_max=40:out_med=12:out_max=16")
+# (spec, seed) and, for the chaos drill, the mid-flash-crowd second
+# at which replica 0 dies
+_SEEDED = [pytest.param(SPEC, 3, id="spec"),
+           pytest.param(DRILL_SPEC, 7, id="drill")]
+_KILLED = [pytest.param(SPEC, 3, 4.5, id="spec"),
+           pytest.param(DRILL_SPEC, 7, 3.5, id="drill")]
+
+
 @pytest.fixture(autouse=True)
 def _fresh():
     obs.reset_registry()
@@ -92,13 +108,17 @@ def test_maybe_from_env(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_trace_byte_identical_per_seed():
-    spec = traffic.parse_spec(SPEC)
-    a = traffic.trace_to_jsonl(traffic.generate_trace(spec, seed=3))
-    b = traffic.trace_to_jsonl(traffic.generate_trace(spec, seed=3))
-    assert a == b and a  # identical bytes, non-empty
-    c = traffic.trace_to_jsonl(traffic.generate_trace(spec, seed=4))
-    assert c != a
+@pytest.mark.parametrize("text, seed", _SEEDED)
+def test_trace_byte_identical_per_seed(text, seed):
+    spec = traffic.parse_spec(text)
+    t1 = traffic.generate_trace(spec, seed=seed)
+    t2 = traffic.generate_trace(spec, seed=seed)
+    assert traffic.trace_to_jsonl(t1) == traffic.trace_to_jsonl(t2), \
+        "trace JSONL not byte-identical for same spec+seed"
+    assert t1 and {r["tenant"] for r in t1} == {"chat", "batch"}, \
+        f"tenant mix missing: {len(t1)} requests"
+    c = traffic.trace_to_jsonl(traffic.generate_trace(spec, seed=seed + 1))
+    assert c != traffic.trace_to_jsonl(t1)
 
 
 def test_trace_shape_and_scaling():
@@ -351,37 +371,51 @@ def test_kill_all_replicas_rejects_everything_after():
                ) or not late
 
 
-def test_plan_capacity_report_identical_twice():
-    spec = traffic.parse_spec(SPEC)
-    kw = dict(replica_counts=(1, 2), rates=(0.5, 2.0), seed=3)
-    mk = lambda n: capacity.simulated_run_rung(  # noqa: E731
-        n, slots=2, decode_tps=60.0)
-    a = capacity.plan_capacity(spec, make_run_rung=mk, **kw)
+def _plan(spec, seed, kill=None, replica_counts=(1, 2)):
+    # slots=2/decode_tps=60: tight enough that losing 1 of 2 replicas
+    # actually drops the frontier a rung (not just reshapes the window)
+    return capacity.plan_capacity(
+        spec, make_run_rung=lambda n: capacity.simulated_run_rung(
+            n, slots=2, decode_tps=60.0, chaos_spec=kill),
+        chaos_spec=kill, replica_counts=replica_counts,
+        rates=(0.5, 1.0, 2.0, 4.0), seed=seed)
+
+
+@pytest.mark.parametrize("text, seed", _SEEDED)
+def test_plan_capacity_report_identical_twice(text, seed):
+    spec = traffic.parse_spec(text)
+    rep_a = _plan(spec, seed)
     obs.reset_registry()  # gauges re-register; report must not care
-    b = capacity.plan_capacity(spec, make_run_rung=mk, **kw)
-    assert capacity.report_to_json(a) == capacity.report_to_json(b)
-    assert a["replicas_needed"]  # the headline table exists
-    kinds = {e["event"] for e in capacity.report_events(a)}
+    rep_b = _plan(spec, seed)
+    assert (capacity.report_to_json(rep_a)
+            == capacity.report_to_json(rep_b)), \
+        "capacity report not identical twice in a row"
+    assert rep_a["replicas_needed"]  # the headline table exists
+    kinds = {e["event"] for e in capacity.report_events(rep_a)}
     assert kinds == {"capacity_rung", "capacity_frontier",
                      "capacity_plan"}
 
 
-def test_chaos_drill_moves_frontier():
-    spec = traffic.parse_spec(SPEC)
-    kw = dict(replica_counts=(2,), rates=(0.5, 1.0, 2.0, 4.0), seed=3)
-    kill = "kill_replica@replica=0:after_s=4.5"
-    mk = lambda k: (lambda n: capacity.simulated_run_rung(  # noqa: E731
-        n, slots=2, decode_tps=60.0, chaos_spec=k))
-    calm = capacity.plan_capacity(spec, make_run_rung=mk(None), **kw)
-    drill = capacity.plan_capacity(spec, make_run_rung=mk(kill),
-                                   chaos_spec=kill, **kw)
-    f_calm = calm["sweeps"]["2"]["frontier"]["interactive"]
-    f_kill = drill["sweeps"]["2"]["frontier"]["interactive"]
+@pytest.mark.parametrize("text, seed, kill_s", _KILLED)
+def test_chaos_drill_moves_frontier(text, seed, kill_s):
+    spec = traffic.parse_spec(text)
+    kill = f"kill_replica@replica=0:after_s={kill_s}"
+    rep_a = _plan(spec, seed, replica_counts=(2,))
+    rep_k = _plan(spec, seed, kill, replica_counts=(2,))
+    assert (rep_k["sweeps"]["2"]["frontier"]
+            != rep_a["sweeps"]["2"]["frontier"]), \
+        "chaos drill did not move the 2-replica frontier"
+    f_calm = rep_a["sweeps"]["2"]["frontier"]["interactive"]
+    f_kill = rep_k["sweeps"]["2"]["frontier"]["interactive"]
     assert (f_kill or 0.0) < f_calm
-    assert drill["chaos"] == kill
-    wins = [w for r in drill["sweeps"]["2"]["rungs"]
+    assert rep_k["chaos"] == kill
+    wins = [w for r in rep_k["sweeps"]["2"]["rungs"]
             for w in r["failover_windows"]]
-    assert any(w["t_down"] == 4.5 for w in wins)
+    assert any(w["t_down"] == kill_s and w["t_recovered"] is not None
+               for w in wins), f"failover window unnamed: {wins}"
+    evs = capacity.report_events(rep_k)
+    assert any(e["event"] == "capacity_frontier" and e["chaos"] == kill
+               for e in evs)
 
 
 def test_skyline_gauges_registered():

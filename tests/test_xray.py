@@ -12,9 +12,8 @@ perfetto trace) with the wire-byte cross-check and roofline columns,
 compile telemetry end-to-end (log-watch regex → counters → ring
 breadcrumb → recompile_storm naming the re-traced function), the
 newest-trace-by-mtime regression (ISSUE 10 satellite), profiling
-primitive edge cases (StepTimer/time_steps/bus_bandwidth), the ledger
-math (direction-aware bands, torn records), and the chaos acceptance
-drill from the issue.
+primitive edge cases (StepTimer/time_steps/bus_bandwidth), and the
+chaos acceptance drill from the issue.
 """
 
 import glob
@@ -555,76 +554,6 @@ def test_xray_feeds_recompile_storm_through_tower(tmp_path):
               if a.kind == "recompile_storm"]
     assert len(storms) == 1
     assert storms[0].attribution["function"] == "train_step"
-
-
-# ---------------------------------------------------------------------------
-# Perf-regression ledger (bench.py --ledger)
-# ---------------------------------------------------------------------------
-
-def _rec(n, metric, value, path="x"):
-    parsed = None if value is None else {"metric": metric, "value": value}
-    return {"n": n, "parsed": parsed, "_path": f"BENCH_r{n:02d}.json"}
-
-
-def test_metric_direction():
-    assert xray.metric_direction("samples/sec/chip (resnet)") == "higher"
-    assert xray.metric_direction("final NLL (lm1b)") == "lower"
-    assert xray.metric_direction("ttft p99") == "lower"
-    assert xray.metric_direction("decode latency_ms") == "lower"
-    assert xray.metric_direction("bus GB/s") == "higher"
-
-
-def test_fit_noise_band_floor_and_mad():
-    band = xray.fit_noise_band([100.0, 100.0, 100.0])
-    assert band["mad"] == 0.0
-    assert band["lo"] == pytest.approx(95.0), "5% floor guards MAD=0"
-    assert band["hi"] == pytest.approx(105.0)
-    band = xray.fit_noise_band([80.0, 100.0, 120.0], mad_k=2.0)
-    assert band["mad"] == 20.0
-    assert band["lo"] == pytest.approx(60.0)
-    assert band["hi"] == pytest.approx(140.0)
-
-
-def test_ledger_flags_throughput_drop_not_gain():
-    recs = [_rec(i, "samples/sec", v)
-            for i, v in enumerate([100.0, 101.0, 99.0], start=1)]
-    v = xray.check_ledger(recs + [_rec(4, "samples/sec", 97.0)])
-    assert v["ok"], "inside the 5% floor band"
-    v = xray.check_ledger(recs + [_rec(4, "samples/sec", 60.0)])
-    assert not v["ok"]
-    assert "samples/sec" in v["regressions"][0]
-    assert "r4" in v["regressions"][0]
-    v = xray.check_ledger(recs + [_rec(4, "samples/sec", 160.0)])
-    assert v["ok"], "a throughput JUMP is not a regression"
-
-
-def test_ledger_lower_is_better_direction():
-    recs = [_rec(i, "final NLL", v)
-            for i, v in enumerate([2.30, 2.31, 2.29], start=1)]
-    v = xray.check_ledger(recs + [_rec(4, "final NLL", 1.9)])
-    assert v["ok"], "NLL improving is fine"
-    v = xray.check_ledger(recs + [_rec(4, "final NLL", 3.2)])
-    assert not v["ok"] and "final NLL" in v["regressions"][0]
-
-
-def test_ledger_skips_torn_records_and_thin_history():
-    recs = [_rec(1, "samples/sec", 100.0), _rec(2, None, None),
-            {"n": 3, "parsed": {"metric": "samples/sec", "value": None}},
-            _rec(4, "samples/sec", 55.0)]
-    v = xray.check_ledger(recs)
-    assert v["skipped_records"] == 2
-    assert v["ok"], "one prior record is insufficient history to judge"
-    assert v["metrics"][0]["status"] == "insufficient_history"
-
-
-def test_load_bench_records_orders_and_tolerates_garbage(tmp_path):
-    for n, v in ((3, 99.0), (1, 100.0), (2, 101.0)):
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(
-            {"n": n, "parsed": {"metric": "m", "value": v}}))
-    (tmp_path / "BENCH_r04.json").write_text("{torn")
-    recs = xray.load_bench_records(tmp_path)
-    assert [r["n"] for r in recs] == [1, 2, 3], "ordered by round, torn " \
-                                                "file dropped"
 
 
 # ---------------------------------------------------------------------------
