@@ -29,6 +29,7 @@ def get_model(cfg: ModelConfig):
         jamba,
         k_exaone,
         lenet,
+        lfm2_moe,
         llama,
         longcat_flash,
         mlp,
@@ -62,6 +63,7 @@ def available_models() -> list[str]:
         jamba,
         k_exaone,
         lenet,
+        lfm2_moe,
         llama,
         longcat_flash,
         mlp,

@@ -225,7 +225,8 @@ def _quantize_kv(x):
     return q, scale
 
 
-def _cache_attention(q, k, v, pos_mask, dtype, kscale=None, vscale=None):
+def _cache_attention(q, k, v, pos_mask, dtype, kscale=None, vscale=None,
+                     scale=None):
     """Decode attention over the KV cache with GQA kept GROUPED: q
     reshapes to (B, T, Hkv, G, D) instead of repeating the cached K/V.
     (The einsum-path `jnp.repeat` materializes H/Hkv copies of the
@@ -244,12 +245,13 @@ def _cache_attention(q, k, v, pos_mask, dtype, kscale=None, vscale=None):
     plane.
 
     q: (B, T, H, D); k/v: (B, S, Hkv, D) float — or int8 when the
-    scales are given; pos_mask: (B|1, T, S). Returns (B, T, H, D)."""
+    scales are given; pos_mask: (B|1, T, S); ``scale`` multiplies the
+    scores (default ``D ** -0.5``). Returns (B, T, H, D)."""
     B, T, H, D = q.shape
     q5 = q.reshape(B, T, k.shape[2], H // k.shape[2], D)
     logits = jnp.einsum("btkgd,bskd->bkgts", q5, k.astype(dtype),
                         preferred_element_type=jnp.float32)
-    logits *= D ** -0.5
+    logits *= D ** -0.5 if scale is None else scale
     if kscale is not None:
         logits *= kscale.transpose(0, 2, 1)[:, :, None, None, :]
     logits = jnp.where(pos_mask[:, None, None], logits, -1e30)
@@ -260,6 +262,45 @@ def _cache_attention(q, k, v, pos_mask, dtype, kscale=None, vscale=None):
                      v.astype(dtype),
                      preferred_element_type=jnp.float32)
     return out.reshape(B, T, H, D).astype(dtype)
+
+
+def lane_pack(head_dim: int, kv_heads: int) -> int:
+    """K/V heads a cache row of one lane tile holds side by side: 2 for
+    an even number of heads of 64, else 1. The chip lays an array out
+    in tiles of 128 lanes along its last axis, and the decode round's
+    two products want the head's dims on them: a cache ``(slots, S,
+    Hkv, 64)`` is transposed whole, every layer, every round, to put
+    them there (four copies of 268 MB a layer at 64 slots x 4,096: 14.9
+    of a round's 30.7 ms on the chip, PERF.md sec. 6, PR 46), where
+    ``(slots, S, Hkv / 2, 128)``, the same bytes in the same order, is
+    read in place like a cache of 128-wide heads."""
+    return 2 if head_dim == 64 and kv_heads % 2 == 0 else 1
+
+
+def _packed_cache_attention(q, k, v, pos_mask, dtype):
+    """:func:`_cache_attention` over a cache that holds ``pack`` K/V
+    heads side by side a row, k/v (B, S, P, pack * D) with K/V head
+    ``j`` in lanes ``[j % pack * D, (j % pack + 1) * D)`` of row ``j //
+    pack``. A query head is laid into its K/V head's lanes, zeros in
+    the others, so that its product with the whole row is its product
+    with its own head, exactly (the other lanes add zeros); of the
+    ``pack * D`` lanes that come back from the values, its own are
+    kept. The products take ``pack`` times the operations, which a
+    decode round, bound by the cache's bytes, does not feel; the bytes
+    read are the cache's own."""
+    B, T, H, D = q.shape
+    P, pack = k.shape[2], k.shape[3] // D
+    G = H // (P * pack)
+    # query head h = (p * pack + r) * G + g reads K/V head p * pack + r
+    q6 = q.reshape(B, T, P, pack, G, 1, D)
+    lanes = (jnp.arange(pack)[:, None] == jnp.arange(pack)[None, :]) \
+        [None, None, None, :, None, :, None]
+    wide = jnp.where(lanes, q6, jnp.zeros((), q.dtype)) \
+        .reshape(B, T, P * pack * G, pack * D)
+    out = _cache_attention(wide, k, v, pos_mask, dtype, scale=D ** -0.5)
+    out = out.reshape(B, T, P, pack, G, pack, D)
+    return jnp.where(lanes, out, jnp.zeros((), out.dtype)).sum(axis=5) \
+        .reshape(B, T, H, D)
 
 
 def _ring_held(last, rows):
@@ -649,6 +690,13 @@ class MultiHeadAttention(nn.Module):
             # init sizes the cache from the (B, max_len) input; a window
             # layer's is its ring, whatever max_len
             kv_shape = (B, self.window or T, kv_heads, self.head_dim)
+            # heads of half a lane tile lie two a row (rows by position
+            # in the compute dtype only: a ring and the int8 layout keep
+            # a head a row)
+            pack = 1 if self.window or int8_cache or self.see_block > 1 \
+                else lane_pack(self.head_dim, kv_heads)
+            if pack > 1:
+                kv_shape = (B, T, kv_heads // pack, pack * self.head_dim)
             if self.see_block > 1:
                 kv_shape = (B, T, kv_heads * self.head_dim)
             cached_k = self.variable(
@@ -739,9 +787,10 @@ class MultiHeadAttention(nn.Module):
                     )
                 else:
                     flat = by_head = lambda x: x  # noqa: E731
-                    if self.see_block > 1:   # a position's heads in a row
+                    if self.see_block > 1 or pack > 1:
+                        # a position's heads in a row, or two a row
                         flat = lambda x: x.reshape(  # noqa: E731
-                            x.shape[:2] + (-1,))
+                            x.shape[:2] + kv_shape[2:])
                         by_head = lambda x: x.reshape(  # noqa: E731
                             x.shape[:2] + (kv_heads, self.head_dim))
                     cached_k.value = write(cached_k.value, flat(k))
@@ -754,6 +803,10 @@ class MultiHeadAttention(nn.Module):
                         out = _round_attention(
                             q, cached_k.value, cached_v.value, seen,
                             lengths, self.dtype)
+                    elif pack > 1:
+                        out = _packed_cache_attention(
+                            q, cached_k.value, cached_v.value, pos_mask,
+                            self.dtype)
                     else:
                         out = _cache_attention(
                             q, by_head(cached_k.value),

@@ -146,9 +146,11 @@ def selective_scan(h, dt, c, b, c_out, a, unroll: int = SCAN_UNROLL,
 class CausalConv1d(nn.Module):
     """Depthwise causal convolution over time with a carried tail:
     ``out_t = bias + sum_j kernel[j] * x_{t - (K-1) + j}``, the ``K - 1``
-    values before the call's first position given as ``tail``."""
+    values before the call's first position given as ``tail``; without
+    ``use_bias`` there is no such leaf and the sum stands alone."""
 
     width: int            # K
+    use_bias: bool = True
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -158,15 +160,30 @@ class CausalConv1d(nn.Module):
         C = x.shape[-1]
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (self.width, C), self.param_dtype)
-        bias = self.param("bias", nn.initializers.zeros, (C,),
-                          self.param_dtype)
+        out = self.param("bias", nn.initializers.zeros, (C,),
+                         self.param_dtype).astype(jnp.float32) \
+            if self.use_bias else 0.0
         T = x.shape[1]
         joined = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-        out = bias.astype(jnp.float32)
         for j in range(self.width):
             out = out + kernel[j].astype(jnp.float32) \
                 * joined[:, j:j + T].astype(jnp.float32)
         return out, joined
+
+
+def carried_tail(joined, before, real):
+    """The tail a call leaves behind: the ``K - 1`` values before each
+    row's first unreal position. ``joined`` (B, K - 1 + T, C) is the
+    tail the call was given, ``before`` (B, K - 1, C), and its T inputs
+    behind it; ``real`` (B, T) bool a left-aligned prefix of each row.
+    A row with no real position keeps ``before`` bit for bit. (A
+    round's row has one position or none: a select, where the general
+    rule is a gather.)"""
+    K1 = before.shape[1]
+    if real.shape[1] == 1:
+        return jnp.where(real[:, :, None], joined[:, 1:], before)
+    at = real.sum(axis=-1)[:, None] + jnp.arange(K1)[None]
+    return jnp.take_along_axis(joined, at[:, :, None], axis=1)
 
 
 class MambaMixer(nn.Module):
@@ -239,15 +256,6 @@ class MambaMixer(nn.Module):
         y = y + skip.astype(jnp.float32) * c32
         if decode and not self.is_initializing():
             state.value = h
-            # the K - 1 values before the row's first unreal position
-            # (a round's row has one position or none: a select, where
-            # the general rule is a gather)
-            if T == 1:
-                tail.value = jnp.where(real[:, :, None], joined[:, 1:],
-                                       before)
-            else:
-                at = real.sum(axis=-1)[:, None] + jnp.arange(K - 1)[None]
-                tail.value = jnp.take_along_axis(joined, at[:, :, None],
-                                                 axis=1)
+            tail.value = carried_tail(joined, before, real)
         return out_proj((y * nn.silu(z.astype(jnp.float32)))
                         .astype(self.dtype))

@@ -1,10 +1,10 @@
 """Counters a model sums on the device, published to the registry.
 
 A served model may count what only its own program sees (a mixture of
-experts: which experts its tokens picked; a state-space layer: the
-positions that advanced its state) without a fetch of its own
-every round. It keeps one 1-D ``uint32`` leaf of running totals in its
-``cache`` collection, which every program execution adds to on the
+experts: which experts its tokens picked; a state-space or a short
+convolution layer: the positions that advanced its state) without a
+fetch of its own every round. It keeps one 1-D ``uint32`` leaf of
+running totals in its ``cache`` collection, which every program execution adds to on the
 device, and declares it: ``device_counter_leaf`` is the leaf's path in
 that collection, ``device_counter_names()`` names the entries,
 ``((metric, labels), ...)``. The serving engine sums that leaf, and no
@@ -56,6 +56,13 @@ class DeviceCounters:
                 "ssm_tokens_total",
                 "real positions that advanced a state-space layer's "
                 "state", labels=labels),
+            "conv_calls_total": reg.counter(
+                "conv_calls_total",
+                "executions of a short-convolution layer", labels=labels),
+            "conv_tokens_total": reg.counter(
+                "conv_tokens_total",
+                "real positions that moved a short-convolution layer's "
+                "carried inputs", labels=labels),
             "block_forwards_total": reg.counter(
                 "block_forwards_total",
                 "forwards of a block of positions by a block decoder's "

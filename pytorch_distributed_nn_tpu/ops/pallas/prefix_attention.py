@@ -296,8 +296,10 @@ def _pallas(q, k, v, q_pos, *, scale: float, block_q: int, block_k: int,
 
 def _kernel_tiles(dk: int, dv: int, bq: int, bk: int) -> bool:
     """Whether the kernel can tile these shapes: a score tile of whole
-    (8, 128) registers, sublanes whole in bf16 too."""
-    return bq % 16 == 0 and bk % 128 == 0 and dk % 8 == 0 and dv % 128 == 0
+    (8, 128) registers, sublanes whole in bf16 too, and heads of whole
+    lane tiles or of half a one (64: LFM2's; the core pads a head's
+    tile to 128 lanes, memory sees the 64)."""
+    return bq % 16 == 0 and bk % 128 == 0 and dk % 8 == 0 and dv % 64 == 0
 
 
 @functools.lru_cache(maxsize=None)

@@ -563,3 +563,88 @@ def test_sdar_serve_programs_fit_the_chip_at_the_cells_size(
     assert held < 16.0e9, held
     if program == "step":
         assert m.alias_size_in_bytes > 1.8e9   # the cache, in place
+
+
+@pytest.mark.parametrize("program", ["step", 128, 1024, 4096])
+def test_lfm2_serve_programs_fit_the_chip_at_the_cells_size(
+        topo, monkeypatch, program):
+    """``lfm2_8b_a1b`` as its cell runs it (14 of 24 layers: the two
+    leading dense convolution layers and three periods of attention and
+    three convolutions before experts; all 32 experts of a layer, every
+    head of 64, the whole vocabulary, bf16; 64 slots x 4,096 positions):
+    the decode round and the smallest, a middle and the largest prefill
+    bucket compile for the described chip and fit its 16 GB beside what
+    they are given, the round's cache donated. A layer's held experts
+    are one ``grouped_experts`` call (``ops/pallas``: the dispatcher
+    asks for the backend, and this test answers for the chip) and no
+    loop; its cache of 64-wide K/V heads lies two heads a row of 128 and
+    is read in place (a head a row, the compiler transposed each leaf
+    whole, twice a layer a round: 0.56 GB of temporaries where there are
+    0.03); a prefill whose scores would be large attends blockwise, one
+    ``prefix_attention`` call an attention layer at heads of 64, and
+    holds no float32 scores of the bucket against the row.
+    ``benchmark/configs/lfm2_8b_a1b.json``'s ``deployment`` quotes what
+    this prints for 14 layers and for the 18 that do not fit."""
+    import re
+
+    from pytorch_distributed_nn_tpu.config import ModelConfig
+    from pytorch_distributed_nn_tpu.inference.generate import init_cache
+    from pytorch_distributed_nn_tpu.models import get_model
+    from pytorch_distributed_nn_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    model = get_model(ModelConfig(name="lfm2_8b_a1b", dtype="bfloat16",
+                                  extra=dict(num_layers=14)))
+    slots, rows = 64, 4096
+    moe_layers, attn_layers = 12, 3
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def on(tree):
+        return jax.tree.map(lambda a: arg(a.shape, a.dtype), tree)
+
+    params = on(jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+        train=False))["params"])
+    if program == "step":
+        cache = on(jax.eval_shape(lambda: init_cache(model, slots, rows)))
+        compiled = jax.jit(
+            lambda *a: engine._serve_step.__wrapped__(*a),
+            static_argnums=(0,), donate_argnums=(2,)).lower(
+            model, params, cache, arg((slots,)), arg((slots,)),
+            arg((slots,), jnp.bool_), arg((slots,)), arg(())).compile()
+        text = compiled.as_text()
+        assert text.count(KERNEL) == moe_layers
+        assert text.count(" while(") == 0
+        # no copy of a cache leaf among the program's own steps: the
+        # scatter and the two products read it where it lies
+        assert not re.findall(
+            rf"%copy[.\d]* = bf16\[{slots},{rows},4,128\]",
+            text[text.index("\nENTRY "):])
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    else:
+        cache = on(jax.eval_shape(lambda: init_cache(model, 1, program)))
+        compiled = jax.jit(
+            lambda *a: engine._serve_prefill.__wrapped__(*a),
+            static_argnums=(0,), donate_argnums=(2,)).lower(
+            model, params, cache, arg((1, program)), arg((1,)),
+            arg((1,))).compile()
+        text = compiled.as_text()
+        blockwise = program * program > 512 * 512
+        assert text.count(KERNEL) == moe_layers \
+            + (attn_layers if blockwise else 0)
+        scores = re.findall(rf"f32\[[\d,]*,(?:{program}|512),{program}\]",
+                            text)
+        assert bool(scores) == (not blockwise), scores[:3]
+    m = compiled.memory_analysis()
+    held = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    print(f"lfm2 {program}: arguments {m.argument_size_in_bytes / 1e9:.3f} "
+          f"GB, temporaries {m.temp_size_in_bytes / 1e9:.3f}, outputs "
+          f"{m.output_size_in_bytes / 1e9:.3f}, aliased "
+          f"{m.alias_size_in_bytes / 1e9:.3f}, held {held / 1e9:.3f}")
+    assert held < 16.0e9, held
+    if program == "step":
+        assert m.alias_size_in_bytes > 1.6e9   # the cache, in place

@@ -53,6 +53,9 @@ TINY = {
     "jamba": dict(num_layers=4, d_model=32, num_heads=4, num_kv_heads=1,
                   mlp_dim=64, vocab_size=101, attn_layer_period=2,
                   attn_layer_offset=1, mamba_dt_rank=4),
+    "lfm2_8b_a1b": dict(num_layers=4, d_model=32, num_heads=4,
+                        num_kv_heads=2, mlp_dim=64, vocab_size=101,
+                        expert_mlp_dim=16, num_experts=8, moe_topk=2),
     "sdar_moe": dict(_SDAR),
     "sdar_30b_a3b_seq2": dict(_SDAR),   # the cell's steps: 2, sequential
 }

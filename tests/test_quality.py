@@ -1626,6 +1626,7 @@ _PATHS_OF_OTHER_TREES = {
     "models/deepseek_v3/modeling_deepseek_v3.py",
     "models/exaone4/modeling_exaone4.py",
     "models/jamba/modeling_jamba.py",
+    "models/lfm2/modeling_lfm2.py",
     "models/qwen3_moe/modeling_qwen3_moe.py",
 }
 

@@ -605,6 +605,10 @@ def _spawn_serve_cli(tmp_path, requests=200, rate=20.0):
     out = tmp_path / "serve.jsonl"
     tiny = ('{"num_layers":1,"d_model":32,"num_heads":2,"num_kv_heads":1,'
             '"mlp_dim":64,"vocab_size":64}')
+    # stderr goes to a file: nobody reads a pipe while the test waits,
+    # and a child that logs more than a pipe holds (XLA warns ~2 KB a
+    # program it loads from the compile cache) would block in write()
+    err = (tmp_path / "serve.err").open("w")
     proc = subprocess.Popen(
         [sys.executable, str(repo / "scripts" / "serve.py"),
          "--preset", "llama3_8b_zero", "--slots", "2",
@@ -613,11 +617,12 @@ def _spawn_serve_cli(tmp_path, requests=200, rate=20.0):
          "--max-prompt", "8", "--metrics-out", str(out),
          "--model.extra", tiny, "--model.compute_dtype", "float32",
          "--model.remat", "false"],
-        cwd=repo, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+        cwd=repo, stdout=subprocess.PIPE, stderr=err,
         text=True,
         env={**os.environ, "JAX_PLATFORMS": "cpu",
              "TPUNN_CHAOS": ""},
     )
+    err.close()
     return proc, out
 
 
@@ -640,18 +645,18 @@ def test_sigterm_drains_and_exits_graceful_code(tmp_path):
                 break
             if proc.poll() is not None:
                 pytest.fail(f"serve.py exited early: "
-                            f"{proc.communicate()[1][-2000:]}")
+                            f"{(tmp_path / 'serve.err').read_text()[-2000:]}")
             time.sleep(0.1)
         else:
             pytest.fail("no serve_request event before timeout")
         proc.send_signal(signal.SIGTERM)
-        stdout, stderr = proc.communicate(timeout=120)
+        stdout, _ = proc.communicate(timeout=120)
     finally:
         if proc.poll() is None:
             proc.kill()
             proc.communicate()
     assert proc.returncode == GRACEFUL_EXIT_CODE, \
-        (proc.returncode, stderr[-2000:])
+        (proc.returncode, (tmp_path / "serve.err").read_text()[-2000:])
     summary = json.loads(stdout.strip().splitlines()[-1])
     assert summary["preempted"] is True
     assert summary["completed"] >= 1
