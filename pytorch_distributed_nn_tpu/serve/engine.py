@@ -560,18 +560,24 @@ def _save_blocks(cache, store, block_size, slot, table, n):
     donation). ``table`` is shape-padded to the per-sequence block
     ceiling so slot/table/n are all traced — ONE program and ONE
     dispatch per retire, however many blocks the sequence spans (the
-    per-block version made the cache-ON bench dispatch-bound)."""
+    per-block version made the cache-ON bench dispatch-bound).
+
+    No loop over the blocks (one cost ~3 us a block of 32 KB): a leaf
+    is one indexed scatter over the whole table, in place in the
+    donated store. The table's tail past ``n`` is zeros and 0 is a
+    physical block, so those entries are sent past the store's end and
+    dropped."""
     def sv(c, s):
         if c.ndim < 2:
             return s
-        def body(j, acc):
-            blk = jax.lax.dynamic_slice(
-                c, (slot, j * block_size) + (0,) * (c.ndim - 2),
-                (1, block_size) + c.shape[2:])
-            return jax.lax.dynamic_update_slice(
-                acc, blk.astype(acc.dtype),
-                (table[j], 0) + (0,) * (acc.ndim - 2))
-        return jax.lax.fori_loop(0, n, body, s)
+        k = min(c.shape[1] // block_size, table.shape[0])
+        rows = jax.lax.dynamic_index_in_dim(c, slot, keepdims=False)
+        blocks = rows[:k * block_size].reshape(
+            (k, block_size) + c.shape[2:])
+        j = jnp.arange(k)
+        past = s.shape[0] + j  # distinct, so the indices stay unique
+        return s.at[jnp.where(j < n, table[:k], past)].set(
+            blocks.astype(s.dtype), mode="drop", unique_indices=True)
     return jax.tree.map(sv, cache, store)
 
 
@@ -579,22 +585,23 @@ def _save_blocks(cache, store, block_size, slot, table, n):
 def _restore_blocks(row_cache, store, block_size, table, n):
     """Copy physical blocks ``table[:n]`` of the store into rows
     [0, n * block_size) of a batch-of-one prefill cache
-    (admission-side prefix restore; one dispatch per admission). The
-    caller guarantees n * block_size <= the row cache's padded length
-    (PrefixCache ``max_rows`` caps matches; out-of-range
-    dynamic_update_slice starts would silently CLAMP and corrupt
-    neighbor rows)."""
+    (admission-side prefix restore; one dispatch per admission). One
+    gather a leaf of the blocks the row cache's length holds (a count
+    from its shape, so no block lands past its end; the caller
+    guarantees n * block_size <= that length, PrefixCache ``max_rows``
+    caps matches), selected against the row cache's own rows from
+    ``n * block_size`` on."""
     def rs(r, s):
         if r.ndim < 2:
             return r
-        def body(j, acc):
-            blk = jax.lax.dynamic_slice(
-                s, (table[j], 0) + (0,) * (s.ndim - 2),
-                (1, block_size) + s.shape[2:])
-            return jax.lax.dynamic_update_slice(
-                acc, blk.astype(acc.dtype),
-                (0, j * block_size) + (0,) * (acc.ndim - 2))
-        return jax.lax.fori_loop(0, n, body, r)
+        k = min(r.shape[1] // block_size, table.shape[0])
+        rows = k * block_size
+        got = s[table[:k]].reshape((1, rows) + s.shape[2:])
+        keep = (jnp.arange(rows) < n * block_size).reshape(
+            (1, rows) + (1,) * (r.ndim - 2))
+        head = jnp.where(keep, got.astype(r.dtype), r[:, :rows])
+        return head if rows == r.shape[1] else \
+            jax.lax.dynamic_update_slice(r, head, (0,) * r.ndim)
     return jax.tree.map(rs, row_cache, store)
 
 
@@ -911,6 +918,13 @@ class ServingEngine:
             "serve_rounds_overlapped_total",
             "decode rounds dispatched before the previous round's "
             "tokens were fetched")
+        # over the device time of jit__save_blocks / jit__restore_blocks
+        # in a trace this is microseconds a block
+        self._c_blocks_copied = reg.counter(
+            "serve_store_blocks_copied_total",
+            "blocks copied between a batch row and the block store: "
+            "save at a retire, restore at an admission with a prefix hit",
+            labels=("direction",))
         # a model's device-side counters (obs/device_counters.py): the
         # model names the cache leaf that holds them and its entries;
         # read every _COUNTER_ROUNDS rounds from the batch cache
@@ -1210,6 +1224,7 @@ class ServingEngine:
                 with jitwatch.dispatch_span("serve/restore", blocks=nb):
                     row_cache = _restore_blocks(
                         row_cache, self._store, bs, table, np.int32(nb))
+                self._c_blocks_copied.inc(nb, direction="restore")
                 trace.on_segment(req.trace, "restore", t_restore,
                                  time.monotonic(), blocks=nb, cached=m)
             # the request's rows of the sampling mirror: its spec, one
@@ -1572,9 +1587,11 @@ class ServingEngine:
             return
         padded = np.zeros((self._blocks_per_seq,), np.int32)
         padded[:nb] = table[:nb]
-        self._store = _save_blocks(
-            self._cache, self._store, bs,
-            np.int32(slot), padded, np.int32(nb))
+        with jitwatch.dispatch_span("serve/save_blocks", blocks=nb):
+            self._store = _save_blocks(
+                self._cache, self._store, bs,
+                np.int32(slot), padded, np.int32(nb))
+        self._c_blocks_copied.inc(nb, direction="save")
 
     def _refuse_blocks(self, what: str) -> None:
         if self._not_by_position:

@@ -12,6 +12,7 @@ run. Skipped as a whole where the topology cannot be described (no
 libtpu, or its lock is held by another process).
 """
 
+import math
 import os
 
 import jax
@@ -425,6 +426,44 @@ def test_serve_step_writes_cache_rows_without_a_loop(topo, monkeypatch,
     was, is_ = before.memory_analysis(), now.memory_analysis()
     assert is_.alias_size_in_bytes == was.alias_size_in_bytes
     assert is_.temp_size_in_bytes <= was.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("family,slots,S,tails", [
+    ("mistral", 8, 4096, [(1024,), (1024,)]),
+    ("by_heads", 8, 4096, [(8, 128), (8, 128)]),
+    # A.X-K1's latent and rotated-key leaves: the second is half a lane
+    # tile wide and lies position-minor on the chip
+    ("latent", 16, 8192, [(512,), (64,)]),
+])
+def test_block_copies_hold_no_loop_on_the_chip(topo, family, slots, S,
+                                               tails):
+    """``_save_blocks`` and ``_restore_blocks`` as the chip's compiler
+    leaves them at the cells' sizes: no ``while`` in either (a loop over
+    the blocks cost ~3 us a block) and the save's store written in
+    place."""
+    from pytorch_distributed_nn_tpu.serve import engine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def tree(lead):
+        return {**{f"leaf{i}": arg(lead + t) for i, t in enumerate(tails)},
+                "index": arg((), jnp.int32)}
+
+    cache, store, row = (tree((slots, S)), tree((slots * S // 16, 16)),
+                         tree((1, S)))
+    table, i32 = arg((S // 16,), jnp.int32), arg((), jnp.int32)
+    save = engine._save_blocks.lower(
+        cache, store, 16, i32, table, i32).compile()
+    restore = engine._restore_blocks.lower(
+        row, store, 16, table, i32).compile()
+    assert " while(" not in save.as_text()
+    assert " while(" not in restore.as_text()
+    held = sum(math.prod(leaf.shape) * 2 for leaf in store.values()
+               if leaf.shape)
+    assert save.memory_analysis().alias_size_in_bytes >= held
 
 
 def _dense_prefill(q, k, v, positions, lengths=None):

@@ -47,6 +47,7 @@ PARENTS = {
     "serve/decode": ("serve/round",),
     "serve/round_host": ("serve/round",),
     "serve/retire": ("serve/admit", "serve/round_host"),
+    "serve/save_blocks": ("serve/retire",),
     "serve/release": ("serve/retire",),
 }
 
@@ -265,6 +266,9 @@ def test_engine_span_in_profiler_trace(traced, name):
         # full blocks the retiring sequence donates: 19 + 2 - 1 rows
         # (the two-token budget ends first), 5 + 3 - 1, 19 + 2 - 1
         assert [st["blocks"] for st in stats] == [1, 0, 1]
+    elif name == "serve/save_blocks":
+        # the dispatch of the copy ahead of a release that has blocks
+        assert [st["blocks"] for st in stats] == [1, 1]
     elif name == "serve/decode":
         # the host's part of the call, before it waits for the chip
         assert all(0 <= st["dispatch_us"] for st in stats)
