@@ -52,6 +52,7 @@ and submitting client threads both touch the pool.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from collections import OrderedDict
 from typing import Iterable
@@ -312,14 +313,35 @@ class KVPool:
         state change — when the block is pinned or not cached (already
         evicted, or promoted to live by a sharer in between): the
         prefix cache's eviction scan treats False as "pick another"."""
-        with self._lock:
-            if block in self._pinned or block not in self._cached:
-                return False
-            del self._cached[block]
-            self._free.append(block)
-            self._publish_locked()
-        meter.on_kv_evict(block)
-        return True
+        with self.releasing_cached() as release:
+            return release(block)
+
+    @contextlib.contextmanager
+    def releasing_cached(self):
+        """One eviction pass: yields ``release(block)``, which is
+        :meth:`release_cached` block by block, the same refusals and
+        the same free list, while the gauges (a sum over the live
+        sequences each time) and the meter are brought up to date
+        once, as the pass ends, for all it released."""
+        gone: list[int] = []
+
+        def release(block: int) -> bool:
+            with self._lock:
+                if block in self._pinned or block not in self._cached:
+                    return False
+                del self._cached[block]
+                self._free.append(block)
+            gone.append(block)
+            return True
+
+        try:
+            yield release
+        finally:
+            if gone:
+                with self._lock:
+                    self._publish_locked()
+                for block in gone:
+                    meter.on_kv_evict(block)
 
     # -- introspection -----------------------------------------------------
 

@@ -127,6 +127,7 @@ class PrefixCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        self.scanned = 0
         self.tokens_saved = 0
         reg = get_registry()
         self._c_hits = reg.counter(
@@ -138,6 +139,9 @@ class PrefixCache:
         self._c_evictions = reg.counter(
             "serve_kv_prefix_evictions_total",
             "cached prefix blocks evicted under pressure")
+        self._c_scanned = reg.counter(
+            "serve_kv_prefix_evict_scanned_total",
+            "cached-ring entries the eviction walks looked at")
         self._c_saved = reg.counter(
             "serve_kv_prefix_tokens_saved_total",
             "prompt tokens whose prefill was skipped")
@@ -262,7 +266,9 @@ class PrefixCache:
                 # stale", which killed the serve loop)
                 with span("serve/evict") as sp:
                     mine = [b for b in match.blocks if self.pool.pin(b)]
-                    sp.set(blocks=self._evict_locked(short))
+                    before = self.scanned
+                    sp.set(blocks=self._evict_locked(short),
+                           scanned=self.scanned - before)
                     for b in mine:
                         self.pool.unpin(b)
             if not self.pool.reserve(seq_id, total_tokens,
@@ -384,31 +390,54 @@ class PrefixCache:
             return self.pool.free(seq_id, retain=retain)
 
     def _evict_locked(self, need: int) -> int:
-        """Shed up to ``need`` unpinned LRU leaf blocks. Counted per
-        block through :meth:`_account`."""
-        shed = 0
-        progress = True
-        while shed < need and progress:
-            progress = False
+        """Shed up to ``need`` blocks, each time the least-recently-
+        parked one that is cached, unpinned and has no indexed child,
+        in one walk of the ring. A chain parks root first, so the walk
+        steps over its interior to reach the leaf; once that is shed
+        its parent is the oldest leaf if the walk has stepped over it
+        (nothing else behind the cursor changed), and the chain is
+        climbed from there. A parent still ahead is met in its turn.
+        Counted per block through :meth:`_account`."""
+        if need <= 0:
+            return 0
+        shed = scanned = 0
+        behind: set[int] = set()  # stepped over: parked, before the cursor
+        with self.pool.releasing_cached() as release:
+
+            def sheds(node: _Node) -> bool:
+                if node.children & self._nodes.keys():
+                    return False  # interior: evicting orphans descendants
+                if not release(node.phys):
+                    return False  # pinned (a COW restore in flight)
+                self._drop_locked(node)
+                self._account("evict", note=f"b{node.phys}")
+                return True
+
             for phys in self.pool.cached_lru():
+                scanned += 1
                 d = self._by_phys.get(phys)
                 if d is None:
                     # cached but never indexed (shouldn't happen):
                     # reclaim it anyway
-                    if self.pool.release_cached(phys):
-                        shed += 1
-                        progress = True
+                    shed += release(phys)
                     continue
                 node = self._nodes[d]
-                if node.children & self._nodes.keys():
-                    continue  # interior: evicting orphans descendants
-                if not self.pool.release_cached(phys):
-                    continue  # pinned (a COW restore in flight)
-                self._drop_locked(node)
-                self._account("evict", note=f"b{phys}")
+                if not sheds(node):
+                    behind.add(phys)
+                    continue
                 shed += 1
-                progress = True
-                break
+                while shed < need:
+                    node = self._nodes.get(node.parent)
+                    if node is None or node.phys not in behind:
+                        break  # a root; a parent live, or still ahead
+                    scanned += 1
+                    if not sheds(node):
+                        break
+                    shed += 1
+                if shed >= need:
+                    break
+        self.scanned += scanned
+        self._c_scanned.inc(scanned)
         return shed
 
     def _drop_locked(self, node: _Node) -> None:

@@ -388,6 +388,216 @@ def test_parity_accounting_invariant_under_eviction_pressure():
 
 
 # ---------------------------------------------------------------------------
+# Eviction: one walk of the ring against the rule it computes (ISSUE 48)
+# ---------------------------------------------------------------------------
+
+def _evict_restart_every_block(self, need: int) -> int:
+    """The rule, plainly (the routine as it stood before ISSUE 48):
+    for every block, a fresh copy of the ring walked from its oldest
+    end to the first cached, unpinned block with no indexed child;
+    shed that one, start over."""
+    shed = 0
+    progress = True
+    while shed < need and progress:
+        progress = False
+        for phys in self.pool.cached_lru():
+            self.scanned += 1
+            d = self._by_phys.get(phys)
+            if d is None:
+                if self.pool.release_cached(phys):
+                    shed += 1
+                    progress = True
+                continue
+            node = self._nodes[d]
+            if node.children & self._nodes.keys():
+                continue
+            if not self.pool.release_cached(phys):
+                continue
+            self._drop_locked(node)
+            self._account("evict", note=f"b{phys}")
+            shed += 1
+            progress = True
+            break
+    return shed
+
+
+# what a script draws from, and how often it does each thing beside
+# admitting and releasing
+_EVICT_SCRIPTS = {
+    "disjoint_chains": dict(trunk=0),
+    "shared_trunk_and_forks": dict(trunk=5),
+    "pinned_cow_tail": dict(trunk=3, mid_block=0.9, hold_restore=0.8),
+    "pinned_matched_chain": dict(trunk=4, export_pin=0.15),
+    "live_parent_cached_child": dict(trunk=6, cut_short=0.6, linger=True),
+    "never_indexed_block": dict(trunk=2, stray=0.15),
+    "need_beyond_what_can_be_shed": dict(trunk=3, linger=True, flood=0.6),
+}
+
+
+_OPS = ("leave", "make_room", "ingest", "stray", "export_pin", "admit")
+
+
+def _run_evict_script(kind: str, seed: int, reference: bool):
+    """One seeded script of admit / release / abandon / make_room /
+    ingest on a small pool. Returns what an observer can tell, op by
+    op (the op's result, its victims in order from the flight ring,
+    ``stats()``, the free list and the ring), and how many eviction
+    passes met the thing ``kind`` is named for."""
+    cfg = _EVICT_SCRIPTS[kind]
+    bs = 4
+    rng = np.random.default_rng(seed)
+    flight.reset_recorder(enabled=True)
+    obs.reset_registry()
+    pool = KVPool(num_blocks=40, block_size=bs)
+    pc = PrefixCache(pool)
+    if reference:
+        pc._evict_locked = _evict_restart_every_block.__get__(pc)
+    trunks = [rng.integers(1, VOCAB, size=cfg["trunk"] * bs)
+              for _ in range(2)]
+
+    def draw_doc(i):  # some whole blocks of a trunk, then its own
+        head = trunks[i % 2][:int(rng.integers(cfg["trunk"] + 1)) * bs]
+        own = rng.integers(1, VOCAB, size=int(rng.integers(2, 9)) * bs)
+        return np.concatenate([head, own]).astype(np.int32)
+
+    docs = [draw_doc(i) for i in range(8)]
+    odds = [0.22 if cfg.get("linger") else 0.40, 0.08, 0.06,
+            cfg.get("stray", 0.0), cfg.get("export_pin", 0.0)]
+    odds.append(1.0 - sum(odds))
+    live: dict = {}     # seq -> (prompt, match or None once restored)
+    exported: list = []  # blocks this script pinned, as an export does
+    log, met = [], 0
+
+    def witness() -> bool:
+        ring = set(pool.cached_lru())
+        if kind == "never_indexed_block":
+            return bool(ring - pc._by_phys.keys())
+        if kind in ("pinned_cow_tail", "pinned_matched_chain"):
+            return bool(ring & pool._pinned)
+        if kind == "live_parent_cached_child":
+            return any(n.phys in ring and n.parent in pc._nodes
+                       and pc._nodes[n.parent].phys not in ring
+                       for n in pc._nodes.values())
+        return True
+
+    def settle(seq):
+        prompt, match = live.pop(seq)
+        if match is not None:
+            pc.finish_restore(match)
+        return prompt
+
+    for i in range(140):
+        flight.reset_recorder(enabled=True)
+        had, before = witness(), pc.evictions + pool.free_blocks
+        what = str(rng.choice(_OPS, p=odds))
+        if what == "leave" and live:
+            seq = sorted(live)[int(rng.integers(len(live)))]
+            prompt = settle(seq)
+            if rng.random() < 0.85:
+                op = ("release", pc.release(seq, np.concatenate(
+                    [prompt, rng.integers(1, VOCAB, size=3)])))
+            else:
+                op = ("abandon", pc.abandon(seq))
+        elif what == "stray" and pool.free_blocks >= 2:
+            # parked by the pool alone: cached, never indexed
+            assert pool.reserve(f"u{i}", 2 * bs)
+            pool.free(f"u{i}", retain=frozenset(pool.block_table(f"u{i}")))
+            op = ("stray", 2)
+        elif what == "export_pin":
+            # as serve.disagg pins a chain across its export window
+            for b in exported:
+                pool.unpin(b)
+            chain = pc.resident_chain(docs[int(rng.integers(8))]).blocks
+            exported[:] = [b for b in chain if pool.pin(b)]
+            op = ("export_pin", len(exported))
+        elif what == "make_room":
+            n = 10 ** 6 if rng.random() < cfg.get("flood", 0.1) \
+                else int(rng.integers(1, 7))
+            op = ("make_room", pc.make_room(n))
+        elif what == "ingest":
+            doc = docs[int(rng.integers(8))]
+            op = ("ingest", pc.ingest(
+                doc[:int(rng.integers(1, len(doc) // bs + 1)) * bs]))
+        else:
+            doc = docs[int(rng.integers(8))]
+            if rng.random() < cfg.get("cut_short", 0.2):
+                doc = doc[:int(rng.integers(1, len(doc) // bs + 1)) * bs + 1]
+            if rng.random() < cfg.get("mid_block", 0.3):
+                doc = doc[:max(2, len(doc) - int(rng.integers(1, bs)))]
+            match = pc.admit(f"s{i}", doc, len(doc) + int(
+                rng.integers(1, 3 * bs)))
+            if match is not None:
+                hold = rng.random() < cfg.get("hold_restore", 0.2)
+                if not hold:
+                    pc.finish_restore(match)
+                live[f"s{i}"] = (doc, match if hold else None)
+            op = ("admit", match)
+        victims = [e["note"] for e in flight.get_recorder().snapshot()
+                   if e["kind"] == "prefix" and e["op"] == "evict"]
+        if kind == "need_beyond_what_can_be_shed":
+            had = op == ("admit", None) or (
+                what == "make_room" and op[1] < n)
+        if had and pc.evictions + pool.free_blocks > before:
+            met += 1
+        log.append((op, victims, pc.stats(), list(pool._free),
+                    pool.cached_lru()))
+    for b in exported:
+        pool.unpin(b)
+    for seq in sorted(live):
+        settle(seq)
+        pc.abandon(seq)
+    assert pool.live_sequences == 0 and not pool._pinned
+    assert pool.free_blocks + pool.cached_blocks == pool.num_blocks
+    return log, met, pc.scanned
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+@pytest.mark.parametrize("kind", sorted(_EVICT_SCRIPTS))
+def test_one_walk_sheds_what_restarting_every_block_sheds(kind, seed):
+    want, met, restarts = _run_evict_script(kind, seed, reference=True)
+    got, _, walk = _run_evict_script(kind, seed, reference=False)
+    assert len(want) == len(got)
+    for i, (a, b) in enumerate(zip(want, got)):
+        assert a == b, (i, a[0], a[1], b[0], b[1])
+    shed = want[-1][2]["prefix_evictions"]
+    assert shed >= 40 and met >= 3, (shed, met)
+    assert walk <= restarts
+
+
+def test_an_admissions_eviction_looks_at_the_ring_once():
+    """The cost by count, as A.X-K1's documents park: a pool of 8,192
+    blocks full of 417-block chains, root first. One admission that
+    sheds ~400 blocks looks at each ring entry at most once and at
+    each block it sheds once more; restarting at the oldest end for
+    every block, the same admission looks 82,158 times."""
+    bs, chain = 16, 417
+    pool = KVPool(num_blocks=8192, block_size=bs)
+    pc = PrefixCache(pool)
+    rng = np.random.default_rng(48)
+    for i in range(8192 // chain + 1):
+        doc = rng.integers(1, 30000, size=chain * bs + 5).astype(np.int32)
+        assert pc.admit(f"d{i}", doc, len(doc)) is not None
+        pc.release(f"d{i}", doc)
+    ring = pool.cached_blocks
+    assert pool.free_blocks < chain and ring > 7500
+    rec = obs.enable_tracing()
+    try:
+        doc = rng.integers(1, 30000, size=400 * bs).astype(np.int32)
+        shed0, scanned0 = pc.evictions, pc.scanned
+        assert pc.admit("cold", doc, len(doc)) is not None
+    finally:
+        obs.disable_tracing()
+    (ev,) = [e for e in rec.events() if e["name"] == "serve/evict"]
+    shed = pc.evictions - shed0
+    assert 300 <= shed <= 400 and ev["args"]["blocks"] == shed
+    assert ev["args"]["scanned"] == pc.scanned - scanned0
+    assert shed <= ev["args"]["scanned"] <= ring + shed
+    assert ev["args"]["scanned"] <= 3 * shed  # 82,158 before
+    total = obs.get_registry().snapshot()
+    assert total["serve_kv_prefix_evict_scanned_total"] == pc.scanned
+
+
+# ---------------------------------------------------------------------------
 # Engine goldens: cache ON == cache OFF == sequential generate
 # ---------------------------------------------------------------------------
 
