@@ -1,11 +1,11 @@
 """Operations and bytes A.X-K1's served rank needs, from shapes.
 
-The numerators of ``decode_hbm_share.axk1`` and
-``prefill_flops_share.axk1`` (``configs/ax_k1.json``'s keys). As in
-``costs.py`` each counts the least the mathematics asks for: a
-multiply-add is 2 operations; padding, positions after the query,
-positions restored from the prefix store (their latent rows are read,
-not computed again) and experts no token picked count nothing.
+The numerators of ``decode_hbm_share`` and ``prefill_flops_share`` in
+this model's cell (``configs/ax_k1.json``'s keys). As in ``costs.py``
+each counts the least the mathematics asks for: a multiply-add is 2
+operations; padding, positions after the query, positions restored from
+the prefix store (their latent rows are read, not computed again) and
+experts no token picked count nothing.
 ``benchmark/tests/test_costs_axk1.py`` pins each on a hand-worked shape.
 """
 

@@ -1,12 +1,13 @@
 """Operations and bytes Jamba's served model needs, from shapes.
 
-The numerators of ``decode_hbm_share.jamba``, ``state_bytes_share.jamba``
-and ``prefill_flops_share.jamba`` (``configs/jamba2_3b.json``'s keys). As
-in ``costs.py`` each counts the least the mathematics asks for: a
-multiply-add is 2 operations; padding and positions after the query
-count nothing; a state is counted at the size the configuration states
-(float32, ``state_dtype`` under ``assumed``), not at what a layout pads
-it to. ``benchmark/tests/test_costs_jamba.py`` pins each on a hand-worked
+The numerators of ``decode_hbm_share``, ``state_bytes_share`` and
+``prefill_flops_share`` in this model's cell
+(``configs/jamba2_3b.json``'s keys). As in ``costs.py`` each counts the
+least the mathematics asks for: a multiply-add is 2 operations; padding
+and positions after the query count nothing; a state is counted at the
+size the configuration states (float32, ``state_dtype`` under
+``assumed``), not at what a layout pads it to.
+``benchmark/tests/test_costs_jamba.py`` pins each on a hand-worked
 shape.
 """
 
@@ -129,8 +130,8 @@ def prefill_scan_bytes_floor(cfg: dict, tokens: int, chunk: int) -> float:
     inputs (the convolved ``c`` and the ``dt_rank + 2 d_state`` values of
     step, B and C a position, in the serving type), its output ``y``, and
     one pass of state a chunk (read at its start, written at its end).
-    No such kernel exists yet (``ROADMAP.md``); this is the denominator
-    the PR that writes it starts from."""
+    Since PR 41 that kernel is the op ``selective_scan`` in a trace; no
+    metric reads it against this floor yet (``PERF.md`` sec. 7)."""
     z = sizes(cfg)
     b = _BYTES[cfg["torch_dtype"]]
     per_layer = (tokens * (2 * z["inner"] + z["rank"] + 2 * z["state"]) * b

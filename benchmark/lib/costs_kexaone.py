@@ -1,8 +1,8 @@
 """Operations and bytes K-EXAONE's served rank needs, from shapes.
 
-The numerators of ``decode_hbm_share.kexaone`` and
-``prefill_flops_share.kexaone`` (``configs/k_exaone_236b.json``'s keys).
-As in ``costs.py`` each counts the least the mathematics asks for: a
+The numerators of ``decode_hbm_share`` and ``prefill_flops_share`` in
+this model's cell (``configs/k_exaone_236b.json``'s keys). As in
+``costs.py`` each counts the least the mathematics asks for: a
 multiply-add is 2 operations; padding, positions outside a mask (beyond
 the window, after the query) and experts no token picked count nothing.
 ``benchmark/tests/test_costs_kexaone.py`` pins each on a hand-worked

@@ -1,12 +1,13 @@
 """Operations and bytes LFM2's served stage needs, from shapes.
 
-The numerators of ``decode_hbm_share.lfm2``, ``state_bytes_share.lfm2``,
-``grouped_experts_hbm_share.lfm2`` and ``prefill_flops_share.lfm2``
-(``configs/lfm2_8b_a1b.json``'s keys). As in ``costs.py`` each counts
-the least the mathematics asks for: a multiply-add is 2 operations;
-padding, positions after the query and experts no token picked count
-nothing; a head is counted at its 64 dims whatever a kernel pads it to.
-``benchmark/tests/test_costs_lfm2.py`` pins each on a hand-worked shape.
+The numerators of ``decode_hbm_share``, ``state_bytes_share``,
+``grouped_experts_hbm_share`` and ``prefill_flops_share`` in this
+model's cell (``configs/lfm2_8b_a1b.json``'s keys). As in ``costs.py``
+each counts the least the mathematics asks for: a multiply-add is 2
+operations; padding, positions after the query and experts no token
+picked count nothing; a head is counted at its 64 dims whatever a kernel
+pads it to. ``benchmark/tests/test_costs_lfm2.py`` pins each on a
+hand-worked shape.
 """
 
 from __future__ import annotations

@@ -1,12 +1,11 @@
 """Operations and bytes LongCat-Flash's served rank needs, from shapes.
 
-The numerators of ``decode_hbm_share.longcat`` and
-``prefill_flops_share.longcat`` (``configs/longcat_flash_omni.json``'s
-keys). As in ``costs.py`` each counts the least the mathematics asks
-for: a multiply-add is 2 operations; padding, masked positions and
-experts no token picked count nothing.
-``benchmark/tests/test_costs_longcat.py`` pins each on a hand-worked
-shape.
+The numerators of ``decode_hbm_share`` and ``prefill_flops_share`` in
+this model's cell (``configs/longcat_flash_omni.json``'s keys). As in
+``costs.py`` each counts the least the mathematics asks for: a
+multiply-add is 2 operations; padding, masked positions and experts no
+token picked count nothing. ``benchmark/tests/test_costs_longcat.py``
+pins each on a hand-worked shape.
 """
 
 from __future__ import annotations

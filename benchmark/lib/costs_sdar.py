@@ -1,10 +1,11 @@
 """Operations and bytes SDAR's served stage needs, from shapes.
 
-The numerators of ``decode_hbm_share.sdar`` and
-``prefill_flops_share.sdar`` (``configs/sdar_30b_a3b.json``'s keys). As
-in ``costs.py`` each counts the least the mathematics asks for: a
-multiply-add is 2 operations; padding, positions outside the mask and
-experts no token picked count nothing. A round computes a block of
+The numerators of ``decode_hbm_share``, ``grouped_experts_hbm_share``
+and ``prefill_flops_share`` in this model's cell
+(``configs/sdar_30b_a3b.json``'s keys). As in ``costs.py`` each counts
+the least the mathematics asks for: a multiply-add is 2 operations;
+padding, positions outside the mask and experts no token picked count
+nothing. A round computes a block of
 ``B`` positions a row: the matrices are read once whatever ``B`` is,
 the row's cached keys and values once for the block's ``B`` queries.
 ``benchmark/tests/test_costs_sdar.py`` pins each on a hand-worked shape.
@@ -26,6 +27,12 @@ def attn_params(cfg: dict) -> int:
 def expert_params(cfg: dict) -> int:
     """One routed expert: gate, up and down."""
     return 3 * cfg["hidden_size"] * cfg["moe_intermediate_size"]
+
+
+def experts_bytes(cfg: dict, experts_touched: float) -> float:
+    """The held experts some position picked, read once each
+    (``experts_touched``: their number summed over the layers)."""
+    return experts_touched * expert_params(cfg) * _BYTES[cfg["torch_dtype"]]
 
 
 def router_params(cfg: dict) -> int:

@@ -6,7 +6,7 @@ match, reservation, the shedding of cached blocks), which runs before
 ``serve/admit`` opens: idle time under it was ``unattributed`` to the
 four classes of ``host_spans.py``. Here it is taken out of what those
 classes leave, so that ``idle_next_admissions_share`` and the remainder
-this module logs add up to the cell's ``idle_unattributed_share.*`` of
+this module logs add up to the cell's ``idle_unattributed_share`` of
 the same run.
 
 ``serve/decode`` carries ``dispatch_us`` (from the span's start to the

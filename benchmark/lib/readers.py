@@ -10,15 +10,38 @@ facts, and, in a traced run, the reduced trace (``trace``).
 
 from __future__ import annotations
 
+import functools
+import importlib
 import re
 import statistics
 
-from benchmark.lib import costs
-from benchmark.lib.common import percentile
+from benchmark.lib import costs, host_spans
+from benchmark.lib import trace_reduce as tr
+from benchmark.lib.common import log, percentile
 
 
 def window_s(run: dict) -> float:
     return run["t1"] - run["t0"]
+
+
+# -- the model's own readers ------------------------------------------------
+
+def for_run(run: dict):
+    """The module under ``benchmark/lib/`` that holds the readers of the
+    run's model, as its configuration names it (``program.readers``);
+    this module for a configuration that names none. A metric several
+    models have is one file under ``metrics/`` that asks here, so no
+    metric file names a model or a cell."""
+    name = run["cfg"].get("program", {}).get("readers", "readers")
+    return importlib.import_module(f"benchmark.lib.{name}")
+
+
+def of_model(run: dict, reader: str, fallback=None):
+    """What the model's ``reader`` says of the run; ``fallback``'s word
+    where the model's module has no such function, and nothing where
+    there is neither: a reader that finds nothing says nothing."""
+    fn = getattr(for_run(run), reader, fallback)
+    return None if fn is None else fn(run)
 
 
 # -- serving --------------------------------------------------------------
@@ -126,20 +149,106 @@ def decode_hbm_share_pct(run: dict):
     return 100.0 * need / (secs * run["peaks"]["hbm_bytes_per_s"])
 
 
-def prefill_flops_share_pct(run: dict):
-    """Operations the traced prefills needed (the window's mean prompt,
-    times the prefills in the trace), over their device time at the
-    chip's peak."""
-    mod = _module(run, r"serve_prefill")
-    t0, t1 = run["t0"], run["t1"]
-    lens = [len(s.prompt) for s in run["sent"]
-            if s.arrivals and t0 <= s.arrivals[0] <= t1]
-    if mod is None or not lens:
+@functools.lru_cache(maxsize=1)
+def _chip0(path: str) -> dict:
+    devs = tr.load(path)
+    return devs[min(devs)]
+
+
+def chip0_events(run: dict):
+    """Chip 0's traced programs and operations (``modules``, ``ops``:
+    ``[(name, start_ns, end_ns)]``), read once for the readers that want
+    them, or None for a run without a trace."""
+    if run.get("trace") is None:
         return None
-    n, secs = mod
-    cfg = run["cfg"]
-    mean = sum(costs.decoder_prefill_flops(cfg, L) for L in lens) / len(lens)
-    return 100.0 * n * mean / (secs * run["peaks"]["bf16_flops"])
+    try:
+        return _chip0(tr.find_xplane(str(host_spans.ROOT / ".bench_trace"
+                                         / run["workload"])))
+    except FileNotFoundError:
+        return None
+
+
+def prefill_spans(run: dict):
+    """``[(start, end, tokens, padded, cached)]`` of the traced
+    ``serve/prefill_into`` spans, or None."""
+    a = host_spans.of_run(run)
+    if a is None:
+        return None
+    into = [(s, e, int(st["tokens"]), int(st["padded"]), int(st["cached"]))
+            for n, s, e, st in a["spans"] if n == "serve/prefill_into"
+            and "cached" in st]
+    return into or None
+
+
+def paired_prefills(run: dict):
+    """``[(device seconds, tokens, padded, cached)]``: each
+    ``serve_prefill`` execution on chip 0 with the ``serve/prefill_into``
+    span that holds its midpoint (the span waits for the execution's
+    token), so a share charges the traced prefills their own work and
+    not the window's mean; an execution whose span began before the
+    session is left out, time and all. None where there is no trace, no
+    span or no pair."""
+    into, dev = prefill_spans(run), chip0_events(run)
+    if into is None or dev is None:
+        return None
+    execs = [(s, e) for n, s, e in dev["modules"] if "serve_prefill" in n]
+    out = []
+    for s, e in execs:
+        mid = 0.5 * (s + e)
+        span = next((sp for sp in into if sp[0] <= mid <= sp[1]), None)
+        if span is not None:
+            out.append(((e - s) / 1e9,) + span[2:])
+    if not out:
+        return None
+    behind = [p for p in out if p[3]]
+    log(f"traced prefills paired with their spans: {len(out)} of "
+        f"{len(execs)} executions, {sum(p[0] for p in out):.3f} s, "
+        f"{sum(p[1] for p in out)} tokens prefilled; {len(behind)} of "
+        f"them behind restored rows, {sum(p[0] for p in behind):.3f} s")
+    return out
+
+
+def prefill_flops_share(run: dict, flops_of):
+    """Operations the traced prefills needed over their device time at
+    the chip's peak, where one prefill of ``tokens`` behind ``cached``
+    restored rows needs ``flops_of(tokens, cached)``: every traced
+    execution is charged its own span's work
+    (:func:`paired_prefills`)."""
+    execs = paired_prefills(run)
+    if execs is None:
+        return None
+    need = sum(flops_of(tokens, cached) for _, tokens, _, cached in execs)
+    return 100.0 * need / (sum(x[0] for x in execs)
+                           * run["peaks"]["bf16_flops"])
+
+
+def op_inside_module(run: dict, op: str, module: str):
+    """``(executions of the traced programs whose name holds ``module``,
+    device seconds of the operations named ``op`` inside them)`` on chip
+    0, or None where either is missing."""
+    dev = chip0_events(run)
+    if dev is None:
+        return None
+    progs = [(s, e) for n, s, e in dev["modules"] if module in n]
+    inside = tr.total(host_spans.intersect(
+        tr.union(progs), tr.union((s, e) for n, s, e in dev["ops"]
+                                  if n == op))) / 1e9
+    if not progs or not inside:
+        return None
+    log(f"{op} inside {len(progs)} traced {module} executions: "
+        f"{inside:.3f} s of their "
+        f"{sum(e - s for s, e in progs) / 1e9:.3f} s")
+    return len(progs), inside
+
+
+def prefill_flops_share_pct(run: dict):
+    """:func:`prefill_flops_share` at ``costs.decoder_prefill_flops``
+    of the ``tokens`` prefilled (scores against restored rows count
+    nothing, so the share reads low, never high, behind a prefix
+    hit)."""
+    return prefill_flops_share(
+        run, lambda tokens, _: costs.decoder_prefill_flops(run["cfg"],
+                                                           tokens))
 
 
 # -- training -------------------------------------------------------------

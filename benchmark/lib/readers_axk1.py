@@ -1,4 +1,4 @@
-"""Readers of the ``.axk1`` metrics that no other cell has.
+"""The readers of ``ax_k1`` (``program.readers``).
 
 The counters are the program's own (``obs``' registry, summed on the
 device over real tokens and published by the engine every 64 decode
@@ -83,18 +83,6 @@ def decode_hbm_share_pct(run: dict):
     return 100.0 * need / (secs * run["peaks"]["hbm_bytes_per_s"])
 
 
-def _prefill_spans(run: dict):
-    """``[(start, end, tokens, padded, cached)]`` of the traced
-    ``serve/prefill_into`` spans, or None."""
-    a = host_spans.of_run(run)
-    if a is None:
-        return None
-    into = [(s, e, int(st["tokens"]), int(st["padded"]), int(st["cached"]))
-            for n, s, e, st in a["spans"] if n == "serve/prefill_into"
-            and "cached" in st]
-    return into or None
-
-
 def prefix_cached_token_share_pct(run: dict):
     """Of the prompt tokens the callers' requests brought, the share the
     prefix store restored, by the engine's own accounting:
@@ -136,41 +124,16 @@ def restore_share_pct(run: dict):
 
 def prefill_flops_share_pct(run: dict):
     """Operations the traced prefills needed over their device time at
-    the chip's peak. Each ``serve_prefill`` execution on chip 0 is paired
-    with the ``serve/prefill_into`` span that holds its midpoint (the
-    span waits for the execution's token) and needs
-    ``costs_axk1.prefill_flops`` of that span's ``tokens`` and
-    ``cached``, with the counters' mean pairs a token a sparse layer; an
-    execution whose span began before the session is left out, time and
-    all."""
-    into = _prefill_spans(run)
+    the chip's peak: each traced execution is charged
+    ``costs_axk1.prefill_flops`` of its own span's ``tokens`` and
+    ``cached`` (``readers.prefill_flops_share``), with the counters' mean
+    pairs a token a sparse layer."""
     c = _routing("prefill")
-    if into is None or c is None:
+    if c is None:
         return None
-    path = tr.find_xplane(str(host_spans.ROOT / ".bench_trace"
-                              / run["workload"]))
-    devs = tr.load(path)
-    execs = [(s, e) for n, s, e in devs[min(devs)]["modules"]
-             if "serve_prefill" in n]
     cfg = run["cfg"]
     pairs = c.get("moe_held_pairs_total", 0.0) \
         / (c["moe_picks_total"] / cfg["num_experts_per_tok"])
-    need = secs = 0.0
-    kinds = {"whole": [0, 0.0], "suffix": [0, 0.0]}
-    for s, e in execs:
-        mid = 0.5 * (s + e)
-        span = next((sp for sp in into if sp[0] <= mid <= sp[1]), None)
-        if span is None:
-            continue
-        _, _, tokens, _, cached = span
-        need += costs_axk1.prefill_flops(cfg, tokens, cached, pairs)
-        secs += (e - s) / 1e9
-        kind = kinds["suffix" if cached else "whole"]
-        kind[0] += 1
-        kind[1] += (e - s) / 1e9
-    if not secs:
-        return None
-    log("traced prefills paired with their spans: "
-        + ", ".join(f"{k} {n} in {t:.3f} s" for k, (n, t) in kinds.items())
-        + f"; {pairs:.3f} pairs a token a sparse layer on held experts")
-    return 100.0 * need / (secs * run["peaks"]["bf16_flops"])
+    return readers.prefill_flops_share(
+        run, lambda t, cached: costs_axk1.prefill_flops(cfg, t, cached,
+                                                        pairs))

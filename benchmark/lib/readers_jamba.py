@@ -1,4 +1,4 @@
-"""Readers of the ``.jamba`` metrics that no other cell has.
+"""The readers of ``jamba2_3b`` (``program.readers``).
 
 The counters are the program's own (``obs``' registry, summed on the
 device over real tokens and published by the engine every 64 decode
@@ -13,8 +13,7 @@ phases differ from the window. A program without the counters gives
 
 from __future__ import annotations
 
-from benchmark.lib import costs_jamba, host_spans, readers, readers_axk1
-from benchmark.lib import trace_reduce as tr
+from benchmark.lib import costs_jamba, readers
 from benchmark.lib.common import log
 from benchmark.lib.readers_kexaone import counters
 
@@ -68,27 +67,9 @@ def state_bytes_share_pct(run: dict):
 
 def prefill_flops_share_pct(run: dict):
     """Operations the traced prefills needed over their device time at
-    the chip's peak, matrix products only. Each ``serve_prefill``
-    execution on chip 0 is paired with the ``serve/prefill_into`` span
-    that holds its midpoint and needs ``costs_jamba.prefill_flops`` of
-    that span's ``tokens`` (as ``readers_axk1.prefill_flops_share_pct``
-    pairs them); an execution whose span began before the session is
-    left out, time and all."""
-    into = readers_axk1._prefill_spans(run)
-    if into is None:
-        return None
-    devs = tr.load(tr.find_xplane(str(host_spans.ROOT / ".bench_trace"
-                                      / run["workload"])))
-    execs = [(s, e) for n, s, e in devs[min(devs)]["modules"]
-             if "serve_prefill" in n]
-    need = secs = 0.0
-    for s, e in execs:
-        mid = 0.5 * (s + e)
-        span = next((sp for sp in into if sp[0] <= mid <= sp[1]), None)
-        if span is None:
-            continue
-        need += costs_jamba.prefill_flops(run["cfg"], span[2])
-        secs += (e - s) / 1e9
-    if not secs:
-        return None
-    return 100.0 * need / (secs * run["peaks"]["bf16_flops"])
+    the chip's peak, matrix products only (the scan's time is in the
+    denominator and its operations are not in the numerator): each
+    traced execution is charged ``costs_jamba.prefill_flops`` of its
+    own span's ``tokens`` (``readers.prefill_flops_share``)."""
+    return readers.prefill_flops_share(
+        run, lambda t, _: costs_jamba.prefill_flops(run["cfg"], t))

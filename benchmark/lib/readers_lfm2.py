@@ -1,4 +1,4 @@
-"""Readers of the ``.lfm2`` metrics that no other cell has.
+"""The readers of ``lfm2_8b_a1b`` (``program.readers``).
 
 The counters are the program's own (``obs``' registry, summed on the
 device over real tokens and published by the engine every 64 decode
@@ -16,10 +16,7 @@ brought them cannot build the model at all) gives ``None`` everywhere.
 
 from __future__ import annotations
 
-import functools
-
-from benchmark.lib import costs_lfm2, host_spans, readers, readers_axk1
-from benchmark.lib import trace_reduce as tr
+from benchmark.lib import costs_lfm2, readers
 from benchmark.lib.common import log
 from benchmark.lib.readers_kexaone import counters
 
@@ -38,6 +35,29 @@ def _rounds():
         return None
     return c["conv_calls_total"], \
         c.get("conv_tokens_total", 0.0) / c["conv_calls_total"]
+
+
+def conv_rows_per_round(run: dict):
+    """Rows whose carried inputs a decode round's short convolutions
+    moved, in the mean: how full the rounds ran."""
+    del run
+    r = _rounds()
+    return None if r is None else r[1]
+
+
+def held_pairs_per_round(run: dict):
+    """Token-expert pairs computed on the held experts, a layer a round:
+    every expert of a layer is held, so active rows x the picks a
+    token."""
+    c = _routing("decode")
+    if c is None:
+        return None
+    k = run["cfg"]["num_experts_per_tok"]
+    rows = c["moe_picks_total"] / c["moe_calls_total"] / k
+    log(f"routing counters, decode: {c['moe_calls_total']:.0f} layer "
+        f"executions, {rows:.2f} active rows a round of {run['slots']}, "
+        f"{k} picks a token")
+    return c.get("moe_held_pairs_total", 0.0) / c["moe_calls_total"]
 
 
 def held_experts_touched_share_pct(run: dict):
@@ -107,21 +127,6 @@ def state_bytes_share_pct(run: dict):
         / costs_lfm2.decode_round_bytes(run["cfg"], *need)
 
 
-@functools.lru_cache(maxsize=1)
-def _chip0(path: str) -> dict:
-    devs = tr.load(path)
-    return devs[min(devs)]
-
-
-def _trace(run: dict):
-    """Chip 0's traced events, read once for the readers that want
-    them, or None for a run without a trace."""
-    if run.get("trace") is None:
-        return None
-    return _chip0(tr.find_xplane(str(host_spans.ROOT / ".bench_trace"
-                                     / run["workload"])))
-
-
 def grouped_experts_hbm_share_pct(run: dict):
     """The kernel's own roofline in the decode round: the bytes of the
     experts the traced rounds touched (by the counters' mean, at
@@ -130,53 +135,25 @@ def grouped_experts_hbm_share_pct(run: dict):
     execution on chip 0, at the chip's peak bandwidth. At eight rows an
     expert the kernel is bound by the experts' bytes."""
     need = _round_need(run)
-    dev = _trace(run)
-    if need is None or dev is None:
+    inside = readers.op_inside_module(run, "grouped_experts", "serve_step")
+    if need is None or inside is None:
         return None
-    steps = [(s, e) for n, s, e in dev["modules"] if "serve_step" in n]
-    inside = tr.total(host_spans.intersect(
-        tr.union(steps), tr.union([(s, e) for n, s, e in dev["ops"]
-                                   if n == "grouped_experts"]))) / 1e9
-    if not steps or not inside:
-        return None
-    total = sum(e - s for s, e in steps) / 1e9
-    log(f"grouped_experts inside {len(steps)} traced rounds: {inside:.3f} "
-        f"s of their {total:.3f} s")
-    return 100.0 * len(steps) * costs_lfm2.experts_bytes(
-        run["cfg"], need[0]) / (inside * run["peaks"]["hbm_bytes_per_s"])
+    n, secs = inside
+    return 100.0 * n * costs_lfm2.experts_bytes(run["cfg"], need[0]) \
+        / (secs * run["peaks"]["hbm_bytes_per_s"])
 
 
 def prefill_flops_share_pct(run: dict):
     """Operations the traced prefills needed over their device time at
-    the chip's peak, matrix products only. Each ``serve_prefill``
-    execution on chip 0 is paired with the ``serve/prefill_into`` span
-    that holds its midpoint and needs ``costs_lfm2.prefill_flops`` of
-    that span's ``tokens`` with the counters' mean pairs a token a
-    sparse layer (as ``readers_axk1.prefill_flops_share_pct`` pairs
-    them); an execution whose span began before the session is left out,
-    time and all."""
-    into = readers_axk1._prefill_spans(run)
+    the chip's peak, matrix products only: each traced execution is
+    charged ``costs_lfm2.prefill_flops`` of its own span's ``tokens``
+    (``readers.prefill_flops_share``), with the counters' mean pairs a token
+    a sparse layer."""
     c = _routing("prefill")
-    dev = _trace(run)
-    if into is None or c is None or dev is None:
+    if c is None:
         return None
     cfg = run["cfg"]
     pairs = c.get("moe_held_pairs_total", 0.0) \
         / (c["moe_picks_total"] / cfg["num_experts_per_tok"])
-    need = secs = 0.0
-    paired = 0
-    execs = [(s, e) for n, s, e in dev["modules"] if "serve_prefill" in n]
-    for s, e in execs:
-        mid = 0.5 * (s + e)
-        span = next((sp for sp in into if sp[0] <= mid <= sp[1]), None)
-        if span is None:
-            continue
-        paired += 1
-        need += costs_lfm2.prefill_flops(cfg, span[2], pairs)
-        secs += (e - s) / 1e9
-    if not secs:
-        return None
-    log(f"traced prefills paired with their spans: {paired} of "
-        f"{len(execs)} executions, {secs:.3f} s; {pairs:.3f} pairs a "
-        f"token a sparse layer on held experts")
-    return 100.0 * need / (secs * run["peaks"]["bf16_flops"])
+    return readers.prefill_flops_share(
+        run, lambda t, _: costs_lfm2.prefill_flops(cfg, t, pairs))

@@ -1,4 +1,4 @@
-"""Readers of the ``.longcat`` metrics that no other cell has.
+"""The readers of ``longcat_flash_omni`` (``program.readers``).
 
 The routing counters are the program's own (``obs``' registry:
 ``moe_*_total{kind,layer}``, summed on the device over real tokens and
@@ -94,20 +94,16 @@ def decode_hbm_share_pct(run: dict):
 
 
 def prefill_flops_share_pct(run: dict):
-    """Operations the traced prefills needed (the window's mean prompt
-    times the prefills in the trace; pairs a token a layer from the
-    counters) over their device time at the chip's peak."""
-    mod = readers._module(run, r"serve_prefill")
-    t0, t1 = run["t0"], run["t1"]
-    lens = [len(s.prompt) for s in run["sent"]
-            if s.arrivals and t0 <= s.arrivals[0] <= t1]
+    """Operations the traced prefills needed over their device time at
+    the chip's peak: each traced execution is charged
+    ``costs_longcat.prefill_flops`` of its own span's ``tokens``
+    (``readers.prefill_flops_share``), with the counters' mean pairs a token
+    a layer."""
     c = counters("prefill")
-    if mod is None or not lens or c is None:
+    if c is None:
         return None
-    n, secs = mod
     cfg = run["cfg"]
     tokens = sum(c["moe_picks_total"]) / cfg["moe_topk"]
     pairs = sum(c.get("moe_held_pairs_total", [0.0])) / tokens
-    mean = sum(costs_longcat.prefill_flops(cfg, L, pairs)
-               for L in lens) / len(lens)
-    return 100.0 * n * mean / (secs * run["peaks"]["bf16_flops"])
+    return readers.prefill_flops_share(
+        run, lambda t, _: costs_longcat.prefill_flops(cfg, t, pairs))

@@ -1,8 +1,8 @@
-"""Readers of the ``.sdar`` metrics that no other cell has.
+"""The readers of ``sdar_30b_a3b`` (``program.readers``).
 
 The counters are the program's own (``obs``' registry, summed on the
 device and published by the engine every 64 rounds): a block decoder's
-``block_forwards_total`` (a live row a round), ``block_commits_total``,
+``block_forwards_total`` (a live row a round),
 ``block_positions_unmasked_total`` and ``block_tokens_emitted_total``,
 and by layer and program kind the routing counts ``moe_*_total`` and
 the attention rows ``attn_rows_*_total`` (``kind`` ``decode`` is the
@@ -16,7 +16,6 @@ everywhere.
 from __future__ import annotations
 
 from benchmark.lib import costs_sdar, host_spans, readers
-from benchmark.lib import trace_reduce as tr
 from benchmark.lib.common import log
 from benchmark.lib.readers_kexaone import counters
 
@@ -40,28 +39,20 @@ def _block_length(run: dict) -> int:
 
 def tokens_per_forward(run: dict):
     """Tokens handed to requests over forwards of a block (a live row a
-    round): a block of B unmasked in S steps gives B / (S + 1)."""
+    round): a block of B unmasked in S steps gives B / S, the step that
+    leaves it whole handing it out (its keys and values are written by
+    the row's next forward, beside that block's first step)."""
     del run
     c = _blocks()
     if c is None:
         return None
     log(f"block counters: {c['block_forwards_total']:.0f} forwards, "
-        f"{c.get('block_commits_total', 0.0):.0f} commits, "
+        f"{c.get('block_commits_fused_total', 0.0):.0f} of them writing "
+        f"an owed block, "
         f"{c.get('block_positions_unmasked_total', 0.0):.0f} positions "
         f"unmasked, {c.get('block_tokens_emitted_total', 0.0):.0f} tokens "
         f"emitted")
     return c.get("block_tokens_emitted_total", 0.0) \
-        / c["block_forwards_total"]
-
-
-def commit_forward_share_pct(run: dict):
-    """Of the forwards of a block, those that unmasked nothing and
-    committed it."""
-    del run
-    c = _blocks()
-    if c is None:
-        return None
-    return 100.0 * c.get("block_commits_total", 0.0) \
         / c["block_forwards_total"]
 
 
@@ -134,6 +125,26 @@ def decode_hbm_share_pct(run: dict):
     return 100.0 * need / (secs * run["peaks"]["hbm_bytes_per_s"])
 
 
+def grouped_experts_hbm_share_pct(run: dict):
+    """The ``grouped_experts`` kernel's own roofline in the block round:
+    the bytes of the experts the traced rounds touched (the counters'
+    mean a round, summed over the layers, at
+    ``costs_sdar.experts_bytes``) over the device time of the
+    ``grouped_experts`` operations inside ``serve_step`` executions on
+    chip 0, at the chip's peak bandwidth. At a round's fifteen or so rows
+    an expert the kernel is bound by the experts' bytes."""
+    c = _routing("decode")
+    inside = readers.op_inside_module(run, "grouped_experts", "serve_step")
+    if c is None or inside is None:
+        return None
+    n, secs = inside
+    cfg = run["cfg"]
+    touched = c.get("moe_held_experts_touched_total", 0.0) \
+        / c["moe_calls_total"] * cfg["num_hidden_layers"]
+    return 100.0 * n * costs_sdar.experts_bytes(cfg, touched) \
+        / (secs * run["peaks"]["hbm_bytes_per_s"])
+
+
 def prefill_flops_share_pct(run: dict):
     """Operations the traced prefills needed over their device time at
     the chip's peak. A block decoder's prefill fetches nothing, so its
@@ -151,10 +162,10 @@ def prefill_flops_share_pct(run: dict):
     into = sorted((s, int(st["tokens"]), int(st["cached"]))
                   for n, s, e, st in a["spans"]
                   if n == "serve/prefill_into" and int(st["tokens"]) > 0)
-    path = tr.find_xplane(str(host_spans.ROOT / ".bench_trace"
-                              / run["workload"]))
-    devs = tr.load(path)
-    execs = sorted((s, e) for n, s, e in devs[min(devs)]["modules"]
+    dev = readers.chip0_events(run)
+    if dev is None:
+        return None
+    execs = sorted((s, e) for n, s, e in dev["modules"]
                    if "serve_prefill" in n)
     cfg = run["cfg"]
     pairs = c.get("moe_held_pairs_total", 0.0) \

@@ -2,8 +2,10 @@
 
 Tokens handed to requests over forwards of a block (a live row a
 round), by the program's counters (``block_tokens_emitted_total`` over
-``block_forwards_total``): a block of 4 unmasked in 2 steps and
-committed by a third forward gives 4 / 3.
+``block_forwards_total``): a block of 4 unmasked in 2 steps gives
+4 / 2; the step that leaves it whole hands it out, and its keys and
+values are written by the row's next forward, beside the next block's
+first step (no forward only commits, since PR 44).
 """
 
 from benchmark.lib import readers_sdar

@@ -23,6 +23,8 @@ def test_parameter_counts_by_hand():
     assert c.params_outside_experts(SMALL) == 3 * (192 + 16 + 16) == 672
     # a key and a value row of 2 heads x 2, bf16
     assert c.kv_bytes_per_position(SMALL) == 2 * 4 * 2 == 16
+    # five experts touched over the layers, bf16
+    assert c.experts_bytes(SMALL, 5) == 5 * 96 * 2 == 960
 
 
 @pytest.mark.parametrize("start,tokens,block,pairs", [
@@ -56,6 +58,7 @@ def test_published_sizes_match_the_issues_table():
     assert c.attn_params(cfg) == 2 * 2048 * 4096 + 2 * 2048 * 512
     assert round(c.attn_params(cfg) / 1e6, 1) == 18.9
     assert round(c.expert_params(cfg) / 1e6, 2) == 4.72
+    assert round(c.experts_bytes(cfg, 1) / 1e6, 1) == 9.4
     layer = c.params_outside_experts(cfg) / 7 + 128 * c.expert_params(cfg)
     assert round(layer / 1e6) == 623
     whole = 7 * layer + 2 * 151936 * 2048

@@ -70,17 +70,17 @@ def test_closed_loop_cell_is_correct_and_its_control_is_not():
     line = bench_run.result_line(
         run, [dict(name=n, unit="x") for n in (
             "serve_throughput", "setup_s",
-            "held_expert_pairs_per_round.axk1",
-            "held_experts_touched_share.axk1", "pick_groups_per_token.axk1",
-            "latent_rows_attended_share.axk1", "decode_round_p50.axk1",
-            "prefill_share.axk1", "peak_hbm_share.axk1",
+            "held_expert_pairs_per_round",
+            "held_experts_touched_share", "pick_groups_per_token.axk1",
+            "latent_rows_attended_share.axk1", "decode_round_p50",
+            "prefill_share", "peak_hbm_share",
             "prefix_cached_token_share.axk1")], traced=False)
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert line["correct"] and line["failed"] == 0
     assert m["serve_throughput"] > 0
     # 8 of 16 router outputs are held here, 4 picks a token from 2 groups
-    assert 0 < m["held_experts_touched_share.axk1"] <= 100
-    assert 0 < m["held_expert_pairs_per_round.axk1"] <= 4 * 4
+    assert 0 < m["held_experts_touched_share"] <= 100
+    assert 0 < m["held_expert_pairs_per_round"] <= 4 * 4
     assert 1.0 <= m["pick_groups_per_token.axk1"] <= 2.0
     assert 0 < m["latent_rows_attended_share.axk1"] < 100
     # documents of 48 tokens in prompts of 52 to 64, three in four
@@ -88,7 +88,7 @@ def test_closed_loop_cell_is_correct_and_its_control_is_not():
     # the traced-only readers say nothing in an untraced run
     assert bench_run.read_metrics(
         [dict(name=n, unit="%") for n in (
-            "decode_hbm_share.axk1", "prefill_flops_share.axk1",
+            "decode_hbm_share", "prefill_flops_share",
             "restore_share.axk1")],
         run) == {}
 

@@ -65,19 +65,19 @@ def test_closed_loop_cell_is_correct_and_its_control_is_not():
     assert not run["control"]["correct"], run["control"]
     line = bench_run.result_line(
         run, [dict(name=n, unit="x") for n in (
-            "serve_throughput", "setup_s", "state_bytes_share.jamba",
-            "decode_round_p50.jamba", "prefill_share.jamba",
-            "peak_hbm_share.jamba")], traced=False)
+            "serve_throughput", "setup_s", "state_bytes_share",
+            "decode_round_p50", "prefill_share",
+            "peak_hbm_share")], traced=False)
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert line["correct"] and line["failed"] == 0
     assert m["serve_throughput"] > 0
     # four rows' state in four layers beside ~118 K parameters
-    assert 10 < m["state_bytes_share.jamba"] < 60
+    assert 10 < m["state_bytes_share"] < 60
     # the traced-only readers say nothing in an untraced run
     assert bench_run.read_metrics(
         [dict(name=n, unit="%") for n in (
-            "decode_hbm_share.jamba", "prefill_flops_share.jamba",
-            "prefill_pad_share.jamba")], run) == {}
+            "decode_hbm_share", "prefill_flops_share",
+            "prefill_pad_share")], run) == {}
 
 
 def _without(leaf: str):

@@ -66,17 +66,17 @@ def test_closed_loop_cell_is_correct_and_its_control_is_not():
     line = bench_run.result_line(
         run, [dict(name=n, unit="x") for n in (
             "serve_throughput", "setup_s",
-            "held_expert_pairs_per_round.kexaone",
-            "held_experts_touched_share.kexaone",
+            "held_expert_pairs_per_round",
+            "held_experts_touched_share",
             "full_rows_attended_share.kexaone", "ring_read_share.kexaone",
-            "decode_round_p50.kexaone", "prefill_share.kexaone",
-            "peak_hbm_share.kexaone")], traced=False)
+            "decode_round_p50", "prefill_share",
+            "peak_hbm_share")], traced=False)
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert line["correct"] and line["failed"] == 0
     assert m["serve_throughput"] > 0
     # 8 of 16 router outputs are held here, 4 picks a token
-    assert 0 < m["held_experts_touched_share.kexaone"] <= 100
-    assert 0 < m["held_expert_pairs_per_round.kexaone"] <= 4 * 4
+    assert 0 < m["held_experts_touched_share"] <= 100
+    assert 0 < m["held_expert_pairs_per_round"] <= 4 * 4
     # three sparse ring layers of 8 rows, one full layer of 128 (the
     # dense layer's ring too: four rings in all)
     assert m["ring_read_share.kexaone"] == pytest.approx(
@@ -84,7 +84,7 @@ def test_closed_loop_cell_is_correct_and_its_control_is_not():
     assert 0 < m["full_rows_attended_share.kexaone"] < 100
     # a traced-only reader says nothing in an untraced run
     assert bench_run.read_metrics(
-        [dict(name="decode_hbm_share.kexaone", unit="%")], run) == {}
+        [dict(name="decode_hbm_share", unit="%")], run) == {}
 
 
 def test_held_experts_left_out_is_not_correct():

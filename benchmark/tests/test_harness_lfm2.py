@@ -66,24 +66,29 @@ def test_closed_loop_cell_is_correct_and_its_control_is_not():
     assert not run["control"]["correct"], run["control"]
     line = bench_run.result_line(
         run, [dict(name=n, unit="x") for n in (
-            "serve_throughput", "setup_s", "state_bytes_share.lfm2",
-            "cache_rows_attended_share.lfm2",
-            "held_experts_touched_share.lfm2", "decode_round_p50.lfm2",
-            "prefill_share.lfm2")], traced=False)
+            "serve_throughput", "setup_s", "state_bytes_share",
+            "cache_rows_attended_share",
+            "held_experts_touched_share", "held_expert_pairs_per_round",
+            "conv_rows_per_round.lfm2", "decode_round_p50",
+            "prefill_share")], traced=False)
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert line["correct"] and line["failed"] == 0
     assert m["serve_throughput"] > 0
     # four rows' two carried inputs in five layers beside ~150 K
     # parameters
-    assert 0 < m["state_bytes_share.lfm2"] < 10
-    assert 0 < m["cache_rows_attended_share.lfm2"] < 100
-    assert 0 < m["held_experts_touched_share.lfm2"] <= 100
-    assert 0 < m["prefill_share.lfm2"] < 100
+    assert 0 < m["state_bytes_share"] < 10
+    assert 0 < m["cache_rows_attended_share"] < 100
+    assert 0 < m["held_experts_touched_share"] <= 100
+    # four slots; every expert is held, so active rows x the picks
+    assert 0 < m["conv_rows_per_round.lfm2"] <= 4
+    assert m["held_expert_pairs_per_round"] \
+        == pytest.approx(2 * m["conv_rows_per_round.lfm2"])
+    assert 0 < m["prefill_share"] < 100
     # traced-only readers say nothing in an untraced run
     assert bench_run.read_metrics(
         [dict(name=n, unit="%") for n in (
-            "decode_hbm_share.lfm2", "grouped_experts_hbm_share.lfm2",
-            "prefill_flops_share.lfm2", "prefill_pad_share.lfm2")],
+            "decode_hbm_share", "grouped_experts_hbm_share",
+            "prefill_flops_share", "prefill_pad_share")],
         run) == {}
 
 

@@ -60,20 +60,20 @@ def test_closed_loop_cell_is_correct_and_its_control_is_not():
     line = bench_run.result_line(
         run, [dict(name=n, unit="x") for n in (
             "serve_throughput", "setup_s",
-            "held_expert_pairs_per_round.longcat",
-            "held_experts_touched_share.longcat",
-            "zero_expert_pick_share.longcat", "decode_round_p50.longcat",
-            "prefill_share.longcat", "peak_hbm_share.longcat")], traced=False)
+            "held_expert_pairs_per_round",
+            "held_experts_touched_share",
+            "zero_expert_pick_share.longcat", "decode_round_p50",
+            "prefill_share", "peak_hbm_share")], traced=False)
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert line["correct"] and line["failed"] == 0
     assert m["serve_throughput"] > 0
     # 8 of 24 router outputs are zero experts, 8 are held here
     assert 15 < m["zero_expert_pick_share.longcat"] < 55
-    assert 0 < m["held_experts_touched_share.longcat"] <= 100
-    assert 0 < m["held_expert_pairs_per_round.longcat"] <= 4 * 4
+    assert 0 < m["held_experts_touched_share"] <= 100
+    assert 0 < m["held_expert_pairs_per_round"] <= 4 * 4
     # a traced-only reader says nothing in an untraced run
     assert bench_run.read_metrics(
-        [dict(name="decode_hbm_share.longcat", unit="%")], run) == {}
+        [dict(name="decode_hbm_share", unit="%")], run) == {}
 
 
 def test_held_experts_left_out_is_not_correct():

@@ -2,9 +2,9 @@
 
 Beside ``test_harness_kexaone.py``, for the block-diffusion decoder: the
 run comes out ``correct``, its int8 control does not, and neither does
-a run whose blocks are causal inside, one whose commit forward leaves
-the last denoising step's keys and values in the cache, nor one whose
-experts' part is left out. ``serving.program_model`` passes a model
+a run whose blocks are causal inside, one whose owed write is left out
+(a finished block keeps its last denoising step's keys and values), nor
+one whose experts' part is left out. ``serving.program_model`` passes a model
 eight sizes and no more, so the sizes it does not pass (head size,
 experts, the generation's) are the defaults of a tiny model registered
 for the length of a test.
@@ -66,26 +66,27 @@ def test_closed_loop_cell_is_correct_and_its_control_is_not():
     line = bench_run.result_line(
         run, [dict(name=n, unit="x") for n in (
             "serve_throughput", "setup_s", "tokens_per_forward.sdar",
-            "commit_forward_share.sdar", "cache_rows_attended_share.sdar",
-            "held_experts_touched_share.sdar",
-            "held_expert_pairs_per_round.sdar", "decode_round_p50.sdar",
-            "prefill_share.sdar", "peak_hbm_share.sdar")], traced=False)
+            "cache_rows_attended_share", "held_experts_touched_share",
+            "held_expert_pairs_per_round", "decode_round_p50",
+            "prefill_share", "peak_hbm_share")], traced=False)
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert line["correct"] and line["failed"] == 0
     assert m["serve_throughput"] > 0
-    # a block of four in two steps and a commit: 4 / 3, a little under
-    # it where a prompt's tail or an answer's end cuts a block short
-    assert 1.1 < m["tokens_per_forward.sdar"] <= 4 / 3 + 1e-9
-    assert 33.3 <= m["commit_forward_share.sdar"] < 45
-    assert 0 < m["cache_rows_attended_share.sdar"] < 100
-    assert 0 < m["held_experts_touched_share.sdar"] <= 100
-    # every expert is held: live rows x 4 positions x 2 picks
-    assert 0 < m["held_expert_pairs_per_round.sdar"] <= 4 * 4 * 2
-    assert 0 < m["prefill_share.sdar"] < 100
+    # a block of four in two steps, handed out by the second (no
+    # forward only commits): 4 / 2, a little under it where a prompt's
+    # tail or an answer's end cuts a block short
+    assert 1.5 < m["tokens_per_forward.sdar"] <= 4 / 2 + 1e-9
+    assert 0 < m["cache_rows_attended_share"] < 100
+    assert 0 < m["held_experts_touched_share"] <= 100
+    # every expert is held: live rows x the positions a row feeds (its
+    # open block of 4, and the 4 of the block it owes) x 2 picks
+    assert 0 < m["held_expert_pairs_per_round"] <= 4 * (4 + 4) * 2
+    assert 0 < m["prefill_share"] < 100
     # traced-only readers say nothing in an untraced run
     assert bench_run.read_metrics(
         [dict(name=n, unit="%") for n in (
-            "decode_hbm_share.sdar", "prefill_flops_share.sdar")], run) == {}
+            "decode_hbm_share", "grouped_experts_hbm_share",
+            "prefill_flops_share")], run) == {}
 
 
 @pytest.mark.parametrize("fault", ["causal_block", "commit_skipped",

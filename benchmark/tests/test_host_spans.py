@@ -195,14 +195,14 @@ def test_recorded_clock_check(recorded):
 
 
 METRICS = {
-    "idle_admit_share.chat": 55.53, "idle_admit_share.docs": 55.53,
+    "idle_admit_share.chat": 55.53, "idle_admit_share": 55.53,
     "idle_round_return_share.chat": 24.70,
-    "idle_round_return_share.docs": 24.70,
+    "idle_round_return_share": 24.70,
     "idle_retire_share.chat": 2.43, "idle_parked_share.chat": 15.49,
     "idle_unattributed_share.chat": 1.15,
-    "idle_unattributed_share.docs": 1.15 + 2.43 + 15.49,
+    "idle_unattributed_share": 1.15 + 2.43 + 15.49,
     "admit_stall_p50.chat": 14.213, "round_host_p50.chat": 3.305,
-    "prefill_pad_share.docs": 100.0 * (1.0 - 11.0 / 48.0),
+    "prefill_pad_share": 100.0 * (1.0 - 11.0 / 48.0),
 }
 
 
@@ -239,7 +239,7 @@ def test_cell_classes_sum_to_the_cells_idle_share(traced_run):
 
     chat = sum(read(f"idle_{k}_share.chat") for k in
                ("admit", "round_return", "retire", "parked", "unattributed"))
-    docs = sum(read(f"idle_{k}_share.docs") for k in
+    docs = sum(read(f"idle_{k}_share") for k in
                ("admit", "round_return", "unattributed"))
     assert chat == pytest.approx(99.30, abs=0.01)
     assert docs == pytest.approx(chat)

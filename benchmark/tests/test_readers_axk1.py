@@ -1,4 +1,4 @@
-"""The ``.axk1`` readers that read the serve loop's spans, on the trace
+"""A.X-K1's readers that read the serve loop's spans, on the trace
 recorded on the chip (``data/recorded_spans.xplane.pb``, see
 ``test_host_spans.py``): three admissions, a whole prompt of 5 tokens
 padded to 16, then twice 3 tokens behind one restored block of 16."""
@@ -17,7 +17,8 @@ CFG = dict(hidden_size=8, num_attention_heads=2, q_lora_rank=4,
            v_head_dim=3, intermediate_size=16, moe_intermediate_size=4,
            n_routed_experts=2, num_experts_per_tok=2, n_shared_experts=1,
            num_hidden_layers=4, first_k_dense_replace=1, vocab_size=10,
-           torch_dtype="bfloat16", expert_parallel=dict(ep_size=3, ep_rank=0))
+           torch_dtype="bfloat16", expert_parallel=dict(ep_size=3, ep_rank=0),
+           program=dict(readers="readers_axk1"))
 
 
 @pytest.fixture()
@@ -44,8 +45,9 @@ def test_restore_share_on_the_recorded_file(traced_run):
     assert _reader("restore_share.axk1")(traced_run) \
         == pytest.approx(100.0 * (0.724590 + 0.580719) / 50.871103,
                          rel=1e-4)
-    for name in ("restore_share.axk1", "prefill_flops_share.axk1"):
-        assert _reader(name)(dict(trace=None, workload="some_cell")) is None
+    for name in ("restore_share.axk1", "prefill_flops_share"):
+        assert _reader(name)(dict(trace=None, workload="some_cell",
+                                  cfg=CFG)) is None
 
 
 def test_cached_share_is_the_stores_own_count_over_the_callers_prompts():
@@ -89,7 +91,7 @@ def test_prefill_flops_pairs_each_execution_with_its_span(traced_run):
     prompt's and twice the suffix's behind 16 rows, with the counters'
     pairs a token. Without the routing counters the reader says
     nothing."""
-    read = _reader("prefill_flops_share.axk1")
+    read = _reader("prefill_flops_share")
     assert read(traced_run) is None
     names = tuple((n, {"kind": "prefill", "layer": "1"}) for n in (
         "moe_calls_total", "moe_picks_total", "moe_held_pairs_total"))
@@ -100,3 +102,22 @@ def test_prefill_flops_pairs_each_execution_with_its_span(traced_run):
     secs = (13167 + 15636 + 15792) / 1e9
     assert read(traced_run) == pytest.approx(100.0 * need / (secs * 1e9),
                                              rel=1e-6)
+
+
+def test_a_dense_decoder_charges_each_prefill_its_own_tokens(traced_run):
+    """Mistral's reader on the same file: since PR 49 it pairs as
+    A.X-K1's does, so the three executions are charged 5, 3 and 3
+    tokens (the restored rows count nothing), not three times the
+    window's mean prompt."""
+    from benchmark.lib import costs
+
+    cfg = dict(hidden_size=8, intermediate_size=16, num_attention_heads=2,
+               num_key_value_heads=1, head_dim=4, num_hidden_layers=2,
+               vocab_size=10, torch_dtype="bfloat16",
+               program=dict(readers="readers"))
+    run = dict(traced_run, cfg=cfg)
+    need = costs.decoder_prefill_flops(cfg, 5) \
+        + 2 * costs.decoder_prefill_flops(cfg, 3)
+    secs = (13167 + 15636 + 15792) / 1e9
+    assert _reader("prefill_flops_share")(run) \
+        == pytest.approx(100.0 * need / (secs * 1e9), rel=1e-6)
