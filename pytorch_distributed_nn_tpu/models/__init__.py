@@ -26,6 +26,7 @@ def get_model(cfg: ModelConfig):
     from pytorch_distributed_nn_tpu.models import (  # noqa: F401
         ax_k1,
         bert,
+        brumby,
         jamba,
         k_exaone,
         lenet,
@@ -60,6 +61,7 @@ def available_models() -> list[str]:
     from pytorch_distributed_nn_tpu.models import (  # noqa: F401
         ax_k1,
         bert,
+        brumby,
         jamba,
         k_exaone,
         lenet,

@@ -63,6 +63,13 @@ class DeviceCounters:
                 "conv_tokens_total",
                 "real positions that moved a short-convolution layer's "
                 "carried inputs", labels=labels),
+            "retention_calls_total": reg.counter(
+                "retention_calls_total",
+                "executions of a power-retention layer", labels=labels),
+            "retention_tokens_total": reg.counter(
+                "retention_tokens_total",
+                "real positions that advanced a power-retention layer's "
+                "state", labels=labels),
             "block_forwards_total": reg.counter(
                 "block_forwards_total",
                 "forwards of a block of positions by a block decoder's "

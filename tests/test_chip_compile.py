@@ -178,6 +178,37 @@ def _selective_scan(T):
     return build
 
 
+def _retention_step(rows):
+    """A decode round's state update and outputs at Brumby-14B's widths
+    (8 key-value heads of 128 under 5 query heads each, a state of
+    8,704 x 128 a head) over ``rows`` slots, in place."""
+    from pytorch_distributed_nn_tpu.ops.pallas import retention as rt
+
+    def build(arg):
+        f32, D = jnp.float32, rt.state_rows(128)
+        return (jax.jit(rt.step.__wrapped__, donate_argnums=(0,)),
+                [arg((rows, 8, D, 128), f32), arg((rows, 8), f32),
+                 arg((rows, 8, 128), f32), arg((rows, 8, 128), f32),
+                 arg((rows, 8, 5, 128), f32), arg((rows,), jnp.bool_)], 1)
+    return build
+
+
+def _retention_chunk(T):
+    """A prefill's sequential part at the same widths: ``T`` positions
+    of one row in chunks of 128, bf16 as served."""
+    from pytorch_distributed_nn_tpu.nn import retention
+    from pytorch_distributed_nn_tpu.ops.pallas import retention as rt
+
+    def build(arg):
+        f32, bf16, D = jnp.float32, jnp.bfloat16, rt.state_rows(128)
+        n, C = T // retention.CHUNK, retention.CHUNK
+        return (jax.jit(rt.chunk.__wrapped__, donate_argnums=(0,)),
+                [arg((1, 8, D, 128), f32), arg((1, 8, n, 5, C, 128), bf16),
+                 arg((1, 8, n, C, 128), bf16), arg((1, 8, n, C, 128), bf16),
+                 arg((1, 8, n), f32)], 1)
+    return build
+
+
 CASES = {
     # Llama-3-8B's head layout (32 q / 8 kv heads of 128), long context
     "flash_fwd_bwd_d128_gqa_T8192": _flash(32, 8, 8192, 128, True),
@@ -225,6 +256,11 @@ CASES = {
     "selective_scan_T128": _selective_scan(128),
     "selective_scan_T4096": _selective_scan(4096),
     "mamba_scan_T4096": _mamba_scan(4096),
+    # Brumby's retention: the round over the cell's 16 slots, a prefill's
+    # chunks at its smallest and largest bucket
+    "retention_step_16rows": _retention_step(16),
+    "retention_chunk_T1024": _retention_chunk(1024),
+    "retention_chunk_T4096": _retention_chunk(4096),
 }
 
 
@@ -687,3 +723,75 @@ def test_lfm2_serve_programs_fit_the_chip_at_the_cells_size(
     assert held < 16.0e9, held
     if program == "step":
         assert m.alias_size_in_bytes > 1.6e9   # the cache, in place
+
+
+@pytest.mark.parametrize("program", ["step", 1024, 4096])
+def test_brumby_serve_programs_fit_the_chip_at_the_cells_size(
+        topo, monkeypatch, program):
+    """``brumby_14b`` as its cell runs it (8 of 40 layers, every width,
+    the whole vocabulary, bf16; 16 slots x 8,192 positions, which bound
+    no leaf: the whole cache is state): the decode round and the
+    smallest and largest prefill bucket compile for the described chip
+    and fit its 16 GB beside what they are given. A layer's state is
+    moved by one ``retention_step`` call a round, in place (the cache
+    donated, the state aliased through the kernel: no copy of a leaf of
+    4.6 GB), and advanced by one ``retention_chunk`` call a prefill; no
+    program holds the logits of every fed position (the head is applied
+    to the row the engine takes).
+    ``benchmark/configs/brumby_14b.json``'s ``deployment`` stands on
+    what this prints."""
+    import re
+
+    from pytorch_distributed_nn_tpu.config import ModelConfig
+    from pytorch_distributed_nn_tpu.inference.generate import init_cache
+    from pytorch_distributed_nn_tpu.models import get_model
+    from pytorch_distributed_nn_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    layers, slots, rows = 8, 16, 8192
+    model = get_model(ModelConfig(name="brumby", dtype="bfloat16",
+                                  extra=dict(num_layers=layers)))
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def on(tree):
+        return jax.tree.map(lambda a: arg(a.shape, a.dtype), tree)
+
+    params = on(jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+        train=False))["params"])
+    if program == "step":
+        cache = on(jax.eval_shape(lambda: init_cache(model, slots, rows)))
+        compiled = jax.jit(
+            lambda *a: engine._serve_step.__wrapped__(*a),
+            static_argnums=(0,), donate_argnums=(2,)).lower(
+            model, params, cache, arg((slots,)), arg((slots,)),
+            arg((slots,), jnp.bool_), arg((slots,)), arg(())).compile()
+    else:
+        cache = on(jax.eval_shape(lambda: init_cache(model, 1, program)))
+        compiled = jax.jit(
+            lambda *a: engine._serve_prefill.__wrapped__(*a),
+            static_argnums=(0,), donate_argnums=(2,)).lower(
+            model, params, cache, arg((1, program)), arg((1,)),
+            arg((1,))).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == layers
+    m = compiled.memory_analysis()
+    held = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    print(f"brumby {program}: arguments {m.argument_size_in_bytes / 1e9:.3f} "
+          f"GB, temporaries {m.temp_size_in_bytes / 1e9:.3f}, outputs "
+          f"{m.output_size_in_bytes / 1e9:.3f}, aliased "
+          f"{m.alias_size_in_bytes / 1e9:.3f}, held {held / 1e9:.3f}")
+    assert held < 16.0e9, held
+    state = rf"f32\[{slots if program == 'step' else 1},8,8704,128\]"
+    assert not re.findall(rf"%copy[.\d]* = {state}",
+                          text[text.index("\nENTRY "):])
+    if program == "step":
+        assert m.alias_size_in_bytes > 4.5e9   # the cache, in place
+        assert m.temp_size_in_bytes < 0.3e9
+    else:
+        assert not re.findall(rf"f32\[(?:1,)?{program},151936\]", text)
+        assert m.temp_size_in_bytes < 1.0e9

@@ -56,6 +56,8 @@ TINY = {
     "lfm2_8b_a1b": dict(num_layers=4, d_model=32, num_heads=4,
                         num_kv_heads=2, mlp_dim=64, vocab_size=101,
                         expert_mlp_dim=16, num_experts=8, moe_topk=2),
+    "brumby": dict(num_layers=2, d_model=32, num_heads=4, num_kv_heads=2,
+                   head_dim=8, mlp_dim=64, vocab_size=101),
     "sdar_moe": dict(_SDAR),
     "sdar_30b_a3b_seq2": dict(_SDAR),   # the cell's steps: 2, sequential
 }
