@@ -39,6 +39,7 @@ from pytorch_distributed_nn_tpu.config import ModelConfig
 from pytorch_distributed_nn_tpu.models import register
 from pytorch_distributed_nn_tpu.models.llama import RMSNorm
 from pytorch_distributed_nn_tpu.models.longcat_flash import KINDS, SwiGLU
+from pytorch_distributed_nn_tpu.nn import head_input
 from pytorch_distributed_nn_tpu.nn.dtypes import get_policy
 from pytorch_distributed_nn_tpu.nn.retention import PowerRetention
 
@@ -95,9 +96,6 @@ class Brumby(nn.Module):
     takes_token_mask = True
     # where in the ``cache`` collection the running totals live
     device_counter_leaf = ("device_counters",)
-    # a prefill is told which row of each sequence it reads, and the head
-    # scores that row alone (151,936 columns: serve/engine._head_kw)
-    takes_head_rows = True
 
     def device_counter_names(self) -> tuple:
         """``(metric, labels)`` of each entry of that leaf."""
@@ -128,8 +126,8 @@ class Brumby(nn.Module):
         bool marks the real tokens, a left-aligned prefix of each row:
         the rest advance no state and reach no counter (their rows of
         the result mean nothing). ``head_rows`` (B, K) int32: which of a
-        sequence's T rows reach the final norm and the head (all of them
-        by default), as :class:`models.sdar_moe.SdarMoe` takes it."""
+        sequence's T rows reach the final norm and the head
+        (``nn.head_input``; all of them by default)."""
         del train   # no dropout, no auxiliary loss: the forward is one
         B, T = tokens.shape
         x = nn.Embed(self.vocab_size, self.d_model,
@@ -164,10 +162,7 @@ class Brumby(nn.Module):
                     jnp.stack([jnp.ones((), jnp.uint32),
                                real.sum().astype(jnp.uint32)]),
                     self.num_layers))
-        if last_only:
-            x = x[:, -1:]
-        if head_rows is not None:
-            x = jnp.take_along_axis(x, head_rows[..., None], axis=1)
+        x = head_input(x, last_only, head_rows)
         x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
                     param_dtype=self.param_dtype, name="final_norm")(x)
         if return_hidden:

@@ -41,6 +41,7 @@ from pytorch_distributed_nn_tpu.config import ModelConfig
 from pytorch_distributed_nn_tpu.models import register
 from pytorch_distributed_nn_tpu.models.llama import RMSNorm
 from pytorch_distributed_nn_tpu.models.longcat_flash import KINDS, SwiGLU
+from pytorch_distributed_nn_tpu.nn import head_input
 from pytorch_distributed_nn_tpu.nn.attention import (
     MultiHeadAttention,
     prefill_in_tiles,
@@ -178,12 +179,14 @@ class Jamba(nn.Module):
     def __call__(self, tokens, *, train: bool = False,
                  decode: bool = False, last_only: bool = False,
                  return_hidden: bool = False, cache_positions=None,
-                 token_mask=None):
+                 token_mask=None, head_rows=None):
         """As :class:`models.llama.Llama` (``last_only``,
         ``return_hidden``, ``cache_positions``). ``token_mask`` (B, T)
         bool marks the real tokens, a left-aligned prefix of each row:
         the rest advance no state and reach no counter (their rows of
-        the result mean nothing)."""
+        the result mean nothing). ``head_rows`` (B, K) int32: which of a
+        sequence's T rows reach the final norm and the head
+        (``nn.head_input``; all of them by default)."""
         del train   # no dropout, no auxiliary loss: the forward is one
         B, T = tokens.shape
         embed = nn.Embed(self.vocab_size, self.d_model,
@@ -224,8 +227,7 @@ class Jamba(nn.Module):
             counters.value = counters.value.at[
                 kind * per_kind:(kind + 1) * per_kind].add(
                     jnp.concatenate(counts))
-        if last_only:
-            x = x[:, -1:]
+        x = head_input(x, last_only, head_rows)
         x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
                     param_dtype=self.param_dtype, name="final_norm")(x)
         if return_hidden:

@@ -55,6 +55,7 @@ from pytorch_distributed_nn_tpu.models.jamba import ATTN_COUNTERS
 from pytorch_distributed_nn_tpu.models.llama import RMSNorm
 from pytorch_distributed_nn_tpu.models.longcat_flash import KINDS, SwiGLU
 from pytorch_distributed_nn_tpu.models.sdar_moe import TokenTable
+from pytorch_distributed_nn_tpu.nn import head_input
 from pytorch_distributed_nn_tpu.nn.attention import (
     MultiHeadAttention,
     cache_rows_read,
@@ -228,12 +229,14 @@ class Lfm2Moe(nn.Module):
     def __call__(self, tokens, *, train: bool = False,
                  decode: bool = False, last_only: bool = False,
                  return_hidden: bool = False, cache_positions=None,
-                 token_mask=None):
+                 token_mask=None, head_rows=None):
         """As :class:`models.llama.Llama` (``last_only``,
         ``return_hidden``, ``cache_positions``). ``token_mask`` (B, T)
         bool marks the real tokens, a left-aligned prefix of each row:
         the rest move no tail and reach no expert and no counter (their
-        rows of the result mean nothing)."""
+        rows of the result mean nothing). ``head_rows`` (B, K) int32:
+        which of a sequence's T rows reach the final norm and the head
+        (``nn.head_input``; all of them by default)."""
         del train   # no dropout, no auxiliary loss: the forward is one
         B, T = tokens.shape
         embed = TokenTable(self.vocab_size, self.d_model,
@@ -276,8 +279,7 @@ class Lfm2Moe(nn.Module):
             counters.value = counters.value.at[
                 kind * per_kind:(kind + 1) * per_kind].add(
                     jnp.concatenate(counts))
-        if last_only:
-            x = x[:, -1:]
+        x = head_input(x, last_only, head_rows)
         x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
                     param_dtype=self.param_dtype, name="embedding_norm")(x)
         if return_hidden:

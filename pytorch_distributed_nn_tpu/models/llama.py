@@ -17,6 +17,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from pytorch_distributed_nn_tpu.config import ModelConfig
 from pytorch_distributed_nn_tpu.models import register
+from pytorch_distributed_nn_tpu.nn import head_input
 from pytorch_distributed_nn_tpu.nn.attention import MultiHeadAttention
 from pytorch_distributed_nn_tpu.nn.dtypes import get_policy
 from pytorch_distributed_nn_tpu.nn.quantized import Int8Dense, Int8Embed
@@ -135,15 +136,17 @@ class Llama(nn.Module):
     def __call__(self, tokens, *, train: bool = False,
                  decode: bool = False, last_only: bool = False,
                  return_hidden: bool = False, cache_positions=None,
-                 lora_bank=None, adapter_ids=None):
+                 lora_bank=None, adapter_ids=None, head_rows=None):
         """``last_only`` returns logits for the final position only
         (B, 1, V) — decode prefill needs just the next-token row, and
         at real vocab sizes the (P-1) unused head projections dominate
-        prefill cost. ``return_hidden`` skips the lm_head and returns
-        the final-norm'd (B, T, D) trunk output — the chunked-xent path
-        (train/losses.py) applies the head blockwise so full logits
-        never materialize. ``cache_positions`` (B,) int32: per-row KV
-        cache indices for continuous batching — see
+        prefill cost. ``head_rows`` (B, K) int32 names the rows instead
+        (``nn.head_input``): a served prefill's last real position of
+        each padded row, (B, K, V). ``return_hidden`` skips the lm_head
+        and returns the final-norm'd (B, T, D) trunk output — the
+        chunked-xent path (train/losses.py) applies the head blockwise
+        so full logits never materialize. ``cache_positions`` (B,)
+        int32: per-row KV cache indices for continuous batching — see
         nn.attention.MultiHeadAttention.
 
         ``lora_bank`` + ``adapter_ids``: per-request LoRA (nn/lora.py).
@@ -207,8 +210,7 @@ class Llama(nn.Module):
                 fused_proj=self.fused_proj,
                 name=f"layer{i}",
             )(x, train, decode, cache_positions, lora)
-        if last_only:
-            x = x[:, -1:]
+        x = head_input(x, last_only, head_rows)
         x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
                     param_dtype=self.param_dtype, name="final_norm")(x)
         if return_hidden:

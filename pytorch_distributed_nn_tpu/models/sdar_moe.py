@@ -54,6 +54,7 @@ from pytorch_distributed_nn_tpu.models import register
 from pytorch_distributed_nn_tpu.models.k_exaone import COUNTERS
 from pytorch_distributed_nn_tpu.models.llama import RMSNorm
 from pytorch_distributed_nn_tpu.models.longcat_flash import KINDS
+from pytorch_distributed_nn_tpu.nn import head_input
 from pytorch_distributed_nn_tpu.nn.attention import (
     MultiHeadAttention,
     cache_rows_read,
@@ -285,10 +286,7 @@ class SdarMoe(nn.Module):
             counters.value = counters.value.at[
                 kind * per_kind:(kind + 1) * per_kind].add(
                     jnp.concatenate(counts))
-        if last_only:
-            x = x[:, -1:]
-        if head_rows is not None:
-            x = jnp.take_along_axis(x, head_rows[..., None], axis=1)
+        x = head_input(x, last_only, head_rows)
         x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
                     param_dtype=self.param_dtype, name="final_norm")(x)
         if return_hidden:

@@ -16,6 +16,7 @@ from flax import linen as nn
 
 from pytorch_distributed_nn_tpu.config import ModelConfig
 from pytorch_distributed_nn_tpu.models import register
+from pytorch_distributed_nn_tpu.nn import head_input
 from pytorch_distributed_nn_tpu.nn.attention import MultiHeadAttention
 from pytorch_distributed_nn_tpu.nn.dtypes import get_policy
 
@@ -101,7 +102,11 @@ class TransformerLM(nn.Module):
     def __call__(self, tokens, *, train: bool = False,
                  positions: Optional[jnp.ndarray] = None,
                  decode: bool = False, last_only: bool = False,
-                 return_hidden: bool = False, cache_positions=None):
+                 return_hidden: bool = False, cache_positions=None,
+                 head_rows=None):
+        """``head_rows`` (B, K) int32: which of a sequence's T rows reach
+        the final norm and the head (``nn.head_input``; all of them by
+        default, the last with ``last_only``)."""
         T = tokens.shape[1]
         if T > self.max_len:
             raise ValueError(
@@ -154,8 +159,7 @@ class TransformerLM(nn.Module):
             x = block_cls(**self.block_kwargs(), ffn=self.layer_ffn(i),
                           name=f"block{i}")(x, train, decode,
                                             cache_positions)
-        if last_only:
-            x = x[:, -1:]
+        x = head_input(x, last_only, head_rows)
         x = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
                          param_dtype=self.param_dtype, name="ln_f")(x)
         if return_hidden:

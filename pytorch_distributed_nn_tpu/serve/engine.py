@@ -202,18 +202,6 @@ def _mask_kw(model, mask) -> dict:
         if getattr(model, "takes_token_mask", False) else {}
 
 
-def _head_kw(model, lengths) -> dict:
-    """``head_rows`` for a model that takes it (``takes_head_rows``): a
-    prefill's logits are read at each row's last real position alone,
-    and such a model applies its head to that row and returns ``(B, 1,
-    V)``. Nothing otherwise: the head then scores every fed position
-    and the program holds ``(bucket, vocab)`` float32 for the one row
-    it reads (2.49 GB at 4,096 x 151,936: PERF.md sec. 6, PR 50), as
-    the other models' programs did and do."""
-    return {"head_rows": (lengths.astype(jnp.int32) - 1)[:, None]} \
-        if getattr(model, "takes_head_rows", False) else {}
-
-
 def _block_of(model):
     """What a block decoder declares (``block_decoding()``: positions a
     block, denoising steps, the rule that unmasks, its threshold, the
@@ -266,22 +254,20 @@ def _apply_prefill_at(model, params, cache, tokens, lengths, starts,
     what prefix-cache suffix prefill needs: the restored rows
     [0, starts[i]) are already in ``cache`` and the suffix computes
     exactly the floats a full from-zero prefill would have. Returns
-    ((B, V) logits at each row's LAST real suffix position, cache).
-    ``extra`` goes to the model as keywords (an engine's ``lora``), so
-    a model never sees a keyword its engine has no use for."""
-    rows = _head_kw(model, lengths)
+    ((B, V) logits at each row's LAST real suffix position, cache):
+    the model is told that row (``head_rows``) and its head scores no
+    other, so no program holds ``(bucket, vocab)`` logits. ``extra``
+    goes to the model as keywords (an engine's ``lora``), so a model
+    never sees a keyword its engine has no use for."""
     logits, mutated = model.apply(
         {"params": params, "cache": cache}, tokens,
         train=False, decode=True, mutable=["cache"],
-        cache_positions=starts.astype(jnp.int32), **extra, **rows,
+        cache_positions=starts.astype(jnp.int32), **extra,
+        head_rows=(lengths.astype(jnp.int32) - 1)[:, None],
         **_mask_kw(model, jnp.arange(tokens.shape[1])[None, :]
                    < lengths[:, None]),
     )
-    if rows:
-        return logits[:, 0, :], mutated["cache"]
-    last = (lengths.astype(jnp.int32) - 1)[:, None, None]
-    next_logits = jnp.take_along_axis(logits, last, axis=1)[:, 0, :]
-    return next_logits, mutated["cache"]
+    return logits[:, 0, :], mutated["cache"]
 
 
 def _draw(logits, sampling, active):

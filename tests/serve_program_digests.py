@@ -20,7 +20,15 @@ it was, every line: a bucket whose dense scores are small
 16 x 16 among them) keeps the dense routine, which was not slower there
 on the chip, so the Mistral and K-EXAONE prefill programs are still
 those of the first commit; at ``G = 1`` the routine lowers to ISSUE
-36's text, so ``longcat.prefill`` is still ISSUE 36's. What the served
+36's text, so ``longcat.prefill`` is still ISSUE 36's. ISSUE 51 wrote
+the three ``*.prefill`` lines anew and meant to: every served model
+takes ``head_rows`` and ``_apply_prefill_at`` passes it, so a prefill's
+final norm and head see the one row the engine reads; the ``*.step``
+lines and the ``trees`` entries stayed byte-equal, and the Brumby-shaped
+and SDAR-shaped programs (``_LEFT_ALONE``: the two families that had
+``head_rows`` before; ``sdar.step`` is ``_block_round``) were added
+from the commit before it, whose text they still are
+(``tests/test_head_rows.py``). What the served
 sizes compile to is ``tests/test_chip_compile.py``'s to hold. A PR that
 means to change those programs writes the file anew and says so:
 
@@ -58,7 +66,20 @@ _SHAPES = {
         num_kv_heads=2, head_dim=16, mlp_dim=192, window=8,
         expert_mlp_dim=32, num_experts=16, moe_topk=4, ep_size=2,
         ep_rank=0)),
+    # the two families that had ``head_rows`` before ISSUE 51: a state a
+    # sequence in place of rows, and a block decoder
+    "brumby": ("brumby", dict(
+        vocab_size=101, num_layers=2, d_model=32, num_heads=4,
+        num_kv_heads=2, head_dim=8, mlp_dim=64, rope_theta=1e4)),
+    "sdar": ("sdar_moe", dict(
+        vocab_size=97, num_layers=2, d_model=64, num_heads=4,
+        num_kv_heads=2, head_dim=16, expert_mlp_dim=32, num_experts=8,
+        moe_topk=2, block_length=4, denoising_steps=2,
+        remasking="sequential", mask_token_id=96)),
 }
+# what ISSUE 51 had to leave alone, beside every family's ``step``
+_LEFT_ALONE = ("brumby.prefill", "brumby.step", "sdar.prefill",
+               "sdar.step")
 
 
 def _model(family: str):
@@ -90,8 +111,14 @@ def lowered(family: str, program: str) -> str:
     with jax.default_matmul_precision(None):
         if program == "step":
             cache = jax.eval_shape(lambda: init_cache(model, 4, 64))
+            state = (vec(4), vec(4))
+            if engine._block_of(model) is not None:
+                # a block decoder's round, under the step's name
+                state = jax.eval_shape(
+                    lambda: engine._idle_block_state(
+                        4, model.block_decoding()["block_length"]))
             return engine._serve_step.lower(
-                model, params, cache, vec(4), vec(4), vec(4, jnp.bool_),
+                model, params, cache, *state, vec(4, jnp.bool_),
                 vec(4), jax.ShapeDtypeStruct((), jnp.int32)).as_text()
         cache = jax.eval_shape(lambda: init_cache(model, 1, 16))
         return engine._serve_prefill.lower(
@@ -130,12 +157,18 @@ def trees(family: str) -> dict:
     }
 
 
+def digest(name: str) -> str:
+    """sha256 of the lowered text of ``<family>.<program>``."""
+    return hashlib.sha256(lowered(*name.split(".")).encode()).hexdigest()
+
+
 def digests() -> dict:
-    out = {f"{family}.{program}": hashlib.sha256(
-        lowered(family, program).encode()).hexdigest()
-        for family in _SHAPES for program in ("prefill", "step")}
+    out = {f"{family}.{program}": digest(f"{family}.{program}")
+           for family in ("mistral", "longcat", "kexaone")
+           for program in ("prefill", "step")}
     out["trees"] = {family: trees(family)
                     for family in ("longcat", "kexaone")}
+    out.update({name: digest(name) for name in _LEFT_ALONE})
     return out
 
 

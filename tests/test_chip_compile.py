@@ -713,6 +713,13 @@ def test_lfm2_serve_programs_fit_the_chip_at_the_cells_size(
         scores = re.findall(rf"f32\[[\d,]*,(?:{program}|512),{program}\]",
                             text)
         assert bool(scores) == (not blockwise), scores[:3]
+        # the tied head scores the row the engine takes (ISSUE 51): no
+        # logits of the bucket (1.07 GB at 4,096), and the temporaries
+        # what this printed then (0.047, 0.232, 1.012 GB; with the
+        # bucket's logits among them 0.298 at 1,024 and 1.108 at 4,096)
+        assert not re.findall(rf"f32\[(?:1,)?{program},65536\]", text)
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < {128: 0.06e9, 1024: 0.26e9, 4096: 1.06e9}[program]
     m = compiled.memory_analysis()
     held = m.argument_size_in_bytes + m.temp_size_in_bytes \
         + m.output_size_in_bytes - m.alias_size_in_bytes
