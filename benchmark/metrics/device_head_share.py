@@ -1,0 +1,15 @@
+"""``device_head_share``
+
+Chip 0's busy time in the traced window spent
+in the final norm, the vocabulary product and the choice of a token
+(scope part ``head``),
+in % of that busy time. The traced run's device events joined with the
+program's own map from compiled instruction to scope
+(``benchmark/lib/scope_shares.py``; the closed-loop served cells).
+"""
+
+from benchmark.lib import scope_shares
+
+
+def read(run: dict):
+    return scope_shares.share_pct(run, "head")

@@ -1,0 +1,15 @@
+"""``device_optimizer_share.train``
+
+Chip 0's busy time in the traced window spent
+in the optimizer's update (scope ``optimizer``, opened in
+``TrainState.apply_gradients``),
+in % of that busy time. The traced run's device events joined with the
+program's own map from compiled instruction to scope
+(``benchmark/lib/scope_shares.py``; the training cells).
+"""
+
+from benchmark.lib import scope_shares
+
+
+def read(run: dict):
+    return scope_shares.share_pct(run, "optimizer")

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -31,25 +32,29 @@ class EncoderBlock(nn.Module):
     def __call__(self, x, mask=None, *, train: bool = False):
         # post-LN (original BERT): sublayer → add → LN
         d = x.shape[-1]
-        y = MultiHeadAttention(
-            num_heads=self.num_heads, head_dim=d // self.num_heads,
-            causal=False, dtype=self.dtype, param_dtype=self.param_dtype,
-            name="attn",
-        )(x, mask=mask)
-        if self.dropout:
-            y = nn.Dropout(self.dropout, deterministic=not train)(y)
-        x = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
-                         param_dtype=self.param_dtype, name="ln1")(x + y)
-        y = nn.Dense(self.mlp_dim, dtype=self.dtype,
-                     param_dtype=self.param_dtype, name="mlp_in")(x)
-        y = nn.gelu(y)
-        y = nn.Dense(d, dtype=self.dtype, param_dtype=self.param_dtype,
-                     name="mlp_out")(y)
-        if self.dropout:
-            y = nn.Dropout(self.dropout, deterministic=not train)(y)
-        return nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
-                            param_dtype=self.param_dtype,
-                            name="ln2")(x + y)
+        # the scopes are metadata for obs/scopes.py
+        with jax.named_scope("mixer"):
+            y = MultiHeadAttention(
+                num_heads=self.num_heads, head_dim=d // self.num_heads,
+                causal=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name="attn",
+            )(x, mask=mask)
+            if self.dropout:
+                y = nn.Dropout(self.dropout, deterministic=not train)(y)
+            x = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
+                             param_dtype=self.param_dtype,
+                             name="ln1")(x + y)
+        with jax.named_scope("ffn"):
+            y = nn.Dense(self.mlp_dim, dtype=self.dtype,
+                         param_dtype=self.param_dtype, name="mlp_in")(x)
+            y = nn.gelu(y)
+            y = nn.Dense(d, dtype=self.dtype, param_dtype=self.param_dtype,
+                         name="mlp_out")(y)
+            if self.dropout:
+                y = nn.Dropout(self.dropout, deterministic=not train)(y)
+            return nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
+                                param_dtype=self.param_dtype,
+                                name="ln2")(x + y)
 
 
 class Bert(nn.Module):
@@ -95,13 +100,15 @@ class Bert(nn.Module):
                 param_dtype=self.param_dtype, name=f"layer{i}",
             )(x, mask=attention_mask, train=train)
         # MLM head: dense + gelu + LN, then decode to vocab
-        x = nn.Dense(self.d_model, dtype=self.dtype,
-                     param_dtype=self.param_dtype, name="mlm_dense")(x)
-        x = nn.gelu(x)
-        x = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
-                         param_dtype=self.param_dtype, name="mlm_ln")(x)
-        return nn.Dense(self.vocab_size, dtype=jnp.float32,
-                        param_dtype=self.param_dtype, name="mlm_decoder")(x)
+        with jax.named_scope("head"):
+            x = nn.Dense(self.d_model, dtype=self.dtype,
+                         param_dtype=self.param_dtype, name="mlm_dense")(x)
+            x = nn.gelu(x)
+            x = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
+                             param_dtype=self.param_dtype, name="mlm_ln")(x)
+            return nn.Dense(self.vocab_size, dtype=jnp.float32,
+                            param_dtype=self.param_dtype,
+                            name="mlm_decoder")(x)
 
 
 @register("bert_base")

@@ -162,13 +162,14 @@ class Brumby(nn.Module):
                     jnp.stack([jnp.ones((), jnp.uint32),
                                real.sum().astype(jnp.uint32)]),
                     self.num_layers))
-        x = head_input(x, last_only, head_rows)
-        x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
-                    param_dtype=self.param_dtype, name="final_norm")(x)
-        if return_hidden:
-            return x
-        return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
-                        param_dtype=self.param_dtype, name="lm_head")(x)
+        with jax.named_scope("head"):
+            x = head_input(x, last_only, head_rows)
+            x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                        param_dtype=self.param_dtype, name="final_norm")(x)
+            if return_hidden:
+                return x
+            return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
+                            param_dtype=self.param_dtype, name="lm_head")(x)
 
 
 @register("brumby")

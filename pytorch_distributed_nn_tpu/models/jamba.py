@@ -227,14 +227,16 @@ class Jamba(nn.Module):
             counters.value = counters.value.at[
                 kind * per_kind:(kind + 1) * per_kind].add(
                     jnp.concatenate(counts))
-        x = head_input(x, last_only, head_rows)
-        x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
-                    param_dtype=self.param_dtype, name="final_norm")(x)
-        if return_hidden:
-            return x
-        # the tied head, accumulated in float32
-        return jnp.einsum("btd,vd->btv", x, embed.embedding.astype(x.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            x = head_input(x, last_only, head_rows)
+            x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                        param_dtype=self.param_dtype, name="final_norm")(x)
+            if return_hidden:
+                return x
+            # the tied head, accumulated in float32
+            return jnp.einsum("btd,vd->btv", x,
+                              embed.embedding.astype(x.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 @register("jamba")

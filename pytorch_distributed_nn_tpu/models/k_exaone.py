@@ -271,13 +271,14 @@ class KExaone(nn.Module):
             counters.value = counters.value.at[
                 kind * per_kind:(kind + 1) * per_kind].add(
                     jnp.concatenate(counts))
-        x = head_input(x, last_only, head_rows)
-        x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
-                    param_dtype=self.param_dtype, name="final_norm")(x)
-        if return_hidden:
-            return x
-        return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
-                        param_dtype=self.param_dtype, name="lm_head")(x)
+        with jax.named_scope("head"):
+            x = head_input(x, last_only, head_rows)
+            x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                        param_dtype=self.param_dtype, name="final_norm")(x)
+            if return_hidden:
+                return x
+            return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
+                            param_dtype=self.param_dtype, name="lm_head")(x)
 
 
 def _build(cfg: ModelConfig, ep_size: int) -> KExaone:

@@ -279,15 +279,17 @@ class Lfm2Moe(nn.Module):
             counters.value = counters.value.at[
                 kind * per_kind:(kind + 1) * per_kind].add(
                     jnp.concatenate(counts))
-        x = head_input(x, last_only, head_rows)
-        x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
-                    param_dtype=self.param_dtype, name="embedding_norm")(x)
-        if return_hidden:
-            return x
-        # the tied head, accumulated in float32
-        table = embed.variables["params"]["table"]
-        return jnp.einsum("btd,vd->btv", x, table.astype(x.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            x = head_input(x, last_only, head_rows)
+            x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                        param_dtype=self.param_dtype,
+                        name="embedding_norm")(x)
+            if return_hidden:
+                return x
+            # the tied head, accumulated in float32
+            table = embed.variables["params"]["table"]
+            return jnp.einsum("btd,vd->btv", x, table.astype(x.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 @register("lfm2_8b_a1b")

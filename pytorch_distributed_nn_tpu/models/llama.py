@@ -61,40 +61,44 @@ class LlamaBlock(nn.Module):
         # for the whole backward (the MaxText long-context pattern)
         x = checkpoint_name(x, "block_in")
         d = x.shape[-1]
-        y = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
-                    param_dtype=self.param_dtype, name="attn_norm")(x)
-        y = MultiHeadAttention(
-            num_heads=self.num_heads, head_dim=d // self.num_heads,
-            num_kv_heads=self.num_kv_heads, causal=True, rotary=True,
-            rope_theta=self.rope_theta, impl=self.attn_impl,
-            use_bias=False, dtype=self.dtype,
-            param_dtype=self.param_dtype, quantized=self.quantized,
-            cache_dtype=self.cache_dtype,
-            fused_qkv=self.quantized and self.fused_proj,
-            name="attn",
-        )(y, decode=decode, cache_positions=cache_positions, lora=lora)
-        x = x + y
-        y = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
-                    param_dtype=self.param_dtype, name="mlp_norm")(x)
-        if self.quantized:
-            dense = lambda f, name: Int8Dense(  # noqa: E731
-                f, dtype=self.dtype, name=name)
-        else:
-            dense = lambda f, name: nn.Dense(  # noqa: E731
-                f, use_bias=False, dtype=self.dtype,
-                param_dtype=self.param_dtype, name=name)
-        if self.quantized and self.fused_proj:
-            # one int8 matmul for gate|up (exact: per-out-channel
-            # scales are concat-invariant) — decode is per-op-launch
-            # bound, see MultiHeadAttention.fused_qkv
-            gate_up = dense(2 * self.mlp_dim, "gate_up")(y)
-            gate = gate_up[..., :self.mlp_dim]
-            up = gate_up[..., self.mlp_dim:]
-        else:
-            gate = dense(self.mlp_dim, "gate_proj")(y)
-            up = dense(self.mlp_dim, "up_proj")(y)
-        y = dense(d, "down_proj")(nn.silu(gate) * up)
-        return x + y
+        # the scopes are metadata for obs/scopes.py: the norm and the
+        # residual add count with the part they stand around
+        with jax.named_scope("mixer"):
+            y = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                        param_dtype=self.param_dtype, name="attn_norm")(x)
+            y = MultiHeadAttention(
+                num_heads=self.num_heads, head_dim=d // self.num_heads,
+                num_kv_heads=self.num_kv_heads, causal=True, rotary=True,
+                rope_theta=self.rope_theta, impl=self.attn_impl,
+                use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, quantized=self.quantized,
+                cache_dtype=self.cache_dtype,
+                fused_qkv=self.quantized and self.fused_proj,
+                name="attn",
+            )(y, decode=decode, cache_positions=cache_positions, lora=lora)
+            x = x + y
+        with jax.named_scope("ffn"):
+            y = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                        param_dtype=self.param_dtype, name="mlp_norm")(x)
+            if self.quantized:
+                dense = lambda f, name: Int8Dense(  # noqa: E731
+                    f, dtype=self.dtype, name=name)
+            else:
+                dense = lambda f, name: nn.Dense(  # noqa: E731
+                    f, use_bias=False, dtype=self.dtype,
+                    param_dtype=self.param_dtype, name=name)
+            if self.quantized and self.fused_proj:
+                # one int8 matmul for gate|up (exact: per-out-channel
+                # scales are concat-invariant) — decode is per-op-launch
+                # bound, see MultiHeadAttention.fused_qkv
+                gate_up = dense(2 * self.mlp_dim, "gate_up")(y)
+                gate = gate_up[..., :self.mlp_dim]
+                up = gate_up[..., self.mlp_dim:]
+            else:
+                gate = dense(self.mlp_dim, "gate_proj")(y)
+                up = dense(self.mlp_dim, "up_proj")(y)
+            y = dense(d, "down_proj")(nn.silu(gate) * up)
+            return x + y
 
 
 class Llama(nn.Module):
@@ -210,16 +214,18 @@ class Llama(nn.Module):
                 fused_proj=self.fused_proj,
                 name=f"layer{i}",
             )(x, train, decode, cache_positions, lora)
-        x = head_input(x, last_only, head_rows)
-        x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
-                    param_dtype=self.param_dtype, name="final_norm")(x)
-        if return_hidden:
-            return x
-        if self.quantized:
-            return Int8Dense(self.vocab_size, dtype=jnp.float32,
-                             name="lm_head")(x)
-        return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
-                        param_dtype=self.param_dtype, name="lm_head")(x)
+        with jax.named_scope("head"):
+            x = head_input(x, last_only, head_rows)
+            x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                        param_dtype=self.param_dtype, name="final_norm")(x)
+            if return_hidden:
+                return x
+            if self.quantized:
+                return Int8Dense(self.vocab_size, dtype=jnp.float32,
+                                 name="lm_head")(x)
+            return nn.Dense(self.vocab_size, use_bias=False,
+                            dtype=jnp.float32, param_dtype=self.param_dtype,
+                            name="lm_head")(x)
 
 
 @register("llama3_8b")

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -39,29 +40,33 @@ class DecoderBlock(nn.Module):
     def __call__(self, x, train: bool = False, decode: bool = False,
                  cache_positions=None):
         d = x.shape[-1]
-        y = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
-                         param_dtype=self.param_dtype, name="ln1")(x)
-        y = MultiHeadAttention(
-            num_heads=self.num_heads, head_dim=d // self.num_heads,
-            causal=True, impl=self.attn_impl, dtype=self.dtype,
-            param_dtype=self.param_dtype, name="attn",
-        )(y, decode=decode, cache_positions=cache_positions)
-        if self.dropout:
-            y = nn.Dropout(self.dropout, deterministic=not train)(y)
-        x = x + y
-        y = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
-                         param_dtype=self.param_dtype, name="ln2")(x)
-        if self.ffn is not None:
-            y = self.ffn(self, y, train)
-        else:
-            y = nn.Dense(self.mlp_dim, dtype=self.dtype,
-                         param_dtype=self.param_dtype, name="mlp_in")(y)
-            y = nn.gelu(y)
-            y = nn.Dense(d, dtype=self.dtype, param_dtype=self.param_dtype,
-                         name="mlp_out")(y)
-        if self.dropout:
-            y = nn.Dropout(self.dropout, deterministic=not train)(y)
-        return x + y
+        # the scopes are metadata for obs/scopes.py
+        with jax.named_scope("mixer"):
+            y = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
+                             param_dtype=self.param_dtype, name="ln1")(x)
+            y = MultiHeadAttention(
+                num_heads=self.num_heads, head_dim=d // self.num_heads,
+                causal=True, impl=self.attn_impl, dtype=self.dtype,
+                param_dtype=self.param_dtype, name="attn",
+            )(y, decode=decode, cache_positions=cache_positions)
+            if self.dropout:
+                y = nn.Dropout(self.dropout, deterministic=not train)(y)
+            x = x + y
+        with jax.named_scope("ffn"):
+            y = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
+                             param_dtype=self.param_dtype, name="ln2")(x)
+            if self.ffn is not None:
+                y = self.ffn(self, y, train)
+            else:
+                y = nn.Dense(self.mlp_dim, dtype=self.dtype,
+                             param_dtype=self.param_dtype, name="mlp_in")(y)
+                y = nn.gelu(y)
+                y = nn.Dense(d, dtype=self.dtype,
+                             param_dtype=self.param_dtype,
+                             name="mlp_out")(y)
+            if self.dropout:
+                y = nn.Dropout(self.dropout, deterministic=not train)(y)
+            return x + y
 
 
 class TransformerLM(nn.Module):
@@ -159,13 +164,14 @@ class TransformerLM(nn.Module):
             x = block_cls(**self.block_kwargs(), ffn=self.layer_ffn(i),
                           name=f"block{i}")(x, train, decode,
                                             cache_positions)
-        x = head_input(x, last_only, head_rows)
-        x = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
-                         param_dtype=self.param_dtype, name="ln_f")(x)
-        if return_hidden:
-            return x
-        return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
-                        param_dtype=self.param_dtype, name="lm_head")(x)
+        with jax.named_scope("head"):
+            x = head_input(x, last_only, head_rows)
+            x = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype,
+                             param_dtype=self.param_dtype, name="ln_f")(x)
+            if return_hidden:
+                return x
+            return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
+                            param_dtype=self.param_dtype, name="lm_head")(x)
 
 
 @register("transformer_lm")

@@ -193,14 +193,15 @@ def _row_update(buf, new, starts):
     serial loop over the batch rows: one iteration for the engine's
     prefill, but B iterations a leaf a round if a decode round used it
     (a block decoder's round writes with :func:`_block_update`)."""
-    if new.shape[1] == 1:
-        return buf.at[jnp.arange(buf.shape[0]), starts].set(
-            new[:, 0], mode="drop", unique_indices=True,
-            indices_are_sorted=True)
-    return jax.vmap(
-        lambda b, n, s: jax.lax.dynamic_update_slice(
-            b, n, (s,) + (0,) * (b.ndim - 1))
-    )(buf, new, starts)
+    with jax.named_scope("cache_write"):
+        if new.shape[1] == 1:
+            return buf.at[jnp.arange(buf.shape[0]), starts].set(
+                new[:, 0], mode="drop", unique_indices=True,
+                indices_are_sorted=True)
+        return jax.vmap(
+            lambda b, n, s: jax.lax.dynamic_update_slice(
+                b, n, (s,) + (0,) * (b.ndim - 1))
+        )(buf, new, starts)
 
 
 def _block_update(buf, new, starts):
@@ -208,9 +209,10 @@ def _block_update(buf, new, starts):
     a few positions (a block decoder's): one indexed scatter a leaf, as
     for one token a row, a position out of range dropped by itself (a
     stopped row at ``max_seq_len``)."""
-    return buf.at[jnp.arange(buf.shape[0])[:, None],
-                  starts[:, None] + jnp.arange(new.shape[1])[None]].set(
-        new, mode="drop", unique_indices=True, indices_are_sorted=True)
+    with jax.named_scope("cache_write"):
+        return buf.at[jnp.arange(buf.shape[0])[:, None],
+                      starts[:, None] + jnp.arange(new.shape[1])[None]].set(
+            new, mode="drop", unique_indices=True, indices_are_sorted=True)
 
 
 def _quantize_kv(x):
@@ -362,11 +364,14 @@ def _ring_attention(q, k, v, ring_k, ring_v, starts, lengths, dtype):
     out = _cache_attention(q.reshape((B * nb, tb) + q.shape[2:]),
                            windows(k_all), windows(v_all), band, dtype)
     out = out.reshape(q.shape)
-    newest = _ring_held(starts + lengths - 1, R) - starts[:, None]
-    fresh = (newest >= 0)[:, :, None, None]
-    take = jnp.clip(newest, 0, T - 1)[:, :, None, None]
-    ring_k = jnp.where(fresh, jnp.take_along_axis(k, take, axis=1), ring_k)
-    ring_v = jnp.where(fresh, jnp.take_along_axis(v, take, axis=1), ring_v)
+    with jax.named_scope("cache_write"):
+        newest = _ring_held(starts + lengths - 1, R) - starts[:, None]
+        fresh = (newest >= 0)[:, :, None, None]
+        take = jnp.clip(newest, 0, T - 1)[:, :, None, None]
+        ring_k = jnp.where(fresh, jnp.take_along_axis(k, take, axis=1),
+                           ring_k)
+        ring_v = jnp.where(fresh, jnp.take_along_axis(v, take, axis=1),
+                           ring_v)
     return out, ring_k, ring_v
 
 
@@ -733,8 +738,9 @@ class MultiHeadAttention(nn.Module):
                     cache_index.value = idx + T
 
                     def write(buf, new):
-                        return jax.lax.dynamic_update_slice(
-                            buf, new, (0, idx) + (0,) * (buf.ndim - 2))
+                        with jax.named_scope("cache_write"):
+                            return jax.lax.dynamic_update_slice(
+                                buf, new, (0, idx) + (0,) * (buf.ndim - 2))
                 else:
                     # per-row mode: each sequence advances at its own
                     # index; the shared counter stays untouched (it is

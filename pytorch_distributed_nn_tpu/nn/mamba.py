@@ -256,6 +256,7 @@ class MambaMixer(nn.Module):
         y = y + skip.astype(jnp.float32) * c32
         if decode and not self.is_initializing():
             state.value = h
-            tail.value = carried_tail(joined, before, real)
+            with jax.named_scope("cache_write"):
+                tail.value = carried_tail(joined, before, real)
         return out_proj((y * nn.silu(z.astype(jnp.float32)))
                         .astype(self.dtype))

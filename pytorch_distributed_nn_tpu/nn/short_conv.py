@@ -28,6 +28,7 @@ type before ``W_out`` (the source computes each in the model's type).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -64,8 +65,9 @@ class ShortConv(nn.Module):
         gate_in, gate_out, x = jnp.split(in_proj(u), 3, axis=-1)
         c, joined = conv(gate_in * x, before)
         if decode and not self.is_initializing():
-            tail.value = carried_tail(
-                joined, before,
-                jnp.ones((B, T), bool) if real is None else real)
+            with jax.named_scope("cache_write"):
+                tail.value = carried_tail(
+                    joined, before,
+                    jnp.ones((B, T), bool) if real is None else real)
         return out_proj((gate_out.astype(jnp.float32) * c)
                         .astype(self.dtype))

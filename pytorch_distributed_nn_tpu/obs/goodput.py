@@ -52,7 +52,7 @@ import itertools
 import threading
 import time
 
-from pytorch_distributed_nn_tpu.obs import jitwatch
+from pytorch_distributed_nn_tpu.obs import jitwatch, scopes
 from pytorch_distributed_nn_tpu.obs import span as _span
 
 PHASES = ("data", "compute", "collective", "checkpoint", "eval", "other")
@@ -224,6 +224,9 @@ class GoodputMeter:
         self._jit_mark: tuple = ()   # ThreadTotals.mark(): ends in total
         self._jit_t = 0.0   # on time.monotonic(), as the events' ends
         self._step_jit: dict | None = None
+        # phase -> () -> (fn, args[, kwargs]) of the program that phase
+        # dispatches: asked only when the phase traced (obs/scopes.py)
+        self.programs: dict = {}
         self._gc = None
         self._gc_seen = 0.0
 
@@ -293,6 +296,9 @@ class GoodputMeter:
                       "cache_misses"):
                 d[k] += seen[k]
         self._step_jit[phase] = d
+        program = self.programs.get(phase)
+        if program is not None:
+            scopes.note(*program())
 
     def step_end(self, step: int = -1, *,
                  steps_covered: int = 1) -> StepBreakdown:

@@ -52,6 +52,7 @@ import threading
 import time
 from typing import Callable, NamedTuple
 
+from pytorch_distributed_nn_tpu.obs import scopes
 from pytorch_distributed_nn_tpu.obs import span as _span
 from pytorch_distributed_nn_tpu.obs.registry import get_registry
 
@@ -332,10 +333,13 @@ def add_sink(fn: Callable) -> None:
         _watch.sinks.append(fn)
 
 
-def remove_sink(fn: Callable) -> None:
+def remove_sink(fn: Callable) -> bool:
+    """Returns whether ``fn`` was listening."""
     w = _watch
     if w is not None and fn in w.sinks:
         w.sinks.remove(fn)
+        return True
+    return False
 
 
 def publish() -> None:
@@ -366,12 +370,16 @@ def publish() -> None:
 class _WatchedSpan:
     """An armed span around a dispatch of a compiled program: at exit,
     if the thread traced, lowered or compiled inside it, the span says
-    so in late arguments."""
+    so in late arguments, and ``program`` (``(fn, args)`` or ``(fn,
+    args, kwargs)`` of the call inside it) is noted for the map from
+    instruction to scope (:func:`obs.scopes.note`): a call that traced
+    is the one moment a new variant of a program appears."""
 
-    __slots__ = ("_span", "_tot", "_mark")
+    __slots__ = ("_span", "_tot", "_mark", "_program")
 
-    def __init__(self, span) -> None:
+    def __init__(self, span, program=None) -> None:
         self._span = span
+        self._program = program
 
     def __enter__(self):
         self._tot = thread_totals()
@@ -390,16 +398,30 @@ class _WatchedSpan:
                            lower_ms=round(d["lower"] * 1e3, 3),
                            compile_ms=round(d["compile"] * 1e3, 3),
                            jit_fun=d["fun"])
+            if self._program is not None and exc[0] is None:
+                scopes.note(*self._program)
         return self._span.__exit__(*exc)
 
 
-def dispatch_span(name: str, cat: str = "app", **args):
+def dispatch_span(name: str, cat: str = "app", program=None, **args):
     """:func:`obs.span` for a span that holds a dispatch: armed, it
     gains ``trace_ms``, ``lower_ms``, ``compile_ms`` and ``jit_fun``
-    when the call inside it did not find its executable; unarmed it is
-    the shared null context and reads nothing."""
+    when the call inside it did not find its executable; unarmed and
+    without a ``program`` it is the shared null context and reads
+    nothing. With a ``program`` it watches the thread's totals either
+    way (one mark, one comparison), and notes the program if the call
+    traced."""
     sp = _span.span(name, cat, **args)
-    return sp if sp is _span._NULL else _WatchedSpan(sp)
+    if sp is _span._NULL and program is None:
+        return sp
+    return _WatchedSpan(sp, program)
+
+
+def noting(program):
+    """:func:`dispatch_span` for a dispatch that has no span of its
+    own: nothing is written, and ``program`` is noted if the call
+    inside it traced."""
+    return _WatchedSpan(_span._NULL, program)
 
 
 watched = _WatchedSpan

@@ -64,7 +64,7 @@ import threading
 import time
 from typing import Callable, Sequence
 
-from pytorch_distributed_nn_tpu.obs import flight, jitwatch
+from pytorch_distributed_nn_tpu.obs import flight, jitwatch, scopes
 from pytorch_distributed_nn_tpu.obs.registry import get_registry
 
 log = logging.getLogger(__name__)
@@ -73,6 +73,7 @@ ENV_XRAY = "TPUNN_XRAY"
 
 #: capture summary filename contract (scripts glob on it)
 SUMMARY_NAME = "xray_summary.json"
+SCOPE_MAP_NAME = "scope_map.json"
 
 
 # ---------------------------------------------------------------------------
@@ -392,13 +393,23 @@ def build_attribution(*, trace_dir: str | None = None,
                       flops_per_step: float | None = None,
                       steps: int = 1,
                       peak_flops: float | None = None,
-                      top: int = 16) -> dict:
+                      top: int = 16,
+                      scope_map: dict | None = None) -> dict:
     """The per-op table: time share per op, analytic FLOPs spread over
     compute rows by time share (→ achieved FLOP/s, roofline fraction
     when a chip peak is known), and the collective block cross-checked
     against ``CommRecorder`` wire bytes. Prefers real trace slices;
     falls back to flight-ring dispatch windows so a ``profiler=0``
-    capture still attributes."""
+    capture still attributes.
+
+    With ``scope_map`` (:func:`obs.scopes.build`'s maps) every row
+    gains ``scope`` and ``part``, found by the slice's name among the
+    noted programs' instructions; a name that is no instruction of the
+    map, or one that two programs put in two parts, is ``unscoped``,
+    not dropped. ``by_part`` sums every row's seconds (before the table
+    is cut to ``top``) by part: time only, no FLOPs, and a slice that
+    nests in another (a ``while``'s body) counts beside it, as in the
+    table."""
     rows: list[dict] = []
     source = "none"
     if trace_dir:
@@ -448,11 +459,19 @@ def build_attribution(*, trace_dir: str | None = None,
         if coll_b and expected:
             comm["ring_vs_recorder"] = coll_b / expected
 
+    by_part: dict = {}
+    if scope_map is not None:
+        modules = scope_map.get("modules", {})
+        for r in rows:
+            r["scope"], r["part"] = scopes.lookup_name(modules, r["op"])
+            by_part[r["part"]] = by_part.get(r["part"], 0.0) + r["time_s"]
+
     rows = rows[:max(int(top), 1)]
     return {
         "source": source,
         "total_s": total,
         "rows": rows,
+        "by_part": by_part,
         "comm": comm,
         "top_op": rows[0]["op"] if rows else "",
         "top_category": rows[0]["category"] if rows else "",
@@ -484,14 +503,21 @@ def render_op_table(att: dict, *, top: int = 12) -> str:
         f"{att.get('total_s', 0.0):.4f}s   collective share "
         f"{att.get('comm', {}).get('collective_share', 0.0):.1%}",
         f"{'op':<44} {'cat':<10} {'calls':>6} {'time_s':>9} "
-        f"{'share':>7} {'roofline':>8}",
+        f"{'share':>7} {'roofline':>8} {'part':<11} scope",
     ]
     for r in att.get("rows", [])[:top]:
         roof = r.get("roofline_frac")
         lines.append(
             f"{r['op'][:44]:<44} {r['category']:<10} {r['calls']:>6} "
             f"{r['time_s']:>9.4f} {r['share']:>7.1%} "
-            f"{(f'{roof:.1%}' if roof is not None else '-'):>8}")
+            f"{(f'{roof:.1%}' if roof is not None else '-'):>8} "
+            f"{r.get('part', '-'):<11} {r.get('scope', '')}".rstrip())
+    by_part = att.get("by_part") or {}
+    if by_part:
+        total = sum(by_part.values()) or 1.0
+        lines.append("by_part (time only): " + "  ".join(
+            f"{p} {t:.4f}s {t / total:.1%}" for p, t in sorted(
+                by_part.items(), key=lambda kv: -kv[1])))
     comm = att.get("comm", {})
     if comm.get("implied_gbps") is not None:
         lines.append(
@@ -504,6 +530,16 @@ def render_op_table(att: dict, *, top: int = 12) -> str:
 # ---------------------------------------------------------------------------
 # The engine
 # ---------------------------------------------------------------------------
+
+def _write_json(path: str, obj: dict) -> None:
+    try:
+        tmp = f"{path}.tmp.{os.getpid()}"
+        with open(tmp, "w") as f:
+            json.dump(obj, f, indent=1, sort_keys=True)
+        os.replace(tmp, path)
+    except OSError as e:
+        log.warning("xray: write of %s failed: %s", path, e)
+
 
 class XrayEngine:
     """Capture policy + compile watch + attribution writer. All entry
@@ -653,6 +689,16 @@ class XrayEngine:
                 peak = peak_flops_per_chip()  # None off-TPU
             except Exception:
                 peak = None
+        # the programs noted so far, compiled again from their shapes
+        # (cache hits) and read once: a capture is an operator's
+        # intervention, and the one place a serving process builds it
+        # (its compiles are the capture's own: not the storm detector's)
+        watching = jitwatch.remove_sink(self._on_jit_event)
+        try:
+            scope_map = scopes.build()
+        finally:
+            if watching:
+                self._install_compile_watch()
         att = build_attribution(
             trace_dir=act["dir"] if act["profiling"] else None,
             events=events,
@@ -660,7 +706,9 @@ class XrayEngine:
             flops_per_step=self.flops_per_step,
             steps=max(self.cfg.steps, 1),
             peak_flops=peak,
+            scope_map=scope_map,
         )
+        _write_json(os.path.join(act["dir"], SCOPE_MAP_NAME), scope_map)
         summary = {
             "reason": act["reason"], "rank": self.rank,
             "trigger_step": act["step"], "t_start": act["t_start"],
@@ -670,14 +718,7 @@ class XrayEngine:
             "compile_seconds": self.compile_seconds_total,
             "attribution": att,
         }
-        path = os.path.join(act["dir"], SUMMARY_NAME)
-        try:
-            tmp = f"{path}.tmp.{os.getpid()}"
-            with open(tmp, "w") as f:
-                json.dump(summary, f, indent=1, sort_keys=True)
-            os.replace(tmp, path)
-        except OSError as e:
-            log.warning("xray: summary write failed: %s", e)
+        _write_json(os.path.join(act["dir"], SUMMARY_NAME), summary)
         flight.record(
             "xray", "capture_done", step=act["step"],
             note=f"{act['reason']} top={att['top_op'] or '?'} "

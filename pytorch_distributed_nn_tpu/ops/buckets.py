@@ -148,16 +148,19 @@ def make_bucket_reduce(
         for number, idxs in enumerate(groups, 1):  # number: unique seeds
             group = [leaves[i] for i in idxs]
             dtype = group[0].dtype
-            if mode == "int8" and jnp.issubdtype(dtype, jnp.floating):
-                means = _int8_mean(group, axis,
-                                   seed * 65537 + number * 257)
-                packed += group
-            elif mode == "bf16" and dtype.itemsize > 2:
-                wire = [g.astype(jnp.bfloat16) for g in group]
-                means = [m.astype(dtype)
-                         for m in cc.tree_all_reduce_mean(wire, axis)]
-            else:
-                means = cc.tree_all_reduce_mean(group, axis)
+            # the scope is metadata: obs/scopes.py tells a bucket's
+            # device time from it
+            with jax.named_scope(f"grad_reduce/bucket{number}"):
+                if mode == "int8" and jnp.issubdtype(dtype, jnp.floating):
+                    means = _int8_mean(group, axis,
+                                       seed * 65537 + number * 257)
+                    packed += group
+                elif mode == "bf16" and dtype.itemsize > 2:
+                    wire = [g.astype(jnp.bfloat16) for g in group]
+                    means = [m.astype(dtype)
+                             for m in cc.tree_all_reduce_mean(wire, axis)]
+                else:
+                    means = cc.tree_all_reduce_mean(group, axis)
             for i, m in zip(idxs, means):
                 reduced[i] = m
         log.info(

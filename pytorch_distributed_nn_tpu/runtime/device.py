@@ -36,7 +36,28 @@ def configure_compile_cache() -> str | None:
     if not os.environ.get(CACHE_ENV):
         jax.config.update("jax_compilation_cache_dir",
                           str(_CHECKOUT / ".jax_cache"))
+    _key_the_scopes()
     return jax.config.jax_compilation_cache_dir
+
+
+def _key_the_scopes() -> None:
+    """Put ``obs.scopes.VERSION`` into the cache's key. JAX leaves an
+    instruction's metadata out of the key, so an executable cached
+    before a named scope was added is found again and loaded with the
+    old ``op_name``s, and the map from instruction to scope
+    (``obs/scopes.py``) would read those. With the version in the key a
+    tree whose scopes changed compiles once more, cold, and then finds
+    its own entries. ``cache_key.custom_hook`` is JAX's own place for a
+    string of the deployment's; where a JAX has none the key stays as
+    it was."""
+    from pytorch_distributed_nn_tpu.obs import scopes
+
+    try:
+        from jax._src import cache_key
+    except ImportError:
+        return
+    if hasattr(cache_key, "custom_hook"):
+        cache_key.custom_hook = lambda: scopes.VERSION
 
 
 def _cpu_asked_for() -> bool:

@@ -314,12 +314,14 @@ def _serve_prefill(model, params, cache, tokens, lengths, starts,
         return None, cache, sampling
     next_logits, cache = _apply_prefill_at(
         model, params, cache, tokens, lengths, starts, **(lora or {}))
-    if sampling is None:
-        return jnp.argmax(next_logits, axis=-1).astype(jnp.int32), cache, None
-    n = sampling["step"].shape[0]
-    toks, sampling = _draw(
-        jnp.broadcast_to(next_logits[0], (n, next_logits.shape[-1])),
-        sampling, True)
+    with jax.named_scope("head"):   # the choice of a token counts there
+        if sampling is None:
+            return (jnp.argmax(next_logits, axis=-1).astype(jnp.int32),
+                    cache, None)
+        n = sampling["step"].shape[0]
+        toks, sampling = _draw(
+            jnp.broadcast_to(next_logits[0], (n, next_logits.shape[-1])),
+            sampling, True)
     return toks, cache, sampling
 
 
@@ -356,10 +358,11 @@ def _serve_step(model, params, cache, last_tok, lengths, active, remaining,
     logits, cache = _apply_decode_ragged(
         model, params, cache, last_tok, lengths, **(lora or {}),
         **_mask_kw(model, active[:, None]))
-    if sampling is None:
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        nxt, sampling = _draw(logits, sampling, active)
+    with jax.named_scope("head"):
+        if sampling is None:
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        else:
+            nxt, sampling = _draw(logits, sampling, active)
     nxt = jnp.where(active, nxt, last_tok)
     lengths = jnp.where(active, lengths + 1, lengths)
     alive = active & (remaining > 1) & (nxt != eos)
@@ -433,32 +436,34 @@ def _block_round(model, block, params, cache, out, place, active, remaining,
         token_mask=active[:, None] & (owing | (jnp.arange(2 * B) < B)),
         head_rows=jnp.where(owing, B, 0) + jnp.arange(B),
         **(lora or {}))
-    V = logits.shape[-1]
-    allowed = jnp.where(jnp.arange(V) == mask_id, -jnp.inf, logits)
-    if sampling is None:
-        x0 = jnp.argmax(allowed, axis=-1).astype(jnp.int32)
-    else:
-        # a row's spec for each of its positions; the key is the row's
-        # at this round, the position's place in its block folded in
-        per = lambda v: jnp.repeat(v, B)  # noqa: E731
-        keys = jax.vmap(lambda k: jax.vmap(
-            lambda i: jax.random.fold_in(k, i))(jnp.arange(B)))(
-            decoding.row_keys(sampling["seed"], sampling["branch"],
-                              sampling["step"]))
-        x0 = decoding.sample_rows(
-            allowed.reshape(-1, V), per(sampling["temp"]),
-            per(sampling["top_k"]), per(sampling["top_p"]),
-            keys.reshape((-1,) + keys.shape[2:])
-        ).astype(jnp.int32).reshape(tok.shape)
-        sampling = dict(sampling, step=jnp.where(
-            active, sampling["step"] + 1, sampling["step"]))
+    with jax.named_scope("head"):
+        V = logits.shape[-1]
+        allowed = jnp.where(jnp.arange(V) == mask_id, -jnp.inf, logits)
+        if sampling is None:
+            x0 = jnp.argmax(allowed, axis=-1).astype(jnp.int32)
+        else:
+            # a row's spec for each of its positions; the key is the row's
+            # at this round, the position's place in its block folded in
+            per = lambda v: jnp.repeat(v, B)  # noqa: E731
+            keys = jax.vmap(lambda k: jax.vmap(
+                lambda i: jax.random.fold_in(k, i))(jnp.arange(B)))(
+                decoding.row_keys(sampling["seed"], sampling["branch"],
+                                  sampling["step"]))
+            x0 = decoding.sample_rows(
+                allowed.reshape(-1, V), per(sampling["temp"]),
+                per(sampling["top_k"]), per(sampling["top_p"]),
+                keys.reshape((-1,) + keys.shape[2:])
+            ).astype(jnp.int32).reshape(tok.shape)
+            sampling = dict(sampling, step=jnp.where(
+                active, sampling["step"] + 1, sampling["step"]))
     n_t = B // S + (step < B % S)
     if rule == "sequential":
         rank = jnp.cumsum(masked, axis=-1) - 1
     else:   # by falling confidence, the leftmost of equals first
-        conf = jnp.exp(
-            jnp.take_along_axis(logits, x0[..., None], axis=-1)[..., 0]
-            - jax.nn.logsumexp(logits, axis=-1))
+        with jax.named_scope("head"):   # a pass over the vocabulary
+            conf = jnp.exp(
+                jnp.take_along_axis(logits, x0[..., None], axis=-1)[..., 0]
+                - jax.nn.logsumexp(logits, axis=-1))
         order = jnp.argsort(-jnp.where(masked, conf, -1.0), axis=-1,
                             stable=True)
         rank = jnp.argsort(order, axis=-1)
@@ -858,6 +863,9 @@ class ServingEngine:
                 "seconds of the serve loop's thread by phase "
                 "(exclusive; they sum to its wall time)",
                 labels=("phase",)))
+        # the decode program, asked for once a variant: when the tally's
+        # dispatch phase traced (obs/scopes.py)
+        self.loop.programs["dispatch"] = self._step_program
         self._overlapped = 0  # rounds dispatched over an unfetched one
         # the programs' ``lora`` argument: the bank and each slot's
         # adapter id (written with the slot state above), or None, and
@@ -1138,6 +1146,9 @@ class ServingEngine:
         One throwaway forward a bucket into a throwaway batch cache: the
         engine's own state is not touched, so a replica may be warmed
         while its driver loop idles."""
+        # (a call that traces here is noted for obs/scopes.py as the
+        # loop's own would be: the loop will find the executable)
+        noting = jitwatch.noting
         cache = _fresh_cache(self.model, self.max_slots, self.max_seq_len)
         if self._block is not None:
             # what a block decoder prefills of a prompt: its whole blocks
@@ -1146,32 +1157,48 @@ class ServingEngine:
                            if int(p) >= B]
         for plen in prompt_lens:
             pad = min(_bucket_len(int(plen)), self.max_seq_len)
-            _, row, _ = _serve_prefill(
-                self.model, self.params, _fresh_cache(self.model, 1, pad),
-                jnp.zeros((1, pad), jnp.int32),
-                jnp.asarray([int(plen)], jnp.int32),
-                jnp.zeros((1,), jnp.int32), self._row_lora(0), None)
+            args = (self.model, self.params,
+                    _fresh_cache(self.model, 1, pad),
+                    jnp.zeros((1, pad), jnp.int32),
+                    jnp.asarray([int(plen)], jnp.int32),
+                    jnp.zeros((1,), jnp.int32), self._row_lora(0), None)
+            with noting((_serve_prefill, args)):
+                _, row, _ = _serve_prefill(*args)
             # static arguments as _prefill_into passes them: a default left
             # out is another program to jax.jit
-            cache = _insert_row(cache, row, 0, totals=self._counter_leaf,
-                                count=True)
+            kw = dict(totals=self._counter_leaf, count=True)
+            with noting((_insert_row, (cache, row, 0), kw)):
+                cache = _insert_row(cache, row, 0, **kw)
         idle = jnp.zeros((self.max_slots,), jnp.int32)
         ids = None if self._lora is None else self._lora["adapter_ids"]
         rows = np.zeros((5, self.max_slots), np.int32)
         # every argument, as _write_slots passes them
         if self._block is not None:
             B = self._block["block_length"]
-            state, _, _ = _write_block_rows(
+            write, args = _write_block_rows, (
                 *_idle_block_state(self.max_slots, B),
                 jnp.zeros((self.max_slots,), bool), idle, rows,
                 np.zeros((self.max_slots, B), np.int32), ids, None, None)
         else:
-            state, _, _ = _write_rows(
+            write, args = _write_rows, (
                 idle, idle, jnp.zeros((self.max_slots,), bool), idle,
                 rows, ids, None, None)
-        nxt, *_ = _serve_step(self.model, self.params, cache, *state,
-                              self._d_eos, self._lora, None)
+        with noting((write, args)):
+            state, _, _ = write(*args)
+        args = (self.model, self.params, cache, *state, self._d_eos,
+                self._lora, None)
+        with noting((_serve_step, args)):
+            nxt, *_ = _serve_step(*args)
         np.asarray(nxt)  # block until compiled + executed
+
+    def _step_program(self):
+        """``_serve_step`` with this engine's arguments as the round
+        just dispatched left them (the program's outputs have its
+        inputs' shapes), for :func:`obs.scopes.note`."""
+        return _serve_step, (
+            self.model, self.params, self._cache, *self._d_slots,
+            self._d_eos, self._lora,
+            self._d_sampling if self._n_sampled else None)
 
     def _prefill_into(self, slots: list, req: Request) -> None:
         """Prefill ONE request into ``len(slots)`` batch rows. The
@@ -1215,16 +1242,20 @@ class ServingEngine:
             tokens[0, :T] = suffix  # left-ALIGNED (pad tail is masked)
             row_cache = None  # nothing to fill and nothing restored
             if T or m:
-                with jitwatch.dispatch_span("serve/fresh_cache"):
+                with jitwatch.dispatch_span(
+                        "serve/fresh_cache",
+                        program=(_zero_cache, (self.model, 1, pad))):
                     row_cache = _fresh_cache(self.model, 1, pad)
             if m > 0:
                 nb = len(match.restore_blocks)
                 table = np.zeros((self._blocks_per_seq,), np.int32)
                 table[:nb] = match.restore_blocks
                 t_restore = time.monotonic()
-                with jitwatch.dispatch_span("serve/restore", blocks=nb):
-                    row_cache = _restore_blocks(
-                        row_cache, self._store, bs, table, np.int32(nb))
+                args = (row_cache, self._store, bs, table, np.int32(nb))
+                with jitwatch.dispatch_span(
+                        "serve/restore", program=(_restore_blocks, args),
+                        blocks=nb):
+                    row_cache = _restore_blocks(*args)
                 self._c_blocks_copied.inc(nb, direction="restore")
                 trace.on_segment(req.trace, "restore", t_restore,
                                  time.monotonic(), blocks=nb, cached=m)
@@ -1242,15 +1273,16 @@ class ServingEngine:
             h["logprob"][slots] = 0.0
             rows = {k: v[slots] for k, v in h.items()} if sampled else None
             firsts = [None] * len(slots)
+            args = (self.model, self.params, row_cache,
+                    jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
+                    jnp.asarray([m], jnp.int32),
+                    self._row_lora(req.adapter), rows) if T else None
             with jitwatch.dispatch_span(
-                    "serve/prefill", request=req.request_id,
-                    prompt_len=L, cached=m):
+                    "serve/prefill",
+                    program=(_serve_prefill, args) if T else None,
+                    request=req.request_id, prompt_len=L, cached=m):
                 if T:
-                    tok0, row_cache, drawn = _serve_prefill(
-                        self.model, self.params, row_cache,
-                        jnp.asarray(tokens), jnp.asarray([T], jnp.int32),
-                        jnp.asarray([m], jnp.int32),
-                        self._row_lora(req.adapter), rows)
+                    tok0, row_cache, drawn = _serve_prefill(*args)
                 if blk is None:
                     t_wait = time.monotonic()
                     firsts = [int(t) for t in np.asarray(tok0)]
@@ -1269,8 +1301,12 @@ class ServingEngine:
                                        blk["denoising_steps"])
             sids = branch_seq_ids(req)
             totals = self._counter_leaf
-            with jitwatch.dispatch_span("serve/insert_row",
-                                        rows=len(slots)):
+            with jitwatch.dispatch_span(
+                    "serve/insert_row",
+                    program=None if row_cache is None else (
+                        _insert_row, (self._cache, row_cache, slots[0]),
+                        dict(totals=totals, count=True)),
+                    rows=len(slots)):
                 for k, slot in enumerate(slots):
                     # one program for every row of a model without totals
                     if row_cache is not None:
@@ -1588,10 +1624,11 @@ class ServingEngine:
             return
         padded = np.zeros((self._blocks_per_seq,), np.int32)
         padded[:nb] = table[:nb]
-        with jitwatch.dispatch_span("serve/save_blocks", blocks=nb):
-            self._store = _save_blocks(
-                self._cache, self._store, bs,
-                np.int32(slot), padded, np.int32(nb))
+        args = (self._cache, self._store, bs, np.int32(slot), padded,
+                np.int32(nb))
+        with jitwatch.dispatch_span(
+                "serve/save_blocks", program=(_save_blocks, args), blocks=nb):
+            self._store = _save_blocks(*args)
         self._c_blocks_copied.inc(nb, direction="save")
 
     def _refuse_blocks(self, what: str) -> None:
@@ -1797,12 +1834,13 @@ class ServingEngine:
         sampled = self._n_sampled > 0
         # (positional, as warmup() passes them: a keyword is another
         # program to jax.jit)
-        self._d_slots, ids, drawn = (_write_rows if blk is None
-                                     else _write_block_rows)(
-            *self._d_slots, rows, *(() if blk is None else (tails,)),
-            None if self._lora is None else self._lora["adapter_ids"],
-            self._d_sampling if sampled else None,
-            self._h_sampling if sampled else None)
+        write = _write_rows if blk is None else _write_block_rows
+        args = (*self._d_slots, rows, *(() if blk is None else (tails,)),
+                None if self._lora is None else self._lora["adapter_ids"],
+                self._d_sampling if sampled else None,
+                self._h_sampling if sampled else None)
+        with jitwatch.noting((write, args)):
+            self._d_slots, ids, drawn = write(*args)
         if ids is not None:
             self._lora = dict(self._lora, adapter_ids=ids)
         if drawn is not None:

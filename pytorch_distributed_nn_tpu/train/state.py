@@ -27,14 +27,15 @@ class TrainState:
     apply_fn: Callable = struct.field(pytree_node=False)
 
     def apply_gradients(self, grads) -> "TrainState":
-        updates, new_opt_state = self.tx.update(
-            grads, self.opt_state, self.params
-        )
-        return self.replace(
-            step=self.step + 1,
-            params=optax.apply_updates(self.params, updates),
-            opt_state=new_opt_state,
-        )
+        with jax.named_scope("optimizer"):   # metadata, obs/scopes.py
+            updates, new_opt_state = self.tx.update(
+                grads, self.opt_state, self.params
+            )
+            return self.replace(
+                step=self.step + 1,
+                params=optax.apply_updates(self.params, updates),
+                opt_state=new_opt_state,
+            )
 
     @classmethod
     def create(cls, *, apply_fn, params, tx, model_state=None,

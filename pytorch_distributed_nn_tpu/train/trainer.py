@@ -261,11 +261,15 @@ class Trainer:
                 # dispatch (the call that traces step_fn): recorded
                 # wire bytes are the goodput breakdown's cross-check
                 # for the collective share
+                # and the call that traces is the one moment the step's
+                # program can be noted (obs/scopes.py: shapes, no buffer)
+                args = (self.state, x, y)
                 with cc.recording() as comm_records:
                     with gp.phase("compute"):
                         with flight.dispatch("train_step", step=g):
-                            self.state, metrics = self.step_fn(
-                                self.state, x, y)
+                            with obs.jitwatch.noting((self.step_fn, args)):
+                                self.state, metrics = self.step_fn(*args)
+                del args
                 if comm_records:
                     gp.wire_bytes_per_step = cc.wire_bytes(comm_records)
                     # per-op attribution cross-checks collective time
