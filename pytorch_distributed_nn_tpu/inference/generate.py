@@ -74,8 +74,9 @@ def _shard_cache(cache, mesh):
     tensor = mesh.shape.get(AXIS_TENSOR, 1)
 
     def spec(x):
-        # (B, T, Hkv, D) payloads and (B, T, Hkv) int8-cache scales
-        # both carry heads at axis 2
+        # flat (B, T, Hkv * D) rows, (B, T, Hkv, D) payloads and
+        # (B, T, Hkv) int8-cache scales all carry heads at axis 2, a
+        # head's lanes together
         if x.ndim in (3, 4) and tensor > 1 and x.shape[2] % tensor == 0:
             return P(None, None, AXIS_TENSOR)
         return P()

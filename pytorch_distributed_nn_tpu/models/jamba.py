@@ -44,11 +44,10 @@ from pytorch_distributed_nn_tpu.models.longcat_flash import KINDS, SwiGLU
 from pytorch_distributed_nn_tpu.nn import head_input
 from pytorch_distributed_nn_tpu.nn.attention import (
     MultiHeadAttention,
-    prefill_in_tiles,
+    cache_rows_read,
 )
 from pytorch_distributed_nn_tpu.nn.dtypes import get_policy
 from pytorch_distributed_nn_tpu.nn.mamba import MambaMixer
-from pytorch_distributed_nn_tpu.ops.pallas.prefix_attention import rows_read
 
 # what a layer counts in one program execution, over real tokens only. A
 # Mamba layer: its executions and the positions that advanced a state. An
@@ -110,13 +109,9 @@ class JambaBlock(nn.Module):
         if not self.attention:
             return out, jnp.stack([jnp.ones((), jnp.uint32),
                                    real.sum().astype(jnp.uint32)])
-        rows = attn.get_variable("cache", "cached_key").shape[1]
-        # a blockwise prefill reads the key tiles its queries' tiles
-        # visit; every other call the whole row for each real query
         return out, jnp.stack([
             jnp.where(real, positions + 1, 0).sum(),
-            rows_read(positions, real, rows) if prefill_in_tiles(T, rows)
-            else real.sum() * rows]).astype(jnp.uint32)
+            cache_rows_read(attn, T, positions, real)]).astype(jnp.uint32)
 
 
 class Jamba(nn.Module):

@@ -51,18 +51,18 @@ from pytorch_distributed_nn_tpu.models.longcat_flash import KINDS, SwiGLU
 from pytorch_distributed_nn_tpu.nn import head_input
 from pytorch_distributed_nn_tpu.nn.attention import (
     MultiHeadAttention,
-    prefill_in_tiles,
+    cache_rows_read,
     ring_rows_scored,
 )
 from pytorch_distributed_nn_tpu.nn.dtypes import get_policy
-from pytorch_distributed_nn_tpu.ops.pallas.prefix_attention import rows_read
 from pytorch_distributed_nn_tpu.parallel.expert import HeldExpertsMoE
 
 # what a layer counts in one program execution, over real tokens only:
 # HeldExpertsMoE's routing counts (none in a dense layer), then the key
 # rows inside the real queries' masks and the key rows the program
-# read for them (the ring; a full layer's row whole, or in a blockwise
-# prefill the key tiles each query's tile visits)
+# read for them (the ring; a full layer's as the call's routine reads
+# them, ``nn/attention.cache_rows_read``: a round's key blocks up to a
+# row's depth, a blockwise prefill's key tiles, else the row whole)
 COUNTERS = ("moe_calls_total", "moe_picks_total", "moe_held_pairs_total",
             "moe_held_experts_touched_total", "attn_rows_attended_total",
             "attn_rows_read_total")
@@ -134,22 +134,16 @@ class KExaoneBlock(nn.Module):
         out = h + norm("post_ffn_norm")(f)
         if not decode or self.is_initializing():
             return out, None
-        rows = attn.get_variable("cache", "cached_key").shape[1]
+        # the ring's rows for each real query; a full layer's as the
+        # routine of this call reads them (``cache_rows_read``)
         if self.window:
             inside = jnp.minimum(positions + 1, self.window)
-            scored = ring_rows_scored(T, self.window)
+            read = real.sum() * ring_rows_scored(T, self.window)
         else:
-            inside, scored = positions + 1, rows
-        n_real = real.sum()
-        # a full layer's blockwise prefill reads the key tiles its
-        # queries' tiles visit; every other call the rows it scores for
-        # each real query
+            inside = positions + 1
+            read = cache_rows_read(attn, T, positions, real)
         return out, jnp.concatenate([routing, jnp.stack([
-            jnp.where(real, inside, 0).sum(),
-            rows_read(positions, real, rows)
-            if not self.window and prefill_in_tiles(T, rows)
-            else n_real * scored,
-        ]).astype(jnp.uint32)])
+            jnp.where(real, inside, 0).sum(), read]).astype(jnp.uint32)])
 
 
 class KExaone(nn.Module):

@@ -54,7 +54,7 @@ class LlamaBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False, decode: bool = False,
-                 cache_positions=None, lora=None):
+                 cache_positions=None, lora=None, lengths=None):
         # inert tag unless the enclosing remat uses a name-aware policy
         # (remat_offload): then this marks the block boundary as
         # offloadable to pinned host memory instead of living in HBM
@@ -75,7 +75,8 @@ class LlamaBlock(nn.Module):
                 cache_dtype=self.cache_dtype,
                 fused_qkv=self.quantized and self.fused_proj,
                 name="attn",
-            )(y, decode=decode, cache_positions=cache_positions, lora=lora)
+            )(y, decode=decode, cache_positions=cache_positions, lora=lora,
+              lengths=lengths)
             x = x + y
         with jax.named_scope("ffn"):
             y = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
@@ -136,11 +137,15 @@ class Llama(nn.Module):
     # model.extra["fused_proj"] = True.
     fused_proj: bool = False
 
+    # the serving engine tells such a model which fed tokens are real
+    takes_token_mask = True
+
     @nn.compact
     def __call__(self, tokens, *, train: bool = False,
                  decode: bool = False, last_only: bool = False,
                  return_hidden: bool = False, cache_positions=None,
-                 lora_bank=None, adapter_ids=None, head_rows=None):
+                 lora_bank=None, adapter_ids=None, head_rows=None,
+                 token_mask=None):
         """``last_only`` returns logits for the final position only
         (B, 1, V) — decode prefill needs just the next-token row, and
         at real vocab sizes the (P-1) unused head projections dominate
@@ -151,7 +156,11 @@ class Llama(nn.Module):
         chunked-xent path (train/losses.py) applies the head blockwise
         so full logits never materialize. ``cache_positions`` (B,)
         int32: per-row KV cache indices for continuous batching — see
-        nn.attention.MultiHeadAttention.
+        nn.attention.MultiHeadAttention. ``token_mask`` (B, T) bool
+        (decode only) marks the real tokens, a left-aligned prefix of
+        each row: the attention is told how many (``lengths``), so that
+        a bucket's padding and a slot that is not live attend to nothing
+        where the routine can skip them.
 
         ``lora_bank`` + ``adapter_ids``: per-request LoRA (nn/lora.py).
         The bank is the stacked ``(n, L, ...)`` factor dict; each batch
@@ -196,6 +205,7 @@ class Llama(nn.Module):
             ids = adapter_ids
             if ids is None:
                 ids = jnp.zeros((tokens.shape[0],), jnp.int32)
+        lengths = None if token_mask is None else token_mask.sum(axis=-1)
         for i in range(self.num_layers):
             if lora_bank is None:
                 lora = None
@@ -213,7 +223,7 @@ class Llama(nn.Module):
                 cache_dtype=self.cache_dtype,
                 fused_proj=self.fused_proj,
                 name=f"layer{i}",
-            )(x, train, decode, cache_positions, lora)
+            )(x, train, decode, cache_positions, lora, lengths)
         with jax.named_scope("head"):
             x = head_input(x, last_only, head_rows)
             x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,

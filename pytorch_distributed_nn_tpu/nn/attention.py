@@ -227,8 +227,7 @@ def _quantize_kv(x):
     return q, scale
 
 
-def _cache_attention(q, k, v, pos_mask, dtype, kscale=None, vscale=None,
-                     scale=None):
+def _cache_attention(q, k, v, pos_mask, dtype, kscale=None, vscale=None):
     """Decode attention over the KV cache with GQA kept GROUPED: q
     reshapes to (B, T, Hkv, G, D) instead of repeating the cached K/V.
     (The einsum-path `jnp.repeat` materializes H/Hkv copies of the
@@ -247,13 +246,12 @@ def _cache_attention(q, k, v, pos_mask, dtype, kscale=None, vscale=None,
     plane.
 
     q: (B, T, H, D); k/v: (B, S, Hkv, D) float — or int8 when the
-    scales are given; pos_mask: (B|1, T, S); ``scale`` multiplies the
-    scores (default ``D ** -0.5``). Returns (B, T, H, D)."""
+    scales are given; pos_mask: (B|1, T, S). Returns (B, T, H, D)."""
     B, T, H, D = q.shape
     q5 = q.reshape(B, T, k.shape[2], H // k.shape[2], D)
     logits = jnp.einsum("btkgd,bskd->bkgts", q5, k.astype(dtype),
                         preferred_element_type=jnp.float32)
-    logits *= D ** -0.5 if scale is None else scale
+    logits *= D ** -0.5
     if kscale is not None:
         logits *= kscale.transpose(0, 2, 1)[:, :, None, None, :]
     logits = jnp.where(pos_mask[:, None, None], logits, -1e30)
@@ -267,42 +265,16 @@ def _cache_attention(q, k, v, pos_mask, dtype, kscale=None, vscale=None,
 
 
 def lane_pack(head_dim: int, kv_heads: int) -> int:
-    """K/V heads a cache row of one lane tile holds side by side: 2 for
-    an even number of heads of 64, else 1. The chip lays an array out
-    in tiles of 128 lanes along its last axis, and the decode round's
-    two products want the head's dims on them: a cache ``(slots, S,
-    Hkv, 64)`` is transposed whole, every layer, every round, to put
-    them there (four copies of 268 MB a layer at 64 slots x 4,096: 14.9
-    of a round's 30.7 ms on the chip, PERF.md sec. 6, PR 46), where
-    ``(slots, S, Hkv / 2, 128)``, the same bytes in the same order, is
-    read in place like a cache of 128-wide heads."""
+    """K/V heads that one lane tile of a flat cache row holds side by
+    side: 2 for an even number of heads of 64, else 1. The chip lays an
+    array out in tiles of 128 lanes along its last axis, and a round's
+    two products want a head's dims on them: read a head of 64 at a
+    time, a cache is transposed whole, every layer, every round (four
+    copies of 268 MB a layer at 64 slots x 4,096: 14.9 of a round's 30.7
+    ms on the chip, PERF.md sec. 6, PR 46). :func:`_round_attention`
+    reads such a row as ``kv_heads / 2`` heads of 128, the same bytes in
+    the same order, in place."""
     return 2 if head_dim == 64 and kv_heads % 2 == 0 else 1
-
-
-def _packed_cache_attention(q, k, v, pos_mask, dtype):
-    """:func:`_cache_attention` over a cache that holds ``pack`` K/V
-    heads side by side a row, k/v (B, S, P, pack * D) with K/V head
-    ``j`` in lanes ``[j % pack * D, (j % pack + 1) * D)`` of row ``j //
-    pack``. A query head is laid into its K/V head's lanes, zeros in
-    the others, so that its product with the whole row is its product
-    with its own head, exactly (the other lanes add zeros); of the
-    ``pack * D`` lanes that come back from the values, its own are
-    kept. The products take ``pack`` times the operations, which a
-    decode round, bound by the cache's bytes, does not feel; the bytes
-    read are the cache's own."""
-    B, T, H, D = q.shape
-    P, pack = k.shape[2], k.shape[3] // D
-    G = H // (P * pack)
-    # query head h = (p * pack + r) * G + g reads K/V head p * pack + r
-    q6 = q.reshape(B, T, P, pack, G, 1, D)
-    lanes = (jnp.arange(pack)[:, None] == jnp.arange(pack)[None, :]) \
-        [None, None, None, :, None, :, None]
-    wide = jnp.where(lanes, q6, jnp.zeros((), q.dtype)) \
-        .reshape(B, T, P * pack * G, pack * D)
-    out = _cache_attention(wide, k, v, pos_mask, dtype, scale=D ** -0.5)
-    out = out.reshape(B, T, P, pack, G, pack, D)
-    return jnp.where(lanes, out, jnp.zeros((), out.dtype)).sum(axis=5) \
-        .reshape(B, T, H, D)
 
 
 def _ring_held(last, rows):
@@ -411,35 +383,62 @@ def _prefill_attention(q, k, v, positions, lengths=None):
         scale=q.shape[-1] ** -0.5))
 
 
+def _round_block(S: int, head_dim: int, kv_heads: int, dtype) -> int:
+    """The key block of a round's kernel over flat rows of ``kv_heads``
+    heads of ``head_dim``, S to a slot; 0 where the dense routine runs
+    (:func:`round_key_block`; heads of 64 two a lane tile count as one
+    of 128)."""
+    pack = lane_pack(head_dim, kv_heads)
+    return round_key_block(S, kv_heads // pack, pack * head_dim, dtype)
+
+
 def _round_attention(q, k, v, seen, lengths, dtype):
-    """A block decoder's round against its cache: q (B, T, H, D), a
-    block or two of fed positions a row; k, v (B, S, Hkv * D), the flat
-    rows such a cache holds (``see_block``), the fed positions written;
-    ``seen`` (B, T) the last key each query sees; ``lengths`` (B,) how
-    many of a row's queries are real. On a TPU in bf16
-    (:func:`round_key_block`) one kernel a layer, whose grid step is a
-    row and a key block: the G query heads of a K/V head times the T
-    positions are that head's query rows, a row's key blocks past what
-    it sees are not read, a query that is not real sees nothing and
-    gets zeros. Anywhere else the dense routine over the whole row,
-    as every other decode round. Returns (B, T, H, D)."""
+    """A decode round against rows by position: q (B, T, H, D), the
+    fed positions of each row (one, or a block decoder's block or two);
+    k, v (B, S, Hkv * D), the flat rows such a cache holds, the fed
+    positions written; ``seen`` (B|1, T) the last key each query sees;
+    ``lengths`` (B,) how many of a row's queries are real (0: a slot
+    that is not live). On a TPU in bf16 (:func:`round_key_block`) one
+    kernel a layer, whose grid step is a row and a key block: the G
+    query heads of a K/V head times the T positions are that head's
+    query rows, a row's key blocks past what it sees are not read, a
+    query that is not real sees nothing and gets zeros, so a row with
+    none reads nothing. Heads of 64 lie two a lane tile
+    (:func:`lane_pack`) and are read as one head of 128: a query is
+    laid into its own head's lanes, zeros in the other's, so that its
+    product with the wide row is its product with its own head,
+    exactly, and of the lanes that come back its own are kept (twice
+    the multiplies, which a round bound by the cache's bytes does not
+    feel; the bytes are the cache's own). Anywhere else the dense
+    routine over the whole row, reshaped by head, which takes no notice
+    of ``lengths``. Returns (B, T, H, D)."""
     B, T, H, D = q.shape
     S, kv_heads = k.shape[1], k.shape[2] // D
     G = H // kv_heads
-    block_k = round_key_block(T * G, S, D, k.dtype)
+    block_k = _round_block(S, D, kv_heads, k.dtype)
     if not block_k:
         heads = lambda x: x.reshape(B, S, kv_heads, D)  # noqa: E731
         return _cache_attention(
             q, heads(k), heads(v),
             jnp.arange(S)[None, None, :] <= seen[:, :, None], dtype)
+    seen = jnp.broadcast_to(seen, (B, T))
     if lengths is not None:
         seen = seen_from(seen, jnp.arange(T)[None] < lengths[:, None])
-    grouped = lambda x: x.reshape(  # noqa: E731
-        B, x.shape[1], x.shape[2] // G, G, D).transpose(0, 2, 1, 3, 4)
+    pack = lane_pack(D, kv_heads)
+    P = kv_heads // pack
+    # query head ((p * pack + r) * G + g) reads K/V head p * pack + r
+    rows = q.reshape(B, T, P, pack, G, D).transpose(0, 2, 3, 1, 4, 5)
+    if pack > 1:
+        lanes = jnp.eye(pack, dtype=bool)[:, None, None, :, None]
+        rows = jnp.where(lanes, rows[..., None, :], jnp.zeros((), q.dtype))
     out = round_attention(
-        grouped(q).reshape(B, kv_heads, T * G, D), k, v,
-        jnp.repeat(seen, G, axis=1), scale=D ** -0.5, block_k=block_k)
-    return out.reshape(B, kv_heads, T, G, D).transpose(
+        rows.reshape(B, P, pack * T * G, pack * D), k, v,
+        jnp.tile(jnp.repeat(seen, G, axis=1), (1, pack)),
+        scale=D ** -0.5, block_k=block_k)
+    if pack > 1:
+        out = jnp.where(lanes, out.reshape(B, P, pack, T, G, pack, D),
+                        jnp.zeros((), out.dtype)).sum(axis=5)
+    return out.reshape(B, P * pack, T, G, D).transpose(
         0, 2, 1, 3, 4).reshape(B, T, H, D).astype(dtype)
 
 
@@ -449,16 +448,15 @@ def cache_rows_read(attn, T: int, seen, real):
     tokens a row reads for its ``real`` (B, T) queries, summed, each
     seeing up to ``seen`` (B, T): the key tiles its query tiles visit
     (a blockwise prefill), the key blocks up to the last position a
-    row's real queries see (:func:`_round_attention`'s kernel), or the
-    row's whole length a query (the dense routine)."""
+    row's real queries see (a round through :func:`_round_attention`'s
+    kernel), or the row's whole length a query (the dense routine)."""
     key = attn.get_variable("cache", "cached_key")
     S = key.shape[1]
     if prefill_in_tiles(T, S):
         return rows_read(seen, real, S)
-    if attn.is_block_round(T):
-        block_k = round_key_block(
-            T * attn.num_heads // (attn.num_kv_heads or attn.num_heads),
-            S, attn.head_dim, key.dtype)
+    if attn.is_round(T) and key.ndim == 3:
+        block_k = _round_block(S, attn.head_dim,
+                               attn.num_kv_heads or attn.num_heads, key.dtype)
         if block_k:
             return round_rows_read(seen, real, S, block_k)
     return real.sum() * S
@@ -515,9 +513,7 @@ class MultiHeadAttention(nn.Module):
     # 0 or 1: causal. A block or two of fed tokens a row (T of
     # ``see_block`` or twice that) are a decode round over every slot,
     # written by one scatter a leaf and attended by
-    # :func:`_round_attention`; for its kernel's sake the cache of such
-    # a layer holds a position's K/V heads side by side in one flat row,
-    # ``(B, S, Hkv * D)``, which every routine but that one reshapes.
+    # :func:`_round_attention`, as one token a row is.
     see_block: int = 0
 
     @nn.nowrap
@@ -525,6 +521,12 @@ class MultiHeadAttention(nn.Module):
         """Whether T fed tokens a row are a block decoder's round."""
         return self.see_block > 1 and T in (self.see_block,
                                             2 * self.see_block)
+
+    @nn.nowrap
+    def is_round(self, T: int) -> bool:
+        """Whether T fed tokens a row are a decode round: one, or a
+        block decoder's block or two."""
+        return T == 1 or self.is_block_round(T)
 
     @nn.compact
     def __call__(self, x, mask: Optional[jax.Array] = None,
@@ -567,9 +569,11 @@ class MultiHeadAttention(nn.Module):
         written by absolute position lands past a row's end and is
         masked until overwritten; in a ring it would displace a real
         position, so it is not written. A blockwise prefill over rows
-        by position (:func:`prefill_in_tiles`) lets the padding attend
-        to nothing (its rows of the result are zeros); the dense routine
-        and the int8 cache take no notice."""
+        by position (:func:`prefill_in_tiles`) and a decode round
+        through the kernel (:func:`_round_attention`) let what is not
+        real attend to nothing (its rows of the result are zeros, and a
+        row with ``lengths`` 0, a slot that is not live, reads no key);
+        the dense routine and the int8 cache take no notice."""
         kv_heads = self.num_kv_heads or self.num_heads
         if self.quantized:
             if self.use_bias:
@@ -693,17 +697,14 @@ class MultiHeadAttention(nn.Module):
                                  "dtype; cache_dtype='int8' has no ring")
             init_k = nn.initializers.zeros
             # init sizes the cache from the (B, max_len) input; a window
-            # layer's is its ring, whatever max_len
-            kv_shape = (B, self.window or T, kv_heads, self.head_dim)
-            # heads of half a lane tile lie two a row (rows by position
-            # in the compute dtype only: a ring and the int8 layout keep
-            # a head a row)
-            pack = 1 if self.window or int8_cache or self.see_block > 1 \
-                else lane_pack(self.head_dim, kv_heads)
-            if pack > 1:
-                kv_shape = (B, T, kv_heads // pack, pack * self.head_dim)
-            if self.see_block > 1:
-                kv_shape = (B, T, kv_heads * self.head_dim)
+            # layer's is its ring, whatever max_len. Rows by position in
+            # the compute dtype lie flat, a position's K/V heads side by
+            # side in one row: the layout a round's kernel reads in
+            # place, and the one every routine but that one reshapes by
+            # head (a ring and the int8 layout keep a head a row)
+            kv_shape = (B, self.window or T, kv_heads, self.head_dim) \
+                if self.window or int8_cache \
+                else (B, T, kv_heads * self.head_dim)
             cached_k = self.variable(
                 "cache", "cached_key", init_k, None, kv_shape,
                 jnp.int8 if int8_cache else k.dtype,
@@ -792,27 +793,23 @@ class MultiHeadAttention(nn.Module):
                         vscale=v_scale.value,
                     )
                 else:
-                    flat = by_head = lambda x: x  # noqa: E731
-                    if self.see_block > 1 or pack > 1:
-                        # a position's heads in a row, or two a row
-                        flat = lambda x: x.reshape(  # noqa: E731
-                            x.shape[:2] + kv_shape[2:])
-                        by_head = lambda x: x.reshape(  # noqa: E731
-                            x.shape[:2] + (kv_heads, self.head_dim))
+                    by_head = lambda x: x.reshape(  # noqa: E731
+                        x.shape[:2] + (kv_heads, self.head_dim))
+
+                    def flat(x):   # a fed position as the leaf holds one
+                        with jax.named_scope("cache_write"):
+                            return x.reshape(x.shape[:2] + kv_shape[2:])
+
                     cached_k.value = write(cached_k.value, flat(k))
                     cached_v.value = write(cached_v.value, flat(v))
                     if prefill_in_tiles(T, S):
                         out = _prefill_attention(
                             q, by_head(cached_k.value),
                             by_head(cached_v.value), seen, lengths)
-                    elif self.is_block_round(T):
+                    elif self.is_round(T):
                         out = _round_attention(
                             q, cached_k.value, cached_v.value, seen,
                             lengths, self.dtype)
-                    elif pack > 1:
-                        out = _packed_cache_attention(
-                            q, cached_k.value, cached_v.value, pos_mask,
-                            self.dtype)
                     else:
                         out = _cache_attention(
                             q, by_head(cached_k.value),

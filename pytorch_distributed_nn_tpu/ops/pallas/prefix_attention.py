@@ -21,12 +21,13 @@ the path of a shape the kernel cannot tile, and the kernel's oracle).
 the shapes.
 
 :func:`round_attention` is the same recurrence for a decode round that
-feeds every row of a batched cache a few positions (a block decoder's:
-a block or two): the keys and values are read where the cache holds
-them, rows by position with a position's heads side by side, a grid
-step a row and a key block, and a row's key blocks past its last
-visible position are not read, where the dense routine scores every
-row's whole padded length and writes the float32 scores out.
+feeds every row of a batched cache a few positions (one; a block
+decoder's block or two): the keys and values are read where the cache
+holds them, rows by position with a position's heads side by side, a
+grid step a row and a key block, and a row's key blocks past its last
+visible position are not read (none of a row that is not live), where
+the dense routine scores every row's whole padded length and writes
+the float32 scores out.
 """
 
 from __future__ import annotations
@@ -61,6 +62,15 @@ VMEM_LIMIT_BYTES = 64 * 2 ** 20
 # bf16, so 512 rows are 1 MB and ~1.3 us of the chip's bandwidth over a
 # step's ~0.35 us, and a row reads half a block past what it has filled
 ROUND_KEY_BLOCKS = (512, 256, 128)
+# where a position holds one K/V head of 128 (Jamba), 512 rows are 128
+# KB a step, under the step's own overhead: 1,024 first (206 -> 172 us a
+# layer at 64 slots x 4,096 on the chip, the dense routine 182; at 4 and
+# 8 heads a position 512 is ahead of 1,024 by 2-17 % and of 256 by 6-38
+# %: PERF.md sec. 6)
+ROUND_KEY_BLOCKS_ONE_HEAD = (1024,) + ROUND_KEY_BLOCKS
+# query rows a K/V head that kernel takes in whole: a bf16 register's
+# sublanes
+QUERY_ROWS = 16
 
 
 def tiles(T: int, S: int, block_q: int, block_k: int) -> tuple:
@@ -345,14 +355,14 @@ def prefix_attention(q, k, v, q_pos, *, scale: float,
                            q_pos, scale=scale, block_q=bq, block_k=bk)
 
 
-def _round_kernel(hi_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
+def _round_kernel(hi_ref, at_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, scale: float, block_k: int):
     """One (row, kj) grid step of :func:`round_attention`: every K/V
     head's tile of the key block, cut from the block's lanes, against
     that head's query rows. ``hi`` (scalar prefetch, (B,)) is the last
     position any query of the row sees: a key block past it is neither
-    fetched nor multiplied, and a row with none (``hi`` < 0) costs its
-    steps' overhead alone."""
+    fetched (``at``, the index map's) nor multiplied, and a row with
+    none (``hi`` < 0) costs its steps' overhead alone."""
     b, kj = pl.program_id(0), pl.program_id(1)
     hi = hi_ref[b]
     first = kj * block_k
@@ -404,28 +414,47 @@ def round_attention(q, k, v, q_pos, *, scale: float, block_k: int,
     position's K/V heads side by side, S in whole blocks of ``block_k``
     (:func:`round_key_block`); q_pos (B, R): key s is visible to query
     row r iff ``s <= q_pos[b, r]``, a negative position sees nothing and
-    gets zeros. Returns (B, Hkv, R, d) in q's dtype."""
+    gets zeros. Returns (B, Hkv, R, d) in q's dtype. R that is not
+    whole registers (one fed position a row: the group's few query
+    heads) is padded to them with query rows that see nothing: a few KB
+    beside the key blocks."""
+    rows = q.shape[2]
+    pad = -rows % QUERY_ROWS
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        q_pos = jnp.pad(q_pos, ((0, 0), (0, pad)), constant_values=-1)
     B, H, R, d = q.shape
     S = k.shape[1]
+    nk = S // block_k
     pos = jnp.minimum(q_pos.astype(jnp.int32), S - 1)
+    hi = pos.max(axis=-1)
+    # the key block a grid step holds: its own where the step visits one,
+    # else the one the last visiting step before it held (the pipeline
+    # fetches a block only when the index moves, so a step that visits
+    # nothing, be it past a row's depth or in a row that is not live,
+    # moves no byte), as a step's number in the grid's order
+    steps = jnp.arange(B * nk, dtype=jnp.int32).reshape(B, nk)
+    at = jax.lax.cummax(
+        jnp.where(steps % nk * block_k <= hi[:, None], steps, 0).reshape(-1),
+        axis=0).reshape(B, nk)
 
-    def row_map(b, kj, hi_ref):
+    def row_map(b, kj, hi_ref, at_ref):
         return (b, 0, 0, 0)
 
-    def kv_map(b, kj, hi_ref):
-        return (b, jnp.minimum(kj, jnp.maximum(hi_ref[b], 0) // block_k), 0)
+    def kv_map(b, kj, hi_ref, at_ref):
+        return (at_ref[b, kj] // nk, at_ref[b, kj] % nk, 0)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_round_kernel, scale=scale, block_k=block_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, S // block_k),
+            num_scalar_prefetch=2,
+            grid=(B, nk),
             in_specs=[
                 pl.BlockSpec((None, H, R, d), row_map),
                 pl.BlockSpec((None, block_k, H * d), kv_map),
                 pl.BlockSpec((None, block_k, H * d), kv_map),
                 pl.BlockSpec((None, R, 1),
-                             lambda b, kj, hi_ref: (b, 0, 0)),
+                             lambda b, kj, hi_ref, at_ref: (b, 0, 0)),
             ],
             out_specs=pl.BlockSpec((None, H, R, d), row_map),
             scratch_shapes=[
@@ -438,19 +467,20 @@ def round_attention(q, k, v, q_pos, *, scale: float, block_k: int,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="round_attention",
-    )(pos.max(axis=-1), q, k, v, pos[..., None])
+    )(hi, at, q, k, v, pos[..., None])
+    return out[:, :, :rows] if pad else out
 
 
-def round_key_block(R: int, S: int, d: int, dtype) -> int:
-    """The key rows a grid step of :func:`round_attention` takes for R
-    query rows a K/V head of width d against S cached rows, or 0 where
-    the kernel is not the routine: off a TPU, off bf16, or off the
-    tiles (whole registers of query rows, whole lane tiles of a head,
-    whole blocks of keys)."""
-    if jax.default_backend() != "tpu" or R % 16 or d % 128 \
+def round_key_block(S: int, heads: int, d: int, dtype) -> int:
+    """The key rows a grid step of :func:`round_attention` takes for
+    ``heads`` K/V heads of width d a position against S cached rows, or
+    0 where the kernel is not the routine: off a TPU, off bf16, or off
+    the tiles (whole lane tiles of a head, whole blocks of keys)."""
+    if jax.default_backend() != "tpu" or d % 128 \
             or jnp.dtype(dtype) != jnp.bfloat16:
         return 0
-    return next((n for n in ROUND_KEY_BLOCKS if S % n == 0), 0)
+    blocks = ROUND_KEY_BLOCKS_ONE_HEAD if heads == 1 else ROUND_KEY_BLOCKS
+    return next((n for n in blocks if S % n == 0), 0)
 
 
 def round_rows_read(q_pos, real, S: int, block_k: int):

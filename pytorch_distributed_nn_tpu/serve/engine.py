@@ -337,12 +337,15 @@ def _serve_step(model, params, cache, last_tok, lengths, active, remaining,
     dynamic batch size would recompile); their tokens/depths are frozen
     by the ``active`` mask and their cache writes land in retired rows
     that the next occupant's prefill overwrites (and masks until it
-    grows there). A row stops HERE, in the step that produces its last
-    token: ``remaining`` (slots,) is the tokens a row may still emit
-    and ``eos`` the engine's stop token (a scalar, -1 for none: no
-    token is negative), and the mask that comes back is the next
-    round's, so a round dispatched before the host has seen this one's
-    tokens computes nothing for a finished row. ``lora`` and absent
+    grows there). A model that takes the ``token_mask`` is told which
+    rows are live, and its attention over rows by position reads no
+    key of the others (``nn/attention._round_attention``). A row stops
+    HERE, in the step that produces its last token: ``remaining``
+    (slots,) is the tokens a row may still emit and ``eos`` the
+    engine's stop token (a scalar, -1 for none: no token is negative),
+    and the mask that comes back is the next round's, so a round
+    dispatched before the host has seen this one's tokens computes
+    nothing for a finished row. ``lora`` and absent
     arguments as in :func:`_serve_prefill`, with ``adapter_ids``
     (slots,). Returns ``(tokens, lengths, active, remaining, cache,
     sampling)``.

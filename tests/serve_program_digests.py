@@ -28,7 +28,19 @@ lines and the ``trees`` entries stayed byte-equal, and the Brumby-shaped
 and SDAR-shaped programs (``_LEFT_ALONE``: the two families that had
 ``head_rows`` before; ``sdar.step`` is ``_block_round``) were added
 from the commit before it, whose text they still are
-(``tests/test_head_rows.py``). What the served
+(``tests/test_head_rows.py``). ISSUE 53 wrote ``mistral.prefill``,
+``mistral.step``, ``kexaone.prefill``, ``kexaone.step`` and the
+``trees`` entry ``kexaone.cache`` anew and meant to: rows by position
+in the compute dtype lie flat in every family, ``(slots, S, Hkv * D)``
+(K-EXAONE's full layers; its rings keep a head a row), so the four
+programs reshape the fed positions before the write and the leaf by
+head before the dense routine, which is still the routine off a TPU
+(a decode round's kernel is chosen by backend and dtype, and this file
+lowers for the CPU in float32), and ``models/llama.py`` takes the
+engine's ``token_mask`` and hands its attention ``lengths``. The
+LongCat lines (latent rows), Brumby's, and ``sdar.prefill`` and
+``sdar.step`` (flat since ISSUE 44, the same routine as before) stayed
+byte-equal. What the served
 sizes compile to is ``tests/test_chip_compile.py``'s to hold. A PR that
 means to change those programs writes the file anew and says so:
 

@@ -142,6 +142,28 @@ def _prefix_attention(T, S, heads=(64, 64), widths=(192, 128)):
     return build
 
 
+def _round_attention(slots, S, heads, rows):
+    """A decode round's attention over the flat rows of a served cache:
+    ``heads`` K/V heads of 128 lanes a position (heads of 64 two a
+    tile), ``rows`` query rows a head (the group's query heads times the
+    fed positions; fewer than a register's sublanes are padded)."""
+    from pytorch_distributed_nn_tpu.ops.pallas import prefix_attention as pa
+
+    def build(arg):
+        def run(q, k, v, pos):
+            bk = next(n for n in (pa.ROUND_KEY_BLOCKS_ONE_HEAD
+                                  if heads == 1 else pa.ROUND_KEY_BLOCKS)
+                      if S % n == 0)
+            return pa.round_attention(q, k, v, pos, scale=128 ** -0.5,
+                                      block_k=bk)
+
+        return run, [arg((slots, heads, rows, 128), jnp.bfloat16),
+                     arg((slots, S, heads * 128), jnp.bfloat16),
+                     arg((slots, S, heads * 128), jnp.bfloat16),
+                     arg((slots, rows), jnp.int32)], 1
+    return build
+
+
 def _mamba_operands(arg, T, c_dtype):
     f32 = jnp.float32
     return [arg((1, 16, 5120), f32), arg((1, T, 5120), f32),
@@ -253,6 +275,18 @@ CASES = {
         4096, 4096, (20, 1), (128, 128)),
     "prefix_attention_gqa20_T1024_S1024": _prefix_attention(
         1024, 1024, (20, 1), (128, 128)),
+    # a decode round over the cells' caches of rows by position, one
+    # fed position a row: Mistral's chat and documents (4 query heads a
+    # K/V head), K-EXAONE's full layers (8), LFM2's (heads of 64 two a
+    # lane tile: 2 x 4 rows a wide head), Jamba's (20 to one); SDAR's
+    # block round (8 positions x 8)
+    "round_attention_32x1024_8heads_4rows": _round_attention(32, 1024, 8, 4),
+    "round_attention_8x4096_8heads_4rows": _round_attention(8, 4096, 8, 4),
+    "round_attention_32x4096_8heads_8rows": _round_attention(32, 4096, 8, 8),
+    "round_attention_64x4096_4heads_8rows": _round_attention(64, 4096, 4, 8),
+    "round_attention_64x4096_1head_20rows": _round_attention(64, 4096, 1, 20),
+    "round_attention_64x2048_4heads_64rows": _round_attention(
+        64, 2048, 4, 64),
     "selective_scan_T128": _selective_scan(128),
     "selective_scan_T4096": _selective_scan(4096),
     "mamba_scan_T4096": _mamba_scan(4096),
@@ -502,6 +536,68 @@ def test_block_copies_hold_no_loop_on_the_chip(topo, family, slots, S,
     assert save.memory_analysis().alias_size_in_bytes >= held
 
 
+def _leaf_sized(text, elements):
+    """The instructions of a compiled program that make a new array of
+    at least ``elements`` bf16 elements, a cache leaf's size: a copy, a
+    transpose, or a fusion that is one (its name says so)."""
+    import re
+
+    made = re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]*(?:copy|transpose)[\w.\-]*) = "
+        r"bf16\[([\d,]+)\]", text, re.M)
+    return [(name, dims) for name, dims in made
+            if math.prod(map(int, dims.split(","))) >= elements]
+
+
+@pytest.mark.parametrize("family,slots,rows,layers", [
+    ("llama", 32, 1024, 2),       # the chat cell's cache, every layer
+    ("llama", 8, 4096, 2),        # the documents'
+    ("k_exaone", 32, 4096, 1),    # LLLG: the one full layer
+])
+def test_a_decode_round_reads_rows_by_position_where_they_lie(
+        topo, monkeypatch, family, slots, rows, layers):
+    """The compiled ``_serve_step`` of a model whose cache is rows by
+    position (two K/V heads of 128 a position, the cells' slots and
+    rows) holds one ``round_attention`` call a layer over them, fed the
+    leaves the round's scatter wrote, and makes no array of a leaf's
+    size on the way: no copy, no transpose, in the entry or in a fusion
+    (the dispatcher asks for the backend, and this test answers for the
+    chip). A ring is not this routine's."""
+    from pytorch_distributed_nn_tpu.config import ModelConfig
+    from pytorch_distributed_nn_tpu.inference.generate import init_cache
+    from pytorch_distributed_nn_tpu.models import get_model
+    from pytorch_distributed_nn_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    name, extra = _SERVED[family]
+    model = get_model(ModelConfig(name=name, dtype="float32",
+                                  compute_dtype="bfloat16",
+                                  extra=dict(extra)))
+
+    def on(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on(jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+        train=False))["params"])
+    cache = on(jax.eval_shape(lambda: init_cache(model, slots, rows)))
+    state = on([jax.ShapeDtypeStruct((slots,), dt) for dt in
+                (jnp.int32, jnp.int32, jnp.bool_, jnp.int32)]
+               + [jax.ShapeDtypeStruct((), jnp.int32)])
+    step = jax.jit(lambda *a: engine._serve_step.__wrapped__(*a),
+                   static_argnums=(0,), donate_argnums=(2,))
+    compiled = step.lower(model, params, cache, *state).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if KERNEL in line]
+    assert len(calls) == layers
+    assert all(f"bf16[{slots},{rows},256]" in line for line in calls)
+    assert not _leaf_sized(text, slots * rows * 256)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * layers * slots * rows * 256 * 2   # the cache, in place
+
+
 def _dense_prefill(q, k, v, positions, lengths=None):
     """``nn/attention._prefill_attention`` as the dense routine it
     replaced: every score of the row at once."""
@@ -652,9 +748,11 @@ def test_lfm2_serve_programs_fit_the_chip_at_the_cells_size(
     they are given, the round's cache donated. A layer's held experts
     are one ``grouped_experts`` call (``ops/pallas``: the dispatcher
     asks for the backend, and this test answers for the chip) and no
-    loop; its cache of 64-wide K/V heads lies two heads a row of 128 and
-    is read in place (a head a row, the compiler transposed each leaf
-    whole, twice a layer a round: 0.56 GB of temporaries where there are
+    loop; its cache of 64-wide K/V heads lies flat, a position's eight
+    heads in one row of 512 lanes, and a round reads it in place, one
+    ``round_attention`` call an attention layer that takes two heads as
+    one of 128 (a head a row, the compiler transposed each leaf whole,
+    twice a layer a round: 0.56 GB of temporaries where there are
     0.03); a prefill whose scores would be large attends blockwise, one
     ``prefix_attention`` call an attention layer at heads of 64, and
     holds no float32 scores of the bucket against the row.
@@ -691,13 +789,11 @@ def test_lfm2_serve_programs_fit_the_chip_at_the_cells_size(
             model, params, cache, arg((slots,)), arg((slots,)),
             arg((slots,), jnp.bool_), arg((slots,)), arg(())).compile()
         text = compiled.as_text()
-        assert text.count(KERNEL) == moe_layers
+        assert text.count(KERNEL) == moe_layers + attn_layers
         assert text.count(" while(") == 0
-        # no copy of a cache leaf among the program's own steps: the
-        # scatter and the two products read it where it lies
-        assert not re.findall(
-            rf"%copy[.\d]* = bf16\[{slots},{rows},4,128\]",
-            text[text.index("\nENTRY "):])
+        # no copy of a cache leaf anywhere in the program: the scatter
+        # and the round's kernel read it where it lies
+        assert not _leaf_sized(text, slots * rows * 512)
         assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
     else:
         cache = on(jax.eval_shape(lambda: init_cache(model, 1, program)))

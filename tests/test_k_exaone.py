@@ -258,8 +258,9 @@ def test_cached_prefill_in_tiles_is_the_dense_one(lengths):
 @pytest.mark.parametrize("max_len", [16, 64, 512])
 def test_a_window_layers_cache_is_its_ring_whatever_the_length(max_len):
     """Window layers keep ``(B, window, kv, head)`` leaves, full layers
-    ``(B, max_len, kv, head)``, side by side in one cache tree; the
-    model declares the rings, and no other leaf."""
+    the flat rows by position ``(B, max_len, kv * head)``, side by side
+    in one cache tree; the model declares the rings, and no other
+    leaf."""
     model = _model(4, 1)
     cache = gen.init_cache(model, 3, max_len)
     (kind, paths), = model.leaves_not_by_position().items()
@@ -269,7 +270,8 @@ def test_a_window_layers_cache_is_its_ring_whatever_the_length(max_len):
     for name, window, _ in model._layers():
         for leaf in ("cached_key", "cached_value"):
             shape = cache[name]["attn"][leaf].shape
-            assert shape == (3, WINDOW if window else max_len, 2, 16)
+            assert shape == ((3, WINDOW, 2, 16) if window
+                             else (3, max_len, 2 * 16))
             assert ((name, "attn", leaf) in rings) == bool(window)
 
 

@@ -275,32 +275,112 @@ def test_prefill_behind_filled_rows_is_the_dense_masked_softmax(
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_one_token_a_row_lowers_as_before_the_blockwise_prefill(monkeypatch):
-    """A decode round (T = 1) keeps the dense routine over the row
-    cache: its lowered text is the one the commit before the blockwise
-    prefill lowered (sha256 taken there; it depends on the installed
-    JAX, as ``tests/data/serve_program_digests.json`` does), and the
-    prefill's routine is never entered."""
+def test_one_token_a_row_off_the_chip_lowers_to_the_dense_routine(
+        monkeypatch):
+    """A decode round (T = 1) off a TPU is the dense routine over the
+    flat row cache reshaped by head: its lowered text is pinned (sha256
+    taken at ISSUE 53, which laid the rows flat and meant to change it;
+    it depends on the installed JAX, as
+    ``tests/data/serve_program_digests.json`` does), the prefill's
+    routine is never entered and the round's kernel is not called."""
     import hashlib
 
     from pytorch_distributed_nn_tpu.nn import attention
 
     def never(*a, **k):
-        raise AssertionError("a decode round took the prefill's routine")
+        raise AssertionError("a decode round off the chip left the dense "
+                             "routine")
 
     attn = _attention_module()
     x = jax.random.normal(jax.random.key(1), (2, 12, 32))
     variables = attn.init(jax.random.key(0), jnp.zeros((2, 24, 32)),
                           decode=True)
     params, cache = variables["params"], variables["cache"]
+    assert cache["cached_key"].shape == (2, 24, 2 * 8)
     monkeypatch.setattr(attention, "_prefill_attention", never)
+    monkeypatch.setattr(attention, "round_attention", never)
     with jax.default_matmul_precision(None):
         text = jax.jit(lambda p, c, x, at: attn.apply(
             {"params": p, "cache": c}, x, decode=True, mutable=["cache"],
             cache_positions=at)).lower(
                 params, cache, x[:, 5:6], jnp.asarray([5, 2])).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == _ONE_TOKEN_TEXT
+    assert hashlib.sha256(text.encode()).hexdigest() == _ONE_TOKEN_TEXT, \
+        hashlib.sha256(text.encode()).hexdigest()
 
 
 _ONE_TOKEN_TEXT = (
-    "b8f227485b2fd75e7c01d8e97672fb8504913d36edac921c3302df0d4ae2cc27")
+    "deaedc903781d676dc1e22a273db631b8ee9319871cf5aa52d1f7d20f15fe17e")
+
+
+# -- a decode round through the kernel, served ------------------------------
+
+# small models of the three families whose decode round changed routine,
+# (registry name, ``extra``). Mistral-shaped: grouped queries over flat
+# rows. LFM2-shaped: heads of 64, two a lane tile, beside convolution
+# state. K-EXAONE-shaped: full layers beside rings.
+_SERVED_SHAPES = {
+    "mistral": ("llama3_8b", dict(
+        num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
+        mlp_dim=128, vocab_size=97, rope_theta=1e6)),
+    "lfm2": ("lfm2_8b_a1b", dict(
+        vocab_size=97, num_layers=4, d_model=256, num_heads=4,
+        num_kv_heads=2, mlp_dim=128, expert_mlp_dim=32, num_experts=4,
+        moe_topk=2, num_dense_layers=1,
+        layer_types=("conv", "full_attention", "conv", "full_attention"))),
+    "k_exaone": ("k_exaone", dict(
+        vocab_size=97, num_layers=4, d_model=64, num_heads=8,
+        num_kv_heads=2, head_dim=16, mlp_dim=96, window=8,
+        expert_mlp_dim=32, num_experts=4, moe_topk=2)),
+}
+
+
+@pytest.mark.parametrize("family", list(_SERVED_SHAPES))
+def test_served_through_the_rounds_kernel_as_generated_alone(
+        monkeypatch, family):
+    """Greedy decode through ``ServingEngine`` with every decode round's
+    attention over rows by position going through the kernel (told it
+    is the routine, interpret mode, key blocks of 16) is token for
+    token the sequential ``generate`` of each prompt alone through the
+    dense routine: seven requests over three slots, so slots retire and
+    are refilled mid-run, a refilled slot's stale rows lie past its new
+    depth, and a slot that is idle for a round reads nothing and its
+    zeros reach nobody."""
+    from pytorch_distributed_nn_tpu.nn import attention
+    from pytorch_distributed_nn_tpu.ops.pallas import prefix_attention as pa
+    from pytorch_distributed_nn_tpu.serve import ServingEngine
+
+    name, extra = _SERVED_SHAPES[family]
+    model = get_model(ModelConfig(name=name, dtype="float32",
+                                  compute_dtype="float32", extra=extra))
+    params = model.init(jax.random.key(2), jnp.zeros((1, 8), jnp.int32),
+                        train=False)["params"]
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 97, size=(n,)).astype(np.int32)
+               for n in (5, 11, 3, 17, 8, 2, 9)]
+    budgets = [7, 3, 9, 5, 12, 4, 6]
+    with jax.default_matmul_precision("highest"):
+        alone = [np.asarray(generate(model, params, p[None], n))[0, len(p):]
+                 for p, n in zip(prompts, budgets)]
+        rounds = []
+        kernel = pa.round_attention
+
+        def counted(*a, **kw):
+            rounds.append(a[0].shape)
+            return kernel(*a, **kw, interpret=True)
+
+        monkeypatch.setattr(attention, "round_key_block",
+                            lambda S, heads, d, dtype: 16 if S % 16 == 0 else 0)
+        monkeypatch.setattr(attention, "round_attention", counted)
+        jax.clear_caches()      # the programs traced with the dense routine
+        try:
+            engine = ServingEngine(model, params, max_slots=3,
+                                   max_seq_len=64, block_size=8,
+                                   max_queue=16, max_prefills_per_round=2)
+            reqs = [engine.submit(p, n) for p, n in zip(prompts, budgets)]
+            engine.run_until_idle()
+        finally:
+            jax.clear_caches()  # nor these kept for the next test
+    assert rounds and all(shape[0] == 3 for shape in rounds)
+    for want, r in zip(alone, reqs):
+        assert r.state == "done"
+        np.testing.assert_array_equal(np.asarray(r.tokens), want)

@@ -14,6 +14,7 @@ test ties the reference's operators to the published code
 (``transformers``' ``Lfm2ForCausalLM``, the dense sibling).
 """
 
+import functools
 import importlib
 import sys
 from pathlib import Path
@@ -435,52 +436,63 @@ def test_the_kernel_at_heads_of_64_is_the_recurrence():
     assert (np.asarray(got[:, :, 200:], np.float32) == 0).all()
 
 
-def test_heads_of_64_lie_two_a_cache_row_and_decode_as_one_a_row(
-        monkeypatch):
-    """A decode cache of 64-wide K/V heads holds two of them side by
-    side in a row of one lane tile, ``(B, S, Hkv / 2, 128)``: the same
-    bytes in the same order as ``(B, S, Hkv, 64)``, so a prefill and
-    three decode rounds at rows of different depths give what the
-    cache of a head a row gives (``lane_pack`` made to say 1), the
-    cached rows equal bit for bit; heads of 128 and an odd number of
-    heads of 64 keep a head a row."""
+@pytest.mark.parametrize("routine", ["dense", "kernel"])
+def test_heads_of_64_lie_two_a_lane_tile_and_decode_as_one_a_row(
+        monkeypatch, routine):
+    """A decode cache of 64-wide K/V heads holds a position's heads side
+    by side in one flat row, ``(B, S, Hkv * 64)``, two of them a lane
+    tile: the same bytes in the same order as ``(B, S, Hkv, 64)``. A
+    prefill (8 and 5 real tokens of a bucket of 8) and three decode
+    rounds at rows of different depths, the rounds through the dense
+    routine over the row by head (the CPU's) or through the round's
+    kernel, which reads two heads as one of 128 with each query in its
+    own head's lanes (told it is the routine, in interpret mode), give
+    each row what the uncached causal forward of its own sequence
+    gives; what a row has not been fed is not written. Heads of 128 and
+    an odd number of heads of 64 are not packed."""
     assert attention.lane_pack(64, 8) == 2
     assert attention.lane_pack(128, 8) == attention.lane_pack(64, 3) == 1
     attn = attention.MultiHeadAttention(
         num_heads=8, head_dim=64, num_kv_heads=4, causal=True, rotary=True,
         use_bias=False, qk_norm=True)
-    x = jax.random.normal(jax.random.key(0), (2, 12, 96))
+    x = jax.random.normal(jax.random.key(0), (2, 11, 96))
     params = attn.init(jax.random.key(1), x)["params"]
-
-    def run():
-        cache = attn.init(jax.random.key(1), jnp.zeros((2, 32, 96)),
-                          decode=True)["cache"]
-        outs = []
-        starts = jnp.asarray([0, 0])
-        for fed, lengths in ((x[:, :8], jnp.asarray([8, 5])),
-                             (x[:, 8:9], None), (x[:, 9:10], None),
-                             (x[:, 10:11], None)):
-            out, mutated = attn.apply(
-                {"params": params, "cache": cache}, fed, decode=True,
-                cache_positions=starts, lengths=lengths, mutable=["cache"])
-            cache = mutated["cache"]
-            starts = starts + (jnp.asarray([8, 5]) if lengths is not None
-                               else 1)
-            outs.append(out)
-        return outs, cache
-
-    packed, cache = run()
-    assert cache["cached_key"].shape == (2, 32, 2, 128)
-    monkeypatch.setattr(attention, "lane_pack", lambda d, h: 1)
-    plain, wide = run()
-    assert wide["cached_key"].shape == (2, 32, 4, 64)
+    # the second row's sequence leaves out its prefill's padding
+    own = [x[0], jnp.concatenate([x[1, :5], x[1, 8:]])]
+    want = [attn.apply({"params": params}, seq[None])[0] for seq in own]
+    asked = []
+    if routine == "kernel":
+        monkeypatch.setattr(
+            attention, "round_key_block",
+            lambda S, heads, d, dtype: asked.append((S, d)) or 16)
+        monkeypatch.setattr(
+            attention, "round_attention",
+            functools.partial(pa.round_attention, interpret=True))
+    cache = attn.init(jax.random.key(1), jnp.zeros((2, 32, 96)),
+                      decode=True)["cache"]
+    assert cache["cached_key"].shape == (2, 32, 4 * 64)
+    depth = np.asarray([0, 0])
+    for fed, real in ((x[:, :8], np.asarray([8, 5])),
+                      (x[:, 8:9], None), (x[:, 9:10], None),
+                      (x[:, 10:11], None)):
+        out, mutated = attn.apply(
+            {"params": params, "cache": cache}, fed, decode=True,
+            cache_positions=jnp.asarray(depth), mutable=["cache"],
+            lengths=None if real is None else jnp.asarray(real))
+        cache = mutated["cache"]
+        for b in range(2):
+            n = 1 if real is None else real[b]
+            gap = out[b, :n] - want[b][depth[b]:depth[b] + n]
+            assert np.abs(np.asarray(gap)).max() < 2e-5
+            assert np.abs(np.asarray(out[b, :n])).max() > 0.05
+        depth = depth + (1 if real is None else real)
+    assert list(depth) == [11, 8]
     for leaf in ("cached_key", "cached_value"):
-        assert np.array_equal(np.asarray(cache[leaf]).reshape(2, 32, 4, 64),
-                              np.asarray(wide[leaf]))
-    assert np.abs(np.asarray(packed[0][1, :5] - plain[0][1, :5])).max() < 1e-6
-    for a, b in zip(packed[1:], plain[1:]):
-        assert np.abs(np.asarray(a - b)).max() < 1e-6
-        assert np.abs(np.asarray(a)).max() > 0.1
+        rows = np.abs(np.asarray(cache[leaf])).max(axis=-1) > 0
+        # (the second row's prefill wrote its bucket's 8, padding too)
+        assert rows[0].tolist() == [True] * 11 + [False] * 21
+        assert rows[1].tolist() == [True] * 8 + [False] * 24
+    assert asked == ([(32, 128)] * 3 if routine == "kernel" else [])
 
 
 def test_a_convolution_without_bias_has_no_such_leaf():

@@ -217,6 +217,19 @@ def test_a_round_reads_the_key_blocks_up_to_what_a_row_sees():
     assert int(pa.round_rows_read(seen, real, 64, 64)) == 24 * 64
 
 
+def _through_the_kernel(monkeypatch, block_k=16):
+    """``nn/attention`` told that the kernel is the routine, in key
+    blocks of ``block_k`` and in interpret mode; returns the (S, d) it
+    was asked about."""
+    asked = []
+    monkeypatch.setattr(
+        attention, "round_key_block",
+        lambda S, heads, d, dtype: asked.append((S, d)) or block_k)
+    monkeypatch.setattr(attention, "round_attention", functools.partial(
+        pa.round_attention, interpret=True))
+    return asked
+
+
 def test_a_block_round_takes_the_kernel_on_a_tpu_in_bf16(monkeypatch):
     """``nn/attention._round_attention`` asks the backend, the dtype and
     the tiles (``round_key_block``): on the CPU the dense routine; told
@@ -224,27 +237,141 @@ def test_a_block_round_takes_the_kernel_on_a_tpu_in_bf16(monkeypatch):
     mode, in key blocks of 16) and agree with the dense routine to
     bf16's rounding."""
     q, k, v, seen, lengths = _round_operands(jnp.bfloat16, 21)
-    q = jnp.repeat(q, 2, axis=2)      # 32 query rows a K/V head: whole
-    k, v = jnp.nan_to_num(k), jnp.nan_to_num(v)     # bf16 registers
-    assert pa.round_key_block(32, 64, 128, jnp.bfloat16) == 0
+    q = jnp.repeat(q, 2, axis=2)      # 32 query rows a K/V head
+    k, v = jnp.nan_to_num(k), jnp.nan_to_num(v)
+    assert pa.round_key_block(64, 2, 128, jnp.bfloat16) == 0
     dense = attention._round_attention(q, k, v, seen, lengths, q.dtype)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(pa, "ROUND_KEY_BLOCKS", (16,))
-    assert pa.round_key_block(32, 64, 128, jnp.bfloat16) == 16
-    assert pa.round_key_block(32, 64, 128, jnp.float32) == 0   # not bf16
-    assert pa.round_key_block(24, 64, 128, jnp.bfloat16) == 0  # registers
-    assert pa.round_key_block(32, 64, 16, jnp.bfloat16) == 0   # lane tiles
-    assert pa.round_key_block(32, 72, 128, jnp.bfloat16) == 0  # key blocks
-    asked = []
-    monkeypatch.setattr(attention, "round_key_block",
-                        lambda R, S, d, dtype: asked.append((R, S)) or 16)
-    monkeypatch.setattr(attention, "round_attention", functools.partial(
-        pa.round_attention, interpret=True))
+    assert pa.round_key_block(64, 2, 128, jnp.bfloat16) == 16
+    assert pa.round_key_block(64, 2, 128, jnp.float32) == 0   # not bf16
+    assert pa.round_key_block(64, 2, 64, jnp.bfloat16) == 0   # lane tiles
+    assert pa.round_key_block(72, 2, 128, jnp.bfloat16) == 0  # key blocks
+    # one K/V head a position: the longer block first, where it divides
+    monkeypatch.setattr(pa, "ROUND_KEY_BLOCKS_ONE_HEAD", (32, 16))
+    assert pa.round_key_block(64, 1, 128, jnp.bfloat16) == 32
+    assert pa.round_key_block(48, 1, 128, jnp.bfloat16) == 16
+    asked = _through_the_kernel(monkeypatch)
     got = attention._round_attention(q, k, v, seen, lengths, q.dtype)
-    assert asked == [(32, 64)] and got.dtype == jnp.bfloat16
+    assert asked == [(64, 16)] and got.dtype == jnp.bfloat16
     real = jnp.arange(8)[None] < lengths[:, None]
     gap = jnp.abs(got.astype(jnp.float32) - dense.astype(jnp.float32))
     assert float(jnp.where(real[..., None, None], gap, 0).max()) < 2e-2
+
+
+# a round of one position a row, (query heads, K/V heads, head width):
+# Mistral's and LFM2's four query heads a K/V head, K-EXAONE's eight,
+# Jamba's twenty to one; heads of 128, and heads of 64 that lie two a
+# lane tile and are read as one head of 128
+_ONE_POSITION = {"g4_d128": (8, 2, 128), "g8_d128": (16, 2, 128),
+                 "g20_d128": (20, 1, 128), "g4_d64_packed": (16, 4, 64),
+                 "g2_d64_packed": (4, 2, 64)}
+# each slot's depth in a cache of 64 rows: ragged, the last row, the
+# first, and (negative) a slot that is not live
+_DEPTHS = (20, 36, -1, 63, 0, 17)
+
+
+def _one_position_operands(shape, dtype, key=30):
+    """``(q (B, 1, H, D), k, v (B, S, Hkv * D) with NaN past what a slot
+    has filled, seen (B, 1), lengths (B,))``."""
+    heads, kv, d = _ONE_POSITION[shape]
+    B, S = len(_DEPTHS), 64
+    ks = jax.random.split(jax.random.key(key), 3)
+    depth = jnp.asarray(_DEPTHS)
+    lengths = (depth >= 0).astype(jnp.int32)
+    seen = jnp.maximum(depth, 0)[:, None]
+    rows = (jnp.arange(S)[None, :] <= depth[:, None])[..., None]
+    k, v = (jnp.where(rows, jax.random.normal(kk, (B, S, kv * d)), jnp.nan)
+            for kk in ks[1:])
+    q = jax.random.normal(ks[0], (B, 1, heads, d))
+    return tuple(x.astype(dtype) for x in (q, k, v)) + (seen, lengths)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("shape", list(_ONE_POSITION))
+def test_a_round_of_one_position_a_row_is_the_dense_routine(
+        monkeypatch, shape, dtype, tol):
+    """One fed position a row through ``_round_attention``'s kernel
+    (interpret mode, key blocks of 16): the few query rows a K/V head
+    are padded to a register's with rows that see nothing, heads of 64
+    are laid into their own half of a lane tile, and every live row, at
+    depth 0 and at the cache's last row too, gets what the dense routine
+    over its rows by head gives; the rows past a slot's depth (NaN) are
+    not read, and a slot that is not live gets zeros."""
+    q, k, v, seen, lengths = _one_position_operands(shape, dtype)
+    heads, kv, d = _ONE_POSITION[shape]
+    B, S = k.shape[:2]
+    by_head = lambda x: jnp.nan_to_num(x).reshape(B, S, kv, d)  # noqa: E731
+    want = attention._cache_attention(
+        q, by_head(k), by_head(v),
+        jnp.arange(S)[None, None, :] <= seen[:, :, None], dtype)
+    asked = _through_the_kernel(monkeypatch)
+    got = attention._round_attention(q, k, v, seen, lengths, dtype)
+    assert asked == [(S, 128)]
+    assert got.shape == q.shape and got.dtype == dtype
+    assert bool(jnp.isfinite(got).all())
+    live = (lengths > 0)[:, None, None, None]
+    gap = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
+    assert float(jnp.where(live, gap, 0).max()) < tol
+    assert float(jnp.abs(jnp.where(live, want, 0)).max()) > 0.5
+    assert float(jnp.where(live, 0, jnp.abs(got)).max()) == 0
+
+
+def test_a_shared_index_round_sees_one_depth_in_every_row(monkeypatch):
+    """``inference/generate``'s shared-index decode hands the routine
+    ``seen`` (1, T) and no ``lengths``: every row at that depth."""
+    q, k, v, _, _ = _one_position_operands("g4_d128", jnp.float32, 31)
+    k, v = jnp.nan_to_num(k), jnp.nan_to_num(v)
+    seen = jnp.asarray([[41]])
+    dense = attention._round_attention(q, k, v, seen, None, q.dtype)
+    _through_the_kernel(monkeypatch)
+    got = attention._round_attention(q, k, v, seen, None, q.dtype)
+    assert float(jnp.abs(got - dense).max()) < 2e-6
+
+
+@pytest.mark.parametrize("shape", ["g4_d128", "g20_d128", "g4_d64_packed"])
+def test_a_round_is_counted_as_the_routine_reads(monkeypatch, shape):
+    """``cache_rows_read``, the counter behind ``attn_rows_read_total``:
+    for one position a row it is ``round_rows_read`` (a live row's key
+    blocks up to its depth, none for a slot that is not live) when the
+    kernel is the routine, and every live row's whole length when the
+    dense routine is: off a TPU, off bf16, off whole key blocks, or for
+    a few tokens a row that are neither a round nor worth tiling."""
+    heads, kv, d = _ONE_POSITION[shape]
+    attn = attention.MultiHeadAttention(
+        num_heads=heads, head_dim=d, num_kv_heads=kv, causal=True,
+        use_bias=False, dtype=jnp.bfloat16)
+    S = 256
+    bound = attn.bind(jax.eval_shape(
+        lambda: attn.init(jax.random.key(0), jnp.zeros((6, S, 32)),
+                          decode=True)))
+    assert bound.get_variable("cache", "cached_key").shape == (6, S, kv * d)
+    depth = jnp.asarray([20, 136, 5, 255, 0, 130])
+    real = jnp.asarray([1, 1, 0, 1, 1, 1], bool)[:, None]
+    seen = depth[:, None]
+    assert int(attention.cache_rows_read(bound, 1, seen, real)) == 5 * S
+    def blocks(*rows):
+        monkeypatch.setattr(pa, "ROUND_KEY_BLOCKS", rows)
+        monkeypatch.setattr(pa, "ROUND_KEY_BLOCKS_ONE_HEAD", rows)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    blocks(128)
+    want = int(pa.round_rows_read(seen, real, S, 128))
+    assert want == 128 + 256 + 0 + 256 + 128 + 256
+    assert int(attention.cache_rows_read(bound, 1, seen, real)) == want
+    # three tokens a row: not a round
+    assert int(attention.cache_rows_read(
+        bound, 3, seen + jnp.arange(3), jnp.broadcast_to(real, (6, 3)))) \
+        == 15 * S
+    blocks(96)      # not whole blocks
+    assert int(attention.cache_rows_read(bound, 1, seen, real)) == 5 * S
+    blocks(128)
+    f32 = attn.clone(dtype=jnp.float32)
+    f32 = f32.bind(jax.eval_shape(
+        lambda: f32.init(jax.random.key(0), jnp.zeros((6, S, 32)),
+                         decode=True)))
+    assert int(attention.cache_rows_read(f32, 1, seen, real)) == 5 * S
 
 
 def _latent_operands(B, T, S, dn=16, dr=8, dv=16, r=16, key=6):
